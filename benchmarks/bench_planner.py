@@ -14,7 +14,9 @@ across strategies — the no-divergence acceptance bar).
 Each (strategy, rep) runs in a fresh subprocess so XLA compiles are
 paid honestly, the FleetPlan is computed in-process, and the telemetry
 trace (``build_trace.jsonl``) supplies the actual compile count the
-plan's prediction is checked against.
+plan's prediction is checked against. This parent imports no JAX and
+pins every child to the CPU platform, one child at a time: the bench
+counts compiles and never takes a chip.
 
 Writes ``BENCH_PLAN.json`` at the repo root (the committed bench
 convention). Run: ``JAX_PLATFORMS=cpu python benchmarks/bench_planner.py``

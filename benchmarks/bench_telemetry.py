@@ -4,7 +4,7 @@ telemetry off vs on, so overhead regressions in the span recorder /
 heartbeat path show up in the bench trajectory.
 
 Writes ``BENCH_TELEMETRY.json`` at the repo root (the committed bench
-convention — BASELINE.json, MULTICHIP_r*.json). The acceptance bar for
+convention, like BASELINE.json). The acceptance bar for
 the observability layer is telemetry-on within 3% of telemetry-off
 wall-clock; the recorder's per-span cost is a few microseconds and the
 heartbeat a few hundred bytes per machine, so the realized overhead on

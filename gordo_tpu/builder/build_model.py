@@ -41,6 +41,7 @@ from ..machine.metadata import (
 )
 from ..models.base import GordoBase
 from ..models.utils import metric_wrapper
+from ..telemetry import device_identity
 from ..utils import disk_registry
 
 logger = logging.getLogger(__name__)
@@ -233,6 +234,7 @@ class ModelBuilder:
                 ),
                 model_meta=self._extract_metadata_from_model(model),
                 training=self._extract_training_summary(model),
+                device=device_identity() or {},
             ),
             dataset=DatasetBuildMetadata(
                 query_duration_sec=time_elapsed_data,

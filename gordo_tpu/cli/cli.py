@@ -71,18 +71,6 @@ logger = logging.getLogger(__name__)
     help="Run with custom log-level.",
     envvar="GORDO_LOG_LEVEL",
 )
-@click.option(
-    "--jax-platform",
-    type=str,
-    default=None,
-    help=(
-        "Force the JAX platform (e.g. 'cpu', 'tpu'). TPU plugins may "
-        "override JAX_PLATFORMS through jax.config, so this sets the config "
-        "value directly — the escape hatch when a builder pod must run "
-        "CPU-only or a TPU runtime is unreachable."
-    ),
-    envvar="GORDO_TPU_PLATFORM",
-)
 @click.pass_context
 def gordo_tpu_cli(gordo_ctx: click.Context, **ctx):
     """The gordo-tpu command line interface."""
@@ -93,11 +81,6 @@ def gordo_tpu_cli(gordo_ctx: click.Context, **ctx):
             "[%(name)s.%(funcName)s:%(lineno)d] %(message)s"
         ),
     )
-    platform = gordo_ctx.params.get("jax_platform")
-    if platform:
-        import jax
-
-        jax.config.update("jax_platforms", platform)
     gordo_ctx.obj = gordo_ctx.params
 
 
@@ -162,6 +145,9 @@ def build(
             project_name=machine_config["project_name"],
         )
 
+        from ..parallel.mesh import announce_device
+
+        announce_device("build")
         logger.info("Building, output will be at: %s", output_dir)
         logger.info("Register dir: %s", model_register_dir)
 
@@ -264,8 +250,12 @@ def get_all_score_strings(machine) -> List[str]:
 @click.option(
     "--workers",
     type=click.IntRange(1, 4),
-    help="The number of worker processes for handling requests.",
-    default=2,
+    help="The number of worker processes for handling requests. Every "
+    "worker is a process that initialises the accelerator, and a chip "
+    "belongs to one process at a time: leave this at 1 unless each worker "
+    "is given a chip of its own. Threads (--threads) share the one "
+    "process's device.",
+    default=1,
     envvar="GORDO_SERVER_WORKERS",
     show_default=True,
 )
@@ -634,7 +624,12 @@ def build_fleet(
     import os
 
     try:
+        # after the distributed handshake: announcing initialises the
+        # backend, and jax.distributed must be up before that
         _maybe_init_distributed()
+        from ..parallel.mesh import announce_device
+
+        announce_device("build-fleet")
 
         # ConfigMap dicts from `workflow generate` are fully resolved; a
         # hand-written document may instead carry project_name at the top
@@ -694,10 +689,12 @@ def build_fleet(
             for _, machine_out in results:
                 machine_out.report()
         logger.info(
-            "Fleet build complete: %d built, %d resumed (skipped), %d failed",
+            "Fleet build complete: %d built, %d resumed (skipped), %d failed; "
+            "contained device faults: %s",
             len(results),
             len(builder.resumed),
             len(builder.build_errors),
+            {k: v for k, v in builder.robustness.items() if v} or "none",
         )
         if builder.build_errors:
             # failFast:false — successes are saved/reported above; exit with

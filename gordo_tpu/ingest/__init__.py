@@ -8,9 +8,10 @@ and stacks a spec bucket's plans into device-resident
 ``[members, features]`` arrays, so preprocessing runs as a fused
 prologue inside the gather program instead of as per-request host numpy.
 :mod:`gordo_tpu.ingest.transfer` carries decoded wire columns
-(:class:`~gordo_tpu.ingest.transfer.RawColumns`) to the device over
-dlpack without the legacy ``column_stack`` staging copy, falling back to
-the host path whenever the columns or backend refuse.
+(:class:`~gordo_tpu.ingest.transfer.RawColumns`) to the serving device
+over dlpack without the legacy ``column_stack`` staging copy; columns
+dlpack cannot export take the host staging rung, chosen by inspecting
+them.
 
 Layering: this package sits beside ``planner``/``parallel`` — it may be
 imported by ``server``/``serve``/``stream`` but never imports them (the
@@ -28,8 +29,6 @@ Both halves are independently switchable:
   so the per-column device dispatch is pure overhead and host staging
   IS the fast rung.
 """
-
-from typing import Optional
 
 from gordo_tpu.ingest.plan import (  # noqa: F401
     FleetIngestPlan,
@@ -55,29 +54,14 @@ def compiled_enabled() -> bool:
     return env_bool(INGEST_COMPILED_ENV, True)
 
 
-#: cached once per process — the default backend cannot change after
-#: the first device op, so one probe answers every request
-_ACCELERATOR: Optional[bool] = None
-
-
-def _accelerator_backend() -> bool:
-    global _ACCELERATOR
-    if _ACCELERATOR is None:
-        try:
-            import jax
-
-            _ACCELERATOR = jax.default_backend() != "cpu"
-        except Exception:  # noqa: BLE001 - no backend = host staging
-            _ACCELERATOR = False
-    return _ACCELERATOR
-
-
 def dlpack_enabled() -> bool:
     """Whether serving's device transfer should try the per-column
-    dlpack rung before the host staging fallback: the env knob is the
+    dlpack rung before the host staging rung: the env knob is the
     operator kill-switch, and on the CPU backend the rung never engages
-    (both rungs stage through host memory there — per-column device
-    dispatch is measurably pure overhead, ~10x on the ingest bench).
-    Explicit ``to_device(..., dlpack=True)`` callers still get the rung
-    on any backend."""
-    return env_bool(INGEST_DLPACK_ENV, True) and _accelerator_backend()
+    (both rungs stage through host memory there, so the per-column
+    device dispatch is pure overhead). Explicit
+    ``to_device(..., dlpack=True)`` callers still get the rung on any
+    backend."""
+    import jax
+
+    return env_bool(INGEST_DLPACK_ENV, True) and jax.default_backend() != "cpu"

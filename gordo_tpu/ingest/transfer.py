@@ -6,23 +6,22 @@ the Arrow wire columns — a full host copy of the payload — and only then
 hands the matrix to the device program, which copies it AGAIN across the
 transfer boundary. :class:`RawColumns` instead carries the decoded wire
 columns as-is (zero-copy views straight out of the Arrow buffers) and
-:func:`to_device` moves them per-column over the dlpack protocol, so the
-first full-matrix materialization happens device-side inside the fused
-program's ``stack``. On backends whose dlpack import aliases host
-memory (TPU DMA path) that removes the staging copy entirely; the CPU
-backend copies on import, so the win there is skipping ``column_stack``
-— either way no intermediate host matrix is built.
+:func:`to_device` moves them per-column over the dlpack protocol onto
+the serving device, so the first full-matrix materialization happens
+device-side inside ``stack``.
 
-The fallback ladder is deliberately boring: ANY dlpack failure
-(non-contiguous column, unsupported dtype, backend refusal) drops the
-whole request to the host path — ``host_matrix()`` + ``jnp.asarray`` —
-which is the exact legacy staging behaviour, so parity is structural.
-Outcomes are counted module-wide (:func:`ingest_stats`) so benches and
+Which rung a request takes is decided by LOOKING at its columns, never
+by catching an exception: dlpack cannot export a read-only or
+non-contiguous buffer (numpy refuses both), so such columns — float32
+columns decoded zero-copy out of an Arrow body are read-only — take the
+host rung, ``host_matrix()`` + one transfer, with the reason counted. A
+refusal from the transfer itself is a fault and propagates. Outcomes are
+counted module-wide (:func:`ingest_stats`) so benches and
 ``/fleet-health`` can see which rung actually served traffic.
 """
 
 import threading
-from typing import Any, List, Optional, Sequence
+from typing import Any, Optional, Sequence
 
 import numpy as np
 
@@ -131,23 +130,40 @@ class RawColumns:
         return int(self.matrix.nbytes)
 
 
-def _dlpack_column(col: np.ndarray) -> Any:
-    """One wire column onto the device via dlpack, as float32. Raises on
-    anything the protocol can't take (caller falls back)."""
+def serving_device() -> Any:
+    """The device the serving programs run on: the default device,
+    where the fleet store's uncommitted bucket params live."""
     import jax
-    import jax.numpy as jnp
 
-    arr = np.asarray(col)
-    if arr.dtype != np.float32:
+    return jax.devices()[0]
+
+
+def _dlpack_refusal(columns: Sequence[np.ndarray]) -> str:
+    """Why ``columns`` cannot cross over dlpack, or ``""`` when they
+    can. float32 columns cross as they are and must be exportable;
+    anything else is cast first, and the cast's copy always is."""
+    for col in columns:
+        if col.dtype != np.float32:
+            continue
+        if not col.flags["C_CONTIGUOUS"]:
+            return "non_contiguous_column"
+        if not col.flags["WRITEABLE"]:
+            return "readonly_column"
+    return ""
+
+
+def _dlpack_column(col: np.ndarray, device: Any) -> Any:
+    """One wire column onto ``device`` via dlpack, as float32."""
+    import jax
+
+    if col.dtype != np.float32:
         # dlpack moves bytes, not values: cast (a copy) first. Arrow f64
         # wires land here; the compiled path computes f32 regardless.
-        arr = np.ascontiguousarray(arr, np.float32)
-    elif not arr.flags["C_CONTIGUOUS"]:
-        raise ValueError("non-contiguous wire column")
-    out = jax.dlpack.from_dlpack(arr)
-    if out.dtype != jnp.float32:  # pragma: no cover - cast path above
-        out = out.astype(jnp.float32)
-    return out
+        col = np.ascontiguousarray(col, np.float32)
+    # without ``device`` the import stays committed to the CPU backend
+    # the numpy buffer lives on, and every program fed from it follows
+    # it there
+    return jax.dlpack.from_dlpack(col, device=device)
 
 
 def to_device(
@@ -156,38 +172,41 @@ def to_device(
     dlpack: bool = True,
 ) -> Any:
     """``raw`` as a ``[rows, width]`` (or ``[padded_rows, width]``)
-    float32 device array.
+    float32 array on :func:`serving_device`.
 
     Fast rung: each wire column crosses via dlpack and the matrix is
     first assembled device-side (``jnp.stack(axis=1)``); row padding, if
-    any, happens on device too. Fallback rung (``dlpack=False``, a
-    padding-incompatible shape, or any dlpack refusal): the legacy host
-    staging — ``host_matrix()`` zero-padded on host, one ``jnp.asarray``
-    transfer. Both rungs return the same values; only the copy count
+    any, happens on device too. Host rung (``dlpack=False``, a
+    matrix-mode payload, or columns dlpack cannot export — see
+    :func:`_dlpack_refusal`): the legacy host staging —
+    ``host_matrix()`` zero-padded on host, one transfer. Both rungs
+    return the same values on the same device; only the copy count
     differs.
     """
+    import jax
     import jax.numpy as jnp
 
+    device = serving_device()
     rows = raw.rows
     target = padded_rows if padded_rows is not None else rows
-    if dlpack and raw.columns is not None and raw.width > 0 and rows > 0:
-        try:
-            device_cols: List[Any] = [
-                _dlpack_column(col) for col in raw.columns
-            ]
-            X = jnp.stack(device_cols, axis=1)
-            if target != rows:
-                X = jnp.zeros((target, raw.width), jnp.float32).at[:rows].set(X)
-            _note_transfer(True, columns=raw.width)
-            return X
-        except Exception as exc:  # noqa: BLE001 - any refusal = host rung
-            _note_transfer(False, reason=type(exc).__name__)
+    if not dlpack:
+        reason = "disabled"
+    elif raw.columns is None or raw.width == 0 or rows == 0:
+        reason = "no_columns"
     else:
-        reason = "disabled" if not dlpack else "no_columns"
-        _note_transfer(False, reason=reason)
+        reason = _dlpack_refusal(raw.columns)
+    if not reason:
+        X = jnp.stack(
+            [_dlpack_column(col, device) for col in raw.columns], axis=1
+        )
+        if target != rows:
+            X = jnp.zeros((target, raw.width), jnp.float32).at[:rows].set(X)
+        _note_transfer(True, columns=raw.width)
+        return X
+    _note_transfer(False, reason=reason)
     host = raw.host_matrix()
     if target != rows:
         padded = np.zeros((target, raw.width), np.float32)
         padded[:rows] = host
         host = padded
-    return jnp.asarray(host)
+    return jax.device_put(host, device)

@@ -78,6 +78,10 @@ class ModelBuildMetadata:
     training: TrainingSummaryMetadata = field(
         default_factory=TrainingSummaryMetadata
     )
+    #: where the model was trained, as JAX reported it: ``platform``,
+    #: ``device_kind`` and device ``count``
+    #: (``telemetry.device_identity``)
+    device: Dict[str, Any] = field(default_factory=dict)
 
 
 @dataclass_json
@@ -195,6 +199,7 @@ def _metadata_to_dict(self: Metadata, **_kwargs) -> Dict[str, Any]:
                     "epochs_configured": training.epochs_configured,
                     "early_stop_epoch": training.early_stop_epoch,
                 },
+                "device": dict(model.device),
             },
             "dataset": {
                 "query_duration_sec": dataset.query_duration_sec,

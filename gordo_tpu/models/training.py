@@ -665,9 +665,9 @@ def fit_single_segmented(
             params, opt_state, series, targets, wtr, wval, rng
         )
         # one coalesced d2h readback — per-element float() would pay the
-        # fixed per-transfer latency once PER EPOCH on tunneled
-        # accelerators. Inside the span: the readback waits on the
-        # program, so the span times real device work, not dispatch.
+        # fixed per-transfer latency once PER EPOCH. Inside the span:
+        # the readback waits on the program, so the span times real
+        # device work, not dispatch.
         losses, val_losses, epochs_ran = jax.device_get(
             (losses, val_losses, epochs_ran)
         )
@@ -753,9 +753,9 @@ def fit_single(
             params, opt_state, Xtr, ytr, wtr, Xval, yval, wval, rng
         )
         # one coalesced d2h readback — per-element float() would pay the
-        # fixed per-transfer latency once PER EPOCH on tunneled
-        # accelerators. Inside the span: the readback waits on the
-        # program, so the span times real device work, not dispatch.
+        # fixed per-transfer latency once PER EPOCH. Inside the span:
+        # the readback waits on the program, so the span times real
+        # device work, not dispatch.
         losses, val_losses, epochs_ran = jax.device_get(
             (losses, val_losses, epochs_ran)
         )

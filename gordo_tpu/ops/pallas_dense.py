@@ -16,9 +16,10 @@ The layer walk is unrolled at trace time from the spec (static), so the
 kernel is recompiled per architecture — exactly like the XLA path, which
 is cached per (spec, shape) too.
 
-CPU tests run with ``interpret=True`` (no TPU needed); numerical parity
-with :func:`gordo_tpu.models.nn.forward_feedforward` is asserted in
-tests/ops/test_pallas_dense.py.
+CPU tests run the kernel through the Pallas interpreter (the
+``interpret`` argument — test-only, no serving path passes it); numerical parity with
+:func:`gordo_tpu.models.nn.forward_feedforward` is asserted in
+tests/ops/test_pallas_dense.py, and on the chip by ``chip_smoke.py``.
 """
 
 from functools import partial
@@ -27,18 +28,42 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable on CPU-only installs too, but guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..models.spec import FeedForwardSpec
 from .activations import resolve_activation
 
 Params = Dict[str, Dict[str, jnp.ndarray]]
+
+# jax.nn.elu / jax.nn.selu are written with expm1, which Mosaic does not
+# lower ("Unimplemented primitive in Pallas TPU lowering"). In the kernel
+# they are spelled with exp instead: exp(x) - 1 differs from expm1(x) by
+# at most one float32 rounding of a value near 1 (~6e-8 absolute), far
+# inside the kernel's tolerance against the XLA forward. min(x, 0) keeps
+# exp from overflowing in the branch ``where`` discards.
+_SELU_ALPHA = 1.6732632423543772848170429916717
+_SELU_SCALE = 1.0507009873554804934193349852946
+
+
+def _elu(x):
+    return jnp.where(x > 0, x, jnp.exp(jnp.minimum(x, 0.0)) - 1.0)
+
+
+def _selu(x):
+    return _SELU_SCALE * jnp.where(
+        x > 0, x, _SELU_ALPHA * (jnp.exp(jnp.minimum(x, 0.0)) - 1.0)
+    )
+
+
+_KERNEL_ACTIVATIONS = {"elu": _elu, "selu": _selu}
+
+
+def kernel_activation(name: str):
+    """The activation as the kernel computes it: the ``ops.activations``
+    function wherever Mosaic lowers it, an expm1-free spelling where it
+    does not. tests/ops/test_pallas_dense.py lowers every name for the
+    TPU on the CPU host."""
+    return _KERNEL_ACTIVATIONS.get(name) or resolve_activation(name)
 
 
 def _layer_names(spec: FeedForwardSpec) -> List[Tuple[str, str]]:
@@ -99,11 +124,16 @@ def fleet_feedforward_pallas(
         for li, (_, act_name) in enumerate(names):
             w = param_refs[2 * li][0]  # [d_in, d_out]
             b = param_refs[2 * li + 1][0, 0]  # [d_out]
+            # the chip's default matmul precision: float32 operands are
+            # rounded to bf16 for the MXU and preferred_element_type sets
+            # only the accumulator — on a v5e the kernel and the XLA
+            # default-precision forward sit at the same distance from a
+            # "highest"-precision reference (PERF.md, PR 22)
             h = jnp.dot(h, w, preferred_element_type=jnp.float32) + b
-            h = resolve_activation(act_name)(h)
+            h = kernel_activation(act_name)(h)
         out_ref[0] = h
 
-    mem = {} if _VMEM is None else {"memory_space": _VMEM}
+    mem = {"memory_space": pltpu.VMEM}
     in_specs = [pl.BlockSpec((1, block_b, F), lambda m, bi: (m, bi, 0), **mem)]
     for key, _ in names:
         w = stacked_params[key]["W"]

@@ -20,7 +20,6 @@ ICI/DCN without change.
 import logging
 import os
 
-from ..utils.env import env_str
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -32,51 +31,76 @@ logger = logging.getLogger(__name__)
 MODEL_AXIS = "models"
 DATA_AXIS = "data"
 
-#: directory for JAX's persistent compilation cache — repeated fleet
-#: builds and server restarts reuse compiled programs instead of paying
-#: the XLA compile again (the FleetPlan's compile-count predictions
-#: count *cold* compiles; a warm cache turns them into disk loads)
-COMPILE_CACHE_ENV = "GORDO_TPU_COMPILE_CACHE"
+#: JAX's own variable for the persistent compilation cache directory.
+#: Where it is set JAX reads it itself and this package sets no
+#: directory in code, so whoever runs the program decides where compiled
+#: programs persist.
+JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-_compile_cache_configured = False
+
+def default_compile_cache_dir() -> str:
+    """``<checkout>/.jax_cache``, computed from the package location: the
+    directory is part of a cache entry's key, so it must be the same
+    path in every process and every run."""
+    package_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(package_root), ".jax_cache")
 
 
 def configure_compile_cache() -> Optional[str]:
     """
-    Point JAX's persistent compilation cache at ``$GORDO_TPU_COMPILE_CACHE``
-    (no-op when unset). Idempotent — called from every mesh/backend init
-    path so any entrypoint (build, plan, serve) gets the same cache.
+    The one place the persistent compilation cache is configured — every
+    entry point that compiles (``make_mesh``, the ``build`` command,
+    ``server.build_app``, ``bench.py``, ``chip_smoke.py``) calls it
+    before its first program. Returns the directory in use, or None.
 
-    The min-compile-time threshold is zeroed: fleet programs are many
-    small autoencoders, and JAX's 1s default would skip exactly the
-    programs a heterogeneous fleet recompiles most often.
+    - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself;
+      no directory is set in code.
+    - unset, on an accelerator: ``<checkout>/.jax_cache``.
+    - unset, on the ``cpu`` platform: no directory. XLA:CPU entries
+      embed the compile host's machine features and are refused (or
+      worse) on another host, and the test suite must not grow a cache
+      inside the checkout.
+
+    The min-compile-time and min-entry-size thresholds are zeroed either
+    way: fleet programs are many small autoencoders, and JAX's 1 s
+    default would skip exactly the programs a heterogeneous fleet
+    recompiles most often. Calling this initialises the JAX backend
+    (it asks for the platform), which is why the CLI group does not call
+    it for host-only commands such as ``fleet-status``.
     """
-    global _compile_cache_configured
-    cache_dir = env_str(COMPILE_CACHE_ENV, None)
-    if not cache_dir:
-        return None
-    if _compile_cache_configured:
-        return cache_dir
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except (OSError, AttributeError, ValueError) as exc:
-        logger.warning(
-            "Persistent compile cache not enabled (%s=%r): %r",
-            COMPILE_CACHE_ENV,
-            cache_dir,
-            exc,
-        )
-        return None
-    _compile_cache_configured = True
-    # device telemetry inventories the configured cache (entries/bytes)
-    # for the fleet-status surface and the Prometheus device collector
-    from ..telemetry.device import note_compile_cache_dir
+    from ..telemetry.device import note_compile_cache_dir, watch_persistent_cache
 
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    cache_dir = os.environ.get(JAX_CACHE_DIR_ENV) or None
+    if cache_dir is None:
+        if jax.default_backend() == "cpu":
+            return None
+        cache_dir = default_compile_cache_dir()
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # device telemetry inventories the cache (entries/bytes on disk and
+    # this process's hits/misses) for fleet-status and Prometheus
     note_compile_cache_dir(cache_dir)
-    logger.info("JAX persistent compilation cache at %s", cache_dir)
+    watch_persistent_cache()
+    logger.debug("JAX persistent compilation cache at %s", cache_dir)
+    return cache_dir
+
+
+def announce_device(entry_point: str) -> Optional[str]:
+    """Configure the compile cache and log where ``entry_point`` runs
+    (platform, device kind and count as JAX reports them) — the first
+    thing ``build``, ``build-fleet`` and ``server.build_app`` do, so a
+    run JAX quietly started on the CPU is visible in the first log
+    line. Returns the compile-cache directory in use."""
+    from ..telemetry.device import device_identity
+
+    cache_dir = configure_compile_cache()
+    logger.info(
+        "%s on %s (persistent compile cache: %s)",
+        entry_point,
+        device_identity(),
+        cache_dir or "none",
+    )
     return cache_dir
 
 

@@ -32,21 +32,8 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-try:  # moved out of experimental in newer JAX
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - older JAX
-    from jax.experimental.shard_map import shard_map
-
-import inspect as _inspect
-
-# newer JAX: check_vma; older: check_rep — either must be off for the
-# replicated-carry + sharded-sequence LSTM scan (see local_score).
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(shard_map).parameters
-    else "check_rep"
-)
 
 from ..ops.windows import model_offset, sliding_windows
 from .mesh import DATA_AXIS
@@ -189,9 +176,9 @@ def _ring_program(
             in_specs=(rep, in_spec),
             out_specs=in_spec,
             # The LSTM scan carry starts replicated (zeros) and becomes
-            # device-varying after consuming the sharded sequence; vma/rep
+            # device-varying after consuming the sharded sequence; vma
             # checking rejects that mixed carry, so it is disabled here.
-            **{_CHECK_KW: False},
+            check_vma=False,
         )
     )
 

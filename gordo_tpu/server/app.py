@@ -631,6 +631,11 @@ def build_app(
     micro-batching engine (``GORDO_TPU_BATCHING`` — see
     ``gordo_tpu.serve``), including its startup warmup pass.
     """
+    from ..parallel.mesh import announce_device
+
+    # before any model is loaded or program compiled: the server shares
+    # the builder's persistent compile cache, and says where it runs
+    announce_device("model server")
     app = GordoServerApp(config)
     app._wsgi_entry = adapt_proxy_deployment(app.wsgi_app)
     # every in-request log record carries its trace_id from here on

@@ -862,28 +862,29 @@ def _build_fleet_forward_program(
     return jax.jit(fused)
 
 
-def program_cache_stats() -> Dict[str, int]:
+def program_cache_stats() -> Dict[str, Any]:
     """Serving program-cache sizes: ``programs`` is the number of cached
     (spec, backend, precision) jit entries, ``signatures`` the number of
     XLA executables compiled inside them (distinct argument shapes) —
-    the number that must stay bounded by the serve shape ladder. A
-    ``signatures`` of -1 means this jax version hides the jit cache."""
+    the number that must stay bounded by the serve shape ladder —
+    and ``by_backend`` the same executables split by serving backend
+    (``pallas``/``xla``), so a surface can tell a compiled kernel from
+    the XLA forward."""
     signatures = 0
     by_precision: Dict[str, int] = {}
+    by_backend: Dict[str, int] = {}
     for (spec, backend, gather, precision, ingest) in list(_program_cache_keys):
         by_precision[precision] = by_precision.get(precision, 0) + 1
-        program = _build_fleet_forward_program(
+        compiled = _build_fleet_forward_program(
             spec, backend, gather, precision, ingest
-        )
-        try:
-            if signatures >= 0:
-                signatures += program._cache_size()
-        except AttributeError:  # jit cache introspection is version-bound
-            signatures = -1
+        )._cache_size()
+        signatures += compiled
+        by_backend[backend] = by_backend.get(backend, 0) + compiled
     return {
         "programs": _build_fleet_forward_program.cache_info().currsize,
         "signatures": signatures,
         "by_precision": by_precision,
+        "by_backend": by_backend,
     }
 
 

@@ -509,8 +509,7 @@ class ProgramCacheCollector:
             "gordo_server_program_cache_size",
             "Compiled serving-program cache size (programs = cached jit "
             "entries per (spec, backend); signatures = XLA executables "
-            "compiled inside them, -1 when the jax version hides the "
-            "jit cache)",
+            "compiled inside them)",
             labels=["cache"],
         )
         family.add_metric(["programs"], stats["programs"])

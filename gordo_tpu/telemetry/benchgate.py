@@ -406,7 +406,7 @@ GATES: Dict[str, List[MetricSpec]] = {
             "truthy",
         ),
         MetricSpec(
-            "broken-dlpack fallback still answers correct bytes",
+            "columns dlpack cannot export answer correct bytes off the host rung",
             "fallback_ok",
             "truthy",
         ),

@@ -13,16 +13,21 @@ closes both gaps:
   emits it as a ``device_utilization`` event at phase boundaries (the
   measured counterpart of the FleetPlan's predicted HBM), and the
   Prometheus device collector reads it at scrape time. Backends without
-  the stats (older CPU jaxlib) degrade to ``{"available": False}`` —
+  the stats (the CPU platform) answer ``{"available": False}`` —
   callers never branch on platform.
 - :func:`note_program_execution` is the process-wide compile-vs-cache-hit
   counter pair, fed by the two places that know: the build side's
   :func:`~gordo_tpu.telemetry.recorder.program_span` (first call per
   signature = compile, later = hit — the jit cache's own semantics) and
   the serving engine's fused-program bookkeeping. The persistent
-  compile-cache directory (``GORDO_TPU_COMPILE_CACHE``), when
-  ``parallel/mesh.py`` configures one, is inventoried by
-  :func:`persistent_cache_info` (entries + bytes on disk).
+  compile-cache directory ``parallel/mesh.configure_compile_cache``
+  settles on (``JAX_COMPILATION_CACHE_DIR``, else
+  ``<checkout>/.jax_cache`` on an accelerator) is inventoried by
+  :func:`persistent_cache_info` (entries + bytes on disk, plus this
+  process's hits and misses against it).
+- :func:`device_identity` names where the process runs (``platform``,
+  ``device_kind``, device count) — every snapshot carries it, so a run
+  JAX quietly started on the CPU never reads like one on the chip.
 
 The counters and snapshots here are stdlib data; only the memory probe
 touches jax, lazily, so importing this module stays free on hosts
@@ -127,23 +132,55 @@ _persistent_cache_dir: Optional[str] = None
 
 def note_compile_cache_dir(path: Optional[str]) -> None:
     """Record the persistent compile-cache directory
-    ``parallel/mesh.configure_compile_cache`` actually configured (the
-    env knob alone does not mean the configure call succeeded)."""
+    ``parallel/mesh.configure_compile_cache`` settled on."""
     global _persistent_cache_dir
     with _cache_dir_lock:
         _persistent_cache_dir = path
 
 
+#: this process's lookups against the persistent cache, fed by JAX's own
+#: monitoring events (a hit is a compile that was loaded from disk)
+_PERSISTENT_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+_persistent_counts = {"hits": 0, "misses": 0}
+_persistent_watch_installed = False
+
+
+def _on_jax_event(event: str, **_kwargs: Any) -> None:
+    key = _PERSISTENT_EVENTS.get(event)
+    if key is not None:
+        with _cache_dir_lock:
+            _persistent_counts[key] += 1
+
+
+def watch_persistent_cache() -> None:
+    """Count persistent-cache hits and misses from here on (idempotent;
+    JAX offers no way to unregister a listener, so it is installed once
+    per process)."""
+    global _persistent_watch_installed
+    with _cache_dir_lock:
+        if _persistent_watch_installed:
+            return
+        _persistent_watch_installed = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_listener(_on_jax_event)
+
+
 def persistent_cache_info() -> Optional[Dict[str, Any]]:
-    """Inventory of the persistent compile cache (entry count + bytes),
-    or None when no cache directory is configured. Best-effort: a
-    vanished directory reports zero entries, never raises."""
+    """Inventory of the persistent compile cache (entry count + bytes on
+    disk, and this process's hits/misses), or None when no cache
+    directory is configured. Best-effort: a vanished directory reports
+    zero entries, never raises. A process that never configured one
+    (``fleet-status`` over an artifact volume) inventories the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names."""
     with _cache_dir_lock:
         cache_dir = _persistent_cache_dir
+        counts = dict(_persistent_counts)
     if cache_dir is None:
-        from ..utils.env import env_str
-
-        cache_dir = env_str("GORDO_TPU_COMPILE_CACHE", None)
+        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
     if not cache_dir:
         return None
     entries = 0
@@ -159,10 +196,40 @@ def persistent_cache_info() -> Optional[Dict[str, Any]]:
                     continue
     except OSError:
         pass
-    return {"path": cache_dir, "entries": entries, "bytes": total_bytes}
+    return {
+        "path": cache_dir,
+        "entries": entries,
+        "bytes": total_bytes,
+        **counts,
+    }
 
 
 # -- device memory ------------------------------------------------------------
+
+
+def _local_devices() -> Optional[list]:
+    """The local devices, or None when JAX (or its backend) is not
+    available — telemetry degrades, it never takes the caller down."""
+    try:
+        import jax
+
+        return jax.local_devices()
+    except Exception:  # noqa: BLE001 - no jax / broken backend
+        return None
+
+
+def device_identity() -> Optional[Dict[str, Any]]:
+    """Where this process runs, as JAX reports it: ``platform``,
+    ``device_kind`` and the local device count (None without a
+    backend)."""
+    devices = _local_devices()
+    if not devices:
+        return None
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "count": len(devices),
+    }
 
 
 def memory_snapshot() -> Optional[Dict[str, Any]]:
@@ -171,6 +238,7 @@ def memory_snapshot() -> Optional[Dict[str, Any]]:
     ``bytes_in_use`` / ``peak_bytes_in_use`` / ``bytes_limit`` summed
     across devices, plus the per-device maxima (the number an HBM-cap
     planner compares against) and how many devices actually reported.
+    A key no device reports is absent from the document.
 
     Returns None when sampling is disabled or jax is unavailable;
     ``{"available": False, ...}`` when the backend has no stats (the
@@ -178,20 +246,16 @@ def memory_snapshot() -> Optional[Dict[str, Any]]:
     """
     if not device_sampling_enabled():
         return None
-    try:
-        import jax
-
-        devices = jax.local_devices()
-    except Exception:  # noqa: BLE001 - no jax / broken backend: telemetry
-        # must degrade, never take the caller down
+    devices = _local_devices()
+    if devices is None:
         return None
     doc: Dict[str, Any] = {
         "devices": len(devices),
         "measured_devices": 0,
         "available": False,
     }
-    totals = {key: 0 for key in _MEMORY_KEYS}
-    maxima = {key: 0 for key in _MEMORY_KEYS}
+    totals: Dict[str, int] = {}
+    maxima: Dict[str, int] = {}
     for device in devices:
         try:
             stats = device.memory_stats()
@@ -202,32 +266,31 @@ def memory_snapshot() -> Optional[Dict[str, Any]]:
         doc["measured_devices"] += 1
         for key in _MEMORY_KEYS:
             value = stats.get(key)
-            if value is None and key == "peak_bytes_in_use":
-                # some backends spell peak differently; fall back to
-                # in-use so the field is never silently absent
-                value = stats.get("bytes_in_use")
             if value is None:
                 continue
             value = int(value)
-            totals[key] += value
-            maxima[key] = max(maxima[key], value)
+            totals[key] = totals.get(key, 0) + value
+            maxima[key] = max(maxima.get(key, 0), value)
     if doc["measured_devices"]:
         doc["available"] = True
-        for key in _MEMORY_KEYS:
-            doc[key] = totals[key]
+        for key, total in totals.items():
+            doc[key] = total
             doc[f"max_{key}"] = maxima[key]
         limit = totals.get("bytes_limit") or 0
-        if limit:
+        if limit and "bytes_in_use" in totals:
             doc["utilization"] = round(totals["bytes_in_use"] / limit, 4)
     return doc
 
 
 def utilization_snapshot() -> Dict[str, Any]:
-    """The full device-telemetry document: memory + compile-cache
-    counters + persistent-cache inventory (each section None/absent when
-    unavailable). This is what the ``device_utilization`` events and the
-    fleet-status surface carry."""
+    """The full device-telemetry document: device identity + memory +
+    compile-cache counters + persistent-cache inventory (each section
+    None/absent when unavailable). This is what the
+    ``device_utilization`` events and the fleet-status surface carry."""
     doc: Dict[str, Any] = {"compile_cache": program_cache_counters()}
+    identity = device_identity()
+    if identity is not None:
+        doc["device"] = identity
     memory = memory_snapshot()
     if memory is not None:
         doc["memory"] = memory

@@ -2002,12 +2002,22 @@ def render_fleet_status(doc: Dict[str, Any]) -> str:
             )
     device = doc.get("device")
     if device:
+        identity = device.get("device")
+        if identity:
+            lines.append(
+                f"Platform:  {identity.get('platform')} — "
+                f"{identity.get('count')} x {identity.get('device_kind')}"
+            )
         memory = device.get("memory")
         if memory and memory.get("available"):
             lines.append(
                 f"Device:    {memory.get('measured_devices', 0)} device(s) — "
-                f"{memory.get('bytes_in_use', 0) / (1 << 20):.1f} MiB in use, "
-                f"peak {memory.get('peak_bytes_in_use', 0) / (1 << 20):.1f} MiB"
+                f"{memory.get('bytes_in_use', 0) / (1 << 20):.1f} MiB in use"
+                + (
+                    f", peak {memory['peak_bytes_in_use'] / (1 << 20):.1f} MiB"
+                    if "peak_bytes_in_use" in memory
+                    else ""
+                )
                 + (
                     f" ({100.0 * memory['utilization']:.1f}% of limit)"
                     if memory.get("utilization") is not None
@@ -2030,7 +2040,9 @@ def render_fleet_status(doc: Dict[str, Any]) -> str:
             lines.append(
                 f"  persistent cache: {persistent.get('entries', 0)} entr"
                 f"{'y' if persistent.get('entries', 0) == 1 else 'ies'}, "
-                f"{persistent.get('bytes', 0) / (1 << 20):.1f} MiB "
+                f"{persistent.get('bytes', 0) / (1 << 20):.1f} MiB, "
+                f"{persistent.get('hits', 0)} hit(s) / "
+                f"{persistent.get('misses', 0)} miss(es) in this process "
                 f"({persistent.get('path')})"
             )
     programs = doc.get("programs")

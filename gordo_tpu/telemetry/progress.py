@@ -63,6 +63,8 @@ class BuildProgress:
         total: int = 0,
         phase_seconds: Optional[Dict[str, float]] = None,
         heartbeat_seconds: Optional[float] = None,
+        robustness: Optional[Dict[str, int]] = None,
+        device: Optional[Dict[str, Any]] = None,
     ):
         self.path = (
             os.path.join(output_dir, BUILD_STATUS_FILE)
@@ -81,6 +83,15 @@ class BuildProgress:
         self.resumed = 0
         self.cached = 0
         self.degraded = 0
+        #: machines the fleet path could not plan, built one by one on
+        #: the sequential ModelBuilder instead
+        self.fallbacks = 0
+        #: reference to the builder's live robustness counters
+        #: (bucket_bisects, sequential_degraded, fleet_retries, ...) — a
+        #: build that exits 0 after containing device faults says so here
+        self.robustness = robustness if robustness is not None else {}
+        #: where the build runs (``telemetry.device_identity``)
+        self.device = device
         self.state = "running"
         self.started_at = time.time()
         #: reference to the builder's live phase_seconds dict — snapshot
@@ -161,7 +172,10 @@ class BuildProgress:
                     "resumed": self.resumed,
                     "cached": self.cached,
                     "degraded": self.degraded,
+                    "fallbacks": self.fallbacks,
                 },
+                "robustness": {k: int(v) for k, v in self.robustness.items()},
+                "device": self.device,
                 "phases": phases,
             }
 
@@ -264,6 +278,19 @@ def render_status(doc: Dict[str, Any]) -> str:
             else ""
         ),
     ]
+    device = doc.get("device")
+    if device:
+        lines.insert(
+            1,
+            f"Device:   {device.get('platform')} — "
+            f"{device.get('count')} x {device.get('device_kind')}",
+        )
+    contained = dict(
+        doc.get("robustness") or {}, fallbacks=machines.get("fallbacks")
+    )
+    shown = [f"{k}={v}" for k, v in sorted(contained.items()) if v]
+    if shown:
+        lines.append("Contained: " + ", ".join(shown))
     if total:
         frac = min(1.0, (done + failed) / total)
         width = 30

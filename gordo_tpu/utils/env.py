@@ -155,15 +155,6 @@ KNOBS: Dict[str, Knob] = _knobs(
         "Performance",
     ),
     Knob(
-        "GORDO_TPU_COMPILE_CACHE", "str", None,
-        "Directory for JAX's persistent compilation cache — repeated "
-        "`build-fleet` runs and server restarts reload compiled programs "
-        "from disk instead of recompiling (applied at every mesh/backend "
-        "init; the min-compile-time threshold is zeroed so small fleet "
-        "programs are cached too).",
-        "Performance",
-    ),
-    Knob(
         "GORDO_TPU_DISABLE_PALLAS", "bool", False,
         "Force the plain-XLA fleet forward program even where the Pallas "
         "kernel is available.",
@@ -173,12 +164,6 @@ KNOBS: Dict[str, Knob] = _knobs(
         "GORDO_TPU_RING_PREDICT_ROWS", "int", 65_536,
         "Row threshold past which windowed models shard the prediction "
         "time axis over the device mesh (`parallel/sequence.py`).",
-        "Performance",
-    ),
-    Knob(
-        "GORDO_TPU_PLATFORM", "str", None,
-        "Device platform override for the CLI (`gordo-tpu --platform`; "
-        "read by click, not `os.environ`).",
         "Performance",
     ),
     # -- Bucket planner ----------------------------------------------------
@@ -509,9 +494,9 @@ KNOBS: Dict[str, Knob] = _knobs(
         "(`gordo_tpu.ingest.to_device`) — skips the intermediate host "
         "`column_stack`. Only engages on accelerator backends: on CPU "
         "both rungs stage through host memory, so host staging is the "
-        "fast rung regardless of this knob. Any per-request dlpack "
-        "failure (and off) falls back to host staging, counted by "
-        "reason in `ingest_stats()['fallback_reasons']`.",
+        "fast rung regardless of this knob. Columns dlpack cannot "
+        "export (read-only, strided) and off take host staging, "
+        "counted by reason in `ingest_stats()['fallback_reasons']`.",
         "Serving",
     ),
     Knob(

@@ -458,3 +458,19 @@ class TestEnsureSingleWorkflow:
     def test_non_numeric_revision_rejected(self, runner, tmp_path):
         result = self._run(runner, tmp_path, "not-a-revision")
         assert result.exit_code != 0
+
+
+def test_run_server_starts_one_serving_process_by_default(runner, monkeypatch):
+    """Every worker process initialises the accelerator, and a chip
+    belongs to one process at a time: with no ``--workers`` the server
+    is one process."""
+    from gordo_tpu.cli import cli
+
+    calls = []
+    monkeypatch.setattr(
+        cli, "run_server", lambda host, port, workers, *a, **kw: calls.append(workers)
+    )
+    monkeypatch.delenv("GORDO_SERVER_WORKERS", raising=False)
+    result = runner.invoke(gordo_tpu_cli, ["run-server", "--port", "5999"])
+    assert result.exit_code == 0, result.output
+    assert calls == [1]

@@ -1,8 +1,9 @@
 """
-Raw-column device-transfer units: both rungs (per-column dlpack and the
-host staging fallback) must produce the same device values, every
-fallback must be counted with its reason, and a backend with no working
-dlpack must degrade gracefully — never fail the request.
+Raw-column device-transfer units: both rungs (per-column dlpack and host
+staging) must produce the same values ON THE SERVING DEVICE, the rung is
+chosen by inspecting the columns with the reason counted, and a refusal
+from the transfer itself propagates — it is never converted into the
+host rung.
 """
 
 import numpy as np
@@ -88,21 +89,52 @@ def test_matrix_mode_takes_the_host_rung():
     assert stats["fallback_reasons"] == {"no_columns": 1}
 
 
-def test_dlpack_unavailable_falls_back_and_counts(monkeypatch):
-    """A backend whose dlpack import refuses (or is absent) must serve
-    every request over the host rung, with the reason counted."""
+def test_both_rungs_land_on_the_serving_device():
+    """The contract the chip smoke asserts on a TPU: whatever the rung,
+    the staged batch sits on ``jax.devices()[0]`` — a dlpack import left
+    on the CPU backend would drag every program fed from it there."""
+    want = {jax.devices()[0]}
+    cols = _columns()
+    for raw, dlpack in (
+        (RawColumns.from_columns(cols), True),
+        (RawColumns.from_columns(cols), False),
+        (RawColumns.from_matrix(np.column_stack(cols)), True),
+    ):
+        assert to_device(raw, dlpack=dlpack).devices() == want
+        assert to_device(raw, padded_rows=8, dlpack=dlpack).devices() == want
+    assert ingest_stats()["dlpack_transfers"] == 2
+
+
+def test_unexportable_columns_take_the_host_rung_by_inspection():
+    """dlpack cannot export read-only buffers (float32 columns decoded
+    zero-copy out of an Arrow body) or strided views: both are detected
+    up front and counted under a fixed reason, not an exception name."""
+    readonly = [np.arange(4, dtype=np.float32) for _ in range(2)]
+    for col in readonly:
+        col.setflags(write=False)
+    strided = [np.arange(8, dtype=np.float32)[::2] for _ in range(2)]
+    for cols in (readonly, strided):
+        X = np.asarray(to_device(RawColumns.from_columns(cols), dlpack=True))
+        np.testing.assert_array_equal(X, np.column_stack(cols))
+    stats = ingest_stats()
+    assert stats["dlpack_transfers"] == 0
+    assert stats["fallback_reasons"] == {
+        "readonly_column": 1,
+        "non_contiguous_column": 1,
+    }
+
+
+def test_transfer_refusal_propagates(monkeypatch):
+    """A refusal from the transfer itself is a fault on the device path:
+    it must surface, not be absorbed as a counted fallback."""
 
     def broken(*_args, **_kwargs):
         raise RuntimeError("dlpack unavailable on this backend")
 
     monkeypatch.setattr(jax.dlpack, "from_dlpack", broken)
-    cols = _columns()
-    X = np.asarray(to_device(RawColumns.from_columns(cols), dlpack=True))
-    np.testing.assert_array_equal(X, np.column_stack(cols).astype(np.float32))
-    stats = ingest_stats()
-    assert stats["dlpack_transfers"] == 0
-    assert stats["host_transfers"] == 1
-    assert stats["fallback_reasons"] == {"RuntimeError": 1}
+    with pytest.raises(RuntimeError, match="dlpack unavailable"):
+        to_device(RawColumns.from_columns(_columns()), dlpack=True)
+    assert ingest_stats()["fallback_reasons"] == {}
 
 
 def test_f64_columns_cast_and_transfer():

@@ -6,6 +6,7 @@ import pytest
 
 from gordo_tpu.models.factories import feedforward_hourglass, feedforward_model
 from gordo_tpu.models.nn import forward_feedforward, init_feedforward
+from gordo_tpu.ops.activations import _ACTIVATIONS
 from gordo_tpu.ops.pallas_dense import (
     fleet_anomaly_scores_pallas,
     fleet_feedforward_pallas,
@@ -66,3 +67,54 @@ def test_pallas_forward_large_batch_blocked(monkeypatch):
     got = pallas_dense.fleet_feedforward_pallas(spec, params, X, interpret=True)
     assert got.shape == (2, 50, 7)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected), rtol=1e-5, atol=1e-6)
+
+
+def _activation_spec(name):
+    return feedforward_model(
+        6, 6, encoding_dim=(8, 4), decoding_dim=(4, 8),
+        encoding_func=(name, name), decoding_func=(name, name),
+    )
+
+
+@pytest.mark.parametrize("name", sorted(_ACTIVATIONS))
+def test_every_activation_lowers_for_tpu(name):
+    """The kernel is the default f32 serving program on a TPU, so every
+    Keras-valid activation must lower through Mosaic — checked on the
+    CPU host by lowering for the ``tpu`` platform (``elu``/``selu`` were
+    written with expm1, which Mosaic does not implement)."""
+    spec = _activation_spec(name)
+    params = _stacked(spec, 2, 4)
+    X = np.zeros((2, 32, 6), np.float32)
+    jax.jit(lambda p, x: fleet_feedforward_pallas(spec, p, x)).trace(
+        params, X
+    ).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.parametrize("name", sorted(_ACTIVATIONS))
+def test_every_activation_matches_jnp(name):
+    """The kernel's spelling of each activation (``kernel_activation``)
+    computes what ``ops.activations`` does."""
+    spec = _activation_spec(name)
+    params = _stacked(spec, 2, 5)
+    X = (np.random.RandomState(5).rand(2, 16, 6).astype(np.float32) - 0.5) * 6
+    expected = jax.vmap(lambda p, x: forward_feedforward(spec, p, x)[0])(params, X)
+    got = fleet_feedforward_pallas(spec, params, X, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(expected), rtol=1e-5, atol=1e-6)
+
+
+def test_no_package_module_interprets_the_kernel():
+    """``interpret=`` is a test-only argument: a serving path that passed
+    it would run the Pallas interpreter in place of the compiled kernel
+    and still answer 200."""
+    import pathlib
+    import re
+
+    import gordo_tpu
+
+    root = pathlib.Path(gordo_tpu.__file__).parent
+    offenders = [
+        str(path.relative_to(root))
+        for path in root.rglob("*.py")
+        if re.search(r"interpret\s*=\s*True", path.read_text())
+    ]
+    assert offenders == []

@@ -6,8 +6,9 @@ SAME params and losses as a 1-device mesh of the same process.
 
 The in-process suite (tests/parallel/test_fleet.py) covers this under
 the conftest's virtual mesh; this subprocess variant pins the XLA flag
-explicitly so the ``MULTICHIP_r*.json`` dryrun invariant stays guarded
-even if the conftest bootstrap changes.
+explicitly so the sharded == one-device invariant stays guarded even if
+the conftest bootstrap changes. On real chips the same comparison is
+part of ``python chip_smoke.py`` wherever it finds more than one device.
 """
 
 import json

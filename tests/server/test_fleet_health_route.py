@@ -61,7 +61,9 @@ def test_fleet_health_route_serves_joined_document(client, collection_dir):
     assert machine["drift"]["reasons"] == ["feature-shift tag-1 (3.00σ)"]
     # device + program sections always present (may be degraded)
     assert "compile_cache" in doc["device"]
-    assert set(doc["programs"]) == {"programs", "signatures", "by_precision"}
+    assert set(doc["programs"]) == {
+        "programs", "signatures", "by_precision", "by_backend"
+    }
     # missing sections are null, not errors
     assert doc["build"] is None
     assert doc["lifecycle"] is None

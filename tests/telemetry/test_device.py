@@ -77,6 +77,25 @@ def test_utilization_snapshot_sections():
     # memory may be absent (no jax stats) but never truthy-and-empty
     if "memory" in doc:
         assert isinstance(doc["memory"], dict)
+    # every snapshot names where the process runs
+    assert doc["device"]["platform"] == "cpu"
+    assert doc["device"]["count"] >= 1 and doc["device"]["device_kind"]
+
+
+def test_memory_keys_a_backend_does_not_report_are_absent(monkeypatch):
+    """``peak_bytes_in_use`` is reported only where a device reports it —
+    never ``bytes_in_use`` under its name."""
+
+    class FakeDevice:
+        def memory_stats(self):
+            return {"bytes_in_use": 10, "bytes_limit": 100}
+
+    monkeypatch.setattr(device, "_local_devices", lambda: [FakeDevice()])
+    snapshot = device.memory_snapshot()
+    assert snapshot["available"] and snapshot["bytes_in_use"] == 10
+    assert "peak_bytes_in_use" not in snapshot
+    assert "max_peak_bytes_in_use" not in snapshot
+    assert snapshot["utilization"] == 0.1
 
 
 def test_persistent_cache_info_counts_entries(tmp_path, monkeypatch):
@@ -87,11 +106,18 @@ def test_persistent_cache_info_counts_entries(tmp_path, monkeypatch):
     device.note_compile_cache_dir(str(cache_dir))
     try:
         info = device.persistent_cache_info()
-        assert info == {"path": str(cache_dir), "entries": 2, "bytes": 150}
+        assert (info["path"], info["entries"], info["bytes"]) == (
+            str(cache_dir), 2, 150
+        )
+        # this process's lookups against it ride along
+        assert {"hits", "misses"} <= set(info)
     finally:
         device.note_compile_cache_dir(None)
-    # unconfigured and no env knob -> None
-    monkeypatch.delenv("GORDO_TPU_COMPILE_CACHE", raising=False)
+    # unconfigured, JAX's own variable names the directory to inventory
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
+    assert device.persistent_cache_info()["entries"] == 2
+    # unconfigured and no variable -> None
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert device.persistent_cache_info() is None
 
 

@@ -29,7 +29,7 @@ def _python_files():
         for name in sorted(files):
             if name.endswith(".py"):
                 yield os.path.join(root, name)
-    for extra in ("bench.py", "__graft_entry__.py"):
+    for extra in ("bench.py", "__graft_entry__.py", "chip_smoke.py"):
         yield os.path.join(REPO_ROOT, extra)
 
 
