@@ -622,7 +622,11 @@ def build_fleet(
     workflow's ConfigMaps by ``workflow generate``.
     """
     import os
+    import time
 
+    # the build records what the command does before and after it as
+    # phases of its own: config_load from here, report at its end
+    started = time.perf_counter()
     try:
         # after the distributed handshake: announcing initialises the
         # backend, and jax.distributed must be up before that
@@ -684,10 +688,9 @@ def build_fleet(
             output_dir if is_coordinator else None,
             model_register_dir=model_register_dir if is_coordinator else None,
             resume=resume,
+            started=started,
+            report=is_coordinator,
         )
-        if is_coordinator:
-            for _, machine_out in results:
-                machine_out.report()
         logger.info(
             "Fleet build complete: %d built, %d resumed (skipped), %d failed; "
             "contained device faults: %s",

@@ -183,7 +183,8 @@ def forward_lstm(
         x = x.astype(dtype)
     h_seq = jnp.transpose(x, (1, 0, 2))  # [time, batch, features] for scan
     for i in range(len(spec.dims)):
-        h_seq = _lstm_layer(params[f"lstm_{i}"], h_seq, spec.activations[i])
+        with jax.named_scope(f"lstm_{i}"):  # one scope a layer, as in params
+            h_seq = _lstm_layer(params[f"lstm_{i}"], h_seq, spec.activations[i])
     last_h = h_seq[-1]
     out = last_h @ params["out"]["W"].astype(dtype) + params["out"]["b"].astype(
         dtype
@@ -217,7 +218,8 @@ def forward_lstm_sequence(
         x_seq = x_seq.astype(dtype)
     h_seq = x_seq
     for i in range(len(spec.dims)):
-        h_seq = _lstm_layer(params[f"lstm_{i}"], h_seq, spec.activations[i])
+        with jax.named_scope(f"lstm_{i}"):  # one scope a layer, as in params
+            h_seq = _lstm_layer(params[f"lstm_{i}"], h_seq, spec.activations[i])
     out = h_seq @ params["out"]["W"].astype(dtype) + params["out"]["b"].astype(
         dtype
     )

@@ -128,6 +128,14 @@ def fit_config_from_kwargs(kwargs: dict) -> Tuple[FitConfig, List[Callback]]:
     return config, host_callbacks
 
 
+#: ``jax.named_scope`` names inside every fused fit program: each
+#: operation's metadata in the HLO (and in a profiler trace) then says
+#: which part of an epoch it belongs to, whatever XLA numbers the fusion
+SHUFFLE_SCOPE = "epoch_shuffle"  # the per-epoch permutation and gathers
+STEPS_SCOPE = "optimizer_steps"  # the scan over an epoch's batches
+VALIDATION_SCOPE = "validation"  # the end-of-epoch validation pass
+
+
 def _tree_where(flag, a, b):
     return jax.tree_util.tree_map(
         lambda x, y: jnp.where(flag, x, y), a, b
@@ -277,10 +285,11 @@ def build_raw_fit_fn(spec: ModelSpec, config: FitConfig):
             # slices via scan-over-xs. Per-batch index gathers were the fleet
             # hot spot on TPU (measured 2.4× whole-fit slowdown at 256
             # models): 640 small gather kernels vs 20 large ones.
-            perm = jax.random.permutation(erng, n_total)
-            Xtr = jnp.take(Xtr, perm, axis=0)
-            ytr = jnp.take(ytr, perm, axis=0)
-            wtr = jnp.take(wtr, perm, axis=0)
+            with jax.named_scope(SHUFFLE_SCOPE):
+                perm = jax.random.permutation(erng, n_total)
+                Xtr = jnp.take(Xtr, perm, axis=0)
+                ytr = jnp.take(ytr, perm, axis=0)
+                wtr = jnp.take(wtr, perm, axis=0)
         batches = (
             Xtr.reshape((steps, config.batch_size) + Xtr.shape[1:]),
             ytr.reshape((steps, config.batch_size) + ytr.shape[1:]),
@@ -304,15 +313,17 @@ def build_raw_fit_fn(spec: ModelSpec, config: FitConfig):
             contribution = jnp.where(has_data, loss * jnp.sum(wb), 0.0)
             return (params, opt_state), contribution
 
-        (params, opt_state), weighted_losses = jax.lax.scan(
-            step, (params, opt_state), batches
-        )
+        with jax.named_scope(STEPS_SCOPE):
+            (params, opt_state), weighted_losses = jax.lax.scan(
+                step, (params, opt_state), batches
+            )
         epoch_loss = jnp.sum(weighted_losses) / jnp.maximum(jnp.sum(wtr), 1.0)
         return params, opt_state, epoch_loss
 
     def evaluate(params, X, y, w):
-        out, _ = forward(spec, params, X)
-        return weighted_mean_loss(per_sample(out, y), w)
+        with jax.named_scope(VALIDATION_SCOPE):
+            out, _ = forward(spec, params, X)
+            return weighted_mean_loss(per_sample(out, y), w)
 
     compute_dtype = jnp.dtype(spec.compute_dtype)
 
@@ -389,9 +400,10 @@ def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
         nv = order.shape[0]
         steps = nv // config.batch_size
         if config.shuffle:
-            perm = jax.random.permutation(erng, nv)
-            order_e = jnp.take(order, perm)
-            wtr_e = jnp.take(wtr, perm)
+            with jax.named_scope(SHUFFLE_SCOPE):
+                perm = jax.random.permutation(erng, nv)
+                order_e = jnp.take(order, perm)
+                wtr_e = jnp.take(wtr, perm)
         else:
             order_e, wtr_e = order, wtr
         starts_b = order_e.reshape(steps, config.batch_size)
@@ -410,9 +422,10 @@ def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
             contribution = jnp.where(has_data, loss * jnp.sum(wb), 0.0)
             return (params, opt_state), contribution
 
-        (params, opt_state), weighted_losses = jax.lax.scan(
-            step, (params, opt_state), (starts_b, w_b)
-        )
+        with jax.named_scope(STEPS_SCOPE):
+            (params, opt_state), weighted_losses = jax.lax.scan(
+                step, (params, opt_state), (starts_b, w_b)
+            )
         epoch_loss = jnp.sum(weighted_losses) / jnp.maximum(jnp.sum(wtr), 1.0)
         return params, opt_state, epoch_loss
 
@@ -430,14 +443,15 @@ def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
             losses = per_sample(out, yb)
             return (acc[0] + jnp.sum(losses * wb), acc[1] + jnp.sum(wb)), None
 
-        (total, wsum), _ = jax.lax.scan(
-            step,
-            (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-            (
-                order.reshape(steps, config.batch_size),
-                w.reshape(steps, config.batch_size),
-            ),
-        )
+        with jax.named_scope(VALIDATION_SCOPE):
+            (total, wsum), _ = jax.lax.scan(
+                step,
+                (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
+                (
+                    order.reshape(steps, config.batch_size),
+                    w.reshape(steps, config.batch_size),
+                ),
+            )
         return jnp.where(wsum > 0, total / wsum, jnp.nan)
 
     compute_dtype = jnp.dtype(spec.compute_dtype)
@@ -555,9 +569,10 @@ def build_raw_segmented_fit_fn(
             contribution = jnp.where(has_data, loss * jnp.sum(wb), 0.0)
             return (params, opt_state), contribution
 
-        (params, opt_state), weighted_losses = jax.lax.scan(
-            step, (params, opt_state), (heads, w_b)
-        )
+        with jax.named_scope(STEPS_SCOPE):
+            (params, opt_state), weighted_losses = jax.lax.scan(
+                step, (params, opt_state), (heads, w_b)
+            )
         epoch_loss = jnp.sum(weighted_losses) / jnp.maximum(jnp.sum(wtr), 1.0)
         return params, opt_state, epoch_loss
 
@@ -576,11 +591,12 @@ def build_raw_segmented_fit_fn(
             contribution = jnp.where(wsum > 0, loss * wsum, 0.0)
             return (acc[0] + contribution, acc[1] + wsum), None
 
-        (total, wsum), _ = jax.lax.scan(
-            step,
-            (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-            (heads, w_b),
-        )
+        with jax.named_scope(VALIDATION_SCOPE):
+            (total, wsum), _ = jax.lax.scan(
+                step,
+                (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
+                (heads, w_b),
+            )
         return jnp.where(wsum > 0, total / wsum, jnp.nan)
 
     compute_dtype = jnp.dtype(spec.compute_dtype)

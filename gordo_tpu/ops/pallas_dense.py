@@ -79,6 +79,10 @@ def _layer_names(spec: FeedForwardSpec) -> List[Tuple[str, str]]:
 # hold the whole [B, F] block in VMEM and fail to compile.
 BLOCK_B = 512
 
+#: the kernel's name in the HLO and in a profiler trace (the custom
+#: call is otherwise told apart only by ``tpu_custom_call`` in its text)
+KERNEL_NAME = "fleet_dense_forward"
+
 
 def fleet_feedforward_pallas(
     spec: FeedForwardSpec,
@@ -148,6 +152,7 @@ def fleet_feedforward_pallas(
         out_specs=pl.BlockSpec((1, block_b, f_out), lambda m, bi: (m, bi, 0), **mem),
         out_shape=jax.ShapeDtypeStruct((M, b_pad, f_out), jnp.float32),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(X.astype(jnp.float32), *flat)
     return out[:, :B]
 

@@ -200,9 +200,21 @@ def _fill_weight_row(wtr, wval, i, n, member, config: FitConfig):
         wval[i, : len(member.val_weights)] = member.val_weights
 
 
+def _jit_named(name: str, fn):
+    """``jax.jit(fn)`` under an explicit name: the XLA module is
+    ``jit_<name>`` in a profiler trace, whatever the wrapped function
+    is called (docs/observability.md lists the names; the chip
+    benchmark finds the fit programs by ``fit`` in theirs)."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 #: jit'd ravel+concat of same-dtype leaves: turns a many-leaf pytree fetch
 #: into one contiguous device buffer, so the host sees ONE transfer.
-_flat_concat = jax.jit(lambda *leaves: jnp.concatenate([l.ravel() for l in leaves]))
+_flat_concat = _jit_named(
+    "fleet_flat_concat",
+    lambda *leaves: jnp.concatenate([l.ravel() for l in leaves]),
+)
 
 #: _flat_concat compiles one XLA program per distinct (leaf count, shapes,
 #: dtypes) signature for the process lifetime; trees with more leaves than
@@ -288,7 +300,7 @@ def host_prng_keys(seeds: Sequence[int]) -> np.ndarray:
 def _fleet_fit_program(spec: ModelSpec, config: FitConfig):
     """jit(vmap) of the raw fused fit over a leading model axis."""
     raw_fit = build_raw_fit_fn(spec, config)
-    return jax.jit(jax.vmap(raw_fit))
+    return _jit_named("fleet_fit", jax.vmap(raw_fit))
 
 
 @lru_cache(maxsize=None)
@@ -297,7 +309,7 @@ def _fleet_windowed_fit_program(spec: ModelSpec, config: FitConfig):
     from ..models.training import build_raw_windowed_fit_fn
 
     raw_fit = build_raw_windowed_fit_fn(spec, config)
-    return jax.jit(jax.vmap(raw_fit))
+    return _jit_named("fleet_windowed_fit", jax.vmap(raw_fit))
 
 
 @lru_cache(maxsize=None)
@@ -309,7 +321,7 @@ def _fleet_segmented_fit_program(
     from ..models.training import build_raw_segmented_fit_fn
 
     raw_fit = build_raw_segmented_fit_fn(spec, config, segments_per_update)
-    return jax.jit(jax.vmap(raw_fit))
+    return _jit_named("fleet_segmented_fit", jax.vmap(raw_fit))
 
 
 #: the shared GORDO_TPU_LSTM_SEGMENTED knob parser lives beside the
@@ -345,7 +357,7 @@ def fleet_windowed_predict_program(spec: ModelSpec, batch_size: int):
         )
         return outs.reshape(steps * batch_size, -1)
 
-    return jax.jit(jax.vmap(predict_one))
+    return _jit_named("fleet_windowed_predict", jax.vmap(predict_one))
 
 
 @lru_cache(maxsize=None)
@@ -356,7 +368,7 @@ def fleet_predict_program(spec: ModelSpec):
     def predict(params, X):
         return forward(spec, params, X)[0]
 
-    return jax.jit(jax.vmap(predict))
+    return _jit_named("fleet_predict", jax.vmap(predict))
 
 
 @lru_cache(maxsize=None)
@@ -364,14 +376,18 @@ def _packed_fit_program(pspec, config: FitConfig):
     """jit(vmap) of the packed block-diagonal fit over the pack axis."""
     from ..models.packing import build_packed_fit_fn
 
-    return jax.jit(jax.vmap(build_packed_fit_fn(pspec, config)))
+    return _jit_named(
+        "fleet_packed_fit", jax.vmap(build_packed_fit_fn(pspec, config))
+    )
 
 
 @lru_cache(maxsize=None)
 def _packed_init_program(pspec):
     from ..models.packing import init_packed
 
-    return jax.jit(jax.vmap(lambda keys: init_packed(keys, pspec)))
+    return _jit_named(
+        "fleet_packed_init", jax.vmap(lambda keys: init_packed(keys, pspec))
+    )
 
 
 @lru_cache(maxsize=None)
@@ -381,7 +397,15 @@ def _fleet_init_program(spec: ModelSpec):
     def init_one(key):
         return init(key, spec)
 
-    return jax.jit(jax.vmap(init_one))
+    return _jit_named("fleet_init", jax.vmap(init_one))
+
+
+def _optimizer_init_program(spec: ModelSpec):
+    """The stacked optimizer state's program (made anew per bucket: it
+    traces and loads from the cache each time, never compiles twice)."""
+    return _jit_named(
+        "fleet_optimizer_init", jax.vmap(spec.optimizer.to_optax().init)
+    )
 
 
 class FleetTrainer:
@@ -675,49 +699,51 @@ class FleetTrainer:
         planner pads sibling HBM-split buckets to one shared rung so they
         reuse a single compiled program).
         """
-        model_axis = self.mesh.devices.shape[0]
-        data_axis = self.mesh.devices.shape[1] if self.mesh.devices.ndim > 1 else 1
-        m_floor = max(len(bucket), m_padded or 0)
-        m_total = -(-m_floor // model_axis) * model_axis
-        # The sample axis must stay a whole number of batches (the fit
-        # program reshapes [steps, batch]) AND divide across the data axis.
-        step = int(np.lcm(config.batch_size, data_axis))
-        n_padded = -(-n_padded // step) * step
+        with telemetry.part_span("stack"):
+            model_axis = self.mesh.devices.shape[0]
+            data_axis = self.mesh.devices.shape[1] if self.mesh.devices.ndim > 1 else 1
+            m_floor = max(len(bucket), m_padded or 0)
+            m_total = -(-m_floor // model_axis) * model_axis
+            # The sample axis must stay a whole number of batches (the fit
+            # program reshapes [steps, batch]) AND divide across the data axis.
+            step = int(np.lcm(config.batch_size, data_axis))
+            n_padded = -(-n_padded // step) * step
 
-        def stacked(attr_arrays):
-            # Fill a preallocated block instead of pad-then-np.stack: one
-            # copy per member, zero rows double as sample padding and
-            # zero-weight dummy models.
-            out = np.zeros(
-                (m_total, n_padded) + np.shape(attr_arrays[0])[1:], np.float32
+            def stacked(attr_arrays):
+                # Fill a preallocated block instead of pad-then-np.stack: one
+                # copy per member, zero rows double as sample padding and
+                # zero-weight dummy models.
+                out = np.zeros(
+                    (m_total, n_padded) + np.shape(attr_arrays[0])[1:], np.float32
+                )
+                for i, a in enumerate(attr_arrays):
+                    out[i, : len(a)] = a
+                return out
+
+            X = stacked([m.X for m in bucket])
+            # The AE fleet overwhelmingly trains y == X; staging X once and
+            # aliasing saves a second 100s-of-MB host copy and its transfer.
+            y = X if all(m.y is m.X for m in bucket) else stacked([m.y for m in bucket])
+
+            wtr = np.zeros((m_total, n_padded), np.float32)
+            wval = np.zeros((m_total, n_padded), np.float32)
+            for i, member in enumerate(bucket):
+                _fill_weight_row(wtr, wval, i, member.n, member, config)
+
+            rngs = host_prng_keys([m.seed for m in bucket] + [0] * (m_total - len(bucket)))
+        with telemetry.part_span("h2d"):
+            w_sharding = model_data_sharding(self.mesh)
+            X_dev = jax.device_put(X, model_data_sharding(self.mesh, extra_dims=X.ndim - 2))
+            y_dev = (
+                X_dev
+                if y is X
+                else jax.device_put(y, model_data_sharding(self.mesh, extra_dims=y.ndim - 2))
             )
-            for i, a in enumerate(attr_arrays):
-                out[i, : len(a)] = a
-            return out
-
-        X = stacked([m.X for m in bucket])
-        # The AE fleet overwhelmingly trains y == X; staging X once and
-        # aliasing saves a second 100s-of-MB host copy and its transfer.
-        y = X if all(m.y is m.X for m in bucket) else stacked([m.y for m in bucket])
-
-        wtr = np.zeros((m_total, n_padded), np.float32)
-        wval = np.zeros((m_total, n_padded), np.float32)
-        for i, member in enumerate(bucket):
-            _fill_weight_row(wtr, wval, i, member.n, member, config)
-
-        rngs = host_prng_keys([m.seed for m in bucket] + [0] * (m_total - len(bucket)))
-        w_sharding = model_data_sharding(self.mesh)
-        X_dev = jax.device_put(X, model_data_sharding(self.mesh, extra_dims=X.ndim - 2))
-        y_dev = (
-            X_dev
-            if y is X
-            else jax.device_put(y, model_data_sharding(self.mesh, extra_dims=y.ndim - 2))
-        )
-        wtr, wval, rngs = jax.device_put(
-            (wtr, wval, rngs),
-            (w_sharding, w_sharding, model_sharding(self.mesh, extra_dims=1)),
-        )
-        return X_dev, y_dev, wtr, wval, rngs
+            wtr, wval, rngs = jax.device_put(
+                (wtr, wval, rngs),
+                (w_sharding, w_sharding, model_sharding(self.mesh, extra_dims=1)),
+            )
+            return X_dev, y_dev, wtr, wval, rngs
 
     def _train_bucket(
         self,
@@ -744,10 +770,11 @@ class FleetTrainer:
             params, _, losses, val_losses, epochs_ran = _traced_outputs(
                 fit(params, opt_state, X, y, wtr, X, y, wval, rngs)
             )
-        return self._collect_results(
-            bucket, params, losses, val_losses, epochs_ran, config,
-            steps=n_padded // config.batch_size,
-        )
+        with telemetry.part_span("collect"):
+            return self._collect_results(
+                bucket, params, losses, val_losses, epochs_ran, config,
+                steps=n_padded // config.batch_size,
+            )
 
     # -- packed training ----------------------------------------------------
 
@@ -823,7 +850,7 @@ class FleetTrainer:
 
         params = _packed_init_program(pspec)(init_rngs)
         params = jax.device_put(params, model_sharding(self.mesh, extra_dims=0))
-        opt_state = jax.jit(jax.vmap(spec.optimizer.to_optax().init))(params)
+        opt_state = _optimizer_init_program(spec)(params)
         fit = _packed_fit_program(pspec, config)
         with telemetry.program_span(
             "fleet_packed_fit",
@@ -882,12 +909,15 @@ class FleetTrainer:
         """Per-member init mirroring fit_single's derivation exactly so a
         fleet member trains bit-for-bit like the single-model path: fit rng
         and init rng are the two halves of split(PRNGKey(seed))."""
-        split_keys = jax.vmap(jax.random.split)(rngs)
-        rngs, init_rngs = split_keys[:, 0], split_keys[:, 1]
-        params = _fleet_init_program(spec)(init_rngs)
-        params = jax.device_put(params, model_sharding(self.mesh, extra_dims=0))
-        opt_state = jax.jit(jax.vmap(spec.optimizer.to_optax().init))(params)
-        return params, opt_state, rngs
+        with telemetry.part_span("init"):
+            split_keys = jax.vmap(jax.random.split)(rngs)
+            rngs, init_rngs = split_keys[:, 0], split_keys[:, 1]
+            params = _fleet_init_program(spec)(init_rngs)
+            params = jax.device_put(
+                params, model_sharding(self.mesh, extra_dims=0)
+            )
+            opt_state = _optimizer_init_program(spec)(params)
+            return params, opt_state, rngs
 
     # -- windowed training --------------------------------------------------
 
@@ -906,46 +936,47 @@ class FleetTrainer:
         series (and aligned targets) shard over ``models`` only; the
         virtual window axis (order + weights) shards over ``data``.
         """
-        model_axis = self.mesh.devices.shape[0]
-        data_axis = self.mesh.devices.shape[1] if self.mesh.devices.ndim > 1 else 1
-        m_floor = max(len(bucket), m_padded or 0)
-        m_total = -(-m_floor // model_axis) * model_axis
-        nw_padded = n_padded - offset
-        step = int(np.lcm(config.batch_size, data_axis))
-        nv_padded = -(-nw_padded // step) * step
+        with telemetry.part_span("stack"):
+            model_axis = self.mesh.devices.shape[0]
+            data_axis = self.mesh.devices.shape[1] if self.mesh.devices.ndim > 1 else 1
+            m_floor = max(len(bucket), m_padded or 0)
+            m_total = -(-m_floor // model_axis) * model_axis
+            nw_padded = n_padded - offset
+            step = int(np.lcm(config.batch_size, data_axis))
+            nv_padded = -(-nw_padded // step) * step
 
-        f_in = bucket[0].series.shape[1]
-        f_out = bucket[0].targets.shape[1]
-        series = np.zeros((m_total, n_padded, f_in), np.float32)
-        ytgt = np.zeros((m_total, nw_padded, f_out), np.float32)
-        order = np.zeros((m_total, nv_padded), np.int32)
-        wtr = np.zeros((m_total, nv_padded), np.float32)
-        wval = np.zeros((m_total, nv_padded), np.float32)
-        for i, member in enumerate(bucket):
-            series[i, : len(member.series)] = member.series
-            ytgt[i, : member.n_windows] = member.targets
-            nv = member.n_windows
-            order[i, :nv] = (
-                member.order if member.order is not None else np.arange(nv)
+            f_in = bucket[0].series.shape[1]
+            f_out = bucket[0].targets.shape[1]
+            series = np.zeros((m_total, n_padded, f_in), np.float32)
+            ytgt = np.zeros((m_total, nw_padded, f_out), np.float32)
+            order = np.zeros((m_total, nv_padded), np.int32)
+            wtr = np.zeros((m_total, nv_padded), np.float32)
+            wval = np.zeros((m_total, nv_padded), np.float32)
+            for i, member in enumerate(bucket):
+                series[i, : len(member.series)] = member.series
+                ytgt[i, : member.n_windows] = member.targets
+                nv = member.n_windows
+                order[i, :nv] = (
+                    member.order if member.order is not None else np.arange(nv)
+                )
+                _fill_weight_row(wtr, wval, i, nv, member, config)
+
+            rngs = host_prng_keys(
+                [m.seed for m in bucket] + [0] * (m_total - len(bucket))
             )
-            _fill_weight_row(wtr, wval, i, nv, member, config)
-
-        rngs = host_prng_keys(
-            [m.seed for m in bucket] + [0] * (m_total - len(bucket))
-        )
-        md = model_data_sharding(self.mesh)
-        series, ytgt, order, wtr, wval, rngs = jax.device_put(
-            (series, ytgt, order, wtr, wval, rngs),
-            (
-                model_sharding(self.mesh, extra_dims=2),
-                model_sharding(self.mesh, extra_dims=2),
-                md,
-                md,
-                md,
-                model_sharding(self.mesh, extra_dims=1),
-            ),
-        )
-        return series, ytgt, order, wtr, wval, rngs
+        with telemetry.part_span("h2d"):
+            md = model_data_sharding(self.mesh)
+            return jax.device_put(
+                (series, ytgt, order, wtr, wval, rngs),
+                (
+                    model_sharding(self.mesh, extra_dims=2),
+                    model_sharding(self.mesh, extra_dims=2),
+                    md,
+                    md,
+                    md,
+                    model_sharding(self.mesh, extra_dims=1),
+                ),
+            )
 
     def _segmented_eligible(
         self, bucket: List[WindowedFleetMember], config: FitConfig
@@ -1016,10 +1047,11 @@ class FleetTrainer:
                 params, _, losses, val_losses, epochs_ran = _traced_outputs(
                     fit(params, opt_state, series, ytgt, order, wtr, wval, rngs)
                 )
-        return self._collect_results(
-            bucket, params, losses, val_losses, epochs_ran, config,
-            steps=order.shape[1] // config.batch_size,
-        )
+        with telemetry.part_span("collect"):
+            return self._collect_results(
+                bucket, params, losses, val_losses, epochs_ran, config,
+                steps=order.shape[1] // config.batch_size,
+            )
 
     def _collect_results(
         self, bucket, params, losses, val_losses, epochs_ran, config, steps
@@ -1069,26 +1101,31 @@ class FleetTrainer:
         self, spec: ModelSpec, stacked_params, X: np.ndarray
     ) -> np.ndarray:
         """Forward the whole bucket: X[M, N, ...] -> [M, N, out]."""
-        X = np.asarray(X, np.float32)
-        m = X.shape[0]
-        model_axis = self.mesh.devices.shape[0]
-        data_axis = self.mesh.devices.shape[1] if self.mesh.devices.ndim > 1 else 1
-        m_total = -(-m // model_axis) * model_axis
-        n = X.shape[1]
-        n_total = -(-n // data_axis) * data_axis
-        if m_total != m or n_total != n:
-            padded = np.zeros((m_total, n_total) + X.shape[2:], X.dtype)
-            padded[:m, :n] = X
-            X = padded
-            stacked_params = jax.tree_util.tree_map(
-                lambda a: np.concatenate(
-                    [a, np.repeat(np.asarray(a)[:1], m_total - m, axis=0)]
-                )
-                if m_total != m
-                else np.asarray(a),
-                stacked_params,
+        with telemetry.part_span("h2d"):  # pad to the mesh, then transfer
+            X = np.asarray(X, np.float32)
+            m = X.shape[0]
+            model_axis = self.mesh.devices.shape[0]
+            data_axis = (
+                self.mesh.devices.shape[1] if self.mesh.devices.ndim > 1 else 1
             )
-        X = jax.device_put(X, model_data_sharding(self.mesh, extra_dims=X.ndim - 2))
+            m_total = -(-m // model_axis) * model_axis
+            n = X.shape[1]
+            n_total = -(-n // data_axis) * data_axis
+            if m_total != m or n_total != n:
+                padded = np.zeros((m_total, n_total) + X.shape[2:], X.dtype)
+                padded[:m, :n] = X
+                X = padded
+                stacked_params = jax.tree_util.tree_map(
+                    lambda a: np.concatenate(
+                        [a, np.repeat(np.asarray(a)[:1], m_total - m, axis=0)]
+                    )
+                    if m_total != m
+                    else np.asarray(a),
+                    stacked_params,
+                )
+            X = jax.device_put(
+                X, model_data_sharding(self.mesh, extra_dims=X.ndim - 2)
+            )
         with telemetry.program_span(
             "fleet_predict",
             (spec, X.shape),
@@ -1096,9 +1133,9 @@ class FleetTrainer:
             shape=str(tuple(X.shape)),
             spec=type(spec).__name__,
         ):
-            out = np.asarray(
-                fetch_to_host(fleet_predict_program(spec)(stacked_params, X))
-            )
+            out = _traced_outputs(fleet_predict_program(spec)(stacked_params, X))
+            with telemetry.part_span("collect"):
+                out = np.asarray(fetch_to_host(out))
         return out[:m, :n]
 
     def predict_windowed_bucket(
@@ -1115,31 +1152,34 @@ class FleetTrainer:
         ``series[M, n, F]`` + ``order[M, nv]`` → ``[M, nv, F_out]``
         (``nv`` is padded to a whole number of ``batch_size`` batches here).
         """
-        series = np.asarray(series, np.float32)
-        order = np.asarray(order, np.int32)
-        m = series.shape[0]
-        model_axis = self.mesh.devices.shape[0]
-        m_total = -(-m // model_axis) * model_axis
-        nv = order.shape[1]
-        nv_pad = -(-nv // batch_size) * batch_size
-        if m_total != m or nv_pad != nv:
-            series = np.concatenate(
-                [series, np.repeat(series[:1], m_total - m, axis=0)]
-            ) if m_total != m else series
-            padded_order = np.zeros((m_total, nv_pad), np.int32)
-            padded_order[:m, :nv] = order
-            order = padded_order
-            stacked_params = jax.tree_util.tree_map(
-                lambda a: np.concatenate(
-                    [a, np.repeat(np.asarray(a)[:1], m_total - m, axis=0)]
+        with telemetry.part_span("h2d"):  # pad to the mesh, then transfer
+            series = np.asarray(series, np.float32)
+            order = np.asarray(order, np.int32)
+            m = series.shape[0]
+            model_axis = self.mesh.devices.shape[0]
+            m_total = -(-m // model_axis) * model_axis
+            nv = order.shape[1]
+            nv_pad = -(-nv // batch_size) * batch_size
+            if m_total != m or nv_pad != nv:
+                series = np.concatenate(
+                    [series, np.repeat(series[:1], m_total - m, axis=0)]
+                ) if m_total != m else series
+                padded_order = np.zeros((m_total, nv_pad), np.int32)
+                padded_order[:m, :nv] = order
+                order = padded_order
+                stacked_params = jax.tree_util.tree_map(
+                    lambda a: np.concatenate(
+                        [a, np.repeat(np.asarray(a)[:1], m_total - m, axis=0)]
+                    )
+                    if m_total != m
+                    else np.asarray(a),
+                    stacked_params,
                 )
-                if m_total != m
-                else np.asarray(a),
-                stacked_params,
+            ms2 = model_sharding(self.mesh, extra_dims=2)
+            series = jax.device_put(series, ms2)
+            order = jax.device_put(
+                order, model_sharding(self.mesh, extra_dims=1)
             )
-        ms2 = model_sharding(self.mesh, extra_dims=2)
-        series = jax.device_put(series, ms2)
-        order = jax.device_put(order, model_sharding(self.mesh, extra_dims=1))
         with telemetry.program_span(
             "fleet_windowed_predict",
             (spec, batch_size, series.shape, order.shape),
@@ -1147,13 +1187,13 @@ class FleetTrainer:
             shape=str(tuple(series.shape)),
             spec=type(spec).__name__,
         ):
-            out = np.asarray(
-                fetch_to_host(
-                    fleet_windowed_predict_program(spec, batch_size)(
-                        stacked_params, series, order
-                    )
+            out = _traced_outputs(
+                fleet_windowed_predict_program(spec, batch_size)(
+                    stacked_params, series, order
                 )
             )
+            with telemetry.part_span("collect"):
+                out = np.asarray(fetch_to_host(out))
         return out[:m, :nv]
 
 

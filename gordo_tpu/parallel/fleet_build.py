@@ -44,8 +44,9 @@ from .. import serializer, telemetry
 from ..builder.build_model import ModelBuilder
 from ..dataset import GordoBaseDataset
 from ..machine import Machine
+from ..telemetry.device import compile_path_counters, watch_compile_path
 from ..telemetry.progress import BUILD_TRACE_FILE
-from ..utils.profiling import maybe_trace
+from ..utils.profiling import annotate, maybe_trace
 from ..machine.metadata import (
     BuildMetadata,
     CrossValidationMetaData,
@@ -276,6 +277,9 @@ class FleetBuilder:
         # listener attributes final-fit device programs here so the
         # cost model's error is observable (event + gauges at build end).
         self._current_phase = ""
+        # span id of the running build_phase span: the explicit parent
+        # of the parts its pool threads record (_part)
+        self._phase_span_id: Optional[str] = None
         self._plan_actuals: Dict[str, float] = defaultdict(float)
         # Per-member fleet health ledger (telemetry/fleet_health.py):
         # build provenance — final losses, failures, degradations —
@@ -288,6 +292,7 @@ class FleetBuilder:
         # one the fleet-status surfaces actually read.
         self._health_ledger_override = health_ledger
         self._ledger: Any = telemetry.NULL_LEDGER
+        self._ledger_flushed = False
         self._output_revision: Optional[str] = None
         # Measured device-utilization actuals: member-axis occupancy of
         # the executed final-fit programs and the max observed HBM peak
@@ -306,19 +311,83 @@ class FleetBuilder:
 
     @contextlib.contextmanager
     def _phase(self, name: str):
-        if self.progress is not None:
-            self.progress.phase(name)
-        start = time.time()
-        previous_phase, self._current_phase = self._current_phase, name
+        started = time.perf_counter()
+        previous = (self._current_phase, self._phase_span_id)
         try:
             with self.recorder.span(
                 "build_phase", phase=name, machines=len(self.machines)
-            ):
-                yield
+            ) as handle:
+                # the phase pays for its own status write and device
+                # sample: nothing of a build lies between two phases
+                if self.progress is not None:
+                    self.progress.phase(name)
+                self._enter_phase(name, handle.span_id or None)
+                try:
+                    yield
+                finally:
+                    self._sample_device(name)
         finally:
-            self._current_phase = previous_phase
-            self.phase_seconds[name] += time.time() - start
-            self._sample_device(name)
+            self._enter_phase(*previous)
+            self.phase_seconds[name] += time.perf_counter() - started
+
+    def _enter_phase(self, name: str, span_id: Optional[str]) -> None:
+        self._current_phase, self._phase_span_id = name, span_id
+        if self.recorder.enabled:
+            # telemetry.part_span (the trainer) reads it
+            self.recorder.phase = name
+
+    def _part(self, name: str, **attributes):
+        """One named piece of work inside the running phase, as a
+        ``build_part`` span under the phase's span — also from a pool
+        thread, which has no enclosing span of its own. The span
+        listener folds its seconds into
+        ``build_status.json["phases"][phase]["parts"][name]``."""
+        return self.recorder.span(
+            "build_part",
+            parent_id=self._phase_span_id,
+            phase=self._current_phase,
+            part=name,
+            **attributes,
+        )
+
+    def _record_part(self, name: str, seconds: float, count: int) -> None:
+        """``count`` pieces of one kind of work as ONE ``build_part``
+        span of their summed seconds: per machine-fold or per artifact
+        they would cost more lines than they are worth (docs/
+        observability.md, the span budget)."""
+        self.recorder.record(
+            "build_part",
+            seconds,
+            phase=self._current_phase,
+            part=name,
+            count=count,
+        )
+
+    def _record_phase(self, name: str, seconds: float) -> None:
+        """A phase timed by the caller, ending now: ``config_load`` ran
+        before this build had a recorder."""
+        self.phase_seconds[name] += seconds
+        if self.progress is not None:
+            self.progress.phase(name)
+        self.recorder.record(
+            "build_phase", seconds, phase=name, machines=len(self.machines)
+        )
+
+    @staticmethod
+    def _annotation(name: str, attributes: Dict[str, Any]):
+        """The recorder's ``annotate`` hook: phases, parts and device
+        programs as ``jax.profiler.TraceAnnotation``s, so a profiler
+        session (``GORDO_TPU_PROFILE_DIR``'s, the chip benchmark's) holds
+        them in its host plane, on its own clock."""
+        if name == "build_phase":
+            return annotate(f"build_phase:{attributes.get('phase')}")
+        if name == "build_part":
+            return annotate(
+                f"build_part:{attributes.get('phase')}/{attributes.get('part')}"
+            )
+        if name == "device_program":
+            return annotate(f"device_program:{attributes.get('program')}")
+        return None
 
     def _sample_device(self, phase: str) -> None:
         """Emit a ``device_utilization`` event (HBM in-use/peak +
@@ -394,6 +463,8 @@ class FleetBuilder:
         model_register_dir: Optional[str] = None,
         replace_cache: bool = False,
         resume: bool = False,
+        started: Optional[float] = None,
+        report: bool = False,
     ) -> List[Tuple[Any, Machine]]:
         """
         Train the whole fleet; optionally dump per-machine artifacts to
@@ -418,6 +489,14 @@ class FleetBuilder:
         ``build_status.json`` beside the journal, and exports phase/
         compile durations, member final losses and machine-progress
         gauges to Prometheus as they happen.
+
+        ``started`` and ``report`` bring the command's own work under
+        the build's phases: the time since ``started`` (a
+        ``time.perf_counter()`` stamp taken where the caller began
+        loading its config) is recorded as phase ``config_load``, and
+        with ``report`` every built machine's reporters
+        (``Machine.report``) run as the last phase, ``report``, before
+        the status turns ``complete``.
         """
         self.build_errors = {}
         self.phase_seconds = defaultdict(float)
@@ -428,6 +507,8 @@ class FleetBuilder:
         self._plan_actuals = defaultdict(float)
         self._member_actuals = defaultdict(int)
         self._device_peak_bytes = 0
+        self._current_phase, self._phase_span_id = "", None
+        self._ledger_flushed = False
         self._project = self.machines[0].project_name if self.machines else ""
         # where this build runs, asked once: the status document and
         # every artifact's metadata carry the same answer
@@ -460,6 +541,7 @@ class FleetBuilder:
             recorder = telemetry.SpanRecorder(
                 sink_path=trace_path, service="gordo-tpu-fleet-build"
             )
+            recorder.annotate = self._annotation
             recorder.add_listener(self._export_span)
             self.progress = telemetry.BuildProgress(
                 output_dir,
@@ -471,13 +553,21 @@ class FleetBuilder:
             )
             self._update_progress_gauges()
         self.recorder = recorder
+        watch_compile_path()
+        compile_before = compile_path_counters()
         try:
-            with telemetry.activate(recorder):
+            # GORDO_TPU_PROFILE_DIR: one device trace of the whole
+            # build, its phases, parts and programs annotated in it
+            with maybe_trace("fleet-build"), telemetry.activate(recorder):
                 with recorder.span(
                     "fleet_build",
                     project=self._project,
                     machines=len(self.machines),
-                ):
+                ) as root:
+                    if started is not None:
+                        self._record_phase(
+                            "config_load", time.perf_counter() - started
+                        )
                     try:
                         results = self._run_build(
                             output_dir, model_register_dir, replace_cache, resume
@@ -491,6 +581,20 @@ class FleetBuilder:
                         # caller had passed it.
                         self.trainer.fleet_plan = self._external_plan
                         self.trainer.plan_strategy = self._external_strategy
+                    if report:
+                        with self._phase("report"):
+                            for _, machine in results:
+                                machine.report()
+                    # what this build spent on the way to its
+                    # executables: tracing, lowering, compiling, loading
+                    compile_after = compile_path_counters()
+                    compile_path = {
+                        key: round(value - compile_before[key], 6)
+                        for key, value in compile_after.items()
+                    }
+                    root.set(**compile_path)
+                    if self.progress is not None:
+                        self.progress.compile = compile_path
         except Exception:
             # a build-level failure (per-machine failures do NOT raise);
             # SystemExit/KeyboardInterrupt skip this on purpose — a
@@ -501,7 +605,9 @@ class FleetBuilder:
             raise
         finally:
             recorder.close()
-            self._ledger.flush()
+            if not self._ledger_flushed:
+                # a build that ended early never reached its finish phase
+                self._ledger.flush()
         if self.progress is not None:
             self.progress.finish("complete")
             self._update_progress_gauges()
@@ -518,73 +624,76 @@ class FleetBuilder:
         trainer_bisects_start = getattr(self.trainer, "bucket_bisects", 0)
         trainer_counts_start = dict(getattr(self.trainer, "bisect_counts", {}))
         config_hashes: Dict[str, str] = {}
-        if output_dir is not None:
-            config_hashes = {
-                m.name: ModelBuilder.calculate_cache_key(m) for m in machines
-            }
-            self._config_hashes = config_hashes
-            # Orphaned `.<name>.tmp-*` staging dirs from a killed run are
-            # dead weight either way; sweep them before anything else.
-            clean_staging_dirs(output_dir)
-            self._journal = (
-                BuildJournal.load(output_dir) if resume else BuildJournal(output_dir)
-            )
-            if resume:
-                remaining = []
-                for machine in machines:
-                    if self._journal.resumable(
-                        machine.name, config_hashes[machine.name]
-                    ):
-                        self.resumed.append(machine.name)
+        cached_results: List[Tuple[Any, Machine]] = []
+        # journal, config hashes, --resume and build-cache probing: host
+        # work before the first machine is planned
+        with self._phase("prepare"):
+            if output_dir is not None:
+                config_hashes = {
+                    m.name: ModelBuilder.calculate_cache_key(m) for m in machines
+                }
+                self._config_hashes = config_hashes
+                # Orphaned `.<name>.tmp-*` staging dirs from a killed run are
+                # dead weight either way; sweep them before anything else.
+                clean_staging_dirs(output_dir)
+                self._journal = (
+                    BuildJournal.load(output_dir) if resume else BuildJournal(output_dir)
+                )
+                if resume:
+                    remaining = []
+                    for machine in machines:
+                        if self._journal.resumable(
+                            machine.name, config_hashes[machine.name]
+                        ):
+                            self.resumed.append(machine.name)
+                        else:
+                            remaining.append(machine)
+                    machines = remaining
+                    logger.info(
+                        "Resume: %d machine(s) already built and verified, "
+                        "%d to build",
+                        len(self.resumed),
+                        len(machines),
+                    )
+                    if self.progress is not None:
+                        self.progress.resumed = len(self.resumed)
+                        self.progress.write(force=True)
+
+            if model_register_dir:
+                # register() dumps atomically under builds/ too — sweep any
+                # staging orphans a killed build left in the shared registry.
+                clean_staging_dirs(os.path.join(str(model_register_dir), "builds"))
+                to_probe, machines = machines, []
+                for machine in to_probe:
+                    cached = ModelBuilder(machine).load_cached(
+                        model_register_dir, replace_cache=replace_cache
+                    )
+                    if cached is not None:
+                        cached_results.append(cached)
                     else:
-                        remaining.append(machine)
-                machines = remaining
+                        machines.append(machine)
                 logger.info(
-                    "Resume: %d machine(s) already built and verified, "
-                    "%d to build",
-                    len(self.resumed),
+                    "Fleet cache: %d hits, %d to build",
+                    len(cached_results),
                     len(machines),
                 )
                 if self.progress is not None:
-                    self.progress.resumed = len(self.resumed)
+                    self.progress.cached = len(cached_results)
                     self.progress.write(force=True)
-
-        cached_results: List[Tuple[Any, Machine]] = []
-        if model_register_dir:
-            # register() dumps atomically under builds/ too — sweep any
-            # staging orphans a killed build left in the shared registry.
-            clean_staging_dirs(os.path.join(str(model_register_dir), "builds"))
-            to_probe, machines = machines, []
-            for machine in to_probe:
-                cached = ModelBuilder(machine).load_cached(
-                    model_register_dir, replace_cache=replace_cache
-                )
-                if cached is not None:
-                    cached_results.append(cached)
-                else:
-                    machines.append(machine)
-            logger.info(
-                "Fleet cache: %d hits, %d to build",
-                len(cached_results),
-                len(machines),
-            )
-            if self.progress is not None:
-                self.progress.cached = len(cached_results)
-                self.progress.write(force=True)
 
         with self._phase("plan"):
             plans, fallbacks = self._plan_all(machines)
-        if self.progress is not None:
-            self.progress.fallbacks = len(fallbacks)
-        if self._journal is not None:
-            for machine in machines:
-                self._journal.record(
-                    machine.name,
-                    "planned",
-                    config_hash=config_hashes.get(machine.name),
-                    flush=False,
-                )
-            self._journal.flush()
+            if self.progress is not None:
+                self.progress.fallbacks = len(fallbacks)
+            if self._journal is not None:
+                for machine in machines:
+                    self._journal.record(
+                        machine.name,
+                        "planned",
+                        config_hash=config_hashes.get(machine.name),
+                        flush=False,
+                    )
+                self._journal.flush()
         plans = self._load_all_data(plans)
         self._prepare_fleet_plan(plans, output_dir)
 
@@ -599,22 +708,21 @@ class FleetBuilder:
             in ("full_build", "cross_val_only")
         ]
         if cv_plans:
-            with maybe_trace("fleet-cross-validation"):
-                self._run_cross_validation(cv_plans)
+            self._run_cross_validation(cv_plans)
             if self._journal is not None:
-                for plan in alive(cv_plans):
-                    self._journal.record(
-                        plan.machine.name, "cv_done", flush=False
-                    )
-                self._journal.flush()
+                with self._phase("cv_finalize"):
+                    for plan in alive(cv_plans):
+                        self._journal.record(
+                            plan.machine.name, "cv_done", flush=False
+                        )
+                    self._journal.flush()
         final_plans = [
             p
             for p in alive(plans)
             if p.machine.evaluation.get("cv_mode", "full_build").lower()
             != "cross_val_only"
         ]
-        with maybe_trace("fleet-final-fit"):
-            self._run_final_fit(final_plans)
+        self._run_final_fit(final_plans)
 
         # Attribute trainer-INTERNAL bisections (resolved inside
         # FleetTrainer without surfacing here) to their machines before
@@ -637,6 +745,47 @@ class FleetBuilder:
                     results.append(self._assemble(plan))
                 except Exception as exc:
                     self._fail(plan.machine.name, exc)
+        if fallbacks or self.degraded:
+            with self._phase("sequential"):
+                self._build_sequentially(machines, fallbacks, results)
+
+        if model_register_dir:
+            with self._phase("register"):
+                for model, machine in results:
+                    try:
+                        ModelBuilder(machine).register(
+                            model, machine, model_register_dir
+                        )
+                    except Exception as exc:
+                        self._fail(machine.name, exc)
+
+        results = cached_results + results
+        if output_dir is not None:
+            with self._phase("dump"):
+                results = self._dump_all(results, output_dir)
+                # compact the per-machine event overlay into the base
+                # journal so a finished build leaves one clean state file
+                self._journal.flush()
+        # Fold in bisections the trainer resolved internally (they never
+        # surfaced as exceptions here, but they are still split-retry
+        # events an operator wants on a dashboard).
+        self.robustness["bucket_bisects"] += max(
+            0, getattr(self.trainer, "bucket_bisects", 0) - trainer_bisects_start
+        )
+        with self._phase("finish"):
+            self._record_prometheus(machines)
+            self._export_plan_accuracy()
+            self._ledger.flush()
+            self._ledger_flushed = True
+        return [
+            (model, machine)
+            for model, machine in results
+            if machine.name not in self.build_errors
+        ]
+
+    def _build_sequentially(self, machines, fallbacks, results) -> None:
+        """The machines the fleet path does not train, one by one on the
+        sequential ModelBuilder, appended to ``results``."""
         for machine in fallbacks:
             logger.info("Fleet fallback to ModelBuilder for %s", machine.name)
             try:
@@ -661,34 +810,6 @@ class FleetBuilder:
                 results.append(ModelBuilder(machine).build())
             except Exception as exc:
                 self._fail(name, exc)
-
-        if model_register_dir:
-            for model, machine in results:
-                try:
-                    ModelBuilder(machine).register(model, machine, model_register_dir)
-                except Exception as exc:
-                    self._fail(machine.name, exc)
-
-        results = cached_results + results
-        if output_dir is not None:
-            with self._phase("dump"):
-                results = self._dump_all(results, output_dir)
-            # compact the per-machine event overlay into the base journal
-            # so a finished build leaves one clean state file
-            self._journal.flush()
-        # Fold in bisections the trainer resolved internally (they never
-        # surfaced as exceptions here, but they are still split-retry
-        # events an operator wants on a dashboard).
-        self.robustness["bucket_bisects"] += max(
-            0, getattr(self.trainer, "bucket_bisects", 0) - trainer_bisects_start
-        )
-        self._record_prometheus(machines)
-        self._export_plan_accuracy()
-        return [
-            (model, machine)
-            for model, machine in results
-            if machine.name not in self.build_errors
-        ]
 
     def _export_span(self, span: dict) -> None:
         """Live Prometheus export of finished telemetry spans — phase
@@ -718,6 +839,13 @@ class FleetBuilder:
             if live is not None and padded:
                 self._member_actuals["live"] += int(live)
                 self._member_actuals["padded"] += int(padded)
+        if name == "build_part" and self.progress is not None:
+            phase = str(attrs.get("phase", ""))
+            self.progress.add_part(
+                phase, str(attrs.get("part", "")), seconds, int(attrs.get("count", 1))
+            )
+            for part, nested in telemetry.nested_part_seconds(attrs).items():
+                self.progress.add_part(phase, part, nested)
         self._feed_health_ledger(name, attrs)
         try:
             from ..server.prometheus import metrics as prom
@@ -828,10 +956,19 @@ class FleetBuilder:
         kill-injection site, so a death right after machine N leaves N
         resumable machines."""
 
+        # (metadata seconds, artifact seconds) a machine, from the pool's
+        # threads (list.append is atomic); two spans a build, not a machine
+        timings: List[Tuple[float, float]] = []
+        clock = time.perf_counter
+
         def dump_one(item):
             model, machine = item
             path = os.path.join(output_dir, machine.name)
-            serializer.dump_atomic(model, path, metadata=machine.to_dict())
+            began = clock()
+            metadata = machine.to_dict()
+            serialized = clock()
+            serializer.dump_atomic(model, path, metadata=metadata)
+            timings.append((serialized - began, clock() - serialized))
             if self._journal is not None:
                 # Record the hash too: cache-hit machines skip the planning
                 # pass (where it is normally journaled), and resume needs it.
@@ -871,6 +1008,10 @@ class FleetBuilder:
             raise
         finally:
             pool.shutdown(wait=True)
+        # serialize: the machine and its build metadata to a plain dict;
+        # write: pickle + JSON + checksum into the staging dir, renamed
+        self._record_part("serialize", sum(t[0] for t in timings), len(timings))
+        self._record_part("write", sum(t[1] for t in timings), len(timings))
         saved = []
         for (model, machine), exc in zip(to_dump, outcomes):
             if exc is not None:
@@ -1205,23 +1346,61 @@ class FleetBuilder:
                     exc,
                 )
 
-            X, y = retry_call(
-                fetch,
-                attempts=1 + max(0, self.data_retries),
-                backoff=self.data_backoff,
-                deadline=self.data_deadline,
-                no_retry=(ConfigException, InsufficientDataError),
-                on_retry=note_retry,
-            )
+            # one span a machine: their summed seconds over the phase's
+            # wall seconds are what the pool's threads gave. This thread
+            # times it (and annotates it, on the profiler's clock); the
+            # main thread, which only waits, writes the span: written
+            # from here, among sixteen threads contending for the GIL,
+            # a span cost the phase about a millisecond. The dataset's
+            # own parts ride on it as <part>_s attributes.
+            attrs = {
+                "phase": "data_fetch",
+                "part": "machine_fetch",
+                "machine": plan.machine.name,
+            }
+            began = time.perf_counter()
+            try:
+                with self._annotation("build_part", attrs):
+                    X, y = retry_call(
+                        fetch,
+                        attempts=1 + max(0, self.data_retries),
+                        backoff=self.data_backoff,
+                        deadline=self.data_deadline,
+                        no_retry=(ConfigException, InsufficientDataError),
+                        on_retry=note_retry,
+                    )
+                attrs["rows"] = len(X)
+                for name, seconds in getattr(
+                    plan.dataset, "fetch_seconds", {}
+                ).items():
+                    attrs[f"{name}_s"] = round(seconds, 6)
+            finally:
+                attrs["retries"] = plan.data_retries
+                fetched[plan.machine.name] = (
+                    time.perf_counter() - began, start, attrs
+                )
             plan.query_duration = time.time() - start
             plan.X, plan.y = X, y
+
+        fetched: Dict[str, Tuple[float, float, Dict[str, Any]]] = {}
+
+        def record_fetch(plan: _Plan, outcome):
+            if plan.machine.name in fetched:
+                seconds, began_wall, attrs = fetched.pop(plan.machine.name)
+                self.recorder.record(
+                    "build_part", seconds, start=began_wall, **attrs
+                )
+            return outcome
 
         with self._phase("data_fetch"):
             pool = concurrent.futures.ThreadPoolExecutor(self.data_workers)
             try:
-                outcomes = list(
-                    pool.map(lambda p: _try_call(load, p), plans)
-                )
+                outcomes = [
+                    record_fetch(plan, outcome)
+                    for plan, outcome in zip(
+                        plans, pool.map(lambda p: _try_call(load, p), plans)
+                    )
+                ]
             except (KeyboardInterrupt, SystemExit):
                 # Same contract as _dump_all: a shutdown signal must not
                 # wait on thousands of queued fetches (and their backoff
@@ -1322,6 +1501,45 @@ class FleetBuilder:
         start = time.time()
         fold_state: Dict[str, Dict[str, Any]] = {p.machine.name: {} for p in plans}
 
+        with self._phase("cv_split"):
+            per_plan_folds, grouped = self._split_folds(plans)
+        for config, (members, fold_items) in grouped.items():
+            live_items = [
+                (plan, fold_idx)
+                for plan, fold_idx in fold_items
+                if not self._skipped(plan.machine.name)
+            ]
+            live_members = [
+                m
+                for m, (plan, _) in zip(members, fold_items)
+                if not self._skipped(plan.machine.name)
+            ]
+            # Chunk by staged bytes: n_machines × n_folds members in ONE
+            # program is the fast path, but an unbounded super-bucket
+            # could out-size HBM on big fleets. Chunks preserve the
+            # fold-major order (threshold accumulators are last-fold-wins
+            # per machine).
+            for chunk_members, chunk_items in _chunk_by_bytes(
+                live_members, live_items, _cv_chunk_bytes()
+            ):
+                self._train_and_score_folds(
+                    chunk_members, chunk_items, config, per_plan_folds, fold_state
+                )
+
+        with self._phase("cv_finalize"):
+            for plan in plans:
+                if self._skipped(plan.machine.name):
+                    continue
+                try:
+                    self._finalize_cv(plan, fold_state[plan.machine.name])
+                except Exception as exc:
+                    self._fail(plan.machine.name, exc)
+                    continue
+                plan.cv_duration = time.time() - start
+
+    def _split_folds(self, plans: List[_Plan]):
+        """Every plan's CV splits and its fold members, grouped by fit
+        config: ``(per_plan_folds, {config: (members, [(plan, fold)])})``."""
         max_folds = 0
         per_plan_folds: Dict[str, List[Tuple[np.ndarray, np.ndarray]]] = {}
         for plan in plans:
@@ -1367,39 +1585,7 @@ class FleetBuilder:
                 members, fold_items = grouped.setdefault(plan.fit_config, ([], []))
                 members.append(member)
                 fold_items.append((plan, fold_idx))
-        for config, (members, fold_items) in grouped.items():
-            live_items = [
-                (plan, fold_idx)
-                for plan, fold_idx in fold_items
-                if not self._skipped(plan.machine.name)
-            ]
-            live_members = [
-                m
-                for m, (plan, _) in zip(members, fold_items)
-                if not self._skipped(plan.machine.name)
-            ]
-            # Chunk by staged bytes: n_machines × n_folds members in ONE
-            # program is the fast path, but an unbounded super-bucket
-            # could out-size HBM on big fleets. Chunks preserve the
-            # fold-major order (threshold accumulators are last-fold-wins
-            # per machine).
-            for chunk_members, chunk_items in _chunk_by_bytes(
-                live_members, live_items, _cv_chunk_bytes()
-            ):
-                self._train_and_score_folds(
-                    chunk_members, chunk_items, config, per_plan_folds, fold_state
-                )
-
-        with self._phase("cv_finalize"):
-            for plan in plans:
-                if self._skipped(plan.machine.name):
-                    continue
-                try:
-                    self._finalize_cv(plan, fold_state[plan.machine.name])
-                except Exception as exc:
-                    self._fail(plan.machine.name, exc)
-                    continue
-                plan.cv_duration = time.time() - start
+        return per_plan_folds, grouped
 
     @staticmethod
     def _make_member(
@@ -1601,18 +1787,24 @@ class FleetBuilder:
             )
             groups.setdefault((plan.spec, geometry), []).append((plan, fold_idx))
         for (spec, geometry), group in groups.items():
-            stacked = stack_member_params(
-                [
-                    by_name[_fold_member_name(p.machine.name, k)]
-                    for p, k in group
-                ]
-            )
-            fold_rows = []  # per item: (train_rows, window_idx, target_rows)
-            for plan, fold_idx in group:
-                train_rows, test_rows = per_plan_folds[plan.machine.name][fold_idx]
-                window_idx, target_rows = self._test_window_rows(plan, test_rows)
-                fold_rows.append((train_rows, window_idx, target_rows))
             with self._phase("cv_predict"):
+                with self._part("stack"):
+                    stacked = stack_member_params(
+                        [
+                            by_name[_fold_member_name(p.machine.name, k)]
+                            for p, k in group
+                        ]
+                    )
+                    # per item: (train_rows, window_idx, target_rows)
+                    fold_rows = []
+                    for plan, fold_idx in group:
+                        train_rows, test_rows = per_plan_folds[
+                            plan.machine.name
+                        ][fold_idx]
+                        window_idx, target_rows = self._test_window_rows(
+                            plan, test_rows
+                        )
+                        fold_rows.append((train_rows, window_idx, target_rows))
                 if geometry == ("windowed",):
                     predictions = self._predict_windowed_group(
                         spec,
@@ -1621,27 +1813,38 @@ class FleetBuilder:
                         [wi for _, wi, _ in fold_rows],
                     )
                 else:
-                    n_max = max(len(wi) for _, wi, _ in fold_rows)
-                    X = np.zeros(
-                        (len(group), n_max) + group[0][0].windows.shape[1:],
-                        np.float32,
-                    )
-                    for i, (p, _) in enumerate(group):
-                        X[i, : len(fold_rows[i][1])] = p.windows[fold_rows[i][1]]
+                    with self._part("stack"):
+                        n_max = max(len(wi) for _, wi, _ in fold_rows)
+                        X = np.zeros(
+                            (len(group), n_max) + group[0][0].windows.shape[1:],
+                            np.float32,
+                        )
+                        for i, (p, _) in enumerate(group):
+                            X[i, : len(fold_rows[i][1])] = p.windows[fold_rows[i][1]]
                     predictions = self.trainer.predict_bucket(spec, stacked, X)
             with self._phase("cv_score"):
+                # per machine-fold work, timed here and recorded as two
+                # spans a group: a span each would be 2 x members lines
+                clock = time.perf_counter
+                metric_seconds = threshold_seconds = 0.0
                 for i, (plan, fold_idx) in enumerate(group):
                     train_rows, window_idx, target_rows = fold_rows[i]
                     y_true = plan.y_arr[target_rows]
                     y_pred = predictions[i, : len(window_idx)]
                     state = fold_state[plan.machine.name]
+                    began = clock()
                     self._accumulate_metric_scores(plan, y_true, y_pred, fold_idx)
+                    scored = clock()
+                    metric_seconds += scored - began
                     if plan.detector is not None:
                         self._accumulate_thresholds(
                             plan, y_true, y_pred, fold_idx, state,
                             y_train=plan.y_arr[train_rows],
                             test_rows=target_rows,
                         )
+                        threshold_seconds += clock() - scored
+                self._record_part("metric_scores", metric_seconds, len(group))
+                self._record_part("thresholds", threshold_seconds, len(group))
 
     def _predict_windowed_group(
         self,
@@ -1655,15 +1858,16 @@ class FleetBuilder:
         trainer's mesh like the dense scoring path. ``window_idx`` gives
         each plan's window positions to predict (the fold-test windows)."""
         orders = window_idx
-        nv_max = max(len(o) for o in orders)
-        n_series_max = max(len(p.X_arr) for p in group)
-        series = np.zeros(
-            (len(group), n_series_max, group[0].X_arr.shape[1]), np.float32
-        )
-        order = np.zeros((len(group), nv_max), np.int32)
-        for i, p in enumerate(group):
-            series[i, : len(p.X_arr)] = p.X_arr
-            order[i, : len(orders[i])] = orders[i]
+        with self._part("stack"):
+            nv_max = max(len(o) for o in orders)
+            n_series_max = max(len(p.X_arr) for p in group)
+            series = np.zeros(
+                (len(group), n_series_max, group[0].X_arr.shape[1]), np.float32
+            )
+            order = np.zeros((len(group), nv_max), np.int32)
+            for i, p in enumerate(group):
+                series[i, : len(p.X_arr)] = p.X_arr
+                order[i, : len(orders[i])] = orders[i]
         return self.trainer.predict_windowed_bucket(
             spec, stacked, series, order, batch_size=self._SCORING_BATCH
         )
@@ -1941,6 +2145,12 @@ class FleetBuilder:
             for plan in member_plans:
                 self._fail(plan.machine.name, exc)
             return
+        with self._phase("assemble"):
+            self._adopt_final_results(member_plans, results, start)
+
+    def _adopt_final_results(self, member_plans, results, start) -> None:
+        """Each machine's trained parameters, history and training
+        summary onto its plan; its detector's scaler fitted."""
         for plan, result in zip(member_plans, results):
             if result.error is not None:
                 if is_device_error(result.error):

@@ -68,7 +68,7 @@ def configure_compile_cache() -> Optional[str]:
     (it asks for the platform), which is why the CLI group does not call
     it for host-only commands such as ``fleet-status``.
     """
-    from ..telemetry.device import note_compile_cache_dir, watch_persistent_cache
+    from ..telemetry.device import note_compile_cache_dir, watch_compile_path
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
@@ -81,7 +81,7 @@ def configure_compile_cache() -> Optional[str]:
     # device telemetry inventories the cache (entries/bytes on disk and
     # this process's hits/misses) for fleet-status and Prometheus
     note_compile_cache_dir(cache_dir)
-    watch_persistent_cache()
+    watch_compile_path()
     logger.debug("JAX persistent compilation cache at %s", cache_dir)
     return cache_dir
 

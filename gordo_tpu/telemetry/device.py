@@ -25,6 +25,12 @@ closes both gaps:
   ``<checkout>/.jax_cache`` on an accelerator) is inventoried by
   :func:`persistent_cache_info` (entries + bytes on disk, plus this
   process's hits and misses against it).
+- :func:`compile_path_counters` sums what JAX's own duration events
+  say the process spent on the way to its executables (tracing,
+  lowering, the backend's compile call, reading the persistent cache),
+  on the same ``jax.monitoring`` registration as the hits and misses
+  (:func:`watch_compile_path`). The fleet builder writes a build's
+  share of them into ``build_status.json["compile"]``.
 - :func:`device_identity` names where the process runs (``platform``,
   ``device_kind``, device count) — every snapshot carries it, so a run
   JAX quietly started on the CPU never reads like one on the chip.
@@ -145,7 +151,30 @@ _PERSISTENT_EVENTS = {
     "/jax/compilation_cache/cache_misses": "misses",
 }
 _persistent_counts = {"hits": 0, "misses": 0}
-_persistent_watch_installed = False
+
+#: the compile path's duration events of this JAX (0.9: all four exist,
+#: ``jax/_src/dispatch.py`` and ``jax/_src/compiler.py``): tracing a
+#: function to a jaxpr, lowering the jaxpr to MLIR, the backend's
+#: compile call, and reading an executable from the persistent cache.
+#: The backend call encloses the cache read, so ``backend_s`` below is
+#: kept net of it and the four seconds add up without counting twice.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_DURATION_KEYS = {
+    _TRACE_EVENT: "trace_s",
+    _LOWER_EVENT: "lower_s",
+    _BACKEND_EVENT: "backend_s",
+    _CACHE_LOAD_EVENT: "cache_load_s",
+}
+#: the keys of :func:`compile_path_counters`, in the order they are shown
+COMPILE_PATH_KEYS = (
+    *_DURATION_KEYS.values(), "programs", "persistent_hits", "persistent_misses"
+)
+_compile_seconds = {key: 0.0 for key in _DURATION_KEYS.values()}
+_compile_programs = 0
+_compile_watch_installed = False
 
 
 def _on_jax_event(event: str, **_kwargs: Any) -> None:
@@ -155,18 +184,52 @@ def _on_jax_event(event: str, **_kwargs: Any) -> None:
             _persistent_counts[key] += 1
 
 
-def watch_persistent_cache() -> None:
-    """Count persistent-cache hits and misses from here on (idempotent;
-    JAX offers no way to unregister a listener, so it is installed once
-    per process)."""
-    global _persistent_watch_installed
+def _on_jax_duration(event: str, seconds: float, **_kwargs: Any) -> None:
+    global _compile_programs
+    key = _DURATION_KEYS.get(event)
+    if key is not None:
+        with _cache_dir_lock:
+            _compile_seconds[key] += seconds
+            if event == _BACKEND_EVENT:
+                _compile_programs += 1
+
+
+def watch_compile_path() -> None:
+    """Count persistent-cache hits and misses, and sum the compile
+    path's seconds, from here on (idempotent; JAX offers no way to
+    unregister a listener, so the pair is installed once per process)."""
+    global _compile_watch_installed
     with _cache_dir_lock:
-        if _persistent_watch_installed:
+        if _compile_watch_installed:
             return
-        _persistent_watch_installed = True
+        _compile_watch_installed = True
     import jax.monitoring
 
     jax.monitoring.register_event_listener(_on_jax_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def compile_path_counters() -> Dict[str, Any]:
+    """What this process has spent on the way to its executables since
+    :func:`watch_compile_path`: ``trace_s`` (function to jaxpr),
+    ``lower_s`` (jaxpr to MLIR), ``backend_s`` (the backend's compile
+    call, net of cache reads), ``cache_load_s`` (executables read from
+    the persistent cache), ``programs`` (executables built or loaded)
+    and the persistent cache's ``persistent_hits`` / ``persistent_misses``.
+    A ``jax.jit`` made anew traces, lowers and loads again though
+    nothing compiles; ``FleetBuilder.build`` writes the difference over
+    a build as ``build_status.json["compile"]``."""
+    with _cache_dir_lock:
+        seconds = dict(_compile_seconds)
+        seconds["backend_s"] = max(
+            0.0, seconds["backend_s"] - seconds["cache_load_s"]
+        )
+        return {
+            **{key: round(value, 6) for key, value in seconds.items()},
+            "programs": _compile_programs,
+            "persistent_hits": _persistent_counts["hits"],
+            "persistent_misses": _persistent_counts["misses"],
+        }
 
 
 def persistent_cache_info() -> Optional[Dict[str, Any]]:

@@ -102,6 +102,14 @@ class BuildProgress:
 
             heartbeat_seconds = env_float(HEARTBEAT_ENV, DEFAULT_HEARTBEAT_SECONDS)
         self.heartbeat_seconds = max(0.0, heartbeat_seconds)
+        #: phase -> part -> [seconds, count]: what ``build_part`` spans
+        #: measured inside a phase, summed over the threads that did it
+        #: (so a pooled phase's parts can exceed its wall ``seconds``)
+        self._parts: Dict[str, Dict[str, List[float]]] = {}
+        #: what the build spent tracing, lowering, compiling and loading
+        #: programs (``telemetry.device.compile_path_counters`` deltas),
+        #: set by the builder at build end
+        self.compile: Optional[Dict[str, Any]] = None
         self._phase: Optional[str] = None
         self._phase_order: List[str] = []
         self._lock = threading.Lock()
@@ -126,6 +134,16 @@ class BuildProgress:
             self.write(force=True)
         elif changed:
             self.write(min_interval=self.PHASE_REENTRY_INTERVAL)
+
+    def add_part(
+        self, phase: str, part: str, seconds: float, count: int = 1
+    ) -> None:
+        """Fold one ``build_part`` span into ``phases[phase]["parts"]``;
+        nothing is written until the next heartbeat or phase entry."""
+        with self._lock:
+            entry = self._parts.setdefault(phase, {}).setdefault(part, [0.0, 0])
+            entry[0] += seconds
+            entry[1] += count
 
     def machine_completed(self, name: str = "") -> None:
         with self._lock:
@@ -157,6 +175,15 @@ class BuildProgress:
                 }
                 for name in self._phase_order
             }
+            for name, parts in self._parts.items():
+                if name in phases:
+                    phases[name]["parts"] = {
+                        part: {"seconds": round(seconds, 6), "count": int(count)}
+                        for part, (seconds, count) in parts.items()
+                    }
+            compile_path = (
+                {"compile": dict(self.compile)} if self.compile is not None else {}
+            )
             return {
                 "version": 1,
                 "project": self.project,
@@ -177,6 +204,7 @@ class BuildProgress:
                 "robustness": {k: int(v) for k, v in self.robustness.items()},
                 "device": self.device,
                 "phases": phases,
+                **compile_path,
             }
 
     #: floor on how often phase RE-entries rewrite the doc — the CV loop
@@ -310,4 +338,16 @@ def render_status(doc: Dict[str, Any]) -> str:
                 f"{float(entry.get('seconds', 0.0)):9.2f}  "
                 f"{entry.get('status', '')}"
             )
+            for part, measured in (entry.get("parts") or {}).items():
+                lines.append(
+                    f"    {part.ljust(max(0, name_width - 2))}  "
+                    f"{float(measured.get('seconds', 0.0)):9.2f}  "
+                    f"x{measured.get('count', 0)} (thread-seconds)"
+                )
+    compile_path = doc.get("compile")
+    if compile_path:
+        lines.append(
+            "Compile path: "
+            + ", ".join(f"{k}={v}" for k, v in compile_path.items())
+        )
     return "\n".join(lines)

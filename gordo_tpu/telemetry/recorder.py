@@ -170,15 +170,19 @@ class NullRecorder:
     enabled = False
     trace_id = ""
     default_parent_id = None
+    phase = ""
+    annotate = None
 
     @contextlib.contextmanager
-    def span(self, name: str, **attributes):
+    def span(self, name: str, parent_id: Optional[str] = None, **attributes):
         yield SpanHandle({})
 
     def event(self, name: str, **attributes) -> None:
         pass
 
-    def record(self, name: str, seconds: float, **attributes) -> None:
+    def record(
+        self, name: str, seconds: float, start: Optional[float] = None, **attributes
+    ) -> None:
         pass
 
     def emit(self, span: dict) -> None:
@@ -208,9 +212,10 @@ class SpanRecorder:
     Span/event recorder: in-memory tree + optional JSONL sink.
 
     Thread-safe — the dump/data thread pools record spans concurrently;
-    parent/child nesting is tracked per thread (a pool thread's spans
-    are roots of their own subtree, which is the truth: they do not run
-    inside the main thread's current span).
+    parent/child nesting is tracked per thread. A span opened on a pool
+    thread has no enclosing span there; the opener names the span it
+    works for with ``parent_id=`` (the fleet builder hands its pool
+    threads the running ``build_phase`` span).
 
     Every finished span is appended to ``sink_path`` as one JSON line
     the instant it closes, so a killed build leaves a complete trace of
@@ -238,6 +243,15 @@ class SpanRecorder:
         #: span id, so stage spans (and the batcher's externally-timed
         #: ``record()`` intervals) nest under the request span
         self.default_parent_id: Optional[str] = None
+        #: the build phase in progress (``FleetBuilder._phase`` keeps it),
+        #: stamped on ``build_part`` spans by :func:`part_span`
+        self.phase = ""
+        #: ``annotate(name, attributes)`` -> a context manager entered
+        #: around the span's body, or None. The fleet builder points it
+        #: at ``jax.profiler.TraceAnnotation`` so phases, parts and
+        #: device programs also lie in a profiler trace's host plane, on
+        #: the profiler's clock; the recorder itself stays stdlib-only.
+        self.annotate: Optional[Callable[[str, Dict[str, Any]], Any]] = None
         self.service = service
         self.sink_path = sink_path
         #: async sink: spans queue to a background writer thread that
@@ -290,24 +304,39 @@ class SpanRecorder:
         return stack
 
     @contextlib.contextmanager
-    def span(self, name: str, **attributes):
+    def span(self, name: str, parent_id: Optional[str] = None, **attributes):
         """Record the enclosed block as one span; exceptions mark the
-        span ``ERROR`` (with the exception repr) and propagate."""
+        span ``ERROR`` (with the exception repr) and propagate. The
+        parent is ``parent_id`` where given, else the span enclosing
+        this one on the calling thread. The start stamp is wall time;
+        the duration is taken from ``time.perf_counter()``, which no
+        clock step can stretch."""
         span_id = rand_hex(16)
         handle = SpanHandle(dict(attributes), self.trace_id, span_id)
         stack = self._stack()
-        parent_id = stack[-1] if stack else self.default_parent_id
+        if parent_id is None:
+            parent_id = stack[-1] if stack else self.default_parent_id
+        annotation = (
+            self.annotate(name, handle.attributes)
+            if self.annotate is not None
+            else None
+        )
         stack.append(span_id)
         start = time.time()
+        started = time.perf_counter()
         error: Optional[BaseException] = None
         try:
-            yield handle
+            if annotation is None:
+                yield handle
+            else:
+                with annotation:
+                    yield handle
         except BaseException as exc:
             error = exc
             raise
         finally:
+            end = start + (time.perf_counter() - started)
             stack.pop()
-            end = time.time()
             self._record(
                 self._span_dict(
                     name,
@@ -338,21 +367,28 @@ class SpanRecorder:
             )
         )
 
-    def record(self, name: str, seconds: float, **attributes) -> None:
-        """An externally-timed interval as a finished span (ends now).
+    def record(
+        self, name: str, seconds: float, start: Optional[float] = None, **attributes
+    ) -> None:
+        """An externally-timed interval as a finished span: ending now,
+        or beginning at the wall-clock stamp ``start``.
 
         For durations measured on ANOTHER thread's clock — e.g. a
         request handler folding the micro-batcher's shared stack/device
         stage times into its own Server-Timing — where a ``with span``
-        block on this recorder would double-count the wait."""
-        end = time.time()
+        block on this recorder would double-count the wait; and for work
+        a pool thread timed and the thread that waits for it writes down
+        (a sink write from a contended thread costs the pool far more
+        than the write itself: its flush gives the GIL away)."""
+        seconds = max(0.0, seconds)
+        end = time.time() if start is None else start + seconds
         stack = self._stack()
         self._record(
             self._span_dict(
                 name,
                 rand_hex(16),
                 stack[-1] if stack else self.default_parent_id,
-                end - max(0.0, seconds),
+                end - seconds,
                 end,
                 dict(attributes),
                 None,
@@ -696,6 +732,29 @@ def reset_seen_programs() -> None:
     the set for the jit caches' lifetime, which is the process)."""
     with _seen_lock:
         _seen_programs.clear()
+
+
+def nested_part_seconds(attributes: Dict[str, Any]) -> Dict[str, float]:
+    """The parts a ``build_part`` span carries as attributes instead of
+    child spans: ``<part>_s`` -> seconds. ``machine_fetch`` carries the
+    dataset's own parts so, because a span each, written from sixteen
+    pool threads, cost more than it told (PERF.md, PR 24)."""
+    return {
+        key[:-2]: float(value)
+        for key, value in attributes.items()
+        if key.endswith("_s") and isinstance(value, (int, float))
+    }
+
+
+def part_span(part: str, **attributes):
+    """A ``build_part`` span on the process-global recorder: one named
+    piece of work inside the build phase in progress (``phase`` is
+    stamped from the recorder). The trainer under the fleet builder
+    records through this, so it imports nothing of the builder;
+    ``FleetBuilder._part`` is the same span with the phase's span as
+    explicit parent, for pool threads."""
+    recorder = get_recorder()
+    return recorder.span("build_part", phase=recorder.phase, part=part, **attributes)
 
 
 def program_span(program: str, key: Hashable, **attributes):

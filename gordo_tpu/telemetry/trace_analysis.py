@@ -440,6 +440,111 @@ def stream_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     }
 
 
+def _covered_seconds(intervals: List[Tuple[float, float]]) -> float:
+    """Length of the union of ``(start, end)`` intervals."""
+    total, reach = 0.0, None
+    for start, end in sorted(intervals):
+        if reach is None or start > reach:
+            total += end - start
+            reach = end
+        elif end > reach:
+            total += end - reach
+            reach = end
+    return total
+
+
+def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
+    """
+    Where a fleet build's time went, from its ``build_phase`` and
+    ``build_part`` spans: per phase (summed over its re-entries) the wall
+    ``seconds``, the ``parts`` recorded inside it (thread-seconds and
+    count: a pooled phase's parts can exceed its wall seconds; a device
+    program run in the phase is listed as ``program <name>``) and its
+    ``self_seconds``, the wall time no part or program covers. A part
+    covers its own interval (overlapping parts count once); a part
+    recorded as a sum over many pieces (``count`` attribute) covers its
+    seconds. Only parts whose parent is the phase cover it: a part
+    nested in another (the fetch of predictions inside its program; the
+    dataset's parts, which ``machine_fetch`` carries as ``<part>_s``
+    attributes) is in the table and already inside its parent's
+    interval. ``compile`` is the compile path's seconds from the
+    ``fleet_build`` root span. None when the trace holds no build phases.
+    """
+    from .aggregate import parse_span_time
+    from .device import COMPILE_PATH_KEYS
+    from .recorder import nested_part_seconds
+
+    phases: Dict[str, Dict[str, Any]] = {}
+    phase_spans: Dict[str, Tuple[str, float, float]] = {}
+    parts: List[dict] = []
+    compile_path = None
+    for span in spans:
+        name = span.get("name")
+        attributes = span.get("attributes") or {}
+        seconds = float(span.get("duration_ms", 0.0)) / 1000.0
+        if name == "build_phase":
+            phase = str(attributes.get("phase", ""))
+            entry = phases.setdefault(
+                phase, {"entries": 0, "seconds": 0.0, "self_seconds": 0.0, "parts": {}}
+            )
+            entry["entries"] += 1
+            entry["seconds"] += seconds
+            start = parse_span_time(span.get("start_time")) or 0.0
+            span_id = (span.get("context") or {}).get("span_id", "")
+            phase_spans[span_id] = (phase, start, start + seconds)
+        elif name in ("build_part", "device_program"):
+            parts.append(span)
+        elif name == "fleet_build" and "trace_s" in attributes:
+            compile_path = {
+                key: attributes[key] for key in COMPILE_PATH_KEYS if key in attributes
+            }
+    if not phases:
+        return None
+    covering: Dict[str, List[Tuple[float, float]]] = {}
+    summed: Dict[str, float] = {}
+
+    def add(phase: str, label: str, seconds: float, count: int) -> None:
+        part = phases[phase]["parts"].setdefault(label, {"seconds": 0.0, "count": 0})
+        part["seconds"] += seconds
+        part["count"] += count
+
+    for span in parts:
+        attributes = span.get("attributes") or {}
+        seconds = float(span.get("duration_ms", 0.0)) / 1000.0
+        parent = span.get("parent_id") or ""
+        if span["name"] == "device_program":
+            # a program covers its phase like a part; it names no phase
+            phase = phase_spans[parent][0] if parent in phase_spans else ""
+            label = f"program {attributes.get('program', '')}"
+        else:
+            phase = str(attributes.get("phase", ""))
+            label = str(attributes.get("part", ""))
+        if phase in phases:
+            add(phase, label, seconds, int(attributes.get("count", 1)))
+            if span["name"] == "build_part":
+                for nested, nested_seconds in nested_part_seconds(attributes).items():
+                    add(phase, nested, nested_seconds, 1)
+        if parent not in phase_spans:
+            continue
+        if "count" in attributes:
+            summed[parent] = summed.get(parent, 0.0) + seconds
+            continue
+        _, phase_start, phase_end = phase_spans[parent]
+        start = parse_span_time(span.get("start_time")) or 0.0
+        start, end = max(start, phase_start), min(start + seconds, phase_end)
+        if end > start:
+            covering.setdefault(parent, []).append((start, end))
+    for span_id, (phase, start, end) in phase_spans.items():
+        covered = _covered_seconds(covering.get(span_id, [])) + summed.get(span_id, 0.0)
+        phases[phase]["self_seconds"] += max(0.0, (end - start) - covered)
+    for entry in phases.values():
+        entry["seconds"] = round(entry["seconds"], 6)
+        entry["self_seconds"] = round(entry["self_seconds"], 6)
+        for part in entry["parts"].values():
+            part["seconds"] = round(part["seconds"], 6)
+    return {"phases": phases, "compile": compile_path}
+
+
 def prediction_accuracy(
     spans: Iterable[dict],
 ) -> Optional[Dict[str, Dict[str, Any]]]:
@@ -518,7 +623,8 @@ def analyze_trace(
     """The full analysis document for one trace (a file path, or a list
     of sink bases to read-merge — the per-worker variants of one
     logical trace): span summaries, the request breakdown, the stream-
-    session breakdown, and the aggregated profile — the JSON shape
+    session breakdown, the build-phase breakdown with each phase's parts
+    and self time, and the aggregated profile — the JSON shape
     ``gordo-tpu trace --as-json``
     prints and the tests golden-check. ``since_ts``/``until_ts``
     restrict the analysis to a time window (``--since``/``--last``);
@@ -539,6 +645,7 @@ def analyze_trace(
         "span_summary": summarize_spans(spans),
         "request_breakdown": request_breakdown(spans),
         "stream_breakdown": stream_breakdown(spans),
+        "build_breakdown": build_breakdown(spans),
         "prediction_accuracy": prediction_accuracy(spans),
         "profile_frames": top_profile_frames(spans),
     }
@@ -682,6 +789,28 @@ def render_analysis(doc: Dict[str, Any]) -> str:
                 out.append(
                     f"critical path ({stream_id}, median): {path_text}"
                 )
+
+    build = doc.get("build_breakdown")
+    if build:
+        out.append(
+            "\nBuild phases (seconds; self = wall time no part covers; "
+            "parts in thread-seconds):"
+        )
+        rows: List[List[Any]] = []
+        for phase, entry in build["phases"].items():
+            rows.append(
+                [phase, entry["entries"], entry["seconds"], entry["self_seconds"]]
+            )
+            for part, measured in entry["parts"].items():
+                rows.append(
+                    [f"  {part}", measured["count"], measured["seconds"], ""]
+                )
+        out.append(_table(rows, ["phase / part", "count", "seconds", "self"]))
+        if build.get("compile"):
+            out.append(
+                "compile path: "
+                + ", ".join(f"{k}={v}" for k, v in build["compile"].items())
+            )
 
     accuracy = doc.get("prediction_accuracy")
     if accuracy:

@@ -12,8 +12,11 @@ TensorBoard-loadable trace (``jax.profiler``) under
 This is the heavyweight, opt-in layer: raw XLA device traces for deep
 kernel work. The always-on, aggregated layer — phase spans, compile/run
 attribution, the live build-status surface — is ``gordo_tpu.telemetry``
-(docs/observability.md); the two compose (a ``maybe_trace`` region can
-enclose spans and vice versa).
+(docs/observability.md). The two meet in :func:`annotate`: the fleet
+builder enters it for every ``build_phase``, ``build_part`` and
+``device_program`` span, so whichever profiler session is running (a
+``maybe_trace`` region, the chip benchmark's own) holds the build's host
+work on the profiler's clock, beside the device's lines.
 """
 # gt-lint: file-disable=jax-stdlib-only -- this module IS the jax.profiler
 # wrapper; the import stays lazy so the utils package imports clean on
@@ -47,10 +50,11 @@ def maybe_trace(label: str):
 
 
 def annotate(label: str):
-    """A ``jax.profiler.TraceAnnotation`` (shows up as a named region in the
-    trace viewer) when profiling is on; a null context otherwise."""
-    if not env_str(PROFILE_DIR_ENV, None):
-        return contextlib.nullcontext()
+    """A ``jax.profiler.TraceAnnotation``: a named region in the host
+    plane of whichever profiler session is running, on that profiler's
+    clock. The one way into ``TraceAnnotation`` for the build path. Not
+    gated on ``GORDO_TPU_PROFILE_DIR``: the session may be someone
+    else's, and without one the annotation records nothing."""
     import jax
 
     return jax.profiler.TraceAnnotation(label)

@@ -1,0 +1,576 @@
+"""
+Inside the build phases (PR 24): ``build_part`` spans and the seconds
+they leave in ``build_status.json``, the compile path's counters, the
+profiler annotations on the spans, and what all of it may cost in lines.
+"""
+
+import concurrent.futures
+import glob
+import json
+import os
+import time
+
+import pytest
+
+from gordo_tpu import telemetry
+from gordo_tpu.machine import Machine
+from gordo_tpu.parallel import FleetBuilder
+from gordo_tpu.telemetry import BuildProgress, SpanRecorder, device, load_status
+from gordo_tpu.telemetry.progress import BUILD_TRACE_FILE
+from gordo_tpu.telemetry.trace_analysis import build_breakdown, render_analysis
+
+from .test_trace_schema import assert_span_schema
+
+pytestmark = pytest.mark.observability
+
+MODEL = {
+    "gordo_tpu.models.anomaly.diff.DiffBasedAnomalyDetector": {
+        "base_estimator": {
+            "gordo_tpu.models.JaxAutoEncoder": {
+                "kind": "feedforward_hourglass",
+                "encoding_layers": 1,
+                "epochs": 1,
+            }
+        }
+    }
+}
+
+#: parts a dense job must record, by phase
+REQUIRED_PARTS = {
+    "data_fetch": {"machine_fetch", "provider_read", "resample_join", "row_filter"},
+    "cv_train": {"stack", "h2d", "init", "collect"},
+    "final_fit": {"stack", "h2d", "init", "collect"},
+    "cv_predict": {"stack", "collect"},
+    "cv_score": {"metric_scores", "thresholds"},
+    "dump": {"serialize", "write"},
+}
+
+COMPILE_KEYS = {
+    "trace_s", "lower_s", "backend_s", "cache_load_s",
+    "programs", "persistent_hits", "persistent_misses",
+}
+
+
+def make_machine(name):
+    return Machine.from_config(
+        {
+            "name": name,
+            "model": MODEL,
+            "dataset": {
+                "type": "RandomDataset",
+                "train_start_date": "2020-01-01T00:00:00+00:00",
+                "train_end_date": "2020-01-05T00:00:00+00:00",
+                "tag_list": ["t1", "t2"],
+            },
+        },
+        project_name="parts-test",
+    )
+
+
+@pytest.fixture(scope="module")
+def built(tmp_path_factory):
+    """One two-machine build with its config load and reporters handed
+    to the builder, as the ``build-fleet`` command does."""
+    out = str(tmp_path_factory.mktemp("parts") / "out")
+    started = time.perf_counter()
+    builder = FleetBuilder([make_machine("bp-a"), make_machine("bp-b")])
+    results = builder.build(output_dir=out, started=started, report=True)
+    assert len(results) == 2
+    with open(os.path.join(out, BUILD_TRACE_FILE)) as f:
+        spans = [json.loads(line) for line in f]
+    return builder, spans, load_status(out)
+
+
+def by_id(spans):
+    return {s["context"]["span_id"]: s for s in spans}
+
+
+# -- the recorder ---------------------------------------------------------------
+
+
+def test_record_places_an_interval_where_its_start_says():
+    rec = SpanRecorder()
+    with rec.span("build_phase", phase="data_fetch") as phase:
+        rec.record("build_part", 2.5, start=1_700_000_000.0, part="machine_fetch")
+        rec.record("queue_wait", 0.25)
+    placed, now = rec.finished("build_part")[0], rec.finished("queue_wait")[0]
+    assert placed["start_time"].startswith("2023-11-14T22:13:20")
+    assert placed["end_time"].startswith("2023-11-14T22:13:22.5")
+    assert placed["duration_ms"] == 2500.0
+    assert placed["parent_id"] == now["parent_id"] == phase.span_id
+    assert placed["attributes"] == {"part": "machine_fetch"}
+    assert now["duration_ms"] == 250.0 and now["end_time"] > "2024"
+
+
+def test_explicit_parent_wins_over_the_thread_stack():
+    rec = SpanRecorder()
+    with rec.span("outer") as outer:
+        with rec.span("inner", parent_id="f" * 16):
+            pass
+        with rec.span("nested"):
+            pass
+    spans = {s["name"]: s for s in rec.finished()}
+    assert spans["inner"]["parent_id"] == "f" * 16
+    assert spans["nested"]["parent_id"] == outer.span_id
+    assert "parent_id" not in spans["inner"]["attributes"]
+
+
+def test_a_pool_thread_span_names_its_parent():
+    """A pool thread has no enclosing span: without ``parent_id`` its
+    span is an orphan, with it a child of the span it works for."""
+    rec = SpanRecorder()
+    with rec.span("build_phase", phase="data_fetch") as phase:
+
+        def work(parent):
+            with rec.span("build_part", parent_id=parent, part="machine_fetch"):
+                pass
+
+        with concurrent.futures.ThreadPoolExecutor(2) as pool:
+            list(pool.map(work, [phase.span_id, None]))
+    parents = sorted(
+        str(s["parent_id"]) for s in rec.finished("build_part")
+    )
+    assert parents == sorted([phase.span_id, "None"])
+
+
+def test_duration_is_monotonic_and_the_start_stamp_wall_time(monkeypatch):
+    """A wall clock stepped back inside a span cannot make its duration
+    negative: the duration comes from ``time.perf_counter()``."""
+    from gordo_tpu.telemetry import recorder as recorder_module
+
+    stamps = iter([1_700_000_100.0, 1_700_000_000.0])
+    real_time = time.time
+    monkeypatch.setattr(
+        recorder_module.time, "time", lambda: next(stamps, real_time())
+    )
+    rec = SpanRecorder()
+    with rec.span("stepped"):
+        time.sleep(0.01)
+    (span,) = rec.finished()
+    assert span["duration_ms"] >= 10.0
+    assert span["start_time"].startswith("2023-11-14")
+    assert span["end_time"] >= span["start_time"]
+
+
+def test_the_annotate_hook_wraps_the_body_of_the_spans_it_takes():
+    entered = []
+
+    class Region:
+        def __init__(self, label):
+            self.label = label
+
+        def __enter__(self):
+            entered.append(("enter", self.label))
+
+        def __exit__(self, *exc):
+            entered.append(("exit", self.label))
+
+    rec = SpanRecorder()
+    rec.annotate = lambda name, attrs: (
+        Region(f"{name}:{attrs.get('phase')}") if name == "build_phase" else None
+    )
+    with rec.span("build_phase", phase="plan"):
+        entered.append(("body", None))
+    with rec.span("other"):
+        pass
+    with pytest.raises(ValueError):
+        with rec.span("build_phase", phase="dump"):
+            raise ValueError("boom")
+    assert entered == [
+        ("enter", "build_phase:plan"), ("body", None), ("exit", "build_phase:plan"),
+        ("enter", "build_phase:dump"), ("exit", "build_phase:dump"),
+    ]
+    assert rec.finished("build_phase")[1]["status"]["status_code"] == "ERROR"
+
+
+def test_part_span_stamps_the_recorders_phase():
+    rec = SpanRecorder()
+    rec.phase = "cv_train"
+    with telemetry.activate(rec):
+        with telemetry.part_span("stack", rows=3):
+            pass
+    (span,) = rec.finished("build_part")
+    assert span["attributes"] == {"phase": "cv_train", "part": "stack", "rows": 3}
+    # without a build the same call records nothing and costs nothing
+    with telemetry.part_span("stack"):
+        pass
+    assert telemetry.get_recorder() is telemetry.NULL_RECORDER
+    assert telemetry.NULL_RECORDER.phase == ""
+
+
+# -- the builder ----------------------------------------------------------------
+
+
+def test_builder_part_from_a_pool_thread_hangs_under_the_running_phase():
+    builder = FleetBuilder([make_machine("pp-a")])
+    builder.recorder = rec = SpanRecorder()
+    with builder._phase("dump"):
+        with builder._part("on-main"):
+            pass
+        with concurrent.futures.ThreadPoolExecutor(1) as pool:
+            pool.submit(_part_in_thread, builder).result(timeout=30)
+    (phase,) = rec.finished("build_phase")
+    parts = {s["attributes"]["part"]: s for s in rec.finished("build_part")}
+    assert parts["on-main"]["parent_id"] == parts["whole"]["parent_id"] == phase["context"]["span_id"]
+    assert parts["whole"]["attributes"] == {"phase": "dump", "part": "whole"}
+    assert rec.phase == "" and builder._phase_span_id is None  # restored at the phase's end
+
+
+def _part_in_thread(builder):
+    with builder._part("whole"):
+        pass
+
+
+def test_parts_nest_under_their_phase_from_the_main_and_from_pool_threads(built):
+    _, spans, _ = built
+    index = by_id(spans)
+    parts = [s for s in spans if s["name"] == "build_part"]
+    assert parts
+    for span in parts:
+        assert_span_schema(span)
+        # every part hangs in the tree under the phase it names: directly,
+        # or inside another part or a device program of that phase
+        node = span
+        while node["name"] != "build_phase":
+            assert node["parent_id"] in index, (span["attributes"], node["name"])
+            node = index[node["parent_id"]]
+        assert node["attributes"]["phase"] == span["attributes"]["phase"]
+    fetches = [s for s in parts if s["attributes"]["part"] == "machine_fetch"]
+    assert len(fetches) == 2  # one a machine: timed on the pool's threads, written by the main
+    for span in fetches:
+        assert index[span["parent_id"]]["attributes"]["phase"] == "data_fetch"
+    # the trainer's parts (the global recorder) hang under the phase too,
+    # the fetch of predictions inside its program
+    inits = [s for s in parts if s["attributes"]["part"] == "init"]
+    assert {index[s["parent_id"]]["attributes"]["phase"] for s in inits} == {
+        "cv_train", "final_fit",
+    }
+    (collect,) = [
+        s for s in parts
+        if s["attributes"]["part"] == "collect"
+        and s["attributes"]["phase"] == "cv_predict"
+    ]
+    assert index[collect["parent_id"]]["name"] == "device_program"
+
+
+def test_machine_fetch_carries_machine_rows_retries_and_the_datasets_parts(built):
+    """One line a machine: the dataset's own parts ride on it as
+    ``<part>_s`` attributes, not as spans of their own."""
+    _, spans, _ = built
+    fetches = [
+        s for s in spans
+        if s["name"] == "build_part" and s["attributes"]["part"] == "machine_fetch"
+    ]
+    assert sorted(s["attributes"]["machine"] for s in fetches) == ["bp-a", "bp-b"]
+    for span in fetches:
+        attributes = span["attributes"]
+        assert set(attributes) == {
+            "phase", "part", "machine", "rows", "retries",
+            "provider_read_s", "resample_join_s", "row_filter_s",
+        }
+        assert attributes["rows"] > 0 and attributes["retries"] == 0
+        nested = telemetry.nested_part_seconds(attributes)
+        assert set(nested) == {"provider_read", "resample_join", "row_filter"}
+        assert 0 < sum(nested.values()) <= span["duration_ms"] / 1000.0
+    assert not [
+        s for s in spans
+        if s["name"] == "build_part" and s["attributes"]["part"] == "provider_read"
+    ]
+
+
+@pytest.mark.parametrize("phase", sorted(REQUIRED_PARTS))
+def test_status_holds_the_parts_of_each_phase(built, phase):
+    builder, _, status = built
+    entry = status["phases"][phase]
+    assert set(entry["parts"]) >= REQUIRED_PARTS[phase]
+    workers = builder.data_workers if phase == "data_fetch" else 8
+    for part, measured in entry["parts"].items():
+        assert measured["count"] >= 1 and measured["seconds"] >= 0.0
+        # thread-seconds: no more than the phase's wall seconds on
+        # every worker at once
+        assert measured["seconds"] <= entry["seconds"] * workers + 1e-3, part
+
+
+def test_a_phase_without_parts_is_written_as_before(built):
+    _, _, status = built
+    for phase in ("plan", "stage", "assemble", "cv_finalize"):
+        assert set(status["phases"][phase]) == {"seconds", "status"}
+
+
+def test_config_load_and_report_are_phases_and_complete_is_the_last_write(built):
+    _, spans, status = built
+    assert status["state"] == "complete" and status["phase"] is None
+    order = list(status["phases"])
+    assert order[0] == "config_load" and order[-1] == "report"
+    assert status["phases"]["config_load"]["seconds"] > 0
+    phases = [s for s in spans if s["name"] == "build_phase"]
+    assert phases[0]["attributes"]["phase"] == "config_load"
+    assert phases[-1]["attributes"]["phase"] == "report"
+    for span in phases:
+        assert span["end_time"] >= span["start_time"]
+
+
+def test_almost_nothing_of_a_build_lies_between_its_phases(built):
+    _, spans, status = built
+    root = next(s for s in spans if s["name"] == "fleet_build")
+    inside = sum(
+        entry["seconds"] for name, entry in status["phases"].items()
+        if name != "config_load"
+    )
+    assert inside <= root["duration_ms"] / 1000.0 + 1e-3
+    assert inside >= 0.97 * root["duration_ms"] / 1000.0
+
+
+def test_compile_counters_in_the_status_and_on_the_root_span(built):
+    _, spans, status = built
+    assert set(status["compile"]) == COMPILE_KEYS
+    assert all(value >= 0 for value in status["compile"].values())
+    # the build traced, lowered and built its programs in this process
+    assert status["compile"]["programs"] >= 1
+    assert status["compile"]["trace_s"] > 0 and status["compile"]["lower_s"] > 0
+    root = next(s for s in spans if s["name"] == "fleet_build")
+    assert {k: root["attributes"][k] for k in COMPILE_KEYS} == status["compile"]
+
+
+def test_span_budget_of_a_two_machine_build(built):
+    """At most 5 added spans a machine and 8 a device program over the
+    lines the parent wrote (which for this job were 45: its phases,
+    programs and per-machine events)."""
+    _, spans, _ = built
+    machines = 2
+    programs = sum(1 for s in spans if s["name"] == "device_program")
+    assert programs == 3
+    parts = [s for s in spans if s["name"] == "build_part"]
+    per_machine = [s for s in parts if s["attributes"]["part"] == "machine_fetch"]
+    assert len(per_machine) == machines
+    assert len(parts) - len(per_machine) <= 8 * programs
+    phases = {s["attributes"]["phase"] for s in spans if s["name"] == "build_phase"}
+    assert len(spans) <= 45 + 5 * machines + 8 * programs + len(
+        phases & {"config_load", "report", "prepare", "cv_split", "finish"}
+    )
+
+
+def test_telemetry_off_records_no_parts_and_builds_the_same(tmp_path, monkeypatch):
+    monkeypatch.setenv(telemetry.TELEMETRY_ENV, "0")
+    out = str(tmp_path / "out")
+    builder = FleetBuilder([make_machine("off-a")])
+    results = builder.build(output_dir=out, started=time.perf_counter(), report=True)
+    assert len(results) == 1
+    assert not os.path.exists(os.path.join(out, BUILD_TRACE_FILE))
+    assert load_status(out) is None
+    assert telemetry.NULL_RECORDER.phase == ""  # the shared null recorder stays clean
+    assert builder.phase_seconds["config_load"] > 0 and "report" in builder.phase_seconds
+
+
+def test_a_failing_reporter_fails_the_build_like_any_phase(tmp_path, monkeypatch):
+    out = str(tmp_path / "out")
+    monkeypatch.setattr(
+        Machine, "report", lambda self: (_ for _ in ()).throw(RuntimeError("no sink"))
+    )
+    with pytest.raises(RuntimeError, match="no sink"):
+        FleetBuilder([make_machine("rep-a")]).build(output_dir=out, report=True)
+    assert load_status(out)["state"] == "failed"
+
+
+# -- the profiler's clock ---------------------------------------------------------
+
+
+def test_phases_parts_and_programs_are_annotations_in_a_profiler_session(
+    tmp_path, monkeypatch
+):
+    """With a session that is not ``GORDO_TPU_PROFILE_DIR``'s (the chip
+    benchmark's, here the test's own) the build's phases, parts and
+    device programs lie in the trace's host plane."""
+    import jax
+    from jax.profiler import ProfileData
+
+    monkeypatch.delenv("GORDO_TPU_PROFILE_DIR", raising=False)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 1  # what benchmarks/chip/procs/common.Trace asks for
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=options)
+    try:
+        FleetBuilder([make_machine("an-a")]).build(output_dir=str(tmp_path / "out"))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(event.name for event in line.events)
+    assert "build_phase:data_fetch" in names and "build_phase:cv_train" in names
+    assert "build_part:data_fetch/machine_fetch" in names
+    assert "dataset:provider_read" in names  # the dataset's own, by the same door
+    assert "build_part:cv_train/init" in names  # the trainer's
+    assert "device_program:fleet_fit" in names
+    assert "device_program:fleet_predict" in names
+
+
+# -- the compile path's counters -------------------------------------------------
+
+
+def test_compile_path_counters_sum_durations_and_keep_backend_net_of_cache_reads():
+    device.watch_compile_path()
+    device.watch_compile_path()  # idempotent
+    before = device.compile_path_counters()
+    assert set(before) == COMPILE_KEYS
+    device._on_jax_duration(device._TRACE_EVENT, 0.25, fun_name="f")
+    device._on_jax_duration(device._LOWER_EVENT, 0.5)
+    # the backend call that encloses a cache read of 0.75 s
+    device._on_jax_duration(device._BACKEND_EVENT, 1.0)
+    device._on_jax_duration(device._CACHE_LOAD_EVENT, 0.75)
+    device._on_jax_duration("/jax/some/other_duration", 9.0)
+    device._on_jax_event("/jax/compilation_cache/cache_hits")
+    after = device.compile_path_counters()
+    moved = {key: round(after[key] - before[key], 6) for key in COMPILE_KEYS}
+    assert moved == {
+        "trace_s": 0.25, "lower_s": 0.5, "backend_s": 0.25, "cache_load_s": 0.75,
+        "programs": 1, "persistent_hits": 1, "persistent_misses": 0,
+    }
+
+
+def test_a_jit_made_anew_shows_in_the_counters():
+    """What ``job_retrace_s`` reads: a fresh ``jax.jit`` of a known
+    function traces and lowers again even though nothing is new."""
+    import jax
+    import jax.numpy as jnp
+
+    device.watch_compile_path()
+    x = jnp.ones(3)
+    readings = []
+    for _ in range(2):
+        before = device.compile_path_counters()
+        jax.jit(lambda x: x * 2 + 1)(x).block_until_ready()
+        after = device.compile_path_counters()
+        readings.append(after["programs"] - before["programs"])
+    assert readings == [1, 1]  # the second jit is new to JAX, not to XLA
+    assert after["trace_s"] > before["trace_s"]
+    assert after["lower_s"] > before["lower_s"]
+
+
+# -- the status document ----------------------------------------------------------
+
+
+def test_progress_parts_and_compile_only_where_recorded(tmp_path):
+    seconds = {"data_fetch": 2.0, "plan": 0.1}
+    progress = BuildProgress(str(tmp_path), project="p", total=2, phase_seconds=seconds)
+    progress.phase("plan")
+    progress.phase("data_fetch")
+    progress.add_part("data_fetch", "machine_fetch", 1.5)
+    progress.add_part("data_fetch", "machine_fetch", 2.5)
+    progress.add_part("data_fetch", "write", 4.0, count=160)
+    progress.add_part("never_entered", "x", 1.0)
+    progress.write(force=True)
+    doc = load_status(str(tmp_path))
+    assert "compile" not in doc
+    assert doc["phases"]["plan"] == {"seconds": 0.1, "status": "done"}
+    assert doc["phases"]["data_fetch"]["parts"] == {
+        "machine_fetch": {"seconds": 4.0, "count": 2},
+        "write": {"seconds": 4.0, "count": 160},
+    }
+    assert "never_entered" not in doc["phases"]
+    progress.compile = {"trace_s": 0.5, "programs": 2}
+    progress.finish("complete")
+    doc = load_status(str(tmp_path))
+    assert doc["compile"] == {"trace_s": 0.5, "programs": 2}
+    rendered = telemetry.render_status(doc)
+    assert "machine_fetch" in rendered and "x2 (thread-seconds)" in rendered
+    assert "Compile path: trace_s=0.5, programs=2" in rendered
+
+
+def test_concurrent_parts_lose_no_update(tmp_path):
+    import sys
+
+    progress = BuildProgress(None, project="p", total=1)
+    progress.phase("data_fetch")
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with concurrent.futures.ThreadPoolExecutor(16) as pool:
+            list(
+                pool.map(
+                    lambda i: progress.add_part("data_fetch", "machine_fetch", 0.5),
+                    range(2000),
+                    timeout=60,
+                )
+            )
+    finally:
+        sys.setswitchinterval(interval)
+    measured = progress.document()["phases"]["data_fetch"]["parts"]["machine_fetch"]
+    assert measured == {"seconds": 1000.0, "count": 2000}
+
+
+# -- gordo-tpu trace ----------------------------------------------------------------
+
+
+def synthetic_build_spans():
+    rec = SpanRecorder()
+    t0 = 1_700_000_000.0
+
+    def span(name, span_id, parent, start, seconds, **attributes):
+        return rec._span_dict(
+            name, span_id, parent, t0 + start, t0 + start + seconds, attributes, None
+        )
+
+    return [
+        span("fleet_build", "r" * 16, None, 0.0, 10.0, trace_s=0.25, programs=2),
+        span("build_phase", "a" * 16, "r" * 16, 0.0, 4.0, phase="data_fetch"),
+        # two pool threads overlap for 1 s: they cover 3 s of the phase's 4
+        span("build_part", "b" * 16, "a" * 16, 0.5, 2.0, phase="data_fetch", part="machine_fetch",
+             provider_read_s=0.75, rows=5),
+        span("build_part", "c" * 16, "a" * 16, 1.5, 2.0, phase="data_fetch", part="machine_fetch",
+             provider_read_s=0.25),
+        # nested in another span: in the table, covers nothing twice
+        span("build_part", "d" * 16, "b" * 16, 0.5, 1.0, phase="data_fetch", part="collect"),
+        span("build_phase", "e" * 16, "r" * 16, 4.0, 3.0, phase="cv_score"),
+        # recorded as sums: they cover their seconds
+        span("build_part", "f" * 16, "e" * 16, 5.0, 2.0, phase="cv_score", part="metric_scores", count=6),
+        span("build_part", "0" * 16, "e" * 16, 6.5, 0.5, phase="cv_score", part="thresholds", count=6),
+        span("build_phase", "1" * 16, "r" * 16, 7.0, 2.0, phase="cv_train"),
+        span("device_program", "2" * 16, "1" * 16, 7.5, 1.25, program="fleet_fit"),
+        span("build_phase", "3" * 16, "r" * 16, 9.0, 0.5, phase="cv_train"),
+    ]
+
+
+def test_build_breakdown_self_time_is_what_no_part_covers():
+    found = build_breakdown(synthetic_build_spans())
+    fetch = found["phases"]["data_fetch"]
+    assert fetch["seconds"] == 4.0 and fetch["self_seconds"] == 1.0
+    assert fetch["parts"] == {
+        "machine_fetch": {"seconds": 4.0, "count": 2},
+        "provider_read": {"seconds": 1.0, "count": 2},
+        "collect": {"seconds": 1.0, "count": 1},
+    }
+    score = found["phases"]["cv_score"]
+    assert score["self_seconds"] == 0.5
+    assert score["parts"]["metric_scores"] == {"seconds": 2.0, "count": 6}
+    train = found["phases"]["cv_train"]
+    assert train["entries"] == 2 and train["seconds"] == 2.5
+    assert train["self_seconds"] == 1.25  # the program covers like a part
+    assert train["parts"] == {"program fleet_fit": {"seconds": 1.25, "count": 1}}
+    assert found["compile"] == {"trace_s": 0.25, "programs": 2}
+    assert build_breakdown([]) is None
+
+
+def test_trace_cli_prints_the_part_table_under_each_phase(built, tmp_path):
+    from click.testing import CliRunner
+
+    from gordo_tpu.cli import gordo_tpu_cli
+
+    _, spans, _ = built
+    path = tmp_path / BUILD_TRACE_FILE
+    path.write_text("".join(json.dumps(s) + "\n" for s in spans))
+    result = CliRunner().invoke(gordo_tpu_cli, ["trace", str(path)])
+    assert result.exit_code == 0, result.output
+    lines = result.output.splitlines()
+    at = next(i for i, line in enumerate(lines) if line.startswith("data_fetch"))
+    assert sorted(line.split()[0] for line in lines[at + 1 : at + 5]) == [
+        "machine_fetch", "provider_read", "resample_join", "row_filter",
+    ]
+    assert any(line.startswith("  program fleet_fit") for line in lines)
+    assert any(line.startswith("compile path: trace_s=") for line in lines)
+    as_json = CliRunner().invoke(gordo_tpu_cli, ["trace", str(path), "--as-json"])
+    doc = json.loads(as_json.output)
+    assert doc["build_breakdown"]["phases"]["dump"]["self_seconds"] >= 0.0
+    assert render_analysis(doc).splitlines()[0].startswith("trace:")
