@@ -19,8 +19,14 @@ mantissa ULP; storing params in bf16 silently drops most updates and
 stalls training — measured: EV −0.02 vs 0.70 on the bf16 test fixture),
 while matmuls/activations cast to the compute dtype per use and the
 OUTPUT, losses and thresholds are always float32.
+
+The LSTM recurrence has a hand-written backward (``jax.custom_vjp``):
+the recurrent weight's gradient is one product over all timesteps after
+the backward time scan, not an accumulator carried through it; the
+undifferentiated forward is the plain ``lax.scan``. See ``_lstm_layer``.
 """
 
+import functools
 from typing import Dict, Tuple
 
 import jax
@@ -36,11 +42,10 @@ _orthogonal = jax.nn.initializers.orthogonal()
 
 
 def _lstm_unroll() -> int:
-    """Unroll factor for the recurrent scan (GORDO_TPU_LSTM_UNROLL,
-    default 4): the LSTM fleet is per-scan-step overhead-bound (see the
-    roofline in docs/architecture.md), so fusing several timesteps into
-    one scan iteration amortizes the per-step cost without changing the
-    math."""
+    """Unroll factor for the recurrent scans, forward and backward
+    (GORDO_TPU_LSTM_UNROLL, default 4): several timesteps in one scan
+    iteration, without changing the math. What a timestep costs on the
+    chip, by layer width, is in docs/architecture.md."""
     from ..utils.env import env_int
 
     return max(1, env_int("GORDO_TPU_LSTM_UNROLL", 4))
@@ -130,6 +135,106 @@ def init_lstm(rng: jax.Array, spec: LSTMSpec) -> Params:
     return params
 
 
+def _lstm_cell(act, Wh, carry, xp_t):
+    """One timestep: ``(h, c)`` and the projected input to the activated
+    gates ``(i, f, o)``, the candidate's pre-activation ``g`` and the new
+    ``(h, c)``."""
+    h, c = carry
+    gates = xp_t + h @ Wh
+    i, f, g, o = jnp.split(gates, 4, axis=-1)
+    i, f, o = jax.nn.sigmoid(i), jax.nn.sigmoid(f), jax.nn.sigmoid(o)
+    c_new = f * c + i * act(g)
+    h_new = o * act(c_new)
+    return (i, f, g, o), (h_new, c_new)
+
+
+@functools.lru_cache(maxsize=None)
+def _lstm_recurrence(activation):
+    """
+    The recurrence of one LSTM layer, ``(Wh, x_proj) -> h_seq``, with a
+    hand-written backward; built once per activation so that ``jit``
+    caches hit. :func:`_lstm_layer` documents the backward's shape.
+    """
+    act = resolve_activation(activation)
+
+    def zero_state(Wh, x_proj):
+        zeros = jnp.zeros((x_proj.shape[1], Wh.shape[0]), x_proj.dtype)
+        return zeros, zeros
+
+    def scan_forward(Wh, x_proj, keep):
+        """The forward time scan, stacking ``keep(gates, (h, c))``."""
+        Wh_c = Wh.astype(x_proj.dtype)
+
+        def step(carry, xp_t):
+            gates, carry = _lstm_cell(act, Wh_c, carry, xp_t)
+            return carry, keep(gates, carry)
+
+        _, kept = jax.lax.scan(
+            step, zero_state(Wh, x_proj), x_proj, unroll=_lstm_unroll()
+        )
+        return kept
+
+    @jax.custom_vjp
+    def recurrence(Wh, x_proj):
+        return scan_forward(Wh, x_proj, lambda gates, carry: carry[0])
+
+    def forward(Wh, x_proj):
+        h_seq, c_seq, gates_seq = scan_forward(
+            Wh,
+            x_proj,
+            lambda gates, carry: (*carry, jnp.concatenate(gates, axis=-1)),
+        )
+        return h_seq, (Wh, h_seq, c_seq, gates_seq)
+
+    def backward(residuals, dh_seq):
+        Wh, h_seq, c_seq, gates_seq = residuals
+        Wh_c = Wh.astype(h_seq.dtype)
+        h0, c0 = zero_state(Wh, h_seq)
+        h_prev_seq = jnp.concatenate([h0[None], h_seq[:-1]])
+        c_prev_seq = jnp.concatenate([c0[None], c_seq[:-1]])
+
+        def step(carry, inputs):
+            dh, dc = carry  # cotangents of h_t, c_t from the timesteps after t
+            dh_out, gates, c_prev, c_new = inputs
+            i, f, g, o = jnp.split(gates, 4, axis=-1)
+            a, a_vjp = jax.vjp(act, g)
+            ac, ac_vjp = jax.vjp(act, c_new)
+            dh = dh + dh_out
+            dc = dc + ac_vjp(dh * o)[0]
+            dgates = jnp.concatenate(
+                [
+                    dc * a * i * (1 - i),
+                    dc * c_prev * f * (1 - f),
+                    a_vjp(dc * i)[0],
+                    dh * ac * o * (1 - o),
+                ],
+                axis=-1,
+            )
+            # the one product left in the loop: contract Wh's gate axis
+            dh_prev = jnp.einsum("bg,hg->bh", dgates, Wh_c)
+            return (dh_prev, dc * f), dgates
+
+        _, dgates_seq = jax.lax.scan(
+            step,
+            (h0, c0),
+            (dh_seq, gates_seq, c_prev_seq, c_seq),
+            reverse=True,
+            unroll=_lstm_unroll(),
+        )
+        with jax.named_scope("lstm_weight_grad"):
+            dWh = jnp.einsum(
+                "tbh,tbg->hg",
+                h_prev_seq,
+                dgates_seq,
+                preferred_element_type=jnp.promote_types(h_seq.dtype, jnp.float32),
+            )
+        # x_proj enters the gates by addition: its cotangent is dgates_seq
+        return dWh.astype(Wh.dtype), dgates_seq
+
+    recurrence.defvjp(forward, backward)
+    return recurrence
+
+
 def _lstm_layer(
     layer: Dict[str, jnp.ndarray], x_seq: jnp.ndarray, activation: str
 ) -> jnp.ndarray:
@@ -141,31 +246,29 @@ def _lstm_layer(
     and the output transform (Keras LSTM semantics); gates use sigmoid.
     Compute dtype follows ``x_seq`` (the caller casts); f32 master params
     are cast at use.
+
+    Backward (hand-written, :func:`_lstm_recurrence`): the recurrent
+    weight's gradient is NOT accumulated in the backward time scan, so
+    no scan carries an array of ``Wh``'s shape. The reverse scan carries
+    only ``(dh, dc)``, keeps one product a timestep (``dgates_t`` against
+    ``Wh``'s gate axis) and stacks ``dgates_t``; after it, ``dWh`` is one
+    product over the whole ``[time * batch]`` axis, accumulated in
+    float32 (scope ``lstm_weight_grad``). ``dgates_seq`` is also the
+    cotangent of the hoisted input projection, so ``dWx``, ``db`` and
+    ``dx_seq`` come from ordinary autodiff of ``x_seq @ Wx + b``. Saved
+    from the forward: ``h_seq``, ``c_seq`` and the gates (``i, f, o``
+    after the sigmoid, the candidate before ``activation``, whose
+    derivative an arbitrary function only gives from its input); no
+    product is recomputed. Same math as autodiff of the plain scan,
+    another summation order for ``dWh``.
     """
-    act = resolve_activation(activation)
     dtype = x_seq.dtype
-    Wx, Wh = layer["Wx"].astype(dtype), layer["Wh"].astype(dtype)
-    b = layer["b"].astype(dtype)
-    units = layer["Wh"].shape[0]
-    batch = x_seq.shape[1]
-    h0 = jnp.zeros((batch, units), x_seq.dtype)
-    c0 = jnp.zeros((batch, units), x_seq.dtype)
+    Wx, b = layer["Wx"].astype(dtype), layer["b"].astype(dtype)
 
     # Hoist the input projection out of the scan: one big [T*B, F] @ [F, 4H]
     # matmul keeps the MXU busy instead of T small ones.
     x_proj = x_seq @ Wx + b
-
-    def step(carry, xp_t):
-        h, c = carry
-        gates = xp_t + h @ Wh
-        i, f, g, o = jnp.split(gates, 4, axis=-1)
-        i, f, o = jax.nn.sigmoid(i), jax.nn.sigmoid(f), jax.nn.sigmoid(o)
-        c_new = f * c + i * act(g)
-        h_new = o * act(c_new)
-        return (h_new, c_new), h_new
-
-    _, h_seq = jax.lax.scan(step, (h0, c0), x_proj, unroll=_lstm_unroll())
-    return h_seq
+    return _lstm_recurrence(activation)(layer["Wh"], x_proj)
 
 
 def forward_lstm(
