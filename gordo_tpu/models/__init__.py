@@ -3,14 +3,17 @@ from .base import GordoBase
 from .callbacks import Callback, EarlyStopping
 from .estimators import (
     JaxAutoEncoder,
+    JaxBackboneForecast,
     JaxBaseEstimator,
     JaxLSTMAutoEncoder,
     JaxLSTMBaseEstimator,
     JaxLSTMForecast,
     JaxRawModelRegressor,
+    JaxWindowedBaseEstimator,
 )
 from .register import register_model_builder
 from .spec import (
+    BackboneSpec,
     Dense,
     FeedForwardSpec,
     LSTMSpec,
@@ -33,7 +36,9 @@ __all__ = [
     "register_model_builder",
     "JaxBaseEstimator",
     "JaxAutoEncoder",
+    "JaxWindowedBaseEstimator",
     "JaxLSTMBaseEstimator",
+    "JaxBackboneForecast",
     "JaxLSTMAutoEncoder",
     "JaxLSTMForecast",
     "JaxRawModelRegressor",
@@ -44,6 +49,7 @@ __all__ = [
     "ModelSpec",
     "FeedForwardSpec",
     "LSTMSpec",
+    "BackboneSpec",
     "OptimizerSpec",
     "Sequential",
     "Dense",

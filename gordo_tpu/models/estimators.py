@@ -37,6 +37,7 @@ from .training import (
     predict_fn,
     segmented_config,
     split_fit_kwargs,
+    windowed_loss_and_grad_norms,
 )
 
 logger = logging.getLogger(__name__)
@@ -262,11 +263,15 @@ class JaxAutoEncoder(JaxBaseEstimator, TransformerMixin):
         return self.predict(X)
 
 
-class JaxLSTMBaseEstimator(JaxBaseEstimator, TransformerMixin, metaclass=abc.ABCMeta):
+class JaxWindowedBaseEstimator(
+    JaxBaseEstimator, TransformerMixin, metaclass=abc.ABCMeta
+):
     """
-    Many-to-one LSTM over sliding windows. Output is ``lookback_window +
-    lookahead - 1`` rows shorter than the input — the model-offset contract
-    that threads through builder metadata and server alignment (reference:
+    Many-to-one model over sliding windows, whatever runs inside the
+    window (stacked LSTMs, a backbone's layers: the ``kind`` factory's
+    spec says). Output is ``lookback_window + lookahead - 1`` rows
+    shorter than the input — the model-offset contract that threads
+    through builder metadata and server alignment (reference:
     gordo/machine/model/models.py:463-698).
     """
 
@@ -386,8 +391,40 @@ class JaxLSTMBaseEstimator(JaxBaseEstimator, TransformerMixin, metaclass=abc.ABC
         y = y.values if isinstance(y, pd.DataFrame) else np.asarray(y)
         return explained_variance_score(y[-len(out):], out)
 
+    def training_loss_and_grad_norms(self, X, y) -> Tuple[float, Any]:
+        """
+        The training loss of the windows of ``(X, y)``, taken as one
+        batch, at the fitted parameters, and the norm of its gradient for
+        each parameter leaf (a tree of floats shaped like ``params_``).
+        Computed by the function the windowed fit program differentiates
+        (``training.windowed_batch_loss_fn``: the same forward, loss,
+        ``compute_dtype`` and rematerialisation), so it says what one
+        more training step on these rows would see: how far from settled
+        the model is on new data, and, held against a plain
+        reimplementation, whether the step computes what it should (the
+        chip benchmark's backbone reference does that).
+        """
+        if self.params_ is None:
+            raise NotFittedError(f"This {type(self).__name__} has not been fitted yet.")
+        X = X.values if isinstance(X, pd.DataFrame) else np.asarray(X)
+        y = y.values if isinstance(y, pd.DataFrame) else np.asarray(y)
+        if y.ndim == 1:
+            y = y.reshape(-1, 1)
+        X = self._validate_and_fix_size_of_X(X)
+        return windowed_loss_and_grad_norms(
+            self.spec_,
+            self.params_,
+            X,
+            window_targets(y, self.lookback_window, self.lookahead),
+        )
+
     def transform(self, X) -> np.ndarray:
         return self.predict(X)
+
+
+#: the base's name from when every windowed model was an LSTM; pickled
+#: artifacts and callers keep it
+JaxLSTMBaseEstimator = JaxWindowedBaseEstimator
 
 
 class JaxLSTMForecast(JaxLSTMBaseEstimator):
@@ -400,6 +437,15 @@ class JaxLSTMAutoEncoder(JaxLSTMBaseEstimator):
     @property
     def lookahead(self) -> int:
         return 0
+
+
+class JaxBackboneForecast(JaxWindowedBaseEstimator):
+    """A backbone (``kind: lfm2_moe``) over a window of sensor rows,
+    predicting the next row (``KerasLSTMForecast`` semantics)."""
+
+    @property
+    def lookahead(self) -> int:
+        return 1
 
 
 class JaxRawModelRegressor(JaxAutoEncoder):

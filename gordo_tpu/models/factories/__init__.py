@@ -1,4 +1,5 @@
-from . import feedforward_autoencoder, lstm_autoencoder  # noqa: F401  (registration)
+from . import backbone, feedforward_autoencoder, lstm_autoencoder  # noqa: F401  (registration)
+from .backbone import lfm2_moe
 from .feedforward_autoencoder import (
     feedforward_hourglass,
     feedforward_model,
@@ -13,4 +14,5 @@ __all__ = [
     "lstm_model",
     "lstm_symmetric",
     "lstm_hourglass",
+    "lfm2_moe",
 ]

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.activations import resolve_activation
-from .spec import FeedForwardSpec, LSTMSpec
+from .spec import FeedForwardSpec, LSTMSpec, ModelSpec
 
 Params = Dict[str, Dict[str, jnp.ndarray]]
 
@@ -330,16 +330,14 @@ def forward_lstm_sequence(
 
 
 def init_fn_for(spec) -> "object":
-    if isinstance(spec, FeedForwardSpec):
-        return init_feedforward
-    if isinstance(spec, LSTMSpec):
-        return init_lstm
-    raise TypeError(f"No init function for spec type {type(spec).__name__}")
+    """The spec's ``(rng, spec) -> params``: the spec answers for itself."""
+    if not isinstance(spec, ModelSpec):
+        raise TypeError(f"No init function for spec type {type(spec).__name__}")
+    return spec.init_fn()
 
 
 def forward_fn_for(spec) -> "object":
-    if isinstance(spec, FeedForwardSpec):
-        return forward_feedforward
-    if isinstance(spec, LSTMSpec):
-        return forward_lstm
-    raise TypeError(f"No forward function for spec type {type(spec).__name__}")
+    """The spec's ``(spec, params, x) -> (output, penalty)``."""
+    if not isinstance(spec, ModelSpec):
+        raise TypeError(f"No forward function for spec type {type(spec).__name__}")
+    return spec.forward_fn()

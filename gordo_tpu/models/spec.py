@@ -92,7 +92,56 @@ class OptimizerSpec:
 
 
 class ModelSpec:
-    """Marker base for architecture specs; concrete specs are frozen dataclasses."""
+    """Base of the architecture specs; concrete specs are frozen
+    dataclasses. A spec answers for itself: which functions initialise
+    and run it, how many parameters it trains and what a sample costs.
+    Consumers ask the spec (``nn.init_fn_for``, ``nn.forward_fn_for``,
+    the planner's counts) instead of choosing by type."""
+
+    #: True for many-to-one models over a ``lookback_window`` of rows:
+    #: they train and score through the on-device windowing programs
+    windowed = False
+
+    #: False for a spec whose forward cannot run under ``vmap`` over a
+    #: member axis on every backend: its members train and score one to
+    #: a program (``planner.packing.trains_alone``)
+    member_axis = True
+
+    def init_fn(self):
+        """``(rng, spec) -> params``."""
+        raise TypeError(f"No init function for spec type {type(self).__name__}")
+
+    def forward_fn(self):
+        """``(spec, params, x) -> (output, penalty)``."""
+        raise TypeError(f"No forward function for spec type {type(self).__name__}")
+
+    def forward_aux_fn(self):
+        """``(spec, params, x, active=None) -> (output, penalty, aux)``
+        where the forward has counters of its own to give (a dict of
+        arrays that a fit program sums over its steps, beside
+        ``steps_run``, the steps that held data), else None. ``active
+        [batch]`` marks the samples that count in the caller's loss: a
+        fit step's padding is no work of the model's to count, or to
+        do where it can be left out."""
+        return None
+
+    def fit_counter_attrs(self, counters: Dict[str, Any]) -> Dict[str, Any]:
+        """What a fit program's ``device_program`` span and
+        ``build_status.json["fit_counters"]`` say of ``counters`` (the
+        forward's own, summed over a fit's members, epochs and steps):
+        each as a list, and whatever of the spec a reader needs to read
+        them."""
+        return {name: value.tolist() for name, value in counters.items()}
+
+    def param_count(self) -> int:
+        """Trainable parameters from the geometry alone; 0 = unknown
+        (the planner then keeps the member in a group of its own)."""
+        return 0
+
+    def flops_per_sample(self) -> float:
+        """Forward FLOPs of one sample (a row, or a window); about 2 a
+        parameter a sample is the dense-layer identity and the fallback."""
+        return 2.0 * self.param_count()
 
     def to_dict(self) -> dict:
         out: Dict[str, Any] = {"spec_type": type(self).__name__}
@@ -143,6 +192,27 @@ class FeedForwardSpec(ModelSpec):
         if self.l1_activity and len(self.l1_activity) != len(self.dims):
             raise ValueError("l1_activity must match dims length when given")
 
+    def init_fn(self):
+        from .nn import init_feedforward
+
+        return init_feedforward
+
+    def forward_fn(self):
+        from .nn import forward_feedforward
+
+        return forward_feedforward
+
+    def _widths(self) -> Tuple[int, ...]:
+        return (self.n_features,) + tuple(self.dims) + (self.n_features_out,)
+
+    def param_count(self) -> int:
+        widths = self._widths()
+        return sum(d_in * d_out + d_out for d_in, d_out in zip(widths, widths[1:]))
+
+    def flops_per_sample(self) -> float:
+        widths = self._widths()
+        return float(sum(2 * d_in * d_out for d_in, d_out in zip(widths, widths[1:])))
+
 
 @dataclass(frozen=True)
 class LSTMSpec(ModelSpec):
@@ -166,6 +236,8 @@ class LSTMSpec(ModelSpec):
     #: LSTMs serve unbatched today, so this is carried, not yet used)
     precision: str = ""
 
+    windowed = True
+
     def __post_init__(self):
         if len(self.dims) != len(self.activations):
             raise ValueError(
@@ -174,6 +246,183 @@ class LSTMSpec(ModelSpec):
             )
         if not self.dims:
             raise ValueError("LSTM spec needs at least one layer")
+
+    def init_fn(self):
+        from .nn import init_lstm
+
+        return init_lstm
+
+    def forward_fn(self):
+        from .nn import forward_lstm
+
+        return forward_lstm
+
+    def param_count(self) -> int:
+        total, d_in = 0, self.n_features
+        for d_h in self.dims:
+            # 4 gates, each [d_in + d_h, d_h] + bias
+            total += 4 * (d_in * d_h + d_h * d_h + d_h)
+            d_in = d_h
+        return total + d_in * self.n_features_out + self.n_features_out
+
+    def flops_per_sample(self) -> float:
+        """One window: the recurrence runs ``lookback_window`` steps."""
+        per_step, d_in = 0.0, self.n_features
+        for d_h in self.dims:
+            per_step += 2.0 * 4 * (d_in + d_h) * d_h
+            d_in = d_h
+        return per_step * self.lookback_window + 2.0 * d_in * self.n_features_out
+
+
+#: layer kinds of a :class:`BackboneSpec`
+OPERATORS = ("conv", "full_attention")
+FFNS = ("dense", "moe")
+
+
+@dataclass(frozen=True)
+class BackboneSpec(ModelSpec):
+    """
+    A language-model backbone as a many-to-one sensor model (ROADMAP,
+    Reach): a linear projection from ``n_features`` to ``hidden_size``
+    stands where the token embedding stood, ``layer_ops[i]`` /
+    ``layer_ffns[i]`` blocks follow at published widths (pre-norm
+    residual blocks, RMSNorm, no bias), and the final norm and a linear
+    head to ``n_features_out``, read at the window's last position,
+    stand where the LM head stood. ``models/backbone.py`` has the layer
+    equations.
+
+    The routed expert layer is a *share* layer: the router scores all
+    ``num_experts`` published experts and keeps ``num_experts_per_tok``
+    of them; this holder computes the part of the result that experts
+    ``expert_offset .. expert_offset + experts_held - 1`` give. With
+    ``experts_held == num_experts`` that is the whole layer.
+    """
+
+    n_features: int
+    n_features_out: int
+    lookback_window: int
+    layer_ops: Tuple[str, ...]
+    layer_ffns: Tuple[str, ...]
+    hidden_size: int = 2048
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    conv_L_cache: int = 3
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 1792
+    num_experts: int = 32
+    experts_held: int = 32
+    expert_offset: int = 0
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 1.0
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-5
+    optimizer: OptimizerSpec = field(default_factory=OptimizerSpec)
+    loss: str = "mse"
+    compute_dtype: str = "float32"
+    precision: str = ""
+
+    windowed = True
+
+    def __post_init__(self):
+        if len(self.layer_ops) != len(self.layer_ffns) or not self.layer_ops:
+            raise ValueError("layer_ops and layer_ffns need one entry a layer")
+        unknown = (set(self.layer_ops) - set(OPERATORS)) | (set(self.layer_ffns) - set(FFNS))
+        if unknown:
+            raise ValueError(f"unknown layer kinds {sorted(unknown)}")
+        if self.hidden_size % self.num_attention_heads:
+            raise ValueError("hidden_size must divide into num_attention_heads")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+        if not (
+            0 < self.experts_held
+            and 0 <= self.expert_offset
+            and self.expert_offset + self.experts_held <= self.num_experts
+        ):
+            raise ValueError(
+                f"experts held {self.expert_offset}..+{self.experts_held} "
+                f"are not among the {self.num_experts} published"
+            )
+        if self.num_experts_per_tok > self.num_experts:
+            raise ValueError("num_experts_per_tok exceeds num_experts")
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def member_axis(self) -> bool:
+        """The grouped products of a routed layer (``lax.ragged_dot``)
+        have no batched form on the TPU: a member a program."""
+        return "moe" not in self.layer_ffns
+
+    def init_fn(self):
+        from .backbone import init_backbone
+
+        return init_backbone
+
+    def forward_fn(self):
+        from .backbone import forward_backbone
+
+        return forward_backbone
+
+    def forward_aux_fn(self):
+        from .backbone import forward_backbone_aux
+
+        return forward_backbone_aux if "moe" in self.layer_ffns else None
+
+    def fit_counter_attrs(self, counters: Dict[str, Any]) -> Dict[str, Any]:
+        """The router counts, with which of the published experts are
+        held here: who reads ``router_tokens`` needs the three."""
+        return {
+            **super().fit_counter_attrs(counters),
+            "num_experts": self.num_experts,
+            "experts_held": self.experts_held,
+            "expert_offset": self.expert_offset,
+        }
+
+    def layer_param_count(self, op: str, ffn: str) -> int:
+        """One block: two norms, its operator, its feed-forward (the
+        expert bias is a buffer, not a parameter)."""
+        h = self.hidden_size
+        kv = self.num_key_value_heads * self.head_dim
+        total = 2 * h
+        if op == "conv":
+            total += h * 3 * h + h * self.conv_L_cache + h * h
+        else:
+            total += 2 * h * h + 2 * h * kv + 2 * self.head_dim
+        if ffn == "dense":
+            return total + 3 * h * self.intermediate_size
+        return total + h * self.num_experts + self.experts_held * 3 * h * self.moe_intermediate_size
+
+    def param_count(self) -> int:
+        h = self.hidden_size
+        layers = sum(
+            self.layer_param_count(op, ffn)
+            for op, ffn in zip(self.layer_ops, self.layer_ffns)
+        )
+        embed = self.n_features * h + h
+        head = h + h * self.n_features_out + self.n_features_out
+        return embed + layers + head
+
+    def flops_per_sample(self) -> float:
+        """One window of ``lookback_window`` tokens: products only,
+        causal attention at its useful half, the expert layer at the
+        pairs this holder expects under even routing."""
+        h, t = self.hidden_size, self.lookback_window
+        kv = self.num_key_value_heads * self.head_dim
+        per_token = 2.0 * self.n_features * h
+        local_pairs = self.num_experts_per_tok * self.experts_held / self.num_experts
+        for op, ffn in zip(self.layer_ops, self.layer_ffns):
+            if op == "conv":
+                per_token += 2.0 * h * 3 * h + 2.0 * h * h + 2.0 * self.conv_L_cache * h
+            else:
+                per_token += 2.0 * h * (2 * h + 2 * kv) + 2.0 * t * h
+            if ffn == "dense":
+                per_token += 6.0 * h * self.intermediate_size
+            else:
+                per_token += 2.0 * h * self.num_experts
+                per_token += local_pairs * 6.0 * h * self.moe_intermediate_size
+        return per_token * t + 2.0 * h * self.n_features_out
 
 
 # ---------------------------------------------------------------------------

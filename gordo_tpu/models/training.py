@@ -142,53 +142,90 @@ def _tree_where(flag, a, b):
     )
 
 
+def _skipping_padding(step):
+    """``step(carry, (starts, weights)) -> (carry, out)`` of a scan over
+    batches, behind a branch: a batch whose weights are all zero leaves
+    the carry as it was and gives zeros for ``out``, without running
+    ``step``. What the masked steps compute for such a batch (a no-op
+    update, a zero contribution) at none of the cost."""
+
+    def branching(carry, batch):
+        def skip(carry, batch):
+            out = jax.eval_shape(step, carry, batch)[1]
+            return carry, jax.tree_util.tree_map(
+                lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), out
+            )
+
+        return jax.lax.cond(jnp.sum(batch[1]) > 0, step, skip, carry, batch)
+
+    return branching
+
+
 def _make_fit_loop(config: FitConfig, train_epoch, evaluate_val):
     """
     The shared epochs×early-stopping scaffold of every fused fit program
     (dense and windowed): scans ``train_epoch`` over per-epoch RNG keys
     with EarlyStopping compiled in as masked updates.
 
-    ``train_epoch(params, opt_state, erng) -> (params, opt_state, loss)``
-    and ``evaluate_val(params) -> val_loss`` (NaN when there is no
-    validation data — see weighted_mean_loss) close over the training
-    arrays; this function owns everything else.
+    ``train_epoch(params, opt_state, erng) -> (params, opt_state, loss,
+    *extras)`` and ``evaluate_val(params) -> val_loss`` (NaN when there
+    is no validation data — see weighted_mean_loss) close over the
+    training arrays; this function owns everything else. ``extras`` are
+    a model's own per-epoch counters (router counts); they are stacked
+    over epochs like the losses.
 
     Returns ``fit_tail(params, opt_state, rng) -> (params, opt_state,
-    losses[epochs], val_losses[epochs], epochs_ran)``.
+    losses[epochs], val_losses[epochs], epochs_ran, *extras[epochs])``.
+
+    Without early stopping nothing can stop an epoch, so the loop carries
+    the state once: no ``stopped`` mask over the update (which keeps the
+    epoch's input state alive beside its output, a second copy of
+    parameters and moments) and no ``best_params``. The masked form
+    computes the same numbers; it is kept for early stopping only.
     """
     es = config.early_stopping
     monitor_val = es is not None and es[0] == "val_loss"
+
+    def fit_plain(params, opt_state, rng):
+        def epoch_body(carry, erng):
+            params, opt_state, loss, *extras = train_epoch(*carry, erng)
+            return (params, opt_state), (loss, evaluate_val(params), *extras)
+
+        (params, opt_state), (losses, val_losses, *extras) = jax.lax.scan(
+            epoch_body, (params, opt_state), jax.random.split(rng, config.epochs)
+        )
+        ran = jnp.array(config.epochs, jnp.int32)
+        return (params, opt_state, losses, val_losses, ran, *extras)
 
     def fit_tail(params, opt_state, rng):
         def epoch_body(carry, erng):
             params, opt_state, best, best_params, wait, stopped = carry
             stopped_at_start = stopped
-            new_params, new_opt, loss = train_epoch(params, opt_state, erng)
+            new_params, new_opt, loss, *extras = train_epoch(params, opt_state, erng)
             # When already stopped, freeze state (masked update keeps one
             # compiled program; tiny models make the dead compute negligible).
             params = _tree_where(stopped, params, new_params)
             opt_state = _tree_where(stopped, opt_state, new_opt)
             val_loss = evaluate_val(params)
-            if es is not None:
-                if monitor_val:
-                    # Per-member fallback: a fleet member with no validation
-                    # rows gets NaN val_loss; monitor train loss instead.
-                    monitor = jnp.where(jnp.isnan(val_loss), loss, val_loss)
-                else:
-                    monitor = loss
-                improved = monitor < best - es[2]
-                best = jnp.where(~stopped & improved, monitor, best)
-                if es[3]:
-                    best_params = _tree_where(
-                        ~stopped & improved, params, best_params
-                    )
-                wait = jnp.where(stopped, wait, jnp.where(improved, 0, wait + 1))
-                stopped = stopped | (wait >= jnp.maximum(es[1], 1))
-            ran = ~stopped_at_start if es is not None else jnp.array(True)
+            if monitor_val:
+                # Per-member fallback: a fleet member with no validation
+                # rows gets NaN val_loss; monitor train loss instead.
+                monitor = jnp.where(jnp.isnan(val_loss), loss, val_loss)
+            else:
+                monitor = loss
+            improved = monitor < best - es[2]
+            best = jnp.where(~stopped & improved, monitor, best)
+            if es[3]:
+                best_params = _tree_where(
+                    ~stopped & improved, params, best_params
+                )
+            wait = jnp.where(stopped, wait, jnp.where(improved, 0, wait + 1))
+            stopped = stopped | (wait >= jnp.maximum(es[1], 1))
             return (params, opt_state, best, best_params, wait, stopped), (
                 loss,
                 val_loss,
-                ran,
+                ~stopped_at_start,
+                *extras,
             )
 
         rngs = jax.random.split(rng, config.epochs)
@@ -200,14 +237,17 @@ def _make_fit_loop(config: FitConfig, train_epoch, evaluate_val):
             jnp.array(0, jnp.int32),
             jnp.array(False),
         )
-        (params, opt_state, _, best_params, _, _), (losses, val_losses, ran) = (
-            jax.lax.scan(epoch_body, init_carry, rngs)
-        )
-        if es is not None and es[3]:
+        (params, opt_state, _, best_params, _, _), (
+            losses, val_losses, ran, *extras
+        ) = jax.lax.scan(epoch_body, init_carry, rngs)
+        if es[3]:
             params = best_params
-        return params, opt_state, losses, val_losses, jnp.sum(ran.astype(jnp.int32))
+        return (
+            params, opt_state, losses, val_losses,
+            jnp.sum(ran.astype(jnp.int32)), *extras,
+        )
 
-    return fit_tail
+    return fit_plain if es is None else fit_tail
 
 
 def _pad_to_batches(
@@ -351,6 +391,94 @@ def build_raw_fit_fn(spec: ModelSpec, config: FitConfig):
 
 
 @lru_cache(maxsize=None)
+def windowed_batch_loss_fn(spec: ModelSpec):
+    """
+    The loss a windowed fit step differentiates: ``(params, series[n, F],
+    ytgt[nw, F], starts[B], weights[B]) -> (loss, aux)``, windows gathered
+    on device from the raw series (``starts[:, None] + arange(lookback)``),
+    ``aux`` the forward's own counters (``spec.forward_aux_fn()``) or
+    None. One function for the fit program
+    (:func:`build_raw_windowed_fit_fn`) and for the loss and gradient
+    norms an estimator reports of itself
+    (:func:`windowed_loss_and_grad_norms_program`): what the second reads
+    is what the first trains on.
+    """
+    forward = forward_fn_for(spec)
+    # a forward with counters of its own (router counts): summed over an
+    # epoch's steps and returned beside the losses; None for the others
+    forward_aux = spec.forward_aux_fn()
+    per_sample = resolve_loss(spec.loss)
+    lookback = spec.lookback_window
+
+    def batch_loss(params, series, ytgt, starts, wb):
+        xb = gather_windows(series, starts, lookback)
+        yb = jnp.take(ytgt, starts, axis=0)
+        if forward_aux is None:
+            (out, penalty), aux = forward(spec, params, xb), None
+        else:
+            # a slot of padding (weight 0) is no sample of this step: a
+            # forward that counts its own work leaves it out of the count
+            out, penalty, aux = forward_aux(spec, params, xb, active=wb > 0)
+        return weighted_mean_loss(per_sample(out, yb), wb) + penalty, aux
+
+    return batch_loss
+
+
+def gather_windows(series, starts, lookback: int):
+    """``series[n, F]`` -> ``[B, lookback, F]``: the windows that start
+    at ``starts``."""
+    idx = starts[:, None] + jnp.arange(lookback)[None, :]
+    return series[idx]
+
+
+@lru_cache(maxsize=None)
+def windowed_loss_and_grad_norms_program(spec: ModelSpec):
+    """Jitted ``(params, series, ytgt, starts, weights) -> (loss, norms)``:
+    the fit step's own loss of one batch (:func:`windowed_batch_loss_fn`,
+    in the spec's ``compute_dtype`` like the fit) and the Euclidean norm
+    of its gradient, a number a parameter leaf."""
+    grad_fn = jax.value_and_grad(windowed_batch_loss_fn(spec), has_aux=True)
+    compute_dtype = jnp.dtype(spec.compute_dtype)
+
+    def loss_and_grad_norms(params, series, ytgt, starts, wb):
+        if compute_dtype != jnp.float32:
+            series, ytgt = series.astype(compute_dtype), ytgt.astype(compute_dtype)
+        (loss, _), grads = grad_fn(params, series, ytgt, starts, wb)
+        return loss, jax.tree_util.tree_map(
+            lambda g: jnp.sqrt(jnp.sum(jnp.square(g))), grads
+        )
+
+    return jax.jit(loss_and_grad_norms)
+
+
+def windowed_loss_and_grad_norms(
+    spec: ModelSpec, params, series: np.ndarray, targets: np.ndarray
+) -> Tuple[float, Any]:
+    """Every window of ``series[n, F]`` with its aligned ``targets[nw,
+    F]`` (ops.windows.window_targets) as one batch through
+    :func:`windowed_loss_and_grad_norms_program`, fetched: the loss as a
+    float, the norms as a tree of floats shaped like ``params``."""
+    series = np.asarray(series, np.float32)
+    targets = np.asarray(targets, np.float32)
+    with telemetry.program_span(
+        "windowed_loss_and_grad_norms",
+        (spec, series.shape, targets.shape),
+        shape=str(tuple(series.shape)),
+        spec=type(spec).__name__,
+    ):
+        loss, norms = jax.device_get(
+            windowed_loss_and_grad_norms_program(spec)(
+                params,
+                series,
+                targets,
+                np.arange(len(targets), dtype=np.int32),
+                np.ones(len(targets), np.float32),
+            )
+        )
+    return float(loss), jax.tree_util.tree_map(float, norms)
+
+
+@lru_cache(maxsize=None)
 def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
     """
     The fused fit for windowed (LSTM) models with windows gathered ON
@@ -358,6 +486,9 @@ def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
 
     ``(params, opt_state, series[n, F], ytgt[nw, F], order[nv], wtr[nv],
     wval[nv], rng) -> (params, opt_state, losses, val_losses, epochs_ran)``
+    and, for a spec whose forward has counters of its own
+    (``spec.forward_aux_fn()``), a sixth output: those counters summed
+    over each epoch's steps, ``[epochs, ...]``.
 
     The dense path pre-materializes ``[n_windows, lookback, F]`` windows —
     a ``lookback×`` HBM blowup that caps LSTM fleet size (1000 machines at
@@ -380,21 +511,17 @@ def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
     (tests/parallel/test_fleet_windowed.py asserts it).
     """
     forward = forward_fn_for(spec)
+    batch_loss = windowed_batch_loss_fn(spec)
     per_sample = resolve_loss(spec.loss)
     tx = spec.optimizer.to_optax()
     lookback = spec.lookback_window
+    # a spec without a member axis runs one member a program and not
+    # under ``vmap``, so a branch there is a branch: a batch of padding
+    # alone is skipped, where a stacked program can only mask it (its
+    # members' batches differ, and ``cond`` under ``vmap`` runs both sides)
+    skip_padding = not spec.member_axis
 
-    def gather_windows(series, starts):
-        idx = starts[:, None] + jnp.arange(lookback)[None, :]
-        return series[idx]  # [B, lookback, F]
-
-    def batch_loss(params, series, ytgt, starts, wb):
-        xb = gather_windows(series, starts)
-        yb = jnp.take(ytgt, starts, axis=0)
-        out, penalty = forward(spec, params, xb)
-        return weighted_mean_loss(per_sample(out, yb), wb) + penalty
-
-    grad_fn = jax.value_and_grad(batch_loss)
+    grad_fn = jax.value_and_grad(batch_loss, has_aux=True)
 
     def train_epoch(params, opt_state, series, ytgt, order, wtr, erng):
         nv = order.shape[0]
@@ -409,10 +536,10 @@ def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
         starts_b = order_e.reshape(steps, config.batch_size)
         w_b = wtr_e.reshape(steps, config.batch_size)
 
-        def step(carry, batch):
+        def masked_step(carry, batch):
             params, opt_state = carry
             starts, wb = batch
-            loss, grads = grad_fn(params, series, ytgt, starts, wb)
+            (loss, aux), grads = grad_fn(params, series, ytgt, starts, wb)
             updates, new_opt_state = tx.update(grads, opt_state, params)
             has_data = jnp.sum(wb) > 0
             params = _tree_where(
@@ -420,14 +547,24 @@ def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
             )
             opt_state = _tree_where(has_data, new_opt_state, opt_state)
             contribution = jnp.where(has_data, loss * jnp.sum(wb), 0.0)
-            return (params, opt_state), contribution
+            if aux is not None:
+                # a fit with counters of its own also counts the steps
+                # that held data: a skipped step counts none
+                aux = {**aux, "steps_run": has_data.astype(jnp.int32)}
+            return (params, opt_state), (contribution, aux)
+
+        step = _skipping_padding(masked_step) if skip_padding else masked_step
 
         with jax.named_scope(STEPS_SCOPE):
-            (params, opt_state), weighted_losses = jax.lax.scan(
+            (params, opt_state), (weighted_losses, aux) = jax.lax.scan(
                 step, (params, opt_state), (starts_b, w_b)
             )
         epoch_loss = jnp.sum(weighted_losses) / jnp.maximum(jnp.sum(wtr), 1.0)
-        return params, opt_state, epoch_loss
+        if aux is None:
+            return params, opt_state, epoch_loss
+        return params, opt_state, epoch_loss, jax.tree_util.tree_map(
+            lambda a: jnp.sum(a, axis=0), aux
+        )
 
     def evaluate(params, series, ytgt, order, w):
         # Batched scan, not one full-window forward: validation memory must
@@ -435,13 +572,15 @@ def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
         nv = order.shape[0]
         steps = nv // config.batch_size
 
-        def step(acc, batch):
+        def scored_step(acc, batch):
             starts, wb = batch
-            xb = gather_windows(series, starts)
+            xb = gather_windows(series, starts, lookback)
             yb = jnp.take(ytgt, starts, axis=0)
             out, _ = forward(spec, params, xb)
             losses = per_sample(out, yb)
             return (acc[0] + jnp.sum(losses * wb), acc[1] + jnp.sum(wb)), None
+
+        step = _skipping_padding(scored_step) if skip_padding else scored_step
 
         with jax.named_scope(VALIDATION_SCOPE):
             (total, wsum), _ = jax.lax.scan(

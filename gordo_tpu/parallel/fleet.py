@@ -175,6 +175,23 @@ def _calibration_attrs(
     )
 
 
+def _fit_counter_attrs(spec: ModelSpec, counters, members: int) -> Dict[str, Any]:
+    """A fit program's own counters (``spec.forward_aux_fn``: the router
+    counts of an expert layer and the steps that ran, ``[members,
+    epochs, ...]``) as span attributes: summed over the live members and
+    the fit's epochs, said by the spec (``ModelSpec.fit_counter_attrs``),
+    and named in ``fit_counters``, by which the builder copies them into
+    ``build_status.json``. Read by the chip benchmark's backbone readers."""
+    counters = fetch_to_host(counters)  # inside the caller's program span
+    attrs = spec.fit_counter_attrs(
+        {
+            name: np.asarray(value)[:members].astype(np.int64).sum(axis=(0, 1))
+            for name, value in counters.items()
+        }
+    )
+    return {**attrs, "fit_counters": sorted(attrs)}
+
+
 def _traced_outputs(outputs):
     """Block on a device program's outputs when a telemetry recorder is
     active, so the enclosing program span times real device work — jit
@@ -200,13 +217,13 @@ def _fill_weight_row(wtr, wval, i, n, member, config: FitConfig):
         wval[i, : len(member.val_weights)] = member.val_weights
 
 
-def _jit_named(name: str, fn):
+def _jit_named(name: str, fn, **jit_kwargs):
     """``jax.jit(fn)`` under an explicit name: the XLA module is
     ``jit_<name>`` in a profiler trace, whatever the wrapped function
     is called (docs/observability.md lists the names; the chip
     benchmark finds the fit programs by ``fit`` in theirs)."""
     fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn)
+    return jax.jit(fn, **jit_kwargs)
 
 
 #: jit'd ravel+concat of same-dtype leaves: turns a many-leaf pytree fetch
@@ -223,6 +240,12 @@ _flat_concat = _jit_named(
 #: chunking keeps each program's signature bounded so the jit cache
 #: can't grow without limit.
 _FLAT_CONCAT_MAX_LEAVES = 256
+
+#: leaves of at least this many bytes are fetched on their own: above it
+#: the fixed latency a coalesced fetch saves is noise beside the copy
+#: (the LSTM cells' largest stacked leaf is 50 MB and stays coalesced; a
+#: backbone's expert weights are 117 MB a leaf)
+_COALESCE_MAX_LEAF_BYTES = 64 << 20
 
 
 def fetch_to_host(tree):
@@ -252,9 +275,15 @@ def fetch_to_host(tree):
     if len(leaves) <= 1 or not all(isinstance(l, jax.Array) for l in leaves):
         return jax.device_get(tree)
     by_dtype: Dict[Any, List[int]] = {}
-    for idx, leaf in enumerate(leaves):
-        by_dtype.setdefault(leaf.dtype, []).append(idx)
     host_leaves: List[Any] = [None] * len(leaves)
+    for idx, leaf in enumerate(leaves):
+        if leaf.nbytes >= _COALESCE_MAX_LEAF_BYTES:
+            # a large leaf's transfer dwarfs the round trip it would
+            # save, and coalescing would copy it twice more (into the
+            # concatenation on the device, out of it on the host)
+            host_leaves[idx] = np.asarray(leaf)
+        else:
+            by_dtype.setdefault(leaf.dtype, []).append(idx)
     for idxs in by_dtype.values():
         for start in range(0, len(idxs), _FLAT_CONCAT_MAX_LEAVES):
             chunk = idxs[start : start + _FLAT_CONCAT_MAX_LEAVES]
@@ -296,6 +325,27 @@ def host_prng_keys(seeds: Sequence[int]) -> np.ndarray:
     return np.stack([hi, lo], axis=-1)
 
 
+def _over_members(spec: ModelSpec, fn):
+    """``fn`` over a leading member axis: ``jax.vmap(fn)``, or, for a
+    spec without a member axis (``ModelSpec.member_axis``), ``fn`` on
+    the one member of a one-member bucket with the axis put back (two
+    reshapes, so donated state still updates in place)."""
+    if spec.member_axis:
+        return jax.vmap(fn)
+
+    def one_member(*args):
+        members = jax.tree_util.tree_leaves(args)[0].shape[0]
+        if members != 1:
+            raise ValueError(
+                f"{type(spec).__name__} trains and scores one member a "
+                f"program (planner.packing.trains_alone); got {members}"
+            )
+        out = fn(*jax.tree_util.tree_map(lambda a: a[0], args))
+        return jax.tree_util.tree_map(lambda a: a[None], out)
+
+    return one_member
+
+
 @lru_cache(maxsize=None)
 def _fleet_fit_program(spec: ModelSpec, config: FitConfig):
     """jit(vmap) of the raw fused fit over a leading model axis."""
@@ -309,7 +359,12 @@ def _fleet_windowed_fit_program(spec: ModelSpec, config: FitConfig):
     from ..models.training import build_raw_windowed_fit_fn
 
     raw_fit = build_raw_windowed_fit_fn(spec, config)
-    return _jit_named("fleet_windowed_fit", jax.vmap(raw_fit))
+    # params and optimizer state are donated: the trainer makes them for
+    # this call alone, and a member whose state is half the chip cannot
+    # live there twice (the inputs beside the loop's own carry)
+    return _jit_named(
+        "fleet_windowed_fit", _over_members(spec, raw_fit), donate_argnums=(0, 1)
+    )
 
 
 @lru_cache(maxsize=None)
@@ -357,7 +412,7 @@ def fleet_windowed_predict_program(spec: ModelSpec, batch_size: int):
         )
         return outs.reshape(steps * batch_size, -1)
 
-    return _jit_named("fleet_windowed_predict", jax.vmap(predict_one))
+    return _jit_named("fleet_windowed_predict", _over_members(spec, predict_one))
 
 
 @lru_cache(maxsize=None)
@@ -397,7 +452,7 @@ def _fleet_init_program(spec: ModelSpec):
     def init_one(key):
         return init(key, spec)
 
-    return _jit_named("fleet_init", jax.vmap(init_one))
+    return _jit_named("fleet_init", _over_members(spec, init_one))
 
 
 def _optimizer_init_program(spec: ModelSpec):
@@ -461,6 +516,17 @@ class FleetTrainer:
         #: its bucket rode through); lets the builder attribute trainer-
         #: internal bisections to machines in BuildMetadata.robustness
         self.bisect_counts: Dict[str, int] = {}
+
+    def _mesh_for(self, spec: ModelSpec) -> Mesh:
+        """The mesh a bucket of ``spec`` runs on: the trainer's, or, for
+        a spec without a member axis (``ModelSpec.member_axis``), the
+        trainer's first device alone: one member has nothing to spread
+        over ``models``, and no dummy member pads its bucket."""
+        if spec.member_axis or self.mesh.devices.size == 1:
+            return self.mesh
+        return Mesh(
+            self.mesh.devices.reshape(-1)[:1].reshape(1, 1), self.mesh.axis_names
+        )
 
     def _packing_factor(self, spec, n_members: int, config: FitConfig) -> int:
         from ..models.packing import auto_packing
@@ -914,7 +980,7 @@ class FleetTrainer:
             rngs, init_rngs = split_keys[:, 0], split_keys[:, 1]
             params = _fleet_init_program(spec)(init_rngs)
             params = jax.device_put(
-                params, model_sharding(self.mesh, extra_dims=0)
+                params, model_sharding(self._mesh_for(spec), extra_dims=0)
             )
             opt_state = _optimizer_init_program(spec)(params)
             return params, opt_state, rngs
@@ -936,9 +1002,10 @@ class FleetTrainer:
         series (and aligned targets) shard over ``models`` only; the
         virtual window axis (order + weights) shards over ``data``.
         """
+        mesh = self._mesh_for(spec)
         with telemetry.part_span("stack"):
-            model_axis = self.mesh.devices.shape[0]
-            data_axis = self.mesh.devices.shape[1] if self.mesh.devices.ndim > 1 else 1
+            model_axis = mesh.devices.shape[0]
+            data_axis = mesh.devices.shape[1] if mesh.devices.ndim > 1 else 1
             m_floor = max(len(bucket), m_padded or 0)
             m_total = -(-m_floor // model_axis) * model_axis
             nw_padded = n_padded - offset
@@ -965,16 +1032,16 @@ class FleetTrainer:
                 [m.seed for m in bucket] + [0] * (m_total - len(bucket))
             )
         with telemetry.part_span("h2d"):
-            md = model_data_sharding(self.mesh)
+            md = model_data_sharding(mesh)
             return jax.device_put(
                 (series, ytgt, order, wtr, wval, rngs),
                 (
-                    model_sharding(self.mesh, extra_dims=2),
-                    model_sharding(self.mesh, extra_dims=2),
+                    model_sharding(mesh, extra_dims=2),
+                    model_sharding(mesh, extra_dims=2),
                     md,
                     md,
                     md,
-                    model_sharding(self.mesh, extra_dims=1),
+                    model_sharding(mesh, extra_dims=1),
                 ),
             )
 
@@ -1042,11 +1109,16 @@ class FleetTrainer:
             with telemetry.program_span(
                 "fleet_windowed_fit",
                 (spec, config, series.shape, order.shape),
+                tokens_per_step=config.batch_size * spec.lookback_window,
                 **span_attrs,
-            ):
-                params, _, losses, val_losses, epochs_ran = _traced_outputs(
-                    fit(params, opt_state, series, ytgt, order, wtr, wval, rngs)
+            ) as span:
+                params, _, losses, val_losses, epochs_ran, *counters = (
+                    _traced_outputs(
+                        fit(params, opt_state, series, ytgt, order, wtr, wval, rngs)
+                    )
                 )
+                if counters:
+                    span.set(**_fit_counter_attrs(spec, counters[0], len(bucket)))
         with telemetry.part_span("collect"):
             return self._collect_results(
                 bucket, params, losses, val_losses, epochs_ran, config,
@@ -1152,11 +1224,12 @@ class FleetTrainer:
         ``series[M, n, F]`` + ``order[M, nv]`` → ``[M, nv, F_out]``
         (``nv`` is padded to a whole number of ``batch_size`` batches here).
         """
+        mesh = self._mesh_for(spec)
         with telemetry.part_span("h2d"):  # pad to the mesh, then transfer
             series = np.asarray(series, np.float32)
             order = np.asarray(order, np.int32)
             m = series.shape[0]
-            model_axis = self.mesh.devices.shape[0]
+            model_axis = mesh.devices.shape[0]
             m_total = -(-m // model_axis) * model_axis
             nv = order.shape[1]
             nv_pad = -(-nv // batch_size) * batch_size
@@ -1175,10 +1248,10 @@ class FleetTrainer:
                     else np.asarray(a),
                     stacked_params,
                 )
-            ms2 = model_sharding(self.mesh, extra_dims=2)
+            ms2 = model_sharding(mesh, extra_dims=2)
             series = jax.device_put(series, ms2)
             order = jax.device_put(
-                order, model_sharding(self.mesh, extra_dims=1)
+                order, model_sharding(mesh, extra_dims=1)
             )
         with telemetry.program_span(
             "fleet_windowed_predict",
@@ -1198,7 +1271,12 @@ class FleetTrainer:
 
 
 def stack_member_params(results: Sequence[FleetResult]):
-    """Re-stack per-member host params into a fleet pytree (serving path)."""
+    """Re-stack per-member host params into a fleet pytree (serving path).
+    One member's leaves get their member axis as a view, not a copy."""
+    if len(results) == 1:
+        return jax.tree_util.tree_map(
+            lambda leaf: np.asarray(leaf)[None], results[0].params
+        )
     return jax.tree_util.tree_map(
         lambda *leaves: np.stack(leaves), *[r.params for r in results]
     )

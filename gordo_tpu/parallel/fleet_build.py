@@ -59,10 +59,11 @@ from ..models.anomaly.diff import (
     DiffBasedAnomalyDetector,
     DiffBasedKFCVAnomalyDetector,
 )
-from ..models.estimators import JaxBaseEstimator, JaxLSTMBaseEstimator
+from ..models.estimators import JaxBaseEstimator, JaxWindowedBaseEstimator
 from ..models.training import FitConfig, fit_config_from_kwargs, split_fit_kwargs
 from ..ops.windows import model_offset as calc_model_offset
 from ..ops.windows import window_targets
+from ..planner.packing import trains_alone, windowed_scoring_batch
 from ..utils.env import env_float, env_int, env_str
 from ..utils.faults import fault_point
 from ..utils.retry import retry_call
@@ -101,6 +102,9 @@ class _Plan:
     n_windows: int = 0  # virtual sample count (== len(X_arr) for dense)
     shuffle_perm: Optional[np.ndarray] = None  # detector-level row shuffle
     offset: int = 0
+    # True when the CV folds are cut over the windows' target rows
+    # (FleetBuilder._cv_splits): a history too short for row folds
+    target_folds: bool = False
     spec: Any = None
     fit_config: FitConfig = None
     seed: int = 42
@@ -121,6 +125,15 @@ class _Plan:
 
 class FleetBuildError(RuntimeError):
     pass
+
+
+#: what ``build_status.json["fit_counters"]`` keeps of a fit program's
+#: ``device_program`` span beside the counters the span names in its
+#: ``fit_counters`` attribute (parallel/fleet._fit_counter_attrs)
+FIT_PROGRAM_KEYS = (
+    "program", "phase", "members", "params", "epochs", "stacked_samples",
+    "tokens_per_step",
+)
 
 
 def _cv_chunk_bytes() -> int:
@@ -146,7 +159,10 @@ def _chunk_by_bytes(members, items, budget: int):
     chunks = []
     start, used = 0, 0
     for i, member in enumerate(members):
-        size = _member_nbytes(member)
+        # a member whose state alone out-sizes a program (the planner's
+        # trains_alone) takes the whole budget: it trains, and its fold
+        # scores, alone
+        size = budget if trains_alone(member.spec) else _member_nbytes(member)
         if i > start and used + size > budget:
             chunks.append((members[start:i], items[start:i]))
             start, used = i, 0
@@ -839,6 +855,15 @@ class FleetBuilder:
             if live is not None and padded:
                 self._member_actuals["live"] += int(live)
                 self._member_actuals["padded"] += int(padded)
+        if (
+            name == "device_program"
+            and attrs.get("fit_counters")
+            and self.progress is not None
+        ):
+            keys = FIT_PROGRAM_KEYS + tuple(attrs["fit_counters"])
+            self.progress.add_fit_counters(
+                {key: attrs[key] for key in keys if key in attrs}
+            )
         if name == "build_part" and self.progress is not None:
             phase = str(attrs.get("phase", ""))
             self.progress.add_part(
@@ -1048,7 +1073,7 @@ class FleetBuilder:
             obj = obj.steps[-1][1]
         if not isinstance(obj, JaxBaseEstimator):
             return None
-        if isinstance(obj, JaxLSTMBaseEstimator) and isinstance(
+        if isinstance(obj, JaxWindowedBaseEstimator) and isinstance(
             detector, DiffBasedKFCVAnomalyDetector
         ):
             # scattered KFold test indices don't map cleanly onto window
@@ -1449,7 +1474,7 @@ class FleetBuilder:
             {"n_features": X_arr.shape[1], "n_features_out": y_arr.shape[1]}
         )
         fit_kwargs, factory_kwargs = split_fit_kwargs(est.sk_params)
-        if isinstance(est, JaxLSTMBaseEstimator):
+        if isinstance(est, JaxWindowedBaseEstimator):
             lookback, lookahead = est.lookback_window, est.lookahead
             plan.offset = calc_model_offset(lookback, lookahead)
             plan.windows = None  # on-device windowing; series stays resident
@@ -1544,7 +1569,7 @@ class FleetBuilder:
         per_plan_folds: Dict[str, List[Tuple[np.ndarray, np.ndarray]]] = {}
         for plan in plans:
             try:
-                splits = list(self._cv_for(plan).split(plan.X_arr))
+                splits = self._cv_splits(plan)
                 plan.cv_splits = self._split_metadata(plan, splits)
             except Exception as exc:
                 self._fail(plan.machine.name, exc)
@@ -1644,6 +1669,30 @@ class FleetBuilder:
             return serializer.from_definition(cv_def)
         return TimeSeriesSplit(n_splits=3)
 
+    def _cv_splits(self, plan: _Plan) -> List[Tuple[np.ndarray, np.ndarray]]:
+        """The plan's CV folds as ``(train rows, test rows)``. A windowed
+        model scores the windows that lie inside a fold's test rows, so a
+        test span no longer than the model's offset holds none: where NO
+        fold of the history is long enough (a lookback of days over a
+        history of days), the folds are cut over the rows that are some
+        window's target instead (rows ``offset..n-1``), and a test
+        window reads its context from the rows before its fold, as the
+        served model does. Histories with room for row folds keep them."""
+        cv = self._cv_for(plan)
+        splits = list(cv.split(plan.X_arr))
+        if plan.offset and all(len(test) <= plan.offset for _, test in splits):
+            rows = np.arange(plan.offset, len(plan.X_arr))
+            splits = [(rows[train], rows[test]) for train, test in cv.split(rows)]
+            plan.target_folds = True
+            logger.warning(
+                "%s: no CV fold of its %d rows is longer than the model's "
+                "offset of %d rows, so no fold would hold a window to score; "
+                "its folds are cut over the %d target rows instead "
+                "(cross_validation.splits says folds-over: target-rows)",
+                plan.machine.name, len(plan.X_arr), plan.offset, len(rows),
+            )
+        return splits
+
     def _window_train_weights(self, plan: _Plan, train_idx: np.ndarray) -> np.ndarray:
         """Row-index fold → window-index training mask."""
         n_windows = plan.n_windows
@@ -1672,13 +1721,14 @@ class FleetBuilder:
         if plan.offset == 0:
             rows = rows[rows < plan.n_windows]
             return rows, rows
+        if plan.target_folds:
+            # the fold is over target rows: the window that predicts row r
+            return rows - plan.offset, rows
         # contiguous test [b, c) → window indices [b, c - offset)
         b, c = int(rows[0]), int(rows[-1]) + 1
         window_idx = np.arange(b, max(c - plan.offset, b))
         window_idx = window_idx[window_idx < plan.n_windows]
         return window_idx, window_idx + plan.offset
-
-    _SCORING_BATCH = 256  # windowed scoring scan batch (bounds HBM)
 
     def _train_and_score_folds(
         self, members, fold_items, config, per_plan_folds, fold_state
@@ -1854,7 +1904,7 @@ class FleetBuilder:
         window_idx: List[np.ndarray],
     ) -> np.ndarray:
         """Predictions for windowed plans, windows gathered on device (scan
-        over _SCORING_BATCH-window batches), model-axis sharded over the
+        over ``planner.packing.windowed_scoring_batch`` windows a step), model-axis sharded over the
         trainer's mesh like the dense scoring path. ``window_idx`` gives
         each plan's window positions to predict (the fold-test windows)."""
         orders = window_idx
@@ -1869,7 +1919,7 @@ class FleetBuilder:
                 series[i, : len(p.X_arr)] = p.X_arr
                 order[i, : len(orders[i])] = orders[i]
         return self.trainer.predict_windowed_bucket(
-            spec, stacked, series, order, batch_size=self._SCORING_BATCH
+            spec, stacked, series, order, batch_size=windowed_scoring_batch(spec)
         )
 
     @staticmethod
@@ -2227,6 +2277,9 @@ class FleetBuilder:
                     metadata[f"fold-{i + 1}-{label}-{endpoint}"] = (
                         value.isoformat() if hasattr(value, "isoformat") else int(value)
                     )
+        if plan.target_folds:
+            # FleetBuilder._cv_splits: the other rule says nothing here
+            metadata["folds-over"] = "target-rows"
         return metadata
 
 

@@ -29,7 +29,7 @@ import os
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-from ..models.spec import FeedForwardSpec, LSTMSpec, ModelSpec
+from ..models.spec import ModelSpec
 from ..utils.env import env_bool
 
 logger = logging.getLogger(__name__)
@@ -210,44 +210,24 @@ def compute_precision(spec: ModelSpec) -> str:
 
 
 def spec_param_count(spec: ModelSpec) -> int:
-    """Trainable parameter count from the spec geometry alone."""
-    if isinstance(spec, FeedForwardSpec):
-        dims = (spec.n_features,) + tuple(spec.dims) + (spec.n_features_out,)
-        return sum(
-            d_in * d_out + d_out for d_in, d_out in zip(dims[:-1], dims[1:])
-        )
-    if isinstance(spec, LSTMSpec):
-        total = 0
-        d_in = spec.n_features
-        for d_h in spec.dims:
-            # 4 gates, each [d_in + d_h, d_h] + bias
-            total += 4 * (d_in * d_h + d_h * d_h + d_h)
-            d_in = d_h
-        total += d_in * spec.n_features_out + spec.n_features_out
-        return total
-    # Unknown spec types (future architectures): no geometry knowledge —
-    # callers treat 0 as "cost unknown, keep the member in its own group".
-    return 0
+    """Trainable parameter count from the spec geometry alone: the spec
+    answers (``ModelSpec.param_count``). 0 for a spec that cannot say —
+    callers treat it as "cost unknown, keep the member in its own group"."""
+    return int(spec.param_count()) if isinstance(spec, ModelSpec) else 0
+
+
+def spec_state_bytes(spec: ModelSpec) -> int:
+    """Bytes one member's training state holds whatever its batch:
+    float32 weights, gradients and the optimizer's two moments."""
+    return 4 * _OPTIMIZER_COPIES * spec_param_count(spec)
 
 
 def spec_flops_per_sample(spec: ModelSpec) -> float:
-    """Forward-pass FLOPs for ONE sample (one window for LSTM specs —
-    the recurrence runs ``lookback_window`` steps per window)."""
-    if isinstance(spec, FeedForwardSpec):
-        dims = (spec.n_features,) + tuple(spec.dims) + (spec.n_features_out,)
-        return float(
-            sum(2 * d_in * d_out for d_in, d_out in zip(dims[:-1], dims[1:]))
-        )
-    if isinstance(spec, LSTMSpec):
-        per_step = 0.0
-        d_in = spec.n_features
-        for d_h in spec.dims:
-            per_step += 2.0 * 4 * (d_in + d_h) * d_h
-            d_in = d_h
-        head = 2.0 * d_in * spec.n_features_out
-        return per_step * spec.lookback_window + head
-    # ~2 FLOPs per parameter per sample is the dense-layer identity;
-    # use it as the generic fallback.
+    """Forward-pass FLOPs for ONE sample (one window for windowed specs):
+    ``ModelSpec.flops_per_sample``, whose fallback is the dense-layer
+    identity of ~2 FLOPs a parameter a sample."""
+    if isinstance(spec, ModelSpec):
+        return float(spec.flops_per_sample())
     return 2.0 * spec_param_count(spec)
 
 

@@ -46,7 +46,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ..models.spec import ModelSpec
 from ..utils.env import env_int, env_str
-from .costmodel import CostModel
+from .costmodel import CostModel, spec_state_bytes
 from .ladder import round_up_ladder, sample_pad_ratio, series_pad_ratio
 
 logger = logging.getLogger(__name__)
@@ -86,6 +86,27 @@ def compile_budget() -> int:
 
 def hbm_cap_bytes() -> int:
     return max(1 << 20, env_int(HBM_CAP_ENV, DEFAULT_HBM_CAP_BYTES))
+
+
+def trains_alone(spec: ModelSpec) -> bool:
+    """True for a member whose training state alone (weights, gradients,
+    optimizer moments) exceeds the per-bucket cap, or whose spec has no
+    member axis (``ModelSpec.member_axis``): no second member can share
+    its program, under any strategy. Such members train, and their folds
+    score, one to a program; programs of one shape share one compile."""
+    return not spec.member_axis or spec_state_bytes(spec) > hbm_cap_bytes()
+
+
+#: windows a scan step of a windowed scoring program forwards (CV fold
+#: scores, the fleet route): bounds the program's memory like a fit batch
+WINDOWED_SCORING_BATCH = 256
+#: the same for a member that trains alone: 256 of its windows are a fit
+#: step's activations eight times over, so it scores at a fit's batch
+ALONE_SCORING_BATCH = 32
+
+
+def windowed_scoring_batch(spec: ModelSpec) -> int:
+    return ALONE_SCORING_BATCH if trains_alone(spec) else WINDOWED_SCORING_BATCH
 
 
 def _round_up_pow2(n: int, batch_size: int) -> int:
@@ -204,19 +225,24 @@ def _naive_buckets(members: Sequence[Any], config: Any) -> List[PlannedBucket]:
         )
         grouped.setdefault(key, []).append(member)
     buckets = []
-    for (spec, n_padded, offset, windowed), bucket_members in grouped.items():
-        buckets.append(
-            PlannedBucket(
-                bucket_id=f"{_bucket_key(spec, config)}-n{n_padded}"
-                + (f"-o{offset}" if windowed else ""),
-                program=_spec_program(bucket_members[0]),
-                spec=spec,
-                members=bucket_members,
-                n_padded=n_padded,
-                offset=offset,
-                windowed=windowed,
+    for (spec, n_padded, offset, windowed), group in grouped.items():
+        # a member larger than the cap trains alone: one-member buckets
+        # of one shape, so one compile runs once a member
+        rosters = [[m] for m in group] if trains_alone(spec) else [group]
+        for idx, bucket_members in enumerate(rosters):
+            buckets.append(
+                PlannedBucket(
+                    bucket_id=f"{_bucket_key(spec, config)}-n{n_padded}"
+                    + (f"-o{offset}" if windowed else "")
+                    + (f"-b{idx}" if len(rosters) > 1 else ""),
+                    program=_spec_program(bucket_members[0]),
+                    spec=spec,
+                    members=bucket_members,
+                    n_padded=n_padded,
+                    offset=offset,
+                    windowed=windowed,
+                )
             )
-        )
     return buckets
 
 
@@ -319,11 +345,14 @@ def _packed_buckets(
             range(len(group)), key=lambda i: (-weights[group[i].name], i)
         )
         bins: List[Tuple[List[Any], int]] = []  # (members, used_bytes)
+        # a member that trains alone takes a bin of its own whatever it
+        # weighs: its spec may have no member axis to share a program on
+        alone = trains_alone(spec)
         for i in order:
             member = group[i]
             size = weights[member.name]
             best_bin = None
-            for b, (bin_members, used) in enumerate(bins):
+            for b, (bin_members, used) in enumerate([] if alone else bins):
                 if used + size <= hbm_cap:
                     if best_bin is None or used > bins[best_bin][1]:
                         best_bin = b

@@ -31,7 +31,8 @@ import numpy as np
 
 from .. import serializer
 from ..models.estimators import JaxBaseEstimator
-from ..models.spec import FeedForwardSpec, LSTMSpec
+from ..models.spec import FeedForwardSpec
+from ..planner.packing import trains_alone, windowed_scoring_batch
 from ..utils.env import env_bool, env_int
 
 logger = logging.getLogger(__name__)
@@ -544,7 +545,9 @@ class RevisionFleet:
             spec = specs.get(name)
             if isinstance(spec, FeedForwardSpec):
                 by_spec.setdefault(spec, []).append(name)
-            elif isinstance(spec, LSTMSpec):
+            elif getattr(spec, "windowed", False):
+                # every windowed spec (LSTMs, backbones) scores through
+                # the on-device window-gather program
                 by_lstm_spec.setdefault(spec, []).append(name)
             else:
                 fallback.append(name)
@@ -575,9 +578,14 @@ class RevisionFleet:
                 r = recon[i, :b]
                 out[n] = (r, mse_vs_raw(r, np.asarray(inputs[n], np.float32)))
         for spec, names in by_lstm_spec.items():
-            self._score_lstm_bucket(
-                spec, names, inputs, out, errors, mse_vs_raw
-            )
+            # machines whose spec trains alone (no member axis, or a state
+            # larger than the planner's cap) score alone too: one program
+            # of one shape, run once a machine
+            rosters = [[n] for n in names] if trains_alone(spec) else [names]
+            for roster in rosters:
+                self._score_lstm_bucket(
+                    spec, roster, inputs, out, errors, mse_vs_raw
+                )
         for n in fallback:
             try:
                 model = self._models[n]
@@ -636,8 +644,6 @@ class RevisionFleet:
             )
         return names, member_params, transformed
 
-    _LSTM_SERVING_BATCH = 256  # window batch of the on-device gather scan
-
     def _score_lstm_bucket(self, spec, names, inputs, out, errors, mse_vs_raw):
         """
         Fused LSTM scoring: every member's raw series stays ``[b, F]`` and
@@ -680,7 +686,7 @@ class RevisionFleet:
         # series shorter than one window would make even the zero-padded
         # gather read out of bounds
         b_max = max(b_max, lookback)
-        batch = self._LSTM_SERVING_BATCH
+        batch = windowed_scoring_batch(spec)  # windows a scan step
         nv_max = -(-max(counts.values()) // batch) * batch
         series = np.zeros((len(kept), b_max, spec.n_features), np.float32)
         order = np.zeros((len(kept), nv_max), np.int32)

@@ -110,6 +110,9 @@ class BuildProgress:
         #: programs (``telemetry.device.compile_path_counters`` deltas),
         #: set by the builder at build end
         self.compile: Optional[Dict[str, Any]] = None
+        #: one entry a fit program that has counters of its own (the
+        #: router counts of an expert layer): ``add_fit_counters``
+        self._fit_counters: List[Dict[str, Any]] = []
         self._phase: Optional[str] = None
         self._phase_order: List[str] = []
         self._lock = threading.Lock()
@@ -144,6 +147,12 @@ class BuildProgress:
             entry = self._parts.setdefault(phase, {}).setdefault(part, [0.0, 0])
             entry[0] += seconds
             entry[1] += count
+
+    def add_fit_counters(self, counters: Dict[str, Any]) -> None:
+        """Keep one fit program's own counters (what its
+        ``device_program`` span carries) for ``fit_counters``."""
+        with self._lock:
+            self._fit_counters.append(dict(counters))
 
     def machine_completed(self, name: str = "") -> None:
         with self._lock:
@@ -184,6 +193,9 @@ class BuildProgress:
             compile_path = (
                 {"compile": dict(self.compile)} if self.compile is not None else {}
             )
+            fit_counters = (
+                {"fit_counters": list(self._fit_counters)} if self._fit_counters else {}
+            )
             return {
                 "version": 1,
                 "project": self.project,
@@ -205,6 +217,7 @@ class BuildProgress:
                 "device": self.device,
                 "phases": phases,
                 **compile_path,
+                **fit_counters,
             }
 
     #: floor on how often phase RE-entries rewrite the doc — the CV loop

@@ -1,0 +1,398 @@
+"""A toy backbone machine on the normal path: ``build-fleet`` (fetch, CV
+folds, thresholds, final fit, dump), ``serializer.load``,
+``model.predict`` and the server's ``/prediction``; what its size forces
+in the planner and the trainer; and the LSTM's fit, bit for bit what the
+masked epoch loop gave."""
+
+import json
+import os
+
+import jax
+import numpy as np
+import pandas as pd
+import pytest
+import yaml
+from werkzeug.test import Client
+
+from gordo_tpu import serializer
+from gordo_tpu.cli import gordo_tpu_cli
+from gordo_tpu.models.factories import lfm2_moe, lstm_model
+from gordo_tpu.models.training import FitConfig, build_raw_windowed_fit_fn
+from gordo_tpu.ops.windows import window_targets
+from gordo_tpu.parallel import FleetTrainer, WindowedFleetMember
+from gordo_tpu.parallel.fleet import _fleet_windowed_fit_program
+from gordo_tpu.planner import packing
+from gordo_tpu.server import build_app
+
+PROJECT = "backbone-proj"
+REVISION = "1700000000027"
+LOOKBACK = 100
+TOY = dict(
+    kind="lfm2_moe", lookback_window=LOOKBACK,
+    layer_types=["conv", "full_attention", "conv"], num_dense_layers=1,
+    hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+    intermediate_size=48, moe_intermediate_size=24, num_experts=8,
+    experts_held=2, num_experts_per_tok=2, epochs=2, batch_size=32,
+)
+TAGS = [f"bb-{i}" for i in range(4)]
+MACHINES = ("compressor-a", "compressor-b")
+
+
+@pytest.fixture(scope="module")
+def built(tmp_path_factory):
+    """Two toy machines of one configuration built by the ``build-fleet``
+    command: 145 rows, 45 windows of 100 rows, three folds and the final
+    fit each. One configuration is one spec, and a spec without a member
+    axis shares no program: every fit and every score runs one machine."""
+    root = tmp_path_factory.mktemp("backbone") / REVISION
+    document = {
+        "project_name": PROJECT,
+        "machines": [{
+            "name": name,
+            "model": {"gordo_tpu.models.anomaly.diff.DiffBasedAnomalyDetector": {
+                "base_estimator": {"sklearn.pipeline.Pipeline": {"steps": [
+                    "sklearn.preprocessing.MinMaxScaler",
+                    {"gordo_tpu.models.JaxBackboneForecast": dict(TOY)},
+                ]}}
+            }},
+            "dataset": {
+                "type": "TimeSeriesDataset",
+                "data_provider": {"type": "RandomDataProvider", "min_size": 145, "max_size": 145},
+                "train_start_date": "2020-01-01T00:00:00+00:00",
+                "train_end_date": "2020-01-02T00:00:00+00:00",
+                "resolution": "10min",
+                "tag_list": TAGS,
+            },
+        } for name in MACHINES],
+    }
+    config_path = str(root.parent / "machines.yaml")
+    with open(config_path, "w") as f:
+        yaml.safe_dump(document, f)
+    try:
+        gordo_tpu_cli.main(["build-fleet", config_path, str(root)], standalone_mode=False)
+        code = 0
+    except SystemExit as exc:
+        code = int(exc.code or 0)
+    return code, str(root)
+
+
+def rows(n):
+    rng = np.random.RandomState(1)
+    return pd.DataFrame(
+        rng.uniform(0, 1, (n, len(TAGS))), columns=TAGS,
+        index=pd.date_range("2021-01-01", periods=n, freq="10min", tz="UTC"),
+    )
+
+
+def test_build_fleet_builds_the_toy_machines(built):
+    code, root = built
+    assert code == 0
+    with open(os.path.join(root, "build_status.json")) as f:
+        status = json.load(f)
+    assert status["state"] == "complete" and status["machines"]["completed"] == 2
+    assert not any(status["robustness"].values())
+    assert {"data_fetch", "cv_train", "cv_predict", "final_fit", "dump"} <= set(status["phases"])
+    # three folds and the final fit a machine: one member a program, its
+    # counters kept (fold-major: each fold's two machines, then the fits)
+    counters = status["fit_counters"]
+    assert len(counters) == 8 and all(c["members"] == 1 for c in counters)
+    for c in counters:
+        assert c["num_experts"] == 8 and c["experts_held"] == 2
+        assert len(c["router_tokens"]) == 2 and len(c["router_tokens"][0]) == 8
+        assert c["pairs_here"] == [sum(layer[:2]) for layer in c["router_tokens"]]
+    # 45 windows: the folds train 12, 23 and 34 of them, the final fit 45;
+    # of an epoch's 3 steps of 32 slots, 1, 1, 2 and 2 hold a window and
+    # run; a step of padding alone is skipped and counts nothing, and in
+    # a step that runs the slots of padding route nothing: the pairs in
+    # all are those of the windows trained
+    a_window = LOOKBACK * 2  # tokens x experts per token
+    assert sorted(c["steps_run"] for c in counters) == [2, 2, 2, 2, 4, 4, 4, 4]  # 2 epochs
+    assert sorted(c["pairs_total"][0] for c in counters) == [
+        2 * windows * a_window for windows in (12, 12, 23, 23, 34, 34, 45, 45)
+    ]
+    assert all(c["pairs_total"][0] == c["pairs_total"][1] for c in counters)
+    assert all(sum(layer) == c["pairs_total"][0] for c in counters for layer in c["router_tokens"])
+    assert all(c["stacked_samples"] == 96 for c in counters)
+    with open(os.path.join(root, "build_trace.jsonl")) as f:
+        spans = [json.loads(line) for line in f]
+    fits = [s["attributes"] for s in spans
+            if s["name"] == "device_program" and "fit" in s["attributes"]["program"]]
+    assert len(fits) == 8 and sum(bool(a["compile"]) for a in fits) == 1  # one compile, run eight times
+    assert all(a["tokens_per_step"] == 32 * LOOKBACK and a["params"] > 0 for a in fits)
+    assert all(set(a["fit_counters"]) >= {"router_tokens", "pairs_here", "steps_run"} for a in fits)
+    scores = [s["attributes"] for s in spans
+              if s["name"] == "device_program" and "predict" in s["attributes"]["program"]]
+    assert len(scores) == 6 and all(a["members"] == 1 for a in scores)  # a fold's machines score alone
+    metadata = serializer.load_metadata(os.path.join(root, "compressor-a"))
+    model_meta = metadata["metadata"]["build_metadata"]["model"]
+    assert model_meta["model_offset"] == LOOKBACK  # lookahead 1
+    meta = model_meta["model_meta"]
+    assert np.isfinite(meta["aggregate-threshold"]) and len(meta["history"]["loss"]) == 2
+    assert all(np.isfinite(t) for t in meta["feature-thresholds"])
+    splits = model_meta["cross_validation"]["splits"]
+    assert len([k for k in splits if k.endswith("train-start")]) == 3
+
+
+def test_the_artifact_loads_and_predicts(built):
+    _, root = built
+    model = serializer.load(os.path.join(root, "compressor-a"))
+    X = rows(LOOKBACK + 6)
+    prediction = np.asarray(model.predict(X))
+    assert prediction.shape == (6, len(TAGS)) and np.isfinite(prediction).all()
+    frame = model.anomaly(X, X)
+    assert len(frame) == 6 and np.isfinite(frame["total-anomaly-scaled"].to_numpy()).all()
+    estimator = model.base_estimator.steps[-1][1]
+    assert type(estimator).__name__ == "JaxBackboneForecast"
+    assert estimator.spec_.experts_held == 2 and estimator.spec_.windowed
+    assert all(isinstance(leaf, np.ndarray) for leaf in jax.tree_util.tree_leaves(estimator.params_))
+
+
+def test_the_server_answers_prediction_from_it(built):
+    from tests.server.conftest import temp_env_vars
+
+    _, root = built
+    X = rows(LOOKBACK + 5)
+    values = {tag: {ts.isoformat(): float(v) for ts, v in X[tag].items()} for tag in TAGS}
+    with temp_env_vars(MODEL_COLLECTION_DIR=root):
+        client = Client(build_app())
+        for route in ("prediction", "anomaly/prediction"):
+            resp = client.post(
+                f"/gordo/v0/{PROJECT}/compressor-a/{route}", json={"X": values, "y": values}
+            )
+            assert resp.status_code == 200, resp.text
+            data = json.loads(resp.data)["data"]
+            assert len(next(iter(data["model-output"].values()))) == 5
+        # the fleet route over both machines of the one spec: each scores
+        # in a program of its own, and gives what its own route gave
+        resp = client.post(
+            f"/gordo/v0/{PROJECT}/prediction/fleet",
+            json={"X": {name: values for name in MACHINES}},
+        )
+        assert resp.status_code == 200, resp.text
+        answered = json.loads(resp.data)["data"]
+        assert set(answered) == set(MACHINES)
+        for name in MACHINES:
+            alone = client.post(f"/gordo/v0/{PROJECT}/{name}/prediction", json={"X": values})
+            want = pd.DataFrame(json.loads(alone.data)["data"]["model-output"])
+            got = pd.DataFrame(answered[name]["model-output"])
+            assert got.shape == (5, len(TAGS))
+            np.testing.assert_allclose(got.to_numpy(), want.to_numpy(), rtol=1e-5)
+
+
+def series(n=150, f=4, seed=0):
+    return np.random.RandomState(seed).rand(n, f).astype(np.float32)
+
+
+def test_a_member_without_a_member_axis_trains_alone():
+    spec = lfm2_moe(4, **{k: v for k, v in TOY.items() if k not in ("kind", "epochs", "batch_size")})
+    lstm = lstm_model(4, lookback_window=LOOKBACK, encoding_dim=(4,), encoding_func=("tanh",),
+                      decoding_dim=(4,), decoding_func=("tanh",))
+    assert packing.trains_alone(spec) and not packing.trains_alone(lstm)
+    config = FitConfig(epochs=1, batch_size=32, shuffle=False)
+
+    def members(s):
+        X = series()
+        return [WindowedFleetMember(name=f"m{i}", spec=s, series=X,
+                                    targets=window_targets(X, LOOKBACK, 1), seed=i)
+                for i in range(3)]
+
+    for strategy in packing.STRATEGIES:  # the toy's state is far under the cap: the axis decides
+        alone = packing.plan_train_buckets(members(spec), config, strategy=strategy)
+        assert [len(b.members) for b in alone] == [1, 1, 1], strategy
+        assert len({b.bucket_id for b in alone}) == 3 and len({b.n_padded for b in alone}) == 1
+        together = packing.plan_train_buckets(members(lstm), config, strategy=strategy)
+        assert [len(b.members) for b in together] == [3], strategy
+
+
+def test_a_member_larger_than_the_cap_trains_alone(monkeypatch):
+    lstm = lstm_model(4, lookback_window=8)
+    assert not packing.trains_alone(lstm)
+    monkeypatch.setenv(packing.HBM_CAP_ENV, str(1 << 20))  # 1 MiB: 1.1 M weights x 16 bytes exceed it
+    assert packing.trains_alone(lstm_model(50, lookback_window=8))
+    # the published cut: 7.59 GB of state against the 4 GiB cap
+    monkeypatch.delenv(packing.HBM_CAP_ENV)
+    big = lfm2_moe(50, layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+                   num_dense_layers=1, experts_held=8)
+    from gordo_tpu.planner.costmodel import spec_state_bytes
+
+    assert spec_state_bytes(big) == 16 * 474_472_626 > packing.hbm_cap_bytes()
+
+
+def test_the_trainer_refuses_a_stacked_bucket_of_them_and_runs_one():
+    spec = lfm2_moe(4, **{k: v for k, v in TOY.items() if k not in ("kind", "epochs", "batch_size")})
+    config = FitConfig(epochs=1, batch_size=32, shuffle=False)
+    X = series()
+    trainer = FleetTrainer()
+    assert trainer._mesh_for(spec).devices.size == 1
+    members = [WindowedFleetMember(name=f"m{i}", spec=spec, series=X,
+                                   targets=window_targets(X, LOOKBACK, 1), seed=i) for i in range(2)]
+    with pytest.raises(ValueError, match="one member a program"):
+        trainer._train_windowed_bucket(spec, 170, LOOKBACK, members, config)
+    results = trainer.train(members, config)
+    assert [r.name for r in results] == ["m0", "m1"] and all(r.error is None for r in results)
+    assert all(np.isfinite(r.history.history["loss"][-1]) for r in results)
+    # fit_single's derivation: the same seed gives the same member
+    again = trainer.train(members[:1], config)[0]
+    for a, b in zip(jax.tree_util.tree_leaves(again.params), jax.tree_util.tree_leaves(results[0].params)):
+        assert np.array_equal(a, b)
+
+
+def test_a_short_history_cuts_its_folds_over_the_target_rows(built):
+    """145 rows under a lookback of 100: no row fold of TimeSeriesSplit(3)
+    (36 rows) holds a window, so the folds are over rows 100..144."""
+    _, root = built
+    metadata = serializer.load_metadata(os.path.join(root, "compressor-a"))
+    cv = metadata["metadata"]["build_metadata"]["model"]["cross_validation"]
+    starts = sorted(v for k, v in cv["splits"].items() if k.endswith("test-start"))
+    assert len(starts) == 3
+    first_target = pd.Timestamp("2020-01-01T00:00:00+00:00") + pd.Timedelta(minutes=10 * LOOKBACK)
+    assert all(pd.Timestamp(s) > first_target for s in starts)
+    scores = cv["scores"]
+    assert all(np.isfinite(v["fold-mean"]) for v in scores.values())
+
+
+def test_an_lstm_with_a_short_history_takes_the_same_rule_and_a_long_one_keeps_row_folds(tmp_path, caplog):
+    """The rule is the windowed path's, not the backbone's: an LSTM with
+    a lookback of 100 over 145 rows has its folds over the target rows
+    (and says so in its metadata and in the log); the same model over 600
+    rows, where a row fold of 150 holds 51 windows, keeps row folds."""
+    lstm = {"gordo_tpu.models.JaxLSTMForecast": dict(
+        kind="lstm_model", lookback_window=LOOKBACK, encoding_dim=[4], encoding_func=["tanh"],
+        decoding_dim=[4], decoding_func=["tanh"], epochs=1, batch_size=32,
+    )}
+
+    def machine(name, rows, end):
+        return {
+            "name": name,
+            "model": {"gordo_tpu.models.anomaly.diff.DiffBasedAnomalyDetector": {
+                "base_estimator": {"sklearn.pipeline.Pipeline": {"steps": [
+                    "sklearn.preprocessing.MinMaxScaler", lstm,
+                ]}}
+            }},
+            "dataset": {
+                "type": "TimeSeriesDataset",
+                "data_provider": {"type": "RandomDataProvider", "min_size": rows, "max_size": rows},
+                "train_start_date": "2020-01-01T00:00:00+00:00", "train_end_date": end,
+                "resolution": "10min", "tag_list": TAGS,
+            },
+        }
+
+    document = {"project_name": PROJECT, "machines": [
+        machine("short", 145, "2020-01-02T00:00:00+00:00"),
+        machine("long", 600, "2020-01-05T03:50:00+00:00"),
+    ]}
+    config_path = str(tmp_path / "machines.yaml")
+    with open(config_path, "w") as f:
+        yaml.safe_dump(document, f)
+    root = str(tmp_path / "out")
+    with caplog.at_level("WARNING", logger="gordo_tpu.parallel.fleet_build"):
+        gordo_tpu_cli.main(["build-fleet", config_path, root], standalone_mode=False)
+    cvs = {
+        name: serializer.load_metadata(os.path.join(root, name))["metadata"]["build_metadata"][
+            "model"]["cross_validation"]
+        for name in ("short", "long")
+    }
+    assert cvs["short"]["splits"]["folds-over"] == "target-rows"
+    assert "folds-over" not in cvs["long"]["splits"]
+    assert [r for r in caplog.records if "short: no CV fold" in r.getMessage()]
+    assert not [r for r in caplog.records if "long: no CV fold" in r.getMessage()]
+    first_target = pd.Timestamp("2020-01-01T00:00:00+00:00") + pd.Timedelta(minutes=10 * LOOKBACK)  # lookahead 1
+    for name, cv in cvs.items():
+        assert all(np.isfinite(v["fold-mean"]) for v in cv["scores"].values()), name
+        starts = [pd.Timestamp(v) for k, v in cv["splits"].items() if k.endswith("train-start")]
+        # row folds train from the first row, target folds from the first target row
+        assert all(s == (first_target if name == "short" else pd.Timestamp("2020-01-01T00:00:00+00:00")) for s in starts)
+
+
+def test_the_lstm_fit_is_bit_for_bit_what_the_masked_loop_gave():
+    """Without early stopping the epoch loop no longer masks the update
+    with ``stopped`` (a second copy of the state); the masked loop, which
+    early stopping keeps, computes the same numbers: an early stopping
+    that can never stop gives the program as it was."""
+    spec = lstm_model(3, lookback_window=6, encoding_dim=(8,), encoding_func=("tanh",),
+                      decoding_dim=(8,), decoding_func=("tanh",))
+    X = series(80, 3, seed=2)
+    targets = window_targets(X, 6, 0)
+    nv = 96
+    order = np.zeros(nv, np.int32)
+    order[: len(targets)] = np.arange(len(targets))
+    wtr = (np.arange(nv) < 60).astype(np.float32)
+    wval = ((np.arange(nv) >= 60) & (np.arange(nv) < len(targets))).astype(np.float32)
+    params = spec.init_fn()(jax.random.PRNGKey(1), spec)
+    rng = jax.random.PRNGKey(2)
+    outs = {}
+    for name, es in (("plain", None), ("masked", ("loss", 10**9, -1e9, False))):
+        config = FitConfig(epochs=3, batch_size=16, shuffle=True, early_stopping=es)
+        fit = jax.jit(build_raw_windowed_fit_fn(spec, config))
+        opt_state = spec.optimizer.to_optax().init(params)
+        outs[name] = jax.device_get(fit(params, opt_state, X, targets, order, wtr, wval, rng))
+    assert len(outs["plain"]) == len(outs["masked"]) == 5  # no sixth output: the LSTM has no counters
+    for a, b in zip(jax.tree_util.tree_leaves(outs["plain"]), jax.tree_util.tree_leaves(outs["masked"])):
+        assert np.array_equal(a, b)
+    assert int(outs["plain"][4]) == 3
+    # and the fleet's program donates its state without changing a number
+    config = FitConfig(epochs=3, batch_size=16, shuffle=True)
+    stack = lambda t: jax.tree_util.tree_map(lambda a: np.asarray(a)[None], t)  # noqa: E731
+    fleet_out = jax.device_get(_fleet_windowed_fit_program(spec, config)(
+        jax.tree_util.tree_map(jax.numpy.asarray, stack(params)),
+        jax.tree_util.tree_map(jax.numpy.asarray, stack(spec.optimizer.to_optax().init(params))),
+        X[None], targets[None], order[None], wtr[None], wval[None], np.asarray(rng)[None],
+    ))
+    for a, b in zip(jax.tree_util.tree_leaves(fleet_out[0]), jax.tree_util.tree_leaves(outs["plain"][0])):
+        np.testing.assert_allclose(a[0], b, rtol=0, atol=1e-6)
+
+
+def test_a_skipped_padding_step_is_the_masked_step(monkeypatch):
+    """A one-member program skips a batch of padding alone behind a
+    branch; masked, the same batch is a no-op update and a zero
+    contribution: the same parameters and losses, and counters that
+    count the steps that ran."""
+    from gordo_tpu.models import training
+
+    spec = lfm2_moe(4, **{k: v for k, v in TOY.items() if k not in ("kind", "epochs", "batch_size")})
+    X = series(150, 4, seed=3)
+    targets = window_targets(X, LOOKBACK, 1)  # 50 windows
+    order = np.zeros(128, np.int32)
+    order[: len(targets)] = np.arange(len(targets))
+    wtr = (np.arange(128) < 40).astype(np.float32)  # 2 of 4 steps hold a window
+    wval = np.zeros(128, np.float32)
+    config = FitConfig(epochs=2, batch_size=32, shuffle=False)
+    params = spec.init_fn()(jax.random.PRNGKey(1), spec)
+    outs = {}
+    for name in ("skipping", "masked"):
+        training.build_raw_windowed_fit_fn.cache_clear()
+        if name == "masked":
+            monkeypatch.setattr(training, "_skipping_padding", lambda step: step)
+        fit = jax.jit(training.build_raw_windowed_fit_fn(spec, config))
+        opt_state = spec.optimizer.to_optax().init(params)
+        outs[name] = jax.device_get(
+            fit(params, opt_state, X, targets, order, wtr, wval, jax.random.PRNGKey(2))
+        )
+    training.build_raw_windowed_fit_fn.cache_clear()
+    for a, b in zip(jax.tree_util.tree_leaves(outs["skipping"][:5]),
+                    jax.tree_util.tree_leaves(outs["masked"][:5])):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6, equal_nan=True)
+    # the 40 windows that count, 2 experts a token, either way: a slot
+    # of padding routes nothing, in a step that runs or in one that is
+    # masked
+    for name in outs:
+        assert outs[name][5]["pairs_total"].tolist() == [[40 * LOOKBACK * 2] * 2] * 2
+        assert outs[name][5]["steps_run"].tolist() == [2, 2]
+
+
+def test_large_leaves_are_fetched_as_they_are_and_one_member_is_not_copied(monkeypatch):
+    from gordo_tpu.parallel import fleet
+    from gordo_tpu.parallel.fleet import FleetResult, fetch_to_host, stack_member_params
+
+    tree = {"big": jax.numpy.arange(4096, dtype=jax.numpy.float32).reshape(64, 64),
+            "small": jax.numpy.ones((3,), jax.numpy.float32), "count": jax.numpy.array(7)}
+    whole = fetch_to_host(tree)
+    monkeypatch.setattr(fleet, "_COALESCE_MAX_LEAF_BYTES", 1024)
+    split = fetch_to_host(tree)
+    for key in tree:
+        assert isinstance(split[key], np.ndarray) and np.array_equal(split[key], whole[key])
+    leaf = np.arange(6, dtype=np.float32).reshape(2, 3)
+    one = stack_member_params([FleetResult("m", {"w": leaf}, None)])
+    assert one["w"].shape == (1, 2, 3) and np.shares_memory(one["w"], leaf)
+    two = stack_member_params([FleetResult("a", {"w": leaf}, None), FleetResult("b", {"w": leaf}, None)])
+    assert two["w"].shape == (2, 2, 3)

@@ -27,16 +27,29 @@ def url(rest: str) -> str:
     return f"/gordo/v0/{PROJECT}/{rest}"
 
 
+def _remove_snapshots(collection_dir):
+    """Every health-ledger dropping in the (session-scoped) collection
+    dir: the snapshot, its per-worker variants and its shard directory.
+    Whichever server test file ran before on this xdist worker may have
+    left one, and a new ledger restores from what it finds."""
+    import shutil
+
+    stem = os.path.splitext(FLEET_HEALTH_FILE)[0]
+    for name in os.listdir(collection_dir):
+        if name.startswith(stem):
+            path = os.path.join(collection_dir, name)
+            shutil.rmtree(path) if os.path.isdir(path) else os.remove(path)
+
+
 @pytest.fixture(autouse=True)
 def _fresh_ledgers(collection_dir):
     reset_ledgers()
+    _remove_snapshots(collection_dir)
     yield
     reset_ledgers()
     # the collection dir is session-scoped; snapshots must not leak
     # into later tests (e.g. model listings)
-    path = os.path.join(collection_dir, FLEET_HEALTH_FILE)
-    if os.path.exists(path):
-        os.remove(path)
+    _remove_snapshots(collection_dir)
 
 
 def test_fleet_health_route_serves_joined_document(client, collection_dir):
