@@ -73,6 +73,7 @@ from ..ops.activations import resolve_activation
 from ..ops.losses import resolve_loss
 from .nn import init_feedforward
 from .spec import FeedForwardSpec, ModelSpec
+from .training import validation_pass
 
 Params = Dict[str, Dict[str, jnp.ndarray]]
 
@@ -298,19 +299,16 @@ def build_packed_fit_fn(spec: PackedFeedForwardSpec, config):
         if compute_dtype != jnp.float32:
             Xtr, ytr = Xtr.astype(compute_dtype), ytr.astype(compute_dtype)
             Xval, yval = Xval.astype(compute_dtype), yval.astype(compute_dtype)
-        has_val = Xval.shape[0] > 0
+        evaluate_val = validation_pass(
+            wval, lambda p: evaluate(p, Xval, yval, wval), shape=(spec.g,)
+        )
 
         def epoch_body(carry, erng):
             params, opt_state = carry
             params, opt_state, losses_g = train_epoch(
                 params, opt_state, Xtr, ytr, wtr, erng
             )
-            val_g = (
-                evaluate(params, Xval, yval, wval)
-                if has_val
-                else jnp.full((spec.g,), jnp.nan, jnp.float32)
-            )
-            return (params, opt_state), (losses_g, val_g)
+            return (params, opt_state), (losses_g, evaluate_val(params))
 
         rngs = jax.random.split(rng, config.epochs)
         (params, opt_state), (losses, val_losses) = jax.lax.scan(

@@ -161,6 +161,40 @@ def _skipping_padding(step):
     return branching
 
 
+def validation_inputs(wval: np.ndarray, *arrays: np.ndarray, axis: int = 0):
+    """The host's side of the one rule of validation: a fit program holds
+    a validation pass only if the bucket it is compiled for has a
+    validation row. ``wval`` holds the validation weights as stacked on
+    the host, ``axis`` its validation axis (1 behind a member axis) and
+    ``arrays`` whatever else a program reads along that axis alone
+    (``Xval``, ``yval``).
+
+    Returns ``(validation_slots, wval, *arrays)``: the number of slots
+    with a validation weight and, where that is 0, every array cut to
+    length zero along ``axis``, which is what :func:`validation_pass`
+    reads inside the program. A bucket in which any member has such a
+    slot keeps its arrays whole: a stacked program can only mask.
+    """
+    slots = int(np.count_nonzero(wval))
+    if slots:
+        return (slots, wval, *arrays)
+    empty = (slice(None),) * axis + (slice(0, 0),)
+    return (0, wval[empty], *(a[empty] for a in arrays))
+
+
+def validation_pass(wval, evaluate, shape=()):
+    """``evaluate_val`` for a fit program handed ``wval``: ``evaluate``
+    where the validation axis has a length, else the constant NaN of
+    ``shape`` (what ``evaluate`` answers for weights that are all zero:
+    see weighted_mean_loss), with no ``validation`` scope traced, lowered
+    or run. The length of an axis is static, so this is decided when the
+    program is traced: :func:`validation_inputs` hands over length zero
+    when nothing would be validated."""
+    if wval.shape[0] > 0:
+        return evaluate
+    return lambda params: jnp.full(shape, jnp.nan, jnp.float32)
+
+
 def _make_fit_loop(config: FitConfig, train_epoch, evaluate_val):
     """
     The shared epochs×early-stopping scaffold of every fused fit program
@@ -302,7 +336,9 @@ def build_raw_fit_fn(spec: ModelSpec, config: FitConfig):
     Everything — ragged lengths, validation split, fold boundaries — is
     expressed through the weight vectors, so the same function serves the
     single-model path (jit) and the fleet path (jit∘vmap over a stacked
-    model axis, sharded across the mesh).
+    model axis, sharded across the mesh). The one thing read from a
+    shape: validation arrays of no rows mean no validation pass
+    (:func:`validation_pass`).
     """
     forward = forward_fn_for(spec)
     per_sample = resolve_loss(spec.loss)
@@ -374,15 +410,11 @@ def build_raw_fit_fn(spec: ModelSpec, config: FitConfig):
             # model regime is bound by), not the f32 staging buffer
             Xtr, ytr = Xtr.astype(compute_dtype), ytr.astype(compute_dtype)
             Xval, yval = Xval.astype(compute_dtype), yval.astype(compute_dtype)
-        has_val = Xval.shape[0] > 0  # static branch: no-val fleets skip it
-
         fit_tail = _make_fit_loop(
             config,
             train_epoch=lambda p, o, erng: train_epoch(p, o, Xtr, ytr, wtr, erng),
-            evaluate_val=lambda p: (
-                evaluate(p, Xval, yval, wval)
-                if has_val
-                else jnp.array(jnp.nan, jnp.float32)
+            evaluate_val=validation_pass(
+                wval, lambda p: evaluate(p, Xval, yval, wval)
             ),
         )
         return fit_tail(params, opt_state, rng)
@@ -504,7 +536,8 @@ def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
       (the detector-level shuffle of fleet_build, plus padding slots that
       point at window 0 with zero weight).
     - ``wtr``/``wval`` are per-VIRTUAL-slot weights, exactly like the
-      dense path's masks.
+      dense path's masks; a ``wval`` of length zero means no validation
+      pass (:func:`validation_pass`).
 
     Given the same virtual ordering and batch geometry, this trains
     bit-for-bit like the dense path on pre-materialized windows
@@ -603,7 +636,9 @@ def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
             train_epoch=lambda p, o, erng: train_epoch(
                 p, o, series, ytgt, order, wtr, erng
             ),
-            evaluate_val=lambda p: evaluate(p, series, ytgt, order, wval),
+            evaluate_val=validation_pass(
+                wval, lambda p: evaluate(p, series, ytgt, order, wval)
+            ),
         )
         return fit_tail(params, opt_state, rng)
 
@@ -748,7 +783,9 @@ def build_raw_segmented_fit_fn(
             train_epoch=lambda p, o, erng: train_epoch(
                 p, o, series, ytgt, wtr, erng
             ),
-            evaluate_val=lambda p: evaluate(p, series, ytgt, wval),
+            evaluate_val=validation_pass(
+                wval, lambda p: evaluate(p, series, ytgt, wval)
+            ),
         )
         return fit_tail(params, opt_state, rng)
 
@@ -801,8 +838,8 @@ def fit_single_segmented(
     wtr = np.zeros(nv, np.float32)
     wtr[: nw - n_val] = 1.0
     wval = np.zeros(nv, np.float32)
-    if n_val:
-        wval[nw - n_val : nw] = 1.0
+    wval[nw - n_val : nw] = 1.0
+    validation_slots, wval = validation_inputs(wval)
 
     rng = jax.random.PRNGKey(seed)
     rng, init_rng = jax.random.split(rng)
@@ -812,9 +849,10 @@ def fit_single_segmented(
     fit = _segmented_fit_program(spec, config, segments)
     with telemetry.program_span(
         "fit_single_segmented",
-        (spec, config, segments, series.shape, targets.shape),
+        (spec, config, segments, series.shape, targets.shape, wval.shape),
         shape=str(tuple(series.shape)),
         spec=type(spec).__name__,
+        validation_slots=validation_slots,
     ):
         params, _, losses, val_losses, epochs_ran = fit(
             params, opt_state, series, targets, wtr, wval, rng
@@ -903,6 +941,7 @@ def fit_single(
         (spec, config, Xtr.shape, Xval.shape),
         shape=str(tuple(Xtr.shape)),
         spec=type(spec).__name__,
+        validation_slots=n_val,
     ):
         params, _, losses, val_losses, epochs_ran = fit(
             params, opt_state, Xtr, ytr, wtr, Xval, yval, wval, rng

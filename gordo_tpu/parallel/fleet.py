@@ -44,6 +44,7 @@ from ..models.training import (
     History,
     build_raw_fit_fn,
     segmented_config,
+    validation_inputs,
 )
 from ..planner.costmodel import (
     CostModel,
@@ -755,7 +756,11 @@ class FleetTrainer:
         config: FitConfig,
         m_padded: Optional[int] = None,
     ):
-        """Stack + mask a bucket; returns device-sharded arrays.
+        """Stack + mask a bucket; returns the device-sharded ``(X, y, wtr,
+        Xval, yval, wval, rngs)`` and the bucket's ``validation_slots``. With no validation row in the
+        bucket the validation arrays have no rows
+        (models.training.validation_inputs) and the program no
+        validation pass; else they are ``X`` and ``y`` again, masked.
 
         The model axis is padded with zero-weight dummies up to a multiple
         of the mesh's model-axis size (sharding requires divisibility);
@@ -795,21 +800,29 @@ class FleetTrainer:
             wval = np.zeros((m_total, n_padded), np.float32)
             for i, member in enumerate(bucket):
                 _fill_weight_row(wtr, wval, i, member.n, member, config)
+            validation_slots, wval, Xval, yval = validation_inputs(
+                wval, X, y, axis=1
+            )
 
             rngs = host_prng_keys([m.seed for m in bucket] + [0] * (m_total - len(bucket)))
         with telemetry.part_span("h2d"):
             w_sharding = model_data_sharding(self.mesh)
-            X_dev = jax.device_put(X, model_data_sharding(self.mesh, extra_dims=X.ndim - 2))
-            y_dev = (
-                X_dev
-                if y is X
-                else jax.device_put(y, model_data_sharding(self.mesh, extra_dims=y.ndim - 2))
+
+            def put(a):
+                return jax.device_put(
+                    a, model_data_sharding(self.mesh, extra_dims=a.ndim - 2)
+                )
+
+            X_dev = put(X)
+            y_dev = X_dev if y is X else put(y)
+            Xval_dev, yval_dev = (
+                (X_dev, y_dev) if validation_slots else (put(Xval), put(yval))
             )
             wtr, wval, rngs = jax.device_put(
                 (wtr, wval, rngs),
                 (w_sharding, w_sharding, model_sharding(self.mesh, extra_dims=1)),
             )
-            return X_dev, y_dev, wtr, wval, rngs
+            return (X_dev, y_dev, wtr, Xval_dev, yval_dev, wval, rngs), validation_slots
 
     def _train_bucket(
         self,
@@ -819,22 +832,24 @@ class FleetTrainer:
         config: FitConfig,
         m_padded: Optional[int] = None,
     ) -> List[FleetResult]:
-        X, y, wtr, wval, rngs = self._stack_bucket(
+        (*data, rngs), validation_slots = self._stack_bucket(
             spec, n_padded, bucket, config, m_padded=m_padded
         )
+        X, wval = data[0], data[-1]
         params, opt_state, rngs = self._init_bucket_params(spec, rngs)
         fit = _fleet_fit_program(spec, config)
         with telemetry.program_span(
             "fleet_fit",
-            (spec, config, X.shape),
+            (spec, config, X.shape, wval.shape),
             members=len(bucket),
             shape=str(tuple(X.shape)),
             spec=type(spec).__name__,
             bytes=_bucket_nbytes(bucket),
+            validation_slots=validation_slots,
             **_calibration_attrs(spec, config, X.shape[0], X.shape[1]),
         ):
             params, _, losses, val_losses, epochs_ran = _traced_outputs(
-                fit(params, opt_state, X, y, wtr, X, y, wval, rngs)
+                fit(params, opt_state, *data, rngs)
             )
         with telemetry.part_span("collect"):
             return self._collect_results(
@@ -893,6 +908,7 @@ class FleetTrainer:
             _fill_weight_row(row_tr, row_val, 0, member.n, member, config)
             wtr[p, :, gi] = row_tr[0]
             wval[p, :, gi] = row_val[0]
+        validation_slots, wval, Xval, yval = validation_inputs(wval, X, y, axis=1)
 
         # Per-member RNG parity with the unpacked path: each member's key
         # splits into (fit, init) halves; the pack trains with its first
@@ -906,6 +922,11 @@ class FleetTrainer:
         md1 = model_data_sharding(self.mesh, extra_dims=1)
         X_dev, wtr_dev, wval_dev = jax.device_put((X, wtr, wval), (md1, md1, md1))
         y_dev = X_dev if aliased else jax.device_put(y, md1)
+        Xval_dev, yval_dev = (
+            (X_dev, y_dev)
+            if validation_slots
+            else jax.device_put((Xval, yval), (md1, md1))
+        )
         fit_rngs, init_rngs = jax.device_put(
             (fit_keys, init_keys),
             (
@@ -920,18 +941,19 @@ class FleetTrainer:
         fit = _packed_fit_program(pspec, config)
         with telemetry.program_span(
             "fleet_packed_fit",
-            (pspec, config, X.shape),
+            (pspec, config, X.shape, wval.shape),
             members=len(bucket),
             packed=g,
             shape=str(tuple(X.shape)),
             spec=type(spec).__name__,
             bytes=_bucket_nbytes(bucket),
+            validation_slots=validation_slots,
             **_calibration_attrs(spec, config, m_total, n_padded),
         ):
             params, _, losses, val_losses = _traced_outputs(
                 fit(
                     params, opt_state, X_dev, y_dev, wtr_dev,
-                    X_dev, y_dev, wval_dev, fit_rngs,
+                    Xval_dev, yval_dev, wval_dev, fit_rngs,
                 )
             )
 
@@ -997,6 +1019,10 @@ class FleetTrainer:
         m_padded: Optional[int] = None,
     ):
         """Stack a windowed bucket; series replicated over the data axis.
+        Returns the device-sharded ``(series, ytgt, order, wtr, wval,
+        rngs)`` and the bucket's ``validation_slots``; with none, ``wval``
+        has no slots (models.training.validation_inputs) and the program
+        no validation pass.
 
         The per-batch window gather indexes arbitrary series rows, so the
         series (and aligned targets) shard over ``models`` only; the
@@ -1027,13 +1053,14 @@ class FleetTrainer:
                     member.order if member.order is not None else np.arange(nv)
                 )
                 _fill_weight_row(wtr, wval, i, nv, member, config)
+            validation_slots, wval = validation_inputs(wval, axis=1)
 
             rngs = host_prng_keys(
                 [m.seed for m in bucket] + [0] * (m_total - len(bucket))
             )
         with telemetry.part_span("h2d"):
             md = model_data_sharding(mesh)
-            return jax.device_put(
+            arrays = jax.device_put(
                 (series, ytgt, order, wtr, wval, rngs),
                 (
                     model_sharding(mesh, extra_dims=2),
@@ -1044,6 +1071,7 @@ class FleetTrainer:
                     model_sharding(mesh, extra_dims=1),
                 ),
             )
+            return arrays, validation_slots
 
     def _segmented_eligible(
         self, bucket: List[WindowedFleetMember], config: FitConfig
@@ -1075,8 +1103,10 @@ class FleetTrainer:
         config: FitConfig,
         m_padded: Optional[int] = None,
     ) -> List[FleetResult]:
-        series, ytgt, order, wtr, wval, rngs = self._stack_windowed_bucket(
-            spec, n_padded, offset, bucket, config, m_padded=m_padded
+        (series, ytgt, order, wtr, wval, rngs), validation_slots = (
+            self._stack_windowed_bucket(
+                spec, n_padded, offset, bucket, config, m_padded=m_padded
+            )
         )
         params, opt_state, rngs = self._init_bucket_params(spec, rngs)
         segments = self._segmented_eligible(bucket, config)
@@ -1085,6 +1115,7 @@ class FleetTrainer:
             shape=str(tuple(series.shape)),
             spec=type(spec).__name__,
             bytes=_bucket_nbytes(bucket),
+            validation_slots=validation_slots,
             **_calibration_attrs(
                 spec, config, series.shape[0], order.shape[1]
             ),
@@ -1098,7 +1129,7 @@ class FleetTrainer:
             fit = _fleet_segmented_fit_program(spec, config, segments)
             with telemetry.program_span(
                 "fleet_segmented_fit",
-                (spec, config, segments, series.shape),
+                (spec, config, segments, series.shape, wval.shape),
                 **span_attrs,
             ):
                 params, _, losses, val_losses, epochs_ran = _traced_outputs(
@@ -1108,7 +1139,7 @@ class FleetTrainer:
             fit = _fleet_windowed_fit_program(spec, config)
             with telemetry.program_span(
                 "fleet_windowed_fit",
-                (spec, config, series.shape, order.shape),
+                (spec, config, series.shape, order.shape, wval.shape),
                 tokens_per_step=config.batch_size * spec.lookback_window,
                 **span_attrs,
             ) as span:

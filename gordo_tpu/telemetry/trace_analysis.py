@@ -459,7 +459,8 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     ``build_part`` spans: per phase (summed over its re-entries) the wall
     ``seconds``, the ``parts`` recorded inside it (thread-seconds and
     count: a pooled phase's parts can exceed its wall seconds; a device
-    program run in the phase is listed as ``program <name>``) and its
+    program run in the phase is listed as ``program <name>``, a fit
+    program with the ``validation_slots`` of its buckets summed) and its
     ``self_seconds``, the wall time no part or program covers. A part
     covers its own interval (overlapping parts count once); a part
     recorded as a sum over many pieces (``count`` attribute) covers its
@@ -521,6 +522,11 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
             label = str(attributes.get("part", ""))
         if phase in phases:
             add(phase, label, seconds, int(attributes.get("count", 1)))
+            if "validation_slots" in attributes:  # a fit program's span
+                part = phases[phase]["parts"][label]
+                part["validation_slots"] = part.get("validation_slots", 0) + int(
+                    attributes["validation_slots"]
+                )
             if span["name"] == "build_part":
                 for nested, nested_seconds in nested_part_seconds(attributes).items():
                     add(phase, nested, nested_seconds, 1)
@@ -802,6 +808,8 @@ def render_analysis(doc: Dict[str, Any]) -> str:
                 [phase, entry["entries"], entry["seconds"], entry["self_seconds"]]
             )
             for part, measured in entry["parts"].items():
+                if "validation_slots" in measured:
+                    part += f" [validation_slots={measured['validation_slots']}]"
                 rows.append(
                     [f"  {part}", measured["count"], measured["seconds"], ""]
                 )

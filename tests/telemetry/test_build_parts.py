@@ -553,6 +553,24 @@ def test_build_breakdown_self_time_is_what_no_part_covers():
     assert build_breakdown([]) is None
 
 
+def test_build_breakdown_sums_and_renders_a_fit_programs_validation_slots():
+    spans = synthetic_build_spans()
+    program = next(s for s in spans if s["name"] == "device_program")
+    for slots in (0, 48):
+        attributes = {"program": "fleet_windowed_fit", "validation_slots": slots}
+        spans.append(dict(program, attributes=attributes))
+    found = build_breakdown(spans)
+    parts = found["phases"]["cv_train"]["parts"]
+    assert parts["program fleet_windowed_fit"] == {
+        "seconds": 2.5, "count": 2, "validation_slots": 48,
+    }
+    assert "validation_slots" not in parts["program fleet_fit"]
+    rendered = render_analysis(
+        {"trace": "t", "spans_read": len(spans), "build_breakdown": found}
+    )
+    assert "  program fleet_windowed_fit [validation_slots=48]" in rendered
+
+
 def test_trace_cli_prints_the_part_table_under_each_phase(built, tmp_path):
     from click.testing import CliRunner
 
