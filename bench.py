@@ -26,10 +26,7 @@ partial-result artifact written after every stage:
    north-star target is defined on (BASELINE.md: 1000 AEs < 10 min).
 3. **lstm-fleet-train** — BASELINE.json parity configs #3/#4: 50-tag
    sliding-window LSTM autoencoder and forecast fleets with on-device
-   window gathering. Rates land in the final line's extras. A separate
-   last-priority **lstm-experiments** stage measures the segmented
-   stateful-scan path and a recurrence unroll sweep against the
-   window-restart baseline.
+   window gathering. Rates land in the final line's extras.
 4. **parity** — the north star's correctness half: the same hourglass AE
    trained on identical data by the reference's Keras/TF2 engine and by
    the JAX engine, both wrapped in DiffBasedAnomalyDetector with the same
@@ -398,22 +395,6 @@ def fleet_train() -> dict:
     losses = [r.history.history["loss"][-1] for r in results]
     assert all(np.isfinite(losses)), "non-finite training losses"
 
-    # Block-diagonal packing (models/packing.py): same fleet, MXU tiles
-    # filled laterally with G models per matmul. Reported alongside the
-    # baseline so the headroom is visible, per-seat.
-    packed_elapsed = None
-    packing = os.environ.get("BENCH_PACKING", "auto")
-    # "0"/"1" both mean "no packing" — a factor of 1 IS the unpacked
-    # program, and timing it twice would just report jitter as speedup.
-    if packing not in ("0", "1"):
-        packed_trainer = FleetTrainer(
-            packing=packing if packing == "auto" else int(packing)
-        )
-        packed_trainer.train(members, config)  # warmup/compile
-        packed_elapsed, packed_results = _timed_best(packed_trainer, members, config)
-        packed_losses = [r.history.history["loss"][-1] for r in packed_results]
-        assert all(np.isfinite(packed_losses)), "non-finite packed losses"
-
     # Mixed-precision (bf16 compute, f32 master params): same fleet with
     # compute_dtype=bfloat16 — in the HBM-bound regime the win is bounded
     # by how much of the per-step traffic is activations/data vs the f32
@@ -443,20 +424,15 @@ def fleet_train() -> dict:
     steps_per_epoch = n_padded // BATCH
     # fwd = 2*W FLOPs/sample; backward ≈ 2×fwd; + one val forward pass
     # over the padded set per epoch = 2*W*n_padded. These are USEFUL
-    # per-model FLOPs — packing executes extra zero-block FLOPs that are
-    # deliberately not counted as achieved work.
+    # per-model FLOPs.
     flops_per_model = N_EPOCHS * (6 * weight_elems * n_padded + 2 * weight_elems * n_padded)
     total_flops = flops_per_model * N_MODELS
 
-    # The headline (and its derived step/FLOP/MFU figures) describe the
-    # BEST of the unpacked and packed runs, labeled via `mode`.
-    best_elapsed = min(elapsed, packed_elapsed or elapsed)
-    mode = "packed" if packed_elapsed is not None and packed_elapsed < elapsed else "unpacked"
-    achieved = total_flops / best_elapsed
+    achieved = total_flops / elapsed
     device = _device()
     peak, hbm_peak = device_peaks(device["device_kind"])
     mfu = achieved / (peak * device["count"])
-    step_time_s = best_elapsed / (N_EPOCHS * steps_per_epoch)
+    step_time_s = elapsed / (N_EPOCHS * steps_per_epoch)
 
     # -- HBM roofline (the bound the architecture targets; VERDICT r4) -----
     # Per training step per member, counted analytically: f32 params and
@@ -482,7 +458,7 @@ def fleet_train() -> dict:
     # docs/architecture.md argues for this regime)
     hbm_floor_ms = bytes_per_step / (hbm_peak * device["count"]) * 1e3
     log(
-        f"roofline ({mode}): {bytes_per_step / 1e6:.2f} MB/step analytic floor "
+        f"roofline: {bytes_per_step / 1e6:.2f} MB/step analytic floor "
         f"-> {achieved_hbm / 1e9:.1f} GB/s achieved"
         f" = {hbm_pct * 100:.1f}% of {hbm_peak / 1e9:.0f} GB/s peak; "
         f"HBM-floor step {hbm_floor_ms:.3f} ms vs measured "
@@ -494,18 +470,13 @@ def fleet_train() -> dict:
         f"fleet: {N_MODELS} AEs x {N_EPOCHS} epochs in {elapsed:.2f}s "
         f"(final loss mean {np.mean(losses):.5f}) on {device}"
     )
-    if packed_elapsed is not None:
-        log(
-            f"packed fleet: same workload in {packed_elapsed:.2f}s "
-            f"({elapsed / packed_elapsed:.2f}x vs unpacked)"
-        )
     if bf16_elapsed is not None:
         log(
             f"bf16 fleet: same workload in {bf16_elapsed:.2f}s "
             f"({elapsed / bf16_elapsed:.2f}x vs f32)"
         )
     log(
-        f"mfu arithmetic ({mode} run): W={weight_elems} dense weights/model, "
+        f"mfu arithmetic: W={weight_elems} dense weights/model, "
         f"n_padded={n_padded} (from {N_SAMPLES}), steps/epoch={steps_per_epoch}, "
         f"useful flops/model = {N_EPOCHS}*(6+2)*{weight_elems}*{n_padded} = {flops_per_model:.3e}, "
         f"achieved {achieved / 1e9:.1f} GFLOP/s vs peak "
@@ -513,17 +484,8 @@ def fleet_train() -> dict:
         f"-> MFU {mfu * 100:.4f}%"
     )
     return {
-        "models_per_hour": N_MODELS / (best_elapsed / 3600.0),
-        "mode": mode,
-        "elapsed_s": round(best_elapsed, 3),
-        "unpacked_elapsed_s": round(elapsed, 3),
-        "unpacked_models_per_hour": round(N_MODELS / (elapsed / 3600.0), 1),
-        "packed_elapsed_s": (
-            round(packed_elapsed, 3) if packed_elapsed is not None else None
-        ),
-        "packed_speedup": (
-            round(elapsed / packed_elapsed, 3) if packed_elapsed else None
-        ),
+        "models_per_hour": N_MODELS / (elapsed / 3600.0),
+        "elapsed_s": round(elapsed, 3),
         "bf16_elapsed_s": (
             round(bf16_elapsed, 3) if bf16_elapsed is not None else None
         ),
@@ -702,9 +664,7 @@ def fleet_build_e2e() -> dict:
 
 def _lstm_fleet_setup():
     """
-    The ONE LSTM fleet definition both LSTM stages measure — the
-    experiments stage's restart baseline is only comparable to the core
-    `lstm_ae` rate because they share this geometry verbatim.
+    The LSTM fleet definition the LSTM stage measures.
 
     Returns ``(members, config, n_lstm, lstm_kwargs)`` where ``members``
     is a ``members(lookahead)`` factory.
@@ -791,12 +751,11 @@ def lstm_fleet_train() -> dict:
     # -- LSTM roofline: the recurrence is a sequential scan; report the
     # loop-iteration arithmetic so "at the sequential bound" is checkable
     # from the artifact (VERDICT r4 weak #3).
-    from gordo_tpu.models.nn import _lstm_unroll
+    from gordo_tpu.models.nn import LSTM_SCAN_UNROLL as unroll
 
     nw = N_SAMPLES - LSTM_LOOKBACK + 1
     nv = -(-nw // BATCH) * BATCH
     updates_per_epoch = nv // BATCH
-    unroll = _lstm_unroll()
     # fwd scan + bwd scan (recompute+grad) per update, each
     # ceil(lookback/unroll) XLA loop iterations, plus the update step
     loop_iters_per_epoch = updates_per_epoch * (
@@ -839,113 +798,6 @@ def lstm_fleet_train() -> dict:
         "epochs": LSTM_EPOCHS,
         "device": device,
     }
-
-
-# -- stage 2b': LSTM experiments (segmented path, unroll sweep) -------------
-
-
-@stage
-def lstm_experiments() -> dict:
-    """
-    The measured answers to the LSTM 100× question, isolated in their own
-    stage so a budget clamp can never take the core LSTM rates down with
-    them (they run LAST):
-
-    - **segmented (stateful-scan) training** at BENCH_LSTM_SEGMENTED
-      segments/update — the ~lookback× FLOP/HBM cut vs window-restart;
-    - **scan-unroll sweep** — the per-scan-iteration-overhead killer:
-      the same window-restart fleet at GORDO_TPU_LSTM_UNROLL 4 (the
-      default), 15, and 60 (= fully unrolled recurrence, no inner loop).
-      The unroll knob is read at trace time, so each sweep point clears
-      the (spec, config)-keyed program caches to force a rebuild.
-    """
-    from gordo_tpu.models import training as training_mod
-    from gordo_tpu.parallel import FleetTrainer
-    from gordo_tpu.parallel import fleet as fleet_mod
-
-    _setup_jax_cache()
-    members, config, n_lstm, _ = _lstm_fleet_setup()
-
-    def clear_program_caches():
-        # the unroll env var is read at trace time; cached programs for
-        # the same (spec, config) must be rebuilt to pick it up
-        fleet_mod._fleet_windowed_fit_program.cache_clear()
-        fleet_mod._fleet_segmented_fit_program.cache_clear()
-        training_mod.build_raw_windowed_fit_fn.cache_clear()
-        training_mod.build_raw_segmented_fit_fn.cache_clear()
-
-    trainer = FleetTrainer()
-    n_runs = min(2, int(os.environ.get("BENCH_TIMED_RUNS", 2)))
-
-    def measure(label: str) -> float:
-        fleet = members(0)
-        trainer.train(fleet, config)  # warmup/compile
-        # best-of-2 like the core LSTM stage, whose rate is the
-        # denominator of every ratio here
-        elapsed, results = _timed_best(trainer, fleet, config, n=n_runs)
-        losses = [r.history.history["loss"][-1] for r in results]
-        assert all(np.isfinite(losses)), f"non-finite {label} losses"
-        rate = n_lstm / (elapsed / 3600.0)
-        log(f"lstm experiment {label}: {elapsed:.2f}s -> {rate:.0f} models/hour")
-        return rate
-
-    result: dict = {"n_models": n_lstm, "device": _device()}
-
-    # Baseline PINNED to unroll=4 (the shipped default) regardless of any
-    # operator GORDO_TPU_LSTM_UNROLL in the environment — every speedup
-    # ratio below is "vs the default configuration", so the baseline must
-    # actually run it.
-    prior_unroll = os.environ.get("GORDO_TPU_LSTM_UNROLL")
-    try:
-        os.environ["GORDO_TPU_LSTM_UNROLL"] = "4"
-        clear_program_caches()
-        base_rate = measure("restart@unroll=4 (baseline)")
-        result["restart_models_per_hour"] = round(base_rate, 1)
-        result["baseline_unroll"] = 4
-        _flush_stage(result)
-
-        seg = os.environ.get("BENCH_LSTM_SEGMENTED", "4")
-        if seg.isdigit() and int(seg) > 0 and BATCH % int(seg) == 0:
-            # per-point isolation: one failed experiment records its
-            # error and the remaining points still run
-            os.environ["GORDO_TPU_LSTM_SEGMENTED"] = seg
-            try:
-                seg_rate = measure(f"segmented G={seg}")
-                result["segmented_models_per_hour"] = round(seg_rate, 1)
-                result["segmented_speedup"] = round(seg_rate / base_rate, 3)
-            except Exception as exc:  # noqa: BLE001 - isolate the point
-                log(f"segmented measurement failed: {exc}")
-                result["segmented_error"] = f"{type(exc).__name__}: {exc}"
-            finally:
-                os.environ.pop("GORDO_TPU_LSTM_SEGMENTED", None)
-            _flush_stage(result)
-        elif seg not in ("", "0"):
-            log(f"segmented skipped: G={seg!r} invalid for batch {BATCH}")
-
-        for unroll_raw in os.environ.get("BENCH_LSTM_UNROLL_SWEEP", "15,60").split(","):
-            unroll = unroll_raw.strip()
-            if not unroll:
-                continue
-            if not unroll.isdigit():
-                log(f"unroll sweep: skipping non-numeric entry {unroll_raw!r}")
-                continue
-            os.environ["GORDO_TPU_LSTM_UNROLL"] = unroll
-            clear_program_caches()
-            try:
-                rate = measure(f"restart@unroll={unroll}")
-                result[f"unroll_{unroll}_models_per_hour"] = round(rate, 1)
-                result[f"unroll_{unroll}_speedup"] = round(rate / base_rate, 3)
-            except Exception as exc:  # noqa: BLE001 - isolate the point
-                log(f"unroll={unroll} measurement failed: {exc}")
-                result[f"unroll_{unroll}_error"] = f"{type(exc).__name__}: {exc}"
-            _flush_stage(result)
-    finally:
-        if prior_unroll is None:
-            os.environ.pop("GORDO_TPU_LSTM_UNROLL", None)
-        else:
-            os.environ["GORDO_TPU_LSTM_UNROLL"] = prior_unroll
-        clear_program_caches()
-    return result
 
 
 # -- stage 2c: anomaly-score parity vs TF2 ---------------------------------
@@ -1038,7 +890,6 @@ def _emit_result(partial: dict) -> int:
     fleet = partial.get("fleet_train")
     e2e = partial.get("fleet_build_e2e")
     lstm = partial.get("lstm_fleet_train")
-    experiments = partial.get("lstm_experiments")
     reference = partial.get("reference_keras")
     parity_rec = partial.get("parity")
 
@@ -1064,7 +915,6 @@ def _emit_result(partial: dict) -> int:
             "step_time_ms": fleet["step_time_ms"] if fleet else None,
             "achieved_gflops": fleet["achieved_gflops"] if fleet else None,
             "mfu": fleet["mfu"] if fleet else None,
-            "packed_speedup": fleet.get("packed_speedup") if fleet else None,
             "bf16_speedup": fleet.get("bf16_speedup") if fleet else None,
             "e2e_models_per_hour": (
                 round(e2e["models_per_hour"], 1) if e2e else None
@@ -1077,7 +927,6 @@ def _emit_result(partial: dict) -> int:
             "lstm_forecast_models_per_hour": (
                 lstm["lstm_forecast_models_per_hour"] if lstm else None
             ),
-            "lstm_experiments": experiments,
             "roofline": fleet.get("roofline") if fleet else None,
             "lstm_roofline": lstm.get("roofline") if lstm else None,
             "parity": (
@@ -1159,9 +1008,6 @@ def main():
         run_stage(partial, "fleet_build_e2e")
     if not os.environ.get("BENCH_SKIP_LSTM"):
         run_stage(partial, "lstm_fleet_train")
-        # experiments (segmented path, unroll sweep) run LAST: if the
-        # budget clamps anything, it is these, never the core rates
-        run_stage(partial, "lstm_experiments")
 
     sys.exit(_emit_result(partial))
 

@@ -33,9 +33,7 @@ from .training import (
     History,
     fit_config_from_kwargs,
     fit_single,
-    fit_single_segmented,
     predict_fn,
-    segmented_config,
     split_fit_kwargs,
     windowed_loss_and_grad_norms,
 )
@@ -327,28 +325,6 @@ class JaxWindowedBaseEstimator(
         self.spec_ = self._build_spec(factory_kwargs)
         config, host_callbacks = fit_config_from_kwargs(fit_kwargs)
         seed = int(fit_kwargs.get("seed", 42))
-
-        # Opt-in segmented (stateful-scan) training — same env knob as the
-        # fleet path: the raw series goes to the device and the host never
-        # materializes the lookback× window blowup. Host callbacks need
-        # the per-epoch loop, which only the dense program provides;
-        # ineligible fits fall through silently.
-        segments = segmented_config()
-        if (
-            segments
-            and not host_callbacks
-            and config.batch_size % segments == 0
-            and len(targets) >= config.batch_size
-        ):
-            self.params_, self._history = fit_single_segmented(
-                self.spec_,
-                X,
-                targets,
-                config,
-                seed=seed,
-                segments=segments,
-            )
-            return self
 
         windows = sliding_windows(X, self.lookback_window, self.lookahead)
         self.params_, self._history = fit_single(

@@ -41,14 +41,10 @@ _glorot = jax.nn.initializers.glorot_uniform()
 _orthogonal = jax.nn.initializers.orthogonal()
 
 
-def _lstm_unroll() -> int:
-    """Unroll factor for the recurrent scans, forward and backward
-    (GORDO_TPU_LSTM_UNROLL, default 4): several timesteps in one scan
-    iteration, without changing the math. What a timestep costs on the
-    chip, by layer width, is in docs/architecture.md."""
-    from ..utils.env import env_int
-
-    return max(1, env_int("GORDO_TPU_LSTM_UNROLL", 4))
+#: Timesteps a scan iteration of the recurrent scans, forward and backward:
+#: several in one iteration, without changing the math. What a timestep costs
+#: on the chip, by layer width, is in docs/architecture.md.
+LSTM_SCAN_UNROLL = 4
 
 
 def init_feedforward(rng: jax.Array, spec: FeedForwardSpec) -> Params:
@@ -170,7 +166,7 @@ def _lstm_recurrence(activation):
             return carry, keep(gates, carry)
 
         _, kept = jax.lax.scan(
-            step, zero_state(Wh, x_proj), x_proj, unroll=_lstm_unroll()
+            step, zero_state(Wh, x_proj), x_proj, unroll=LSTM_SCAN_UNROLL
         )
         return kept
 
@@ -219,7 +215,7 @@ def _lstm_recurrence(activation):
             (h0, c0),
             (dh_seq, gates_seq, c_prev_seq, c_seq),
             reverse=True,
-            unroll=_lstm_unroll(),
+            unroll=LSTM_SCAN_UNROLL,
         )
         with jax.named_scope("lstm_weight_grad"):
             dWh = jnp.einsum(
@@ -296,37 +292,6 @@ def forward_lstm(
         resolve_activation(spec.out_activation)(out).astype(jnp.float32),
         jnp.zeros((), jnp.float32),
     )
-
-
-def forward_lstm_sequence(
-    spec: LSTMSpec, params: Params, x_seq: jnp.ndarray
-) -> jnp.ndarray:
-    """
-    Run the stacked LSTM over ``x_seq`` of shape ``[time, batch,
-    n_features]`` and emit the Dense-head output at EVERY timestep:
-    ``[time, batch, n_features_out]``.
-
-    This is the segmented-training forward (training.py
-    build_raw_segmented_fit_fn): one recurrence pass over a span of the
-    series yields the many-to-one output of every window ending inside
-    the span, instead of re-running the first ``lookback-1`` steps of
-    each stride-1 window from scratch. The output at time ``t`` equals
-    :func:`forward_lstm` on a window ending at ``t`` whose hidden state
-    was warmed by the span's earlier steps (identical when the span
-    starts exactly ``lookback`` steps before ``t``). Same dtype
-    contract: compute in ``spec.compute_dtype``, float32 out.
-    """
-    dtype = jnp.dtype(spec.compute_dtype)
-    if x_seq.dtype != dtype:
-        x_seq = x_seq.astype(dtype)
-    h_seq = x_seq
-    for i in range(len(spec.dims)):
-        with jax.named_scope(f"lstm_{i}"):  # one scope a layer, as in params
-            h_seq = _lstm_layer(params[f"lstm_{i}"], h_seq, spec.activations[i])
-    out = h_seq @ params["out"]["W"].astype(dtype) + params["out"]["b"].astype(
-        dtype
-    )
-    return resolve_activation(spec.out_activation)(out).astype(jnp.float32)
 
 
 def init_fn_for(spec) -> "object":

@@ -41,17 +41,6 @@ from .spec import ModelSpec
 logger = logging.getLogger(__name__)
 
 
-def segmented_config() -> Optional[int]:
-    """The opt-in segments-per-update for segmented LSTM training (env
-    GORDO_TPU_LSTM_SEGMENTED: 0/unset = off, N = segments per update;
-    see build_raw_segmented_fit_fn for the trade). Shared by the fleet
-    trainer and the single-model estimator path."""
-    from ..utils.env import env_int
-
-    value = env_int("GORDO_TPU_LSTM_SEGMENTED", 0)
-    return value if value > 0 else None
-
-
 @dataclass(frozen=True)
 class FitConfig:
     """Static (hashable) fit configuration — part of the compilation key."""
@@ -182,17 +171,17 @@ def validation_inputs(wval: np.ndarray, *arrays: np.ndarray, axis: int = 0):
     return (0, wval[empty], *(a[empty] for a in arrays))
 
 
-def validation_pass(wval, evaluate, shape=()):
+def validation_pass(wval, evaluate):
     """``evaluate_val`` for a fit program handed ``wval``: ``evaluate``
-    where the validation axis has a length, else the constant NaN of
-    ``shape`` (what ``evaluate`` answers for weights that are all zero:
+    where the validation axis has a length, else the constant NaN
+    (what ``evaluate`` answers for weights that are all zero:
     see weighted_mean_loss), with no ``validation`` scope traced, lowered
     or run. The length of an axis is static, so this is decided when the
     program is traced: :func:`validation_inputs` hands over length zero
     when nothing would be validated."""
     if wval.shape[0] > 0:
         return evaluate
-    return lambda params: jnp.full(shape, jnp.nan, jnp.float32)
+    return lambda params: jnp.full((), jnp.nan, jnp.float32)
 
 
 def _make_fit_loop(config: FitConfig, train_epoch, evaluate_val):
@@ -646,240 +635,9 @@ def build_raw_windowed_fit_fn(spec: ModelSpec, config: FitConfig):
 
 
 @lru_cache(maxsize=None)
-def build_raw_segmented_fit_fn(
-    spec: ModelSpec, config: FitConfig, segments_per_update: int
-):
-    """
-    Segmented (stateful-scan) fit for windowed LSTM models:
-
-    ``(params, opt_state, series[n, F], ytgt[nw, F], wtr[nv], wval[nv],
-    rng) -> (params, opt_state, losses, val_losses, epochs_ran)``
-
-    The window-restart path (build_raw_windowed_fit_fn) re-runs the
-    recurrence from zero state for every stride-1 window: a batch of B
-    windows costs ``B×lookback`` cell applications for ``B+lookback-1``
-    distinct timesteps — a ~``lookback×`` FLOP/HBM redundancy (reference
-    semantics: Keras stateless LSTM over materialized windows,
-    gordo/machine/model/models.py:713-793).
-
-    Here each Adam update still covers the SAME B consecutive windows as
-    the unshuffled windowed path, but computes them as
-    ``segments_per_update`` (G) parallel segments of ``L = B/G``
-    consecutive windows: one recurrence pass of ``L+lookback-1`` steps
-    per segment yields every window output in the segment via
-    :func:`nn.forward_lstm_sequence`. Cell applications per update drop
-    from ``B×lookback`` to ``B + G×(lookback-1)``; sequential depth
-    rises from ``lookback`` to ``L+lookback-1``. ``G=B`` (L=1) is
-    bit-equivalent to the windowed path (tests assert it); small ``G``
-    trades depth for a ~``lookback×`` FLOP cut.
-
-    Semantics difference (the reason this is opt-in): within a segment,
-    window ``j`` at position ``p`` sees hidden state warmed by the
-    ``p-lookback+1`` preceding segment steps instead of starting cold —
-    the first window of each segment is exactly cold, later ones
-    approximate it (LSTM state forgets geometrically). Training is
-    therefore TBPTT-like; serving still scores cold windows. Parity is
-    gated at the anomaly-surface level like TF parity
-    (compat/tf_parity.py), not bit-level.
-
-    Requires ``config.shuffle == False`` (the product LSTM path pins
-    this, matching the reference's unshuffled timeseries generator) and
-    identity window order — segments must be consecutive windows.
-    """
-    if config.shuffle:
-        raise ValueError("segmented LSTM training requires shuffle=False")
-    from .nn import forward_lstm_sequence
-
-    per_sample = resolve_loss(spec.loss)
-    tx = spec.optimizer.to_optax()
-    lookback = spec.lookback_window
-    G = segments_per_update
-    B = config.batch_size
-    if B % G:
-        raise ValueError(f"batch_size {B} not divisible by segments {G}")
-    L = B // G
-    span = L + lookback - 1  # timesteps one segment must read
-
-    def update_loss(params, series, ytgt, starts, w):
-        # starts: [G] window-start heads of this update's segments;
-        # w: [G, L] per-window weights (0 for padding)
-        n = series.shape[0]
-        t_idx = jnp.minimum(starts[:, None] + jnp.arange(span)[None, :], n - 1)
-        segs = series[t_idx]  # [G, span, F]
-        out_seq = forward_lstm_sequence(
-            spec, params, jnp.transpose(segs, (1, 0, 2))
-        )  # [span, G, F_out]
-        outs = jnp.transpose(out_seq[lookback - 1 :], (1, 0, 2))  # [G, L, Fo]
-        w_idx = jnp.minimum(
-            starts[:, None] + jnp.arange(L)[None, :], ytgt.shape[0] - 1
-        )
-        targets = ytgt[w_idx]  # [G, L, F_out]
-        losses = per_sample(
-            outs.reshape(B, -1), targets.reshape(B, -1)
-        )
-        return weighted_mean_loss(losses, w.reshape(B))
-
-    grad_fn = jax.value_and_grad(update_loss)
-
-    def train_epoch(params, opt_state, series, ytgt, wtr, erng):
-        del erng  # shuffle=False: epoch order is the window order
-        nv = wtr.shape[0]
-        K = nv // B  # updates per epoch, same count as the windowed path
-        heads = (
-            jnp.arange(K)[:, None] * B + jnp.arange(G)[None, :] * L
-        )  # [K, G]
-        w_b = wtr.reshape(K, G, L)
-
-        def step(carry, batch):
-            params, opt_state = carry
-            starts, wb = batch
-            loss, grads = grad_fn(params, series, ytgt, starts, wb)
-            updates, new_opt_state = tx.update(grads, opt_state, params)
-            has_data = jnp.sum(wb) > 0
-            params = _tree_where(
-                has_data, optax.apply_updates(params, updates), params
-            )
-            opt_state = _tree_where(has_data, new_opt_state, opt_state)
-            contribution = jnp.where(has_data, loss * jnp.sum(wb), 0.0)
-            return (params, opt_state), contribution
-
-        with jax.named_scope(STEPS_SCOPE):
-            (params, opt_state), weighted_losses = jax.lax.scan(
-                step, (params, opt_state), (heads, w_b)
-            )
-        epoch_loss = jnp.sum(weighted_losses) / jnp.maximum(jnp.sum(wtr), 1.0)
-        return params, opt_state, epoch_loss
-
-    def evaluate(params, series, ytgt, wval):
-        nv = wval.shape[0]
-        K = nv // B
-        heads = jnp.arange(K)[:, None] * B + jnp.arange(G)[None, :] * L
-        w_b = wval.reshape(K, G, L)
-
-        def step(acc, batch):
-            starts, wb = batch
-            loss = update_loss(params, series, ytgt, starts, wb)
-            wsum = jnp.sum(wb)
-            # an all-padding batch yields NaN mean loss; NaN*0 is NaN,
-            # so the guard (not the weight) must zero its contribution
-            contribution = jnp.where(wsum > 0, loss * wsum, 0.0)
-            return (acc[0] + contribution, acc[1] + wsum), None
-
-        with jax.named_scope(VALIDATION_SCOPE):
-            (total, wsum), _ = jax.lax.scan(
-                step,
-                (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-                (heads, w_b),
-            )
-        return jnp.where(wsum > 0, total / wsum, jnp.nan)
-
-    compute_dtype = jnp.dtype(spec.compute_dtype)
-
-    def fit(params, opt_state, series, ytgt, wtr, wval, rng):
-        if compute_dtype != jnp.float32:
-            series, ytgt = series.astype(compute_dtype), ytgt.astype(compute_dtype)
-        fit_tail = _make_fit_loop(
-            config,
-            train_epoch=lambda p, o, erng: train_epoch(
-                p, o, series, ytgt, wtr, erng
-            ),
-            evaluate_val=validation_pass(
-                wval, lambda p: evaluate(p, series, ytgt, wval)
-            ),
-        )
-        return fit_tail(params, opt_state, rng)
-
-    return fit
-
-
-@lru_cache(maxsize=None)
 def _fit_program(spec: ModelSpec, config: FitConfig):
     """Jitted single-model fused fit program for (spec, config)."""
     return jax.jit(build_raw_fit_fn(spec, config))
-
-
-@lru_cache(maxsize=None)
-def _segmented_fit_program(spec: ModelSpec, config: FitConfig, segments: int):
-    """Jitted single-model segmented fit program."""
-    return jax.jit(build_raw_segmented_fit_fn(spec, config, segments))
-
-
-def fit_single_segmented(
-    spec: ModelSpec,
-    series: np.ndarray,
-    targets: np.ndarray,
-    config: FitConfig,
-    seed: int = 42,
-    segments: int = 4,
-) -> Tuple[Any, History]:
-    """
-    Single-model segmented (stateful-scan) LSTM fit: the estimator-path
-    twin of the fleet's segmented program. Takes the RAW ``series[n, F]``
-    and aligned ``targets[nw, F]`` (ops.windows.window_targets) — the
-    host never materializes the ``lookback×`` window blowup the dense
-    single-model path pays. Validation split is the Keras-style tail
-    fraction over the window axis, exactly like :func:`fit_single` over
-    materialized windows. See :func:`build_raw_segmented_fit_fn` for the
-    semantics trade vs window-restart training.
-    """
-    if config.shuffle:
-        raise ValueError("segmented LSTM training requires shuffle=False")
-    series = np.asarray(series, np.float32)
-    targets = np.asarray(targets, np.float32)
-    nw = len(targets)
-    batch_size = config.batch_size
-    if batch_size % segments or nw < batch_size:
-        raise ValueError(
-            f"segments={segments} needs batch_size divisible by it and at "
-            f"least one full batch of windows (nw={nw}, batch={batch_size})"
-        )
-    nv = -(-nw // batch_size) * batch_size
-    n_val = int(nw * config.validation_split)
-    wtr = np.zeros(nv, np.float32)
-    wtr[: nw - n_val] = 1.0
-    wval = np.zeros(nv, np.float32)
-    wval[nw - n_val : nw] = 1.0
-    validation_slots, wval = validation_inputs(wval)
-
-    rng = jax.random.PRNGKey(seed)
-    rng, init_rng = jax.random.split(rng)
-    params = init_fn_for(spec)(init_rng, spec)
-    opt_state = spec.optimizer.to_optax().init(params)
-
-    fit = _segmented_fit_program(spec, config, segments)
-    with telemetry.program_span(
-        "fit_single_segmented",
-        (spec, config, segments, series.shape, targets.shape, wval.shape),
-        shape=str(tuple(series.shape)),
-        spec=type(spec).__name__,
-        validation_slots=validation_slots,
-    ):
-        params, _, losses, val_losses, epochs_ran = fit(
-            params, opt_state, series, targets, wtr, wval, rng
-        )
-        # one coalesced d2h readback — per-element float() would pay the
-        # fixed per-transfer latency once PER EPOCH. Inside the span:
-        # the readback waits on the program, so the span times real
-        # device work, not dispatch.
-        losses, val_losses, epochs_ran = jax.device_get(
-            (losses, val_losses, epochs_ran)
-        )
-    epochs_ran = int(epochs_ran)
-    history = {"loss": [float(l) for l in losses[:epochs_ran]]}
-    if n_val:
-        history["val_loss"] = [float(l) for l in val_losses[:epochs_ran]]
-    return params, History(
-        history=history,
-        params={
-            "epochs": config.epochs,
-            # train-only, like fit_single over materialized windows
-            "steps": (nw - n_val + batch_size - 1) // batch_size,
-            "verbose": 0,
-            "metrics": list(history),
-            "segmented": segments,
-        },
-        epoch=list(range(epochs_ran)),
-    )
 
 
 def fit_single(

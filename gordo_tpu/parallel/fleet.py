@@ -43,7 +43,6 @@ from ..models.training import (
     FitConfig,
     History,
     build_raw_fit_fn,
-    segmented_config,
     validation_inputs,
 )
 from ..planner.costmodel import (
@@ -369,24 +368,6 @@ def _fleet_windowed_fit_program(spec: ModelSpec, config: FitConfig):
 
 
 @lru_cache(maxsize=None)
-def _fleet_segmented_fit_program(
-    spec: ModelSpec, config: FitConfig, segments_per_update: int
-):
-    """jit(vmap) of the segmented (stateful-scan) LSTM fit over the model
-    axis (models/training.py build_raw_segmented_fit_fn)."""
-    from ..models.training import build_raw_segmented_fit_fn
-
-    raw_fit = build_raw_segmented_fit_fn(spec, config, segments_per_update)
-    return _jit_named("fleet_segmented_fit", jax.vmap(raw_fit))
-
-
-#: the shared GORDO_TPU_LSTM_SEGMENTED knob parser lives beside the
-#: segmented program builder (models/training.py) — both the fleet and
-#: the single-model estimator path read it from there
-_segmented_config = segmented_config
-
-
-@lru_cache(maxsize=None)
 def fleet_windowed_predict_program(spec: ModelSpec, batch_size: int):
     """
     jit(vmap) forward for windowed members: windows gathered from the raw
@@ -428,25 +409,6 @@ def fleet_predict_program(spec: ModelSpec):
 
 
 @lru_cache(maxsize=None)
-def _packed_fit_program(pspec, config: FitConfig):
-    """jit(vmap) of the packed block-diagonal fit over the pack axis."""
-    from ..models.packing import build_packed_fit_fn
-
-    return _jit_named(
-        "fleet_packed_fit", jax.vmap(build_packed_fit_fn(pspec, config))
-    )
-
-
-@lru_cache(maxsize=None)
-def _packed_init_program(pspec):
-    from ..models.packing import init_packed
-
-    return _jit_named(
-        "fleet_packed_init", jax.vmap(lambda keys: init_packed(keys, pspec))
-    )
-
-
-@lru_cache(maxsize=None)
 def _fleet_init_program(spec: ModelSpec):
     init = init_fn_for(spec)
 
@@ -472,15 +434,6 @@ class FleetTrainer:
     ----------
     mesh
         Fleet mesh (default: all local devices on the model axis).
-    packing
-        Block-diagonal model packing (models/packing.py): ``None``/1 off,
-        an int for a fixed factor, or ``"auto"`` to fill the 128-lane MXU
-        tile (``128 // widest layer``). Packing G models turns G tiny
-        matmuls into one tile-filling matmul — per-model math is
-        preserved exactly (masked block-diagonal weights; see the module
-        docstring for the shared-shuffle caveat). Applies to feedforward
-        buckets without early stopping; everything else falls back to the
-        unpacked program.
     plan_strategy
         Bucket-construction strategy (``gordo_tpu.planner``): ``naive``
         (the historical exact-key grouping; the default, also via
@@ -499,13 +452,11 @@ class FleetTrainer:
     def __init__(
         self,
         mesh: Optional[Mesh] = None,
-        packing=None,
         plan_strategy: Optional[str] = None,
         fleet_plan: Optional[Any] = None,
         cost_table: Optional[Any] = None,
     ):
         self.mesh = mesh if mesh is not None else make_mesh()
-        self.packing = packing
         self.plan_strategy = plan_strategy
         self.fleet_plan = fleet_plan
         self.cost_table = cost_table
@@ -528,26 +479,6 @@ class FleetTrainer:
         return Mesh(
             self.mesh.devices.reshape(-1)[:1].reshape(1, 1), self.mesh.axis_names
         )
-
-    def _packing_factor(self, spec, n_members: int, config: FitConfig) -> int:
-        from ..models.packing import auto_packing
-        from ..models.spec import FeedForwardSpec
-
-        if not self.packing or self.packing == 1:
-            return 1
-        if not isinstance(spec, FeedForwardSpec):
-            return 1
-        if config.early_stopping is not None:
-            return 1
-        from ..ops.losses import resolve_loss
-
-        try:
-            resolve_loss(spec.loss)
-        except ValueError:
-            return 1
-        if self.packing == "auto":
-            return auto_packing(spec, n_members)
-        return max(1, min(int(self.packing), n_members))
 
     # -- bucketing ----------------------------------------------------------
     # Bucket construction lives in gordo_tpu.planner.packing
@@ -656,30 +587,17 @@ class FleetTrainer:
                     failures,
                 )
                 continue
-            # Sibling HBM-split buckets rely on the shared m_padded rung
-            # for their one-compile contract; the block-diagonal packed
-            # program has no member-axis floor, so those buckets skip it.
-            g = (
-                self._packing_factor(pb.spec, len(bucket), config)
-                if pb.m_padded is None
-                else 1
-            )
             logger.info(
-                "Fleet bucket %s: %d models, spec=%s, padded_n=%d%s",
+                "Fleet bucket %s: %d models, spec=%s, padded_n=%d",
                 pb.bucket_id,
                 len(bucket),
                 type(pb.spec).__name__,
                 pb.n_padded,
-                f", packed x{g}" if g > 1 else "",
             )
             self._run_bucket_degraded(
-                lambda b, _p=pb, _g=g: (
-                    self._train_bucket_packed(_p.spec, _p.n_padded, b, config, _g)
-                    if _g > 1
-                    else self._train_bucket(
-                        _p.spec, _p.n_padded, b, config,
-                        m_padded=bucket_m_padded(_p, b),
-                    )
+                lambda b, _p=pb: self._train_bucket(
+                    _p.spec, _p.n_padded, b, config,
+                    m_padded=bucket_m_padded(_p, b),
                 ),
                 bucket,
                 by_name,
@@ -857,142 +775,6 @@ class FleetTrainer:
                 steps=n_padded // config.batch_size,
             )
 
-    # -- packed training ----------------------------------------------------
-
-    def _train_bucket_packed(
-        self,
-        spec: ModelSpec,
-        n_padded: int,
-        bucket: List[FleetMember],
-        config: FitConfig,
-        g: int,
-    ) -> List[FleetResult]:
-        """
-        Train the bucket as ceil(M/G) block-diagonal supermodels
-        (models/packing.py): G members share each device matmul, filling
-        the MXU tile that a single tiny model would leave ~99% idle.
-        Downstream (scoring, serving, artifacts) sees ordinary per-member
-        params — unpacking happens right here.
-        """
-        from ..models.packing import (
-            PackedFeedForwardSpec,
-            init_packed,
-            unpack_params,
-        )
-
-        pspec = PackedFeedForwardSpec(base=spec, g=g)
-        model_axis = self.mesh.devices.shape[0]
-        data_axis = self.mesh.devices.shape[1] if self.mesh.devices.ndim > 1 else 1
-        packs = -(-len(bucket) // g)
-        packs_total = -(-packs // model_axis) * model_axis
-        m_total = packs_total * g
-        step = int(np.lcm(config.batch_size, data_axis))
-        n_padded = -(-n_padded // step) * step
-
-        f_in, f_out = spec.n_features, spec.n_features_out
-        # AE fleets overwhelmingly train y == X; aliasing skips the second
-        # [P, n, G·F] host block and its device transfer (same optimization
-        # as _stack_bucket's).
-        aliased = f_in == f_out and all(m.y is m.X for m in bucket)
-        X = np.zeros((packs_total, n_padded, g * f_in), np.float32)
-        y = X if aliased else np.zeros((packs_total, n_padded, g * f_out), np.float32)
-        wtr = np.zeros((packs_total, n_padded, g), np.float32)
-        wval = np.zeros((packs_total, n_padded, g), np.float32)
-        for i, member in enumerate(bucket):
-            p, gi = divmod(i, g)
-            X[p, : member.n, gi * f_in : (gi + 1) * f_in] = member.X
-            if not aliased:
-                y[p, : member.n, gi * f_out : (gi + 1) * f_out] = member.y
-            row_tr = np.zeros((1, n_padded), np.float32)
-            row_val = np.zeros((1, n_padded), np.float32)
-            _fill_weight_row(row_tr, row_val, 0, member.n, member, config)
-            wtr[p, :, gi] = row_tr[0]
-            wval[p, :, gi] = row_val[0]
-        validation_slots, wval, Xval, yval = validation_inputs(wval, X, y, axis=1)
-
-        # Per-member RNG parity with the unpacked path: each member's key
-        # splits into (fit, init) halves; the pack trains with its first
-        # member's fit key (one shared shuffle stream per pack).
-        seeds = [m.seed for m in bucket] + [0] * (m_total - len(bucket))
-        member_keys = host_prng_keys(seeds)
-        split_keys = jax.vmap(jax.random.split)(member_keys)
-        fit_keys = np.asarray(split_keys[:, 0]).reshape(packs_total, g, 2)[:, 0]
-        init_keys = np.asarray(split_keys[:, 1]).reshape(packs_total, g, 2)
-
-        md1 = model_data_sharding(self.mesh, extra_dims=1)
-        X_dev, wtr_dev, wval_dev = jax.device_put((X, wtr, wval), (md1, md1, md1))
-        y_dev = X_dev if aliased else jax.device_put(y, md1)
-        Xval_dev, yval_dev = (
-            (X_dev, y_dev)
-            if validation_slots
-            else jax.device_put((Xval, yval), (md1, md1))
-        )
-        fit_rngs, init_rngs = jax.device_put(
-            (fit_keys, init_keys),
-            (
-                model_sharding(self.mesh, extra_dims=1),
-                model_sharding(self.mesh, extra_dims=2),
-            ),
-        )
-
-        params = _packed_init_program(pspec)(init_rngs)
-        params = jax.device_put(params, model_sharding(self.mesh, extra_dims=0))
-        opt_state = _optimizer_init_program(spec)(params)
-        fit = _packed_fit_program(pspec, config)
-        with telemetry.program_span(
-            "fleet_packed_fit",
-            (pspec, config, X.shape, wval.shape),
-            members=len(bucket),
-            packed=g,
-            shape=str(tuple(X.shape)),
-            spec=type(spec).__name__,
-            bytes=_bucket_nbytes(bucket),
-            validation_slots=validation_slots,
-            **_calibration_attrs(spec, config, m_total, n_padded),
-        ):
-            params, _, losses, val_losses = _traced_outputs(
-                fit(
-                    params, opt_state, X_dev, y_dev, wtr_dev,
-                    Xval_dev, yval_dev, wval_dev, fit_rngs,
-                )
-            )
-
-        host_params, losses, val_losses = fetch_to_host((params, losses, val_losses))
-        losses = np.asarray(losses)
-        val_losses = np.asarray(val_losses)
-
-        results = []
-        steps = n_padded // config.batch_size
-        for i, member in enumerate(bucket):
-            p, gi = divmod(i, g)
-            pack_params = jax.tree_util.tree_map(lambda a: a[p], host_params)
-            member_params = jax.tree_util.tree_map(
-                np.asarray, unpack_params(pack_params, pspec, gi)
-            )
-            history = {"loss": [float(l) for l in losses[p][:, gi]]}
-            member_val = val_losses[p][:, gi]
-            if not np.all(np.isnan(member_val)):
-                history["val_loss"] = [float(l) for l in member_val]
-            results.append(
-                FleetResult(
-                    name=member.name,
-                    seed=member.seed,
-                    params=member_params,
-                    history=History(
-                        history=history,
-                        params={
-                            "epochs": config.epochs,
-                            "steps": steps,
-                            "verbose": 0,
-                            "metrics": list(history),
-                            "packed": g,
-                        },
-                        epoch=list(range(config.epochs)),
-                    ),
-                )
-            )
-        return results
-
     def _init_bucket_params(self, spec: ModelSpec, rngs):
         """Per-member init mirroring fit_single's derivation exactly so a
         fleet member trains bit-for-bit like the single-model path: fit rng
@@ -1073,27 +855,6 @@ class FleetTrainer:
             )
             return arrays, validation_slots
 
-    def _segmented_eligible(
-        self, bucket: List[WindowedFleetMember], config: FitConfig
-    ) -> Optional[int]:
-        """Segments-per-update when the opt-in segmented path applies to
-        this bucket, else None. Segments need consecutive windows, so any
-        shuffle or explicit member ordering/weighting keeps the
-        window-restart path."""
-        segments = _segmented_config()
-        if not segments or config.shuffle:
-            return None
-        if config.batch_size % segments:
-            return None
-        if any(
-            m.order is not None
-            or m.train_weights is not None
-            or m.val_weights is not None
-            for m in bucket
-        ):
-            return None
-        return segments
-
     def _train_windowed_bucket(
         self,
         spec: ModelSpec,
@@ -1109,8 +870,11 @@ class FleetTrainer:
             )
         )
         params, opt_state, rngs = self._init_bucket_params(spec, rngs)
-        segments = self._segmented_eligible(bucket, config)
-        span_attrs = dict(
+        fit = _fleet_windowed_fit_program(spec, config)
+        with telemetry.program_span(
+            "fleet_windowed_fit",
+            (spec, config, series.shape, order.shape, wval.shape),
+            tokens_per_step=config.batch_size * spec.lookback_window,
             members=len(bucket),
             shape=str(tuple(series.shape)),
             spec=type(spec).__name__,
@@ -1119,37 +883,14 @@ class FleetTrainer:
             **_calibration_attrs(
                 spec, config, series.shape[0], order.shape[1]
             ),
-        )
-        if segments is not None:
-            logger.info(
-                "Segmented LSTM training: %d segments/update (L=%d)",
-                segments,
-                config.batch_size // segments,
+        ) as span:
+            params, _, losses, val_losses, epochs_ran, *counters = (
+                _traced_outputs(
+                    fit(params, opt_state, series, ytgt, order, wtr, wval, rngs)
+                )
             )
-            fit = _fleet_segmented_fit_program(spec, config, segments)
-            with telemetry.program_span(
-                "fleet_segmented_fit",
-                (spec, config, segments, series.shape, wval.shape),
-                **span_attrs,
-            ):
-                params, _, losses, val_losses, epochs_ran = _traced_outputs(
-                    fit(params, opt_state, series, ytgt, wtr, wval, rngs)
-                )
-        else:
-            fit = _fleet_windowed_fit_program(spec, config)
-            with telemetry.program_span(
-                "fleet_windowed_fit",
-                (spec, config, series.shape, order.shape, wval.shape),
-                tokens_per_step=config.batch_size * spec.lookback_window,
-                **span_attrs,
-            ) as span:
-                params, _, losses, val_losses, epochs_ran, *counters = (
-                    _traced_outputs(
-                        fit(params, opt_state, series, ytgt, order, wtr, wval, rngs)
-                    )
-                )
-                if counters:
-                    span.set(**_fit_counter_attrs(spec, counters[0], len(bucket)))
+            if counters:
+                span.set(**_fit_counter_attrs(spec, counters[0], len(bucket)))
         with telemetry.part_span("collect"):
             return self._collect_results(
                 bucket, params, losses, val_losses, epochs_ran, config,

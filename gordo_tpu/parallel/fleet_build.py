@@ -211,22 +211,7 @@ class FleetBuilder:
     ):
         self.machines = list(machines)
         if trainer is None:
-            # GORDO_TPU_PACKING=auto|<int> turns on block-diagonal model
-            # packing (models/packing.py) for the whole build path —
-            # including the `build-fleet` CLI — without new flags.
-
-            packing: Any = env_str("GORDO_TPU_PACKING", None)
-            if packing and packing != "auto":
-                try:
-                    packing = int(packing)
-                except ValueError:
-                    logger.warning(
-                        "Invalid GORDO_TPU_PACKING=%r (want an int or "
-                        "'auto'); packing disabled",
-                        packing,
-                    )
-                    packing = None
-            trainer = FleetTrainer(packing=packing)
+            trainer = FleetTrainer()
         # Bucket planning (gordo_tpu.planner): strategy / pre-computed
         # FleetPlan / calibrated cost table ride on the trainer — it is
         # the component that materializes buckets. Explicit arguments win

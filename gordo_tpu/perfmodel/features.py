@@ -8,10 +8,8 @@ for the model's benefit:
   fleet trainer since PR 3) carry the planner's static features
   (``flops_per_sample``/``stacked_members``/``stacked_samples``/
   ``epochs``) plus the compile-vs-run split; run spans train the
-  ``device_ms`` target, compile spans the ``compile_ms`` target.
-  Crucially this includes the block-diagonal (g>1) shapes the analytic
-  model is blind to (the PR 5 caveat): the regressor trains on whatever
-  the device actually ran.
+  ``device_ms`` target, compile spans the ``compile_ms`` target: the
+  regressor trains on whatever the device actually ran.
 - ``serve_batch`` spans (``serve_trace*.jsonl``) carry the fused batch
   shape (``padded_members``/``padded_rows``/``precision``) and, since
   PR 20, ``flops_per_sample`` — each with the measured ``device_ms``

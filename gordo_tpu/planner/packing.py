@@ -28,13 +28,6 @@ fix, so existing LSTM fleets DO get new padded shapes on the default
 path) — ``packed`` is the cost-optimized packer. Both are deterministic
 in member order.
 
-Known limitation: the cost model prices the plain ``fleet_fit`` /
-``fleet_windowed_fit`` programs. When the trainer's block-diagonal MXU
-packing kicks in (``GORDO_TPU_PACKING``, g>1) the realized program is
-``fleet_packed_fit`` with a different stacked layout, so predictions
-for those buckets are approximate — predicted-vs-actual telemetry
-still records honestly what ran.
-
 Dependency note: members are duck-typed (``.name``/``.spec``/``.n`` or
 ``.series``/``.n_windows``) — this module must not import
 ``gordo_tpu.parallel`` (the trainer imports *us*).

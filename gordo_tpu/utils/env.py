@@ -133,25 +133,8 @@ def _knobs(*knobs: Knob) -> Dict[str, Knob]:
 KNOBS: Dict[str, Knob] = _knobs(
     # -- Training / device performance ------------------------------------
     Knob(
-        "GORDO_TPU_LSTM_UNROLL", "int", 4,
-        "Recurrence scan unroll factor for LSTM models.",
-        "Performance",
-    ),
-    Knob(
-        "GORDO_TPU_LSTM_SEGMENTED", "int", 0,
-        "Opt-in segmented (stateful-scan) LSTM training: segments per "
-        "update; must divide `batch_size`, requires `shuffle: false` "
-        "(see `docs/architecture.md`).",
-        "Performance",
-    ),
-    Knob(
         "GORDO_TPU_CV_CHUNK_BYTES", "int", 1 << 30,
         "Fleet CV super-bucket memory budget in bytes.",
-        "Performance",
-    ),
-    Knob(
-        "GORDO_TPU_PACKING", "str", None,
-        "Block-diagonal packing factor for fleet programs, or `auto`.",
         "Performance",
     ),
     Knob(

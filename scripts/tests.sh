@@ -39,7 +39,7 @@ case "$component" in
     all)      run -m "not slow" tests/ ;;
     fast)     run -m "not slow" tests/ --ignore=tests/parallel --ignore=tests/models --ignore=tests/server --ignore=tests/serve --ignore=tests/lifecycle ;;
     # The parallel job runs its compile-heavy suites INCLUDING the
-    # slow-marked LSTM/packing/sequence fleet modules — that is exactly
+    # slow-marked sequence fleet module — that is exactly
     # why it has its own matrix job; only the multi-process distributed
     # tests (their own `slow` cost class, run by the `slow` component)
     # are excluded here.

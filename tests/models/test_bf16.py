@@ -102,19 +102,6 @@ def test_bf16_fleet_bucket(sine_data):
         assert np.isfinite(result.history.history["loss"][-1])
 
 
-def test_bf16_packed_fleet(sine_data):
-    spec = feedforward_hourglass(4, compute_dtype="bfloat16")
-    members = [
-        FleetMember(name=f"m{i}", spec=spec, X=sine_data, y=sine_data, seed=i)
-        for i in range(4)
-    ]
-    results = FleetTrainer(packing=2).train(
-        members, FitConfig(epochs=5, batch_size=64)
-    )
-    for result in results:
-        assert np.isfinite(result.history.history["loss"][-1])
-
-
 def test_bf16_lstm_trains(sine_data):
     model = JaxLSTMAutoEncoder(
         kind="lstm_model",

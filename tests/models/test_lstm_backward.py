@@ -115,7 +115,7 @@ def assert_same_gradients(expected, got, tolerance):
 def test_gradients_match_autodiff_of_plain_scan(
     monkeypatch, units, lookback, unroll, dtype, vmapped
 ):
-    monkeypatch.setenv("GORDO_TPU_LSTM_UNROLL", str(unroll))
+    monkeypatch.setattr(nn, "LSTM_SCAN_UNROLL", unroll)
     spec, params, x = make_case(
         units, lookback, dtype, members=MEMBERS if vmapped else None
     )
@@ -147,7 +147,7 @@ def test_undifferentiated_forward_is_bit_identical():
     spec, params, x = make_case(64, 7, "float32")
     plain = jax.jit(
         lambda p, xx: stacked(
-            lambda *a: plain_lstm_layer(*a, nn._lstm_unroll()), spec, p, xx
+            lambda *a: plain_lstm_layer(*a, nn.LSTM_SCAN_UNROLL), spec, p, xx
         )[1]
     )
     new = jax.jit(lambda p, xx: stacked(nn._lstm_layer, spec, p, xx)[1])

@@ -154,28 +154,6 @@ def test_planned_m_padded_bucket_still_bisects_on_oom(monkeypatch):
     )  # every bisected half dropped the floor
 
 
-def test_planned_m_padded_bucket_skips_block_diagonal_packing(monkeypatch):
-    """Sibling HBM-split buckets rely on the shared member rung for
-    their one-compile contract; the block-diagonal packed program has no
-    member-axis floor, so those buckets must take the plain path."""
-    packed_calls = []
-    real_packed = FleetTrainer._train_bucket_packed
-
-    def spy(self, spec, n_padded, bucket, config, g):
-        packed_calls.append(len(bucket))
-        return real_packed(self, spec, n_padded, bucket, config, g)
-
-    monkeypatch.setattr(FleetTrainer, "_train_bucket_packed", spy)
-    members = [_member(f"bp{i}", 128, i) for i in range(4)]
-    results = FleetTrainer(
-        plan_strategy="packed",
-        packing=2,
-        fleet_plan=_split_bin_plan(members),
-    ).train(members, CONFIG)
-    assert all(r.error is None for r in results)
-    assert packed_calls == []
-
-
 def test_builder_packed_persists_plan_journal_and_accuracy(tmp_path):
     """A packed build drops fleet_plan.json beside the artifacts, the
     journal records the plan hash, and the trace carries the plan +

@@ -20,7 +20,6 @@ from gordo_tpu.models.training import (
     VALIDATION_SCOPE,
     FitConfig,
     fit_single,
-    fit_single_segmented,
     validation_inputs,
 )
 from gordo_tpu.ops.windows import sliding_windows, window_targets
@@ -280,44 +279,6 @@ def test_zero_length_validation_axis_on_a_models_by_data_mesh(kind):
             jax.tree_util.tree_leaves(a.params), jax.tree_util.tree_leaves(b.params)
         ):
             np.testing.assert_allclose(leaf_a, leaf_b, rtol=1e-4, atol=1e-6)
-
-
-# -- the other fits under the same rule -------------------------------------------------
-
-
-def test_packed_bucket_follows_the_rule():
-    config = FitConfig(epochs=2, batch_size=16, shuffle=False)
-    members = [_member("dense", f"m{i}", i) for i in range(4)]
-    plain = FleetTrainer(packing=2).train(members, config)
-    assert all("val_loss" not in r.history.history for r in plain)
-    mixed = FleetTrainer(packing=2).train(
-        members[:3] + [_member("dense", "m3", 3, **_tail_weights(16))], config
-    )
-    assert "val_loss" in mixed[3].history.history
-    for a, b in zip(plain[:2], mixed[:2]):  # the pack without the validating member
-        assert "val_loss" not in b.history.history
-        assert a.history.history["loss"] == b.history.history["loss"]
-        _assert_same_bits(a.params, b.params)
-
-
-@pytest.mark.parametrize("validation_split", [0.0, 0.25])
-def test_fit_single_segmented_follows_the_rule(validation_split, tmp_path):
-    config = FitConfig(
-        epochs=2, batch_size=16, shuffle=False, validation_split=validation_split
-    )
-    series = _series(8)
-    sink = tmp_path / "trace.jsonl"
-    recorder = telemetry.SpanRecorder(sink_path=str(sink))
-    with telemetry.activate(recorder):
-        _, history = fit_single_segmented(
-            LSTM_SPEC, series, window_targets(series, LOOKBACK, 0), config, segments=4
-        )
-    recorder.close()
-    (span,) = [json.loads(line) for line in sink.read_text().splitlines()]
-    assert span["attributes"]["program"] == "fit_single_segmented"
-    assert span["attributes"]["validation_slots"] == int(64 * validation_split)
-    assert ("val_loss" in history.history) == bool(validation_split)
-    assert np.isfinite(history.history["loss"]).all()
 
 
 # -- the span: validation_slots, and compile once a variant ---------------------------------

@@ -15,11 +15,6 @@ from gordo_tpu.parallel.fleet import (
     stack_member_params,
 )
 
-#: LSTM fleet compiles are multi-minute on CPU hosts: this suite runs
-#: in the dedicated `parallel` CI job (scripts/tests.sh), outside the
-#: sub-15-minute tier-1 `-m 'not slow'` budget.
-pytestmark = pytest.mark.slow
-
 LOOKBACK = 8
 
 
