@@ -171,6 +171,7 @@ class JaxBaseEstimator(GordoBase, BaseEstimator):
         return spec
 
     def fit(self, X, y, **kwargs):
+        target_is_input = y is X  # fit_single asks it of the arrays it is handed
         if isinstance(y, np.ndarray) and y.ndim == 1:
             y = y.reshape(-1, 1)
         X = X.values if isinstance(X, (pd.DataFrame, pd.Series)) else np.asarray(X)
@@ -185,10 +186,11 @@ class JaxBaseEstimator(GordoBase, BaseEstimator):
         self.spec_ = self._build_spec(factory_kwargs)
         config, host_callbacks = fit_config_from_kwargs(fit_kwargs)
         seed = int(fit_kwargs.get("seed", 42))
+        X = np.asarray(X, np.float32)
         self.params_, self._history = fit_single(
             self.spec_,
-            np.asarray(X, np.float32),
-            np.asarray(y, np.float32),
+            X,
+            X if target_is_input else np.asarray(y, np.float32),
             config,
             seed=seed,
             host_callbacks=host_callbacks,

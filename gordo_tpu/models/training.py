@@ -11,8 +11,12 @@ wrapper (gordo/machine/model/models.py:243-287). Design is TPU-first:
   per-batch (or even per-epoch) host↔device ping-pong.
 - **Static shapes.** Data is padded host-side to a whole number of batches
   with a weight mask; shuffling is a device-side ``jax.random.permutation``
-  per epoch, so the compiled program is reused across epochs and across
-  models with the same (spec, shape).
+  per epoch, an index vector of which each step gathers its batch. The
+  dense fit keeps its samples for that as rows ``[X | y | w]`` packed
+  whole into vector rows of 128 floats, since a gather on the chip moves
+  a vector row at once and an array with rows in lanes an element at a
+  time. The compiled program is reused across epochs and across models
+  with the same (spec, shape).
 - **Keras-compatible semantics** where they matter for parity: the
   validation split is the *last* fraction of the data (taken before
   shuffling), shuffle applies to the training portion only, epoch "loss" is
@@ -23,6 +27,7 @@ logic over a stacked model axis; both paths share these functions.
 """
 
 import logging
+import math
 from dataclasses import dataclass
 from functools import lru_cache
 from typing import Any, Dict, List, Optional, Tuple
@@ -120,9 +125,14 @@ def fit_config_from_kwargs(kwargs: dict) -> Tuple[FitConfig, List[Callback]]:
 #: ``jax.named_scope`` names inside every fused fit program: each
 #: operation's metadata in the HLO (and in a profiler trace) then says
 #: which part of an epoch it belongs to, whatever XLA numbers the fusion
-SHUFFLE_SCOPE = "epoch_shuffle"  # the per-epoch permutation and gathers
+SHUFFLE_SCOPE = "epoch_shuffle"  # the per-epoch permutation, and each batch's gather
 STEPS_SCOPE = "optimizer_steps"  # the scan over an epoch's batches
 VALIDATION_SCOPE = "validation"  # the end-of-epoch validation pass
+
+
+#: floats of one TPU vector row. The dense fit packs whole samples into
+#: rows of this width, because a row of it is what a gather moves at once
+LANES = 128
 
 
 def _tree_where(flag, a, b):
@@ -162,13 +172,14 @@ def validation_inputs(wval: np.ndarray, *arrays: np.ndarray, axis: int = 0):
     with a validation weight and, where that is 0, every array cut to
     length zero along ``axis``, which is what :func:`validation_pass`
     reads inside the program. A bucket in which any member has such a
-    slot keeps its arrays whole: a stacked program can only mask.
+    slot keeps its arrays whole: a stacked program can only mask. An
+    array that is None (no target array: the target is the input) stays so.
     """
     slots = int(np.count_nonzero(wval))
     if slots:
         return (slots, wval, *arrays)
     empty = (slice(None),) * axis + (slice(0, 0),)
-    return (0, wval[empty], *(a[empty] for a in arrays))
+    return (0, wval[empty], *(None if a is None else a[empty] for a in arrays))
 
 
 def validation_pass(wval, evaluate):
@@ -274,16 +285,18 @@ def _make_fit_loop(config: FitConfig, train_epoch, evaluate_val):
 
 
 def _pad_to_batches(
-    X: np.ndarray, y: np.ndarray, batch_size: int
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
-    """Pad to a whole number of batches; returns (X, y, weights, steps)."""
+    X: np.ndarray, y: Optional[np.ndarray], batch_size: int
+) -> Tuple[np.ndarray, Optional[np.ndarray], np.ndarray, int]:
+    """Pad to a whole number of batches; returns (X, y, weights, steps).
+    ``y`` None (the target is the input) stays None."""
     n = X.shape[0]
     steps = max(1, -(-n // batch_size))
     total = steps * batch_size
     pad = total - n
     if pad:
         X = np.concatenate([X, np.repeat(X[-1:], pad, axis=0)], axis=0)
-        y = np.concatenate([y, np.repeat(y[-1:], pad, axis=0)], axis=0)
+        if y is not None:
+            y = np.concatenate([y, np.repeat(y[-1:], pad, axis=0)], axis=0)
     weights = np.concatenate(
         [np.ones(n, dtype=X.dtype), np.zeros(pad, dtype=X.dtype)]
     )
@@ -298,7 +311,7 @@ def _eval_fn(spec: ModelSpec):
     @jax.jit
     def evaluate(params, X, y, w):
         out, _ = forward(spec, params, X)
-        return weighted_mean_loss(per_sample(out, y), w)
+        return weighted_mean_loss(per_sample(out, X if y is None else y), w)
 
     return evaluate
 
@@ -315,6 +328,18 @@ def predict_fn(spec: ModelSpec):
     return predict
 
 
+def shuffle_columns(config: FitConfig, X_row: Tuple[int, ...], y_row=None) -> int:
+    """Columns of the one float32 row a sample that the dense fit packs
+    and its steps gather (the ``shuffle_columns`` of its
+    ``device_program`` span): the inputs' ``X_row`` elements, the
+    targets' ``y_row`` where they are an array of their own (None: the
+    target is the input) and the weight; 0 for a fit that does not
+    shuffle."""
+    if not config.shuffle:
+        return 0
+    return math.prod(X_row) + (0 if y_row is None else math.prod(y_row)) + 1
+
+
 @lru_cache(maxsize=None)
 def build_raw_fit_fn(spec: ModelSpec, config: FitConfig):
     """
@@ -322,16 +347,27 @@ def build_raw_fit_fn(spec: ModelSpec, config: FitConfig):
     (params, opt_state, Xtr, ytr, wtr, Xval, yval, wval, rng) ->
     (params, opt_state, losses[epochs], val_losses[epochs], epochs_ran).
 
+    ``ytr`` and ``yval`` are None where the target is the input (a bare
+    autoencoder; behind a scaler ``X`` is scaled and ``y`` is not): the
+    program then holds and gathers one array where a second parameter,
+    which XLA cannot know to be the same, would be a second copy.
+
     Everything — ragged lengths, validation split, fold boundaries — is
     expressed through the weight vectors, so the same function serves the
     single-model path (jit) and the fleet path (jit∘vmap over a stacked
-    model axis, sharded across the mesh). The one thing read from a
-    shape: validation arrays of no rows mean no validation pass
-    (:func:`validation_pass`).
+    model axis, sharded across the mesh). Two things are read from what
+    it is handed: validation arrays of no rows mean no validation pass
+    (:func:`validation_pass`), and no target array means a sample's row
+    is ``[X | w]`` and not ``[X | y | w]``.
+
+    Inputs (or targets) of more than two dimensions join that row
+    flattened and get their shape back after the gather. A fit that does
+    not shuffle takes the same path with its indices in order.
     """
     forward = forward_fn_for(spec)
     per_sample = resolve_loss(spec.loss)
     tx = spec.optimizer.to_optax()
+    compute_dtype = jnp.dtype(spec.compute_dtype)
 
     def batch_loss(params, xb, yb, wb):
         out, penalty = forward(spec, params, xb)
@@ -342,28 +378,66 @@ def build_raw_fit_fn(spec: ModelSpec, config: FitConfig):
 
     grad_fn = jax.value_and_grad(batch_loss)
 
-    def train_epoch(params, opt_state, Xtr, ytr, wtr, erng):
-        n_total = Xtr.shape[0]
-        steps = n_total // config.batch_size
-        if config.shuffle:
-            # One whole-array permutation per epoch, then contiguous batch
-            # slices via scan-over-xs. Per-batch index gathers were the fleet
-            # hot spot on TPU (measured 2.4× whole-fit slowdown at 256
-            # models): 640 small gather kernels vs 20 large ones.
-            with jax.named_scope(SHUFFLE_SCOPE):
-                perm = jax.random.permutation(erng, n_total)
-                Xtr = jnp.take(Xtr, perm, axis=0)
-                ytr = jnp.take(ytr, perm, axis=0)
-                wtr = jnp.take(wtr, perm, axis=0)
-        batches = (
-            Xtr.reshape((steps, config.batch_size) + Xtr.shape[1:]),
-            ytr.reshape((steps, config.batch_size) + ytr.shape[1:]),
-            wtr.reshape(steps, config.batch_size),
+    def epoch_rows(X, y, w):
+        """The training data as an epoch reads it: ``[X | y | w]``, one
+        float32 row a sample (``y`` left out where it is None), as many
+        whole rows to a vector of ``LANES`` floats as fit, behind the
+        function that takes a batch of them by its indices:
+        ``take_batch(idx) -> (xb, yb, wb)`` with ``xb`` and ``yb``
+        in the compute dtype and ``yb`` being ``xb`` where there was no
+        target array. Which samples a batch holds is the only thing the
+        packing does not change."""
+        n = X.shape[0]
+        arrays = [X] if y is None else [X, y]
+        flat = jnp.concatenate(
+            [a.reshape(n, -1) for a in arrays] + [w[:, None]], axis=1
         )
+        columns = flat.shape[1]
+        ends = np.cumsum([math.prod(a.shape[1:]) for a in arrays])
+        per_vector = max(1, LANES // columns)
+        vectors = -(-n // per_vector)
+        flat = jnp.pad(flat, ((0, vectors * per_vector - n), (0, 0)))
+        flat = flat.reshape(vectors, per_vector * columns)
+        rows = jnp.pad(flat, ((0, 0), (0, -flat.shape[1] % LANES)))
 
-        def step(carry, batch):
+        def take_batch(idx):
+            with jax.named_scope(SHUFFLE_SCOPE):
+                vector = jnp.take(rows, idx // per_vector, axis=0)
+                slot = (idx % per_vector)[:, None]
+                batch = vector[:, :columns]
+                for j in range(1, per_vector):
+                    here = vector[:, j * columns : (j + 1) * columns]
+                    batch = jnp.where(slot == j, here, batch)
+            *parts, wb = jnp.split(batch, ends, axis=1)
+            parts = [
+                part.reshape(idx.shape + a.shape[1:]).astype(compute_dtype)
+                for part, a in zip(parts, arrays)
+            ]
+            return parts[0], parts[-1], wb.reshape(idx.shape)
+
+        return take_batch
+
+    def train_epoch(params, opt_state, take_batch, weight, erng):
+        # The epoch's order is an index vector and each step gathers its
+        # own batch of vector rows (features in lanes: what XLA:TPU moves
+        # 128 floats at a time). The arrays themselves XLA:TPU keeps
+        # with rows in lanes, since 20 features in lanes would pad to
+        # 128, and a gather across lanes costs by the element, about one
+        # a nanosecond: on a v5e at [480, 16384] a take an array (20, 20
+        # and 1 columns) cost 61 ns a row an epoch and one take of the
+        # 41 as one row 54, a batch at a time or all at once alike; the
+        # packed rows cost 12, steps included (PERF.md 6, PR 30).
+        n_total = weight.shape[0]
+        steps = n_total // config.batch_size
+        with jax.named_scope(SHUFFLE_SCOPE):
+            if config.shuffle:
+                order = jax.random.permutation(erng, n_total)
+            else:
+                order = jnp.arange(n_total)
+
+        def step(carry, idx):
             params, opt_state = carry
-            xb, yb, wb = batch
+            xb, yb, wb = take_batch(idx)
             loss, grads = grad_fn(params, xb, yb, wb)
             updates, new_opt_state = tx.update(grads, opt_state, params)
             # An all-padding batch (possible for short members of a padded
@@ -380,9 +454,9 @@ def build_raw_fit_fn(spec: ModelSpec, config: FitConfig):
 
         with jax.named_scope(STEPS_SCOPE):
             (params, opt_state), weighted_losses = jax.lax.scan(
-                step, (params, opt_state), batches
+                step, (params, opt_state), order.reshape(steps, config.batch_size)
             )
-        epoch_loss = jnp.sum(weighted_losses) / jnp.maximum(jnp.sum(wtr), 1.0)
+        epoch_loss = jnp.sum(weighted_losses) / jnp.maximum(jnp.sum(weight), 1.0)
         return params, opt_state, epoch_loss
 
     def evaluate(params, X, y, w):
@@ -390,18 +464,15 @@ def build_raw_fit_fn(spec: ModelSpec, config: FitConfig):
             out, _ = forward(spec, params, X)
             return weighted_mean_loss(per_sample(out, y), w)
 
-    compute_dtype = jnp.dtype(spec.compute_dtype)
-
     def fit(params, opt_state, Xtr, ytr, wtr, Xval, yval, wval, rng):
-        if compute_dtype != jnp.float32:
-            # one on-device cast up front: the epoch scan then re-reads the
-            # half-width copy from HBM every step (the bandwidth the tiny-
-            # model regime is bound by), not the f32 staging buffer
-            Xtr, ytr = Xtr.astype(compute_dtype), ytr.astype(compute_dtype)
-            Xval, yval = Xval.astype(compute_dtype), yval.astype(compute_dtype)
+        take_batch = epoch_rows(Xtr, ytr, wtr)
+        # one on-device cast up front: every epoch's validation pass
+        # re-reads the half-width copy, not the f32 staging buffer
+        Xval = Xval.astype(compute_dtype)
+        yval = Xval if yval is None else yval.astype(compute_dtype)
         fit_tail = _make_fit_loop(
             config,
-            train_epoch=lambda p, o, erng: train_epoch(p, o, Xtr, ytr, wtr, erng),
+            train_epoch=lambda p, o, erng: train_epoch(p, o, take_batch, wtr, erng),
             evaluate_val=validation_pass(
                 wval, lambda p: evaluate(p, Xval, yval, wval)
             ),
@@ -651,16 +722,26 @@ def fit_single(
 ) -> Tuple[Any, History]:
     """
     Train one model described by ``spec`` on host arrays ``(X, y)``.
+    Where ``y is X`` the fit program is handed no target array
+    (:func:`build_raw_fit_fn`).
 
     Returns (params pytree, History). ``host_callbacks`` forces the per-epoch
     host loop; otherwise the whole fit is a single device program.
     """
     n = X.shape[0]
     n_val = int(n * config.validation_split)
-    Xtr_raw, ytr_raw = X[: n - n_val], y[: n - n_val]
-    Xval_raw, yval_raw = X[n - n_val :], y[n - n_val :]
 
-    batch_size = min(config.batch_size, max(1, len(Xtr_raw)))
+    def tail_split(a):
+        """(training rows, validation rows) as float32; no array, none"""
+        if a is None:
+            return None, None
+        a = np.asarray(a, np.float32)
+        return a[: n - n_val], a[n - n_val :]
+
+    Xtr, Xval = tail_split(X)
+    ytr, yval = tail_split(None if y is X else y)
+
+    batch_size = min(config.batch_size, max(1, len(Xtr)))
     if batch_size != config.batch_size:
         config = FitConfig(
             epochs=config.epochs,
@@ -670,11 +751,7 @@ def fit_single(
             early_stopping=config.early_stopping,
         )
 
-    Xtr, ytr, wtr, _ = _pad_to_batches(
-        np.asarray(Xtr_raw, np.float32), np.asarray(ytr_raw, np.float32), batch_size
-    )
-    Xval = np.asarray(Xval_raw, np.float32)
-    yval = np.asarray(yval_raw, np.float32)
+    Xtr, ytr, wtr, _ = _pad_to_batches(Xtr, ytr, batch_size)
     wval = np.ones(len(Xval), np.float32)
 
     rng = jax.random.PRNGKey(seed)
@@ -694,12 +771,15 @@ def fit_single(
         )
 
     fit = _fit_program(spec, config)
+    y_row = None if ytr is None else ytr.shape[1:]
     with telemetry.program_span(
         "fit_single",
-        (spec, config, Xtr.shape, Xval.shape),
+        (spec, config, Xtr.shape, y_row, Xval.shape),
         shape=str(tuple(Xtr.shape)),
         spec=type(spec).__name__,
         validation_slots=n_val,
+        shuffle_columns=shuffle_columns(config, Xtr.shape[1:], y_row),
+        fit_counters=["shuffle_columns"],
     ):
         params, _, losses, val_losses, epochs_ran = fit(
             params, opt_state, Xtr, ytr, wtr, Xval, yval, wval, rng
@@ -747,7 +827,7 @@ def _fit_host_loop(
     )
     evaluate = _eval_fn(spec)
     empty = np.zeros((0,) + Xtr.shape[1:], np.float32)
-    empty_y = np.zeros((0,) + ytr.shape[1:], np.float32)
+    empty_y = None if ytr is None else np.zeros((0,) + ytr.shape[1:], np.float32)
     empty_w = np.zeros((0,), np.float32)
 
     history: Dict[str, List[float]] = {"loss": []}

@@ -43,6 +43,7 @@ from ..models.training import (
     FitConfig,
     History,
     build_raw_fit_fn,
+    shuffle_columns,
     validation_inputs,
 )
 from ..planner.costmodel import (
@@ -679,6 +680,8 @@ class FleetTrainer:
         bucket the validation arrays have no rows
         (models.training.validation_inputs) and the program no
         validation pass; else they are ``X`` and ``y`` again, masked.
+        ``y`` and ``yval`` are None where every member's target is its
+        input (models.training.build_raw_fit_fn).
 
         The model axis is padded with zero-weight dummies up to a multiple
         of the mesh's model-axis size (sharding requires divisibility);
@@ -710,9 +713,11 @@ class FleetTrainer:
                 return out
 
             X = stacked([m.X for m in bucket])
-            # The AE fleet overwhelmingly trains y == X; staging X once and
-            # aliasing saves a second 100s-of-MB host copy and its transfer.
-            y = X if all(m.y is m.X for m in bucket) else stacked([m.y for m in bucket])
+            # A bare AE trains y == X: the block is staged once and the
+            # program told so (no target array), which saves a second
+            # 100s-of-MB host copy, its transfer, and on the device a second
+            # copy of every sample in the rows the steps gather.
+            y = None if all(m.y is m.X for m in bucket) else stacked([m.y for m in bucket])
 
             wtr = np.zeros((m_total, n_padded), np.float32)
             wval = np.zeros((m_total, n_padded), np.float32)
@@ -727,12 +732,13 @@ class FleetTrainer:
             w_sharding = model_data_sharding(self.mesh)
 
             def put(a):
+                if a is None:  # no target array
+                    return None
                 return jax.device_put(
                     a, model_data_sharding(self.mesh, extra_dims=a.ndim - 2)
                 )
 
-            X_dev = put(X)
-            y_dev = X_dev if y is X else put(y)
+            X_dev, y_dev = put(X), put(y)
             Xval_dev, yval_dev = (
                 (X_dev, y_dev) if validation_slots else (put(Xval), put(yval))
             )
@@ -753,17 +759,20 @@ class FleetTrainer:
         (*data, rngs), validation_slots = self._stack_bucket(
             spec, n_padded, bucket, config, m_padded=m_padded
         )
-        X, wval = data[0], data[-1]
+        X, y, wval = data[0], data[1], data[-1]
+        y_row = None if y is None else y.shape[2:]
         params, opt_state, rngs = self._init_bucket_params(spec, rngs)
         fit = _fleet_fit_program(spec, config)
         with telemetry.program_span(
             "fleet_fit",
-            (spec, config, X.shape, wval.shape),
+            (spec, config, X.shape, y_row, wval.shape),
             members=len(bucket),
             shape=str(tuple(X.shape)),
             spec=type(spec).__name__,
             bytes=_bucket_nbytes(bucket),
             validation_slots=validation_slots,
+            shuffle_columns=shuffle_columns(config, X.shape[2:], y_row),
+            fit_counters=["shuffle_columns"],
             **_calibration_attrs(spec, config, X.shape[0], X.shape[1]),
         ):
             params, _, losses, val_losses, epochs_ran = _traced_outputs(

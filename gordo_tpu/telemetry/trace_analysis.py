@@ -460,7 +460,8 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     ``seconds``, the ``parts`` recorded inside it (thread-seconds and
     count: a pooled phase's parts can exceed its wall seconds; a device
     program run in the phase is listed as ``program <name>``, a fit
-    program with the ``validation_slots`` of its buckets summed) and its
+    program with the ``validation_slots`` of its buckets summed and, for
+    the dense fit, the widest ``shuffle_columns`` among them) and its
     ``self_seconds``, the wall time no part or program covers. A part
     covers its own interval (overlapping parts count once); a part
     recorded as a sum over many pieces (``count`` attribute) covers its
@@ -522,10 +523,14 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
             label = str(attributes.get("part", ""))
         if phase in phases:
             add(phase, label, seconds, int(attributes.get("count", 1)))
+            part = phases[phase]["parts"][label]
             if "validation_slots" in attributes:  # a fit program's span
-                part = phases[phase]["parts"][label]
                 part["validation_slots"] = part.get("validation_slots", 0) + int(
                     attributes["validation_slots"]
+                )
+            if "shuffle_columns" in attributes:  # a dense fit program's span
+                part["shuffle_columns"] = max(
+                    part.get("shuffle_columns", 0), int(attributes["shuffle_columns"])
                 )
             if span["name"] == "build_part":
                 for nested, nested_seconds in nested_part_seconds(attributes).items():
@@ -808,8 +813,13 @@ def render_analysis(doc: Dict[str, Any]) -> str:
                 [phase, entry["entries"], entry["seconds"], entry["self_seconds"]]
             )
             for part, measured in entry["parts"].items():
-                if "validation_slots" in measured:
-                    part += f" [validation_slots={measured['validation_slots']}]"
+                counters = [
+                    f"{key}={measured[key]}"
+                    for key in ("validation_slots", "shuffle_columns")
+                    if key in measured
+                ]
+                if counters:
+                    part += f" [{', '.join(counters)}]"
                 rows.append(
                     [f"  {part}", measured["count"], measured["seconds"], ""]
                 )

@@ -332,6 +332,26 @@ def test_compile_counters_in_the_status_and_on_the_root_span(built):
     assert {k: root["attributes"][k] for k in COMPILE_KEYS} == status["compile"]
 
 
+def test_dense_fit_spans_and_the_status_carry_shuffle_columns(built):
+    """Both fit programs of a dense autoencoder's build shuffled rows of
+    its 2 tags and the weight, and no second array for the targets; the
+    status keeps it a fit program, as it keeps a backbone's counters."""
+    _, spans, status = built
+    fits = [
+        s for s in spans
+        if s["name"] == "device_program" and s["attributes"]["program"] == "fleet_fit"
+    ]
+    assert len(fits) == 2
+    for span in fits:
+        assert_span_schema(span)
+        attributes = span["attributes"]
+        assert attributes["shuffle_columns"] == 3 and attributes["validation_slots"] == 0
+        assert attributes["fit_counters"] == ["shuffle_columns"]
+    assert [
+        (c["program"], c["members"], c["shuffle_columns"]) for c in status["fit_counters"]
+    ] == [("fleet_fit", 6, 3), ("fleet_fit", 2, 3)]  # three folds' members, then the fits
+
+
 def test_span_budget_of_a_two_machine_build(built):
     """At most 5 added spans a machine and 8 a device program over the
     lines the parent wrote (which for this job were 45: its phases,
@@ -569,6 +589,26 @@ def test_build_breakdown_sums_and_renders_a_fit_programs_validation_slots():
         {"trace": "t", "spans_read": len(spans), "build_breakdown": found}
     )
     assert "  program fleet_windowed_fit [validation_slots=48]" in rendered
+
+
+def test_build_breakdown_keeps_and_renders_a_dense_fits_shuffle_columns():
+    """Beside ``validation_slots``: the widest row a bucket of the
+    program shuffled (21 = 20 tags and the weight; 41 where a bucket's
+    targets are an array of their own)."""
+    spans = synthetic_build_spans()
+    program = next(s for s in spans if s["name"] == "device_program")
+    for columns in (21, 41):
+        attributes = {
+            "program": "fleet_fit", "validation_slots": 0, "shuffle_columns": columns,
+        }
+        spans.append(dict(program, attributes=attributes))
+    found = build_breakdown(spans)
+    part = found["phases"]["cv_train"]["parts"]["program fleet_fit"]
+    assert part["shuffle_columns"] == 41 and part["validation_slots"] == 0
+    rendered = render_analysis(
+        {"trace": "t", "spans_read": len(spans), "build_breakdown": found}
+    )
+    assert "  program fleet_fit [validation_slots=0, shuffle_columns=41]" in rendered
 
 
 def test_trace_cli_prints_the_part_table_under_each_phase(built, tmp_path):
