@@ -7,7 +7,8 @@ Math parity with the reference (gordo/machine/model/anomaly/diff.py):
     Wraps any estimator + scaler. ``cross_validate`` runs
     TimeSeriesSplit(3); per fold it computes per-tag MAE and the per-
     timestep MSE of *scaled* residuals; thresholds are
-    ``metric.rolling(6).min().max()`` of the **last** fold (plus optional
+    ``metric.rolling(6).min().max()`` of the **last** fold (over every row
+    of a fold that scored fewer than six: :func:`threshold_run`; plus optional
     ``window``-smoothed variants). ``anomaly`` emits tag-level scaled /
     unscaled errors, total (mean-square) errors, optional smoothed columns,
     and confidence = error / threshold.
@@ -46,6 +47,23 @@ def _default_base_estimator():
     from ..estimators import JaxAutoEncoder
 
     return JaxAutoEncoder(kind="feedforward_hourglass")
+
+
+#: rows of the run whose minimum a threshold takes: the reference's
+#: ``metric.rolling(6).min().max()``
+THRESHOLD_RUN = 6
+
+
+def threshold_run(rows: int) -> int:
+    """The run a fold of ``rows`` scored rows takes its thresholds over:
+    :data:`THRESHOLD_RUN`, or every row the fold has where it has fewer
+    (a lookback of weeks leaves a history of months a handful of target
+    rows a fold, and ``rolling(6)`` of four values is no value at all: a
+    model without thresholds, which the server refuses to score). Such
+    thresholds are weaker than the reference's: the metadata of a model
+    that serves them says ``thresholds-degraded`` and the rows of the run
+    (``threshold-run-rows``; the last fold's, whose thresholds serve)."""
+    return max(1, min(THRESHOLD_RUN, rows))
 
 
 class DiffBasedAnomalyDetector(AnomalyDetectorBase):
@@ -111,6 +129,11 @@ class DiffBasedAnomalyDetector(AnomalyDetectorBase):
             metadata["aggregate-thresholds-per-fold"] = (
                 self.aggregate_thresholds_per_fold_
             )
+        if getattr(self, "threshold_run_rows_", THRESHOLD_RUN) < THRESHOLD_RUN:
+            # the thresholds served are a minimum over fewer rows than
+            # the reference's run of six: said where a reader looks
+            metadata["thresholds-degraded"] = True
+            metadata["threshold-run-rows"] = self.threshold_run_rows_
         metadata["window"] = self.window
         metadata["smoothing-method"] = self.smoothing_method
         if getattr(self, "smooth_feature_thresholds_", None) is not None:
@@ -193,10 +216,11 @@ class DiffBasedAnomalyDetector(AnomalyDetectorBase):
             scaled_mse = self._scaled_mse_per_timestep(fold_model, y_true, y_pred)
             mae = self._absolute_error(y_true, y_pred)
 
-            aggregate_threshold_fold = float(scaled_mse.rolling(6).min().max())
+            run = self.threshold_run_rows_ = threshold_run(len(scaled_mse))
+            aggregate_threshold_fold = float(scaled_mse.rolling(run).min().max())
             self.aggregate_thresholds_per_fold_[f"fold-{i}"] = aggregate_threshold_fold
 
-            tag_thresholds_fold = mae.rolling(6).min().max()
+            tag_thresholds_fold = mae.rolling(run).min().max()
             tag_thresholds_fold.name = f"fold-{i}"
             feature_folds[f"fold-{i}"] = tag_thresholds_fold
 

@@ -1,7 +1,9 @@
 """
 Init and forward of :class:`~gordo_tpu.models.spec.BackboneSpec`: the
-four layer kinds of the LFM2-MoE family (HF ``modeling_lfm2_moe``) as
-pure functions over an explicit parameter tree, like :mod:`.nn`.
+layer kinds of the LFM2-MoE family (HF ``modeling_lfm2_moe``) and of
+``kind: keye_vl2`` (the ``qwen3_moe`` shape with DeepSeek-Sparse-
+Attention's indexer) as pure functions over an explicit parameter tree,
+like :mod:`.nn`.
 
 ``u`` is the ``[batch, T, hidden]`` sequence of a batch of windows.
 
@@ -13,10 +15,28 @@ pure functions over an explicit parameter tree, like :mod:`.nn`.
 - ``full_attention``: grouped-query causal attention with an RMSNorm
   over each head of ``q`` and ``k`` and a rotary embedding in the
   half-rotation layout at positions ``0..T-1``.
+- ``sparse_attention`` (:func:`sparse_attention`): the same q, k, v,
+  but query ``t`` attends to ``S_t``, the ``min(t + 1, index_topk)``
+  causal keys of largest index score ``I[t, s] = sum_j w[t, j] *
+  relu(qI[t, j] . kI[s])`` (a small indexer of its own heads over
+  ``stop_gradient(u)``). Computed in square tiles of ``index_chunk``
+  queries by ``index_chunk`` keys: a block of queries against the tiles
+  up to its diagonal under a running softmax, the keys outside ``S_t``
+  masked. No score outlives its tile, forward or backward, nothing
+  above the diagonal is computed, and the loops over blocks and tiles
+  have one body each whatever the window's length: sixteen blocks of
+  sixteen different lengths were sixteen programs to compile, four
+  minutes of them at 8,192 rows. The indexer learns from ``KL(p_t ||
+  r_t)``, ``p`` the head-mean of the attention weights (detached), ``r``
+  the softmax of ``I`` over ``S_t``: the forward's ``penalty``. The
+  forecast loss gives the indexer no gradient and the penalty gives
+  nothing else any (:func:`_selected_attention` has the derivative rule).
 - dense feed-forward ``W_2(silu(u W_1) * (u W_3))``.
 - routed experts (:func:`moe_ffn`): the guide's *share* layer. The
   router scores all published experts (sigmoid), the ``k`` largest
-  ``score + bias`` are chosen, the chosen scores, normalised, weigh.
+  ``score + bias`` are chosen, the chosen scores, normalised, weigh
+  (``router: softmax``: a softmax over all logits, its ``k`` largest
+  renormalised to sum 1, no bias).
   This holder keeps the (token, expert) pairs whose expert it holds,
   sorts them by expert, runs the three products as grouped products
   (``jax.lax.ragged_dot``; XLA:TPU lowers it to a tiled grouped kernel
@@ -42,6 +62,7 @@ the bytes of ``params``, :data:`REMAT_MIN_PARAM_BYTES`. A routed layer
 then still keeps :data:`SAVED_PRODUCTS`.
 """
 
+from functools import partial
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -55,6 +76,12 @@ CONV_SCOPE = "short_conv"
 ATTENTION_SCOPE = "gqa_attention"
 ROUTE_SCOPE = "moe_route"
 EXPERTS_SCOPE = "moe_experts"
+#: ... and of sparse attention's three parts: the index scores (and the
+#: indexer's objective), the k-th largest of each query, the attention's
+#: tiles under the selection
+INDEX_SCOPE = "sparse_index"
+SELECT_SCOPE = "sparse_select"
+SPARSE_ATTENTION_SCOPE = "sparse_attention"
 
 #: what a rematerialised routed layer keeps for its backward pass: the two
 #: grouped products that feed the gate. They are the part of a step whose
@@ -64,6 +91,12 @@ EXPERTS_SCOPE = "moe_experts"
 #: the published widths 2.6 GiB more scratch over four layers: 13.0 of
 #: 15.75 GiB by the compiler's count for a v5e)
 SAVED_PRODUCTS = ("moe_h1", "moe_h3")
+
+#: what a rematerialised ``sparse_attention`` layer keeps besides: the
+#: selection, a bit a (query, key) pair (8 MB a window of 8,192 rows).
+#: With it the rematerialised forward masks and does not select a second
+#: time, and what it masks is what the first forward masked, to the bit
+SAVED_SELECTION = "sparse_selection"
 
 #: parameters of at least this many bytes rematerialise their layers in
 #: the backward pass: below it a step's saved activations are small
@@ -93,8 +126,11 @@ def init_backbone(rng: jax.Array, spec: BackboneSpec) -> Dict:
     fan-in (the config states no initialiser), norm gains one, the conv
     taps uniform in +-1/sqrt(L), the expert bias a small seeded buffer."""
     h, dh = spec.hidden_size, spec.head_dim
-    kv = spec.num_key_value_heads * dh
-    keys = iter(jax.random.split(rng, 2 + 8 * len(spec.layer_ops)))
+    kv, qo = spec.num_key_value_heads * dh, spec.num_attention_heads * dh
+    # a split of another length is other keys: the kinds that were here
+    # before the indexer keep their 8 a layer, and so their weights
+    a_layer = 12 if "sparse_attention" in spec.layer_ops else 8
+    keys = iter(jax.random.split(rng, 2 + a_layer * len(spec.layer_ops)))
     ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
     params: Dict = {
         "embed": {
@@ -115,12 +151,20 @@ def init_backbone(rng: jax.Array, spec: BackboneSpec) -> Dict:
             }
         else:
             layer["attn"] = {
-                "wq": _normal(next(keys), (h, h), h),
+                "wq": _normal(next(keys), (h, qo), h),
                 "wk": _normal(next(keys), (h, kv), h),
                 "wv": _normal(next(keys), (h, kv), h),
-                "wo": _normal(next(keys), (h, h), h),
+                "wo": _normal(next(keys), (qo, h), qo),
                 "q_norm": ones(dh),
                 "k_norm": ones(dh),
+            }
+        if op == "sparse_attention":
+            heads, width = spec.index_n_heads, spec.index_head_dim
+            layer["indexer"] = {
+                "wq": _normal(next(keys), (h, heads * width), h),
+                "wk": _normal(next(keys), (h, width), h),
+                "k_norm": {"gain": ones(width), "bias": jnp.zeros((width,), jnp.float32)},
+                "w": _normal(next(keys), (h, heads), h),
             }
         if ffn == "dense":
             width = spec.intermediate_size
@@ -131,14 +175,16 @@ def init_backbone(rng: jax.Array, spec: BackboneSpec) -> Dict:
             }
         else:
             width, held = spec.moe_intermediate_size, spec.experts_held
-            layer["moe"] = {
-                "router": _normal(next(keys), (h, spec.num_experts), h),
-                "expert_bias": 0.01
-                * jax.random.normal(next(keys), (spec.num_experts,), jnp.float32),
-                "w1": _normal(next(keys), (held, h, width), h),
-                "w3": _normal(next(keys), (held, h, width), h),
-                "w2": _normal(next(keys), (held, width, h), width),
-            }
+            layer["moe"] = {"router": _normal(next(keys), (h, spec.num_experts), h)}
+            if spec.router == "sigmoid_bias":
+                layer["moe"]["expert_bias"] = 0.01 * jax.random.normal(
+                    next(keys), (spec.num_experts,), jnp.float32
+                )
+            layer["moe"].update(
+                w1=_normal(next(keys), (held, h, width), h),
+                w3=_normal(next(keys), (held, h, width), h),
+                w2=_normal(next(keys), (held, width, h), width),
+            )
         params[f"layer_{i}"] = layer
     params["head"] = {
         "norm": ones(h),
@@ -185,17 +231,28 @@ def rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
+def _heads(spec: BackboneSpec, w: Dict, u: jnp.ndarray):
+    """``u [B, T, hidden]`` -> ``(q [B, T, heads, dh], k, v [B, T,
+    kv_heads, dh])``: the projections, the per-head RMSNorm of ``q`` and
+    ``k`` and their rotary embedding, as both attentions take them."""
+    dtype = u.dtype
+    batch, length, _ = u.shape
+    heads, kv_heads, dh = spec.num_attention_heads, spec.num_key_value_heads, spec.head_dim
+    q = (u @ w["wq"].astype(dtype)).reshape(batch, length, heads, dh)
+    k = (u @ w["wk"].astype(dtype)).reshape(batch, length, kv_heads, dh)
+    v = (u @ w["wv"].astype(dtype)).reshape(batch, length, kv_heads, dh)
+    q = rotary(rms_norm(q, w["q_norm"], spec.norm_eps), spec.rope_theta)
+    k = rotary(rms_norm(k, w["k_norm"], spec.norm_eps), spec.rope_theta)
+    return q, k, v
+
+
 def gqa_attention(spec: BackboneSpec, w: Dict, u: jnp.ndarray) -> jnp.ndarray:
     dtype = u.dtype
     batch, length, _ = u.shape
     heads, kv_heads, dh = spec.num_attention_heads, spec.num_key_value_heads, spec.head_dim
     group = heads // kv_heads
     with jax.named_scope(ATTENTION_SCOPE):
-        q = (u @ w["wq"].astype(dtype)).reshape(batch, length, heads, dh)
-        k = (u @ w["wk"].astype(dtype)).reshape(batch, length, kv_heads, dh)
-        v = (u @ w["wv"].astype(dtype)).reshape(batch, length, kv_heads, dh)
-        q = rotary(rms_norm(q, w["q_norm"], spec.norm_eps), spec.rope_theta)
-        k = rotary(rms_norm(k, w["k_norm"], spec.norm_eps), spec.rope_theta)
+        q, k, v = _heads(spec, w, u)
         # each key/value head serves `group` query heads: no repeat of k, v
         q = q.reshape(batch, length, kv_heads, group, dh)
         scores = jnp.einsum("bqngd,bknd->bngqk", q, k) * (1.0 / jnp.sqrt(float(dh))).astype(dtype)
@@ -204,6 +261,309 @@ def gqa_attention(spec: BackboneSpec, w: Dict, u: jnp.ndarray) -> jnp.ndarray:
         weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
         out = jnp.einsum("bngqk,bknd->bqngd", weights, v)
         return out.reshape(batch, length, heads * dh) @ w["wo"].astype(dtype)
+
+
+def layer_norm(x, gain, bias, eps):
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    variance = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+    return (x - mean) * jax.lax.rsqrt(variance + eps) * gain + bias
+
+
+def indexer_inputs(spec: BackboneSpec, w: Dict, u: jnp.ndarray):
+    """``u [B, T, hidden]`` -> the indexer's ``(qI [B, T, heads, d], kI
+    [B, T, d], wI [B, T, heads])``, the two scales folded into ``wI``.
+    The indexer sees its input detached and computes in float32 at full
+    precision whatever the layer's dtype: what it feeds is a choice."""
+    heads, width = spec.index_n_heads, spec.index_head_dim
+    batch, length, _ = u.shape
+    x = jax.lax.stop_gradient(u).astype(jnp.float32)
+    project = lambda m: jnp.dot(x, m, precision=jax.lax.Precision.HIGHEST)  # noqa: E731
+    qi = rotary(project(w["wq"]).reshape(batch, length, heads, width), spec.rope_theta)
+    ki = layer_norm(project(w["wk"]), w["k_norm"]["gain"], w["k_norm"]["bias"], spec.norm_eps)
+    ki = rotary(ki[:, :, None, :], spec.rope_theta)[:, :, 0, :]
+    wi = project(w["w"]) * (float(heads) ** -0.5 * float(width) ** -0.5)
+    return qi, ki, wi
+
+
+def index_scores(qi: jnp.ndarray, ki: jnp.ndarray, wi: jnp.ndarray) -> jnp.ndarray:
+    """``I[t, s] = sum_j wI[t, j] * relu(qI[t, j] . kI[s])``: ``qI [Q,
+    heads, d]``, ``kI [S, d]``, ``wI [Q, heads]`` -> ``[Q, S]`` float32.
+    At full float32 precision, as the router's logits are: the scores
+    choose, and a product of bfloat16-rounded operands would put about
+    one key in a thousand on the other side of the k-th largest than
+    the reference's float32 has it."""
+    dots = jnp.einsum("qjd,sd->qjs", qi, ki, precision=jax.lax.Precision.HIGHEST)
+    return jnp.sum(wi[:, :, None] * jax.nn.relu(dots), axis=1)
+
+
+def _blocked(a: jnp.ndarray, chunk: int) -> jnp.ndarray:
+    """``a [T, ...]`` -> ``[blocks, chunk, ...]``, zeros after row ``T``."""
+    blocks = -(-a.shape[0] // chunk)
+    a = jnp.pad(a, ((0, blocks * chunk - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
+    return a.reshape((blocks, chunk) + a.shape[1:])
+
+
+def _pack_bits(keep: jnp.ndarray) -> jnp.ndarray:
+    """``keep [Q, tiles, chunk]`` bool -> ``[Q, tiles, ceil(chunk / 32)]``
+    uint32, key ``s`` of a tile in bit ``s % 32`` of word ``s // 32``."""
+    rows, tiles, chunk = keep.shape
+    words = -(-chunk // 32)
+    padded = jnp.pad(keep, ((0, 0), (0, 0), (0, words * 32 - chunk))).reshape(rows, tiles, words, 32)
+    return jnp.sum(padded.astype(jnp.uint32) << jnp.arange(32, dtype=jnp.uint32), axis=-1)
+
+
+def _unpack_bits(packed: jnp.ndarray, chunk: int) -> jnp.ndarray:
+    """:func:`_pack_bits` back, a tile or all of them: ``[..., words]``
+    uint32 -> ``[..., chunk]`` bool."""
+    bits = (packed[..., None] >> jnp.arange(32, dtype=jnp.uint32)) & jnp.uint32(1)
+    return bits.reshape(packed.shape[:-1] + (-1,))[..., :chunk].astype(bool)
+
+
+def _tile(a: jnp.ndarray, j, axis: int = 0) -> jnp.ndarray:
+    return jax.lax.dynamic_index_in_dim(a, j, axis, keepdims=False)
+
+
+def select_keys(spec: BackboneSpec, qi, ki, wi) -> jnp.ndarray:
+    """One window's selection ``S_t`` of every query, a bit a key, in
+    tiles of ``index_chunk`` queries by ``index_chunk`` keys: blocked
+    inputs (:func:`_blocked`) -> ``[blocks, chunk, blocks, words]``
+    uint32 (:func:`_pack_bits`; 8 MB for 8,192 rows where the scores it
+    is taken from are 268). ``S_t`` is what ``jax.lax.top_k`` of the
+    causal index scores takes: the ``index_topk`` largest, of equal
+    scores the earlier key (a relu makes exact ties: every head's
+    product negative is a score of 0). Found from the k-th entry of that
+    top-k alone: a key is kept if its score is larger, or equal and its
+    position no later. One loop over the blocks of queries; a block's
+    scores are computed a tile at a time up to its diagonal, the tiles
+    above it never."""
+    blocks, chunk = qi.shape[:2]
+    keys = blocks * chunk
+    qi, ki, wi = jax.lax.stop_gradient((qi, ki, wi))
+    positions = jnp.arange(keys)
+
+    def block_of(i):
+        causal = positions[None, :] <= (i * chunk + jnp.arange(chunk))[:, None]
+
+        def chosen():
+            def write(j, scores):
+                with jax.named_scope(INDEX_SCOPE):
+                    tile = index_scores(_tile(qi, i), _tile(ki, j), _tile(wi, i))
+                return jax.lax.dynamic_update_slice(scores, tile, (0, j * chunk))
+
+            scores = jnp.full((chunk, keys), -jnp.inf, jnp.float32)
+            scores = jax.lax.fori_loop(0, i + 1, write, scores)
+            with jax.named_scope(SELECT_SCOPE):
+                scores = jnp.where(causal, scores, -jnp.inf)
+                # the k-th largest is -inf for a query with fewer causal
+                # keys than that, which keeps them all
+                values, at = jax.lax.top_k(scores, spec.index_topk)
+                kth, kth_at = values[:, -1:], at[:, -1:]
+                keep = (scores > kth) | ((scores == kth) & (positions[None, :] <= kth_at))
+                return keep & causal
+
+        if spec.index_topk >= keys:  # no query has more causal keys than it may keep
+            keep = causal
+        else:  # nor has one of a block that ends at or before row top-k
+            keep = jax.lax.cond((i + 1) * chunk <= spec.index_topk, lambda: causal, chosen)
+        return _pack_bits(keep.reshape(chunk, blocks, chunk))
+
+    return jax.lax.map(block_of, jnp.arange(blocks))
+
+
+def _tile_scores(q, k, keep, scale):
+    """A tile of the attention's logits under the selection: ``q [Q, n,
+    g, dh]``, ``k [S, n, dh]``, ``keep [Q, S]`` -> ``[n, g, Q, S]``
+    float32, ``-inf`` outside the selection."""
+    scores = jnp.einsum("qngd,knd->ngqk", q, k) * scale.astype(q.dtype)
+    return jnp.where(keep, scores.astype(jnp.float32), -jnp.inf)
+
+
+def _log_sum_exp_step(top, total, scores):
+    """One step of a running ``log(sum(exp))`` over the last axis:
+    ``(top, total)`` so far and a tile of scores -> ``(top, total, the
+    tile's exp(scores - top), what the sums so far scale by)``; rows that
+    have seen nothing yet stay at ``(-inf, 0)``."""
+    new_top = jnp.maximum(top, jnp.max(scores, axis=-1))
+    safe = jnp.where(jnp.isfinite(new_top), new_top, 0.0)
+    weights = jnp.exp(scores - safe[..., None])
+    scale = jnp.exp(top - safe)
+    return new_top, total * scale + jnp.sum(weights, axis=-1), weights, scale
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _selected_attention(length: int, q, k, v, qi, ki, wi, selected):
+    """One window's attention under its selection and the indexer's
+    objective, blocked inputs (:func:`_blocked`; ``length`` rows of them
+    are the window's): ``(out [blocks, chunk, n, g, dh], sum over t of
+    KL(p_t || r_t))``. A block of queries at a time against the tiles
+    of keys up to its diagonal, in loops (one body whatever the length):
+    the running softmax for the output and its log-normaliser, then a
+    second pass for ``p``, the head-mean of the attention's weights,
+    which needs that normaliser. Its own derivative rule: the backward
+    pass recomputes a tile's weights from the normalisers kept, so no
+    score outlives its tile; the output's cotangent reaches ``q``, ``k``,
+    ``v`` alone and the objective's the indexer alone (``p`` is a
+    constant there): the two gradients are disjoint by construction."""
+    return _selected_attention_fwd(length, q, k, v, qi, ki, wi, selected)[0]
+
+
+def _selected_attention_fwd(length, q, k, v, qi, ki, wi, selected):
+    blocks, chunk, kv_heads, group, dh = q.shape
+    scale = 1.0 / jnp.sqrt(jnp.float32(dh))
+
+    def block_of(i):
+        q_i, qi_i, wi_i, selected_i = _tile(q, i), _tile(qi, i), _tile(wi, i), _tile(selected, i)
+        valid = (i * chunk + jnp.arange(chunk) < length)[:, None]
+
+        def attend(j, carry):
+            top, total, acc = carry
+            keep = _unpack_bits(_tile(selected_i, j, 1), chunk)
+            with jax.named_scope(SPARSE_ATTENTION_SCOPE):
+                scores = _tile_scores(q_i, _tile(k, j), keep, scale)
+                top, total, weights, rescale = _log_sum_exp_step(top, total, scores)
+                acc = acc * rescale[..., None] + jnp.einsum(
+                    "ngqk,knd->ngqd", weights.astype(q.dtype), _tile(v, j)
+                ).astype(jnp.float32)
+            return top, total, acc
+
+        rows = (kv_heads, group, chunk)
+        top, total, acc = jax.lax.fori_loop(
+            0, i + 1, attend,
+            (jnp.full(rows, -jnp.inf, jnp.float32), jnp.zeros(rows, jnp.float32),
+             jnp.zeros(rows + (dh,), jnp.float32)),
+        )
+        out = jnp.transpose(acc / total[..., None], (2, 0, 1, 3)).astype(q.dtype)
+        log_z = top + jnp.log(total)
+
+        def objective(j, carry):
+            # KL(p || r) = sum p log p - sum p I + log(sum exp I) sum p,
+            # the sums over the keys kept: one pass
+            top_i, total_i, p_log_p, p_index, p_sum = carry
+            keep = _unpack_bits(_tile(selected_i, j, 1), chunk) & valid
+            with jax.named_scope(SPARSE_ATTENTION_SCOPE):
+                scores = _tile_scores(q_i, _tile(k, j), keep, scale)
+                p = jnp.mean(jnp.exp(scores - log_z[..., None]), axis=(0, 1))
+            with jax.named_scope(INDEX_SCOPE):
+                index = jnp.where(keep, index_scores(qi_i, _tile(ki, j), wi_i), -jnp.inf)
+                top_i, total_i, _, _ = _log_sum_exp_step(top_i, total_i, index)
+                seen = keep & (p > 0)
+                p_log_p = p_log_p + jnp.sum(jnp.where(seen, p * jnp.log(jnp.where(seen, p, 1.0)), 0.0), -1)
+                p_index = p_index + jnp.sum(jnp.where(seen, p * jnp.where(seen, index, 0.0), 0.0), -1)
+                p_sum = p_sum + jnp.sum(jnp.where(seen, p, 0.0), -1)
+            return top_i, total_i, p_log_p, p_index, p_sum
+
+        zeros = jnp.zeros((chunk,), jnp.float32)
+        top_i, total_i, p_log_p, p_index, p_sum = jax.lax.fori_loop(
+            0, i + 1, objective, (jnp.full((chunk,), -jnp.inf, jnp.float32), zeros, zeros, zeros, zeros)
+        )
+        # a row of padding has kept nothing: (-inf, 0) -> 0, and adds nothing
+        log_z_i = jnp.where(total_i > 0, top_i + jnp.log(jnp.where(total_i > 0, total_i, 1.0)), 0.0)
+        kl = jnp.sum(p_log_p - p_index + log_z_i * p_sum)
+        return out, kl, log_z, log_z_i, p_sum
+
+    out, kl, log_z, log_z_i, p_sum = jax.lax.map(block_of, jnp.arange(blocks))
+    return (out, jnp.sum(kl)), (q, k, v, qi, ki, wi, selected, out, log_z, log_z_i, p_sum)
+
+
+def _selected_attention_bwd(length, kept, cotangents):
+    q, k, v, qi, ki, wi, selected, out, log_z, log_z_i, p_sum = kept
+    d_out, d_kl = cotangents
+    blocks, chunk, kv_heads, group, dh = q.shape
+    scale = 1.0 / jnp.sqrt(jnp.float32(dh))
+
+    def add_tile(total, j, tile):
+        return jax.lax.dynamic_update_index_in_dim(total, _tile(total, j) + tile, j, 0)
+
+    def block_of(carry, i):
+        q_i, qi_i, wi_i, selected_i = _tile(q, i), _tile(qi, i), _tile(wi, i), _tile(selected, i)
+        d_out_i = _tile(d_out, i)
+        log_z_b, log_z_i_b, p_sum_b = _tile(log_z, i), _tile(log_z_i, i), _tile(p_sum, i)
+        valid = (i * chunk + jnp.arange(chunk) < length)[:, None]
+        # sum over the keys of weights x their cotangent, from the output
+        delta = jnp.einsum(
+            "qngd,qngd->ngq", d_out_i.astype(jnp.float32), _tile(out, i).astype(jnp.float32)
+        )
+
+        def tile_of(j, carry):
+            d_q, d_qi, d_wi, d_k, d_v, d_ki = carry
+            keep = _unpack_bits(_tile(selected_i, j, 1), chunk)
+            k_j, v_j, ki_j = _tile(k, j), _tile(v, j), _tile(ki, j)
+            with jax.named_scope(SPARSE_ATTENTION_SCOPE):
+                weights = jnp.exp(_tile_scores(q_i, k_j, keep, scale) - log_z_b[..., None])
+                d_v = add_tile(d_v, j, jnp.einsum(
+                    "ngqk,qngd->knd", weights.astype(q.dtype), d_out_i
+                ).astype(jnp.float32))
+                d_weights = jnp.einsum("qngd,knd->ngqk", d_out_i, v_j).astype(jnp.float32)
+                d_scores = (weights * (d_weights - delta[..., None])).astype(q.dtype) * scale.astype(q.dtype)
+                d_q = d_q + jnp.einsum("ngqk,knd->qngd", d_scores, k_j).astype(jnp.float32)
+                d_k = add_tile(d_k, j, jnp.einsum("ngqk,qngd->knd", d_scores, q_i).astype(jnp.float32))
+            with jax.named_scope(INDEX_SCOPE):
+                # d KL / d I[t, s] = r[t, s] sum_s' p[t, s'] - p[t, s]
+                counted = keep & valid
+                p = jnp.where(counted, jnp.mean(weights, axis=(0, 1)), 0.0)
+                index, back = jax.vjp(index_scores, qi_i, ki_j, wi_i)
+                r = jnp.where(counted, jnp.exp(jnp.where(counted, index, -jnp.inf) - log_z_i_b[:, None]), 0.0)
+                add_qi, add_ki, add_wi = back(d_kl * (r * p_sum_b[:, None] - p))
+            return d_q, d_qi + add_qi, d_wi + add_wi, d_k, d_v, add_tile(d_ki, j, add_ki)
+
+        d_k, d_v, d_ki = carry
+        d_q, d_qi, d_wi, d_k, d_v, d_ki = jax.lax.fori_loop(
+            0, i + 1, tile_of,
+            (jnp.zeros(q_i.shape, jnp.float32), jnp.zeros_like(qi_i), jnp.zeros_like(wi_i), d_k, d_v, d_ki),
+        )
+        return (d_k, d_v, d_ki), (d_q, d_qi, d_wi)
+
+    zeros = (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32), jnp.zeros_like(ki))
+    (d_k, d_v, d_ki), (d_q, d_qi, d_wi) = jax.lax.scan(block_of, zeros, jnp.arange(blocks))
+    return d_q.astype(q.dtype), d_k.astype(k.dtype), d_v.astype(v.dtype), d_qi, d_ki, d_wi, None
+
+
+_selected_attention.defvjp(_selected_attention_fwd, _selected_attention_bwd)
+
+
+def sparse_attention(
+    spec: BackboneSpec, w: Dict, wi: Dict, u: jnp.ndarray, active: Optional[jnp.ndarray] = None
+):
+    """Grouped-query attention over the keys the indexer ``wi`` selects
+    (module docstring), over ``u [B, T, hidden]``: ``(output, the
+    indexer's objective, counts)``. The objective is the mean over the
+    windows that count (``active``; None: all) of the mean over ``t``
+    of ``KL(p_t || r_t)``; ``counts`` are float32 ``(keys_selected,
+    keys_causal)`` of those windows: a window's 33.6 M causal pairs at
+    8,192 rows fit an int32, an epoch's do not."""
+    dtype = u.dtype
+    batch, length, _ = u.shape
+    heads, kv_heads, dh = spec.num_attention_heads, spec.num_key_value_heads, spec.head_dim
+    blocked = jax.vmap(lambda a: _blocked(a, spec.index_chunk))
+    with jax.named_scope(SPARSE_ATTENTION_SCOPE):
+        q, k, v = _heads(spec, w, u)
+        q = q.reshape(batch, length, kv_heads, heads // kv_heads, dh)
+        q, k, v = blocked(q), blocked(k), blocked(v)
+    with jax.named_scope(INDEX_SCOPE):
+        index_in = tuple(blocked(a) for a in indexer_inputs(spec, wi, u))
+    # the choice, once: it carries no gradient, and a layer
+    # rematerialised in the backward pass is handed it back by name
+    selected = jax.lax.map(lambda one: select_keys(spec, *one), index_in)
+    selected = checkpoint_name(selected, SAVED_SELECTION)
+    out, kl = jax.lax.map(
+        lambda one: _selected_attention(length, *one), (q, k, v, *index_in, selected)
+    )
+    with jax.named_scope(SPARSE_ATTENTION_SCOPE):
+        out = out.reshape(batch, -1, heads * dh)[:, :length] @ w["wo"].astype(dtype)
+    # the pairs kept, off the bits of the window's own rows
+    rows = (jnp.arange(selected.shape[1] * selected.shape[2]) < length).reshape(selected.shape[1:3])
+    kept = jnp.sum(
+        jax.lax.population_count(selected).astype(jnp.int32) * rows[None, :, :, None, None],
+        axis=(1, 2, 3, 4),
+    )
+    counted = jnp.ones((batch,), jnp.float32) if active is None else active.astype(jnp.float32)
+    windows = jnp.maximum(jnp.sum(counted), 1.0)
+    objective = jnp.sum(kl * counted) / (windows * length)
+    counts = (
+        jnp.sum(kept.astype(jnp.float32) * counted),
+        jnp.sum(counted) * (length * (length + 1) / 2.0),
+    )
+    return out, objective, counts
 
 
 def dense_ffn(w: Dict, u: jnp.ndarray) -> jnp.ndarray:
@@ -221,6 +581,9 @@ def route(spec: BackboneSpec, w: Dict, tokens: jnp.ndarray):
     logits = jnp.dot(
         tokens.astype(jnp.float32), w["router"], precision=jax.lax.Precision.HIGHEST
     )
+    if spec.router == "softmax":
+        picked, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), spec.num_experts_per_tok)
+        return chosen, picked / jnp.sum(picked, axis=-1, keepdims=True)
     scores = jax.nn.sigmoid(logits)
     bias = jax.lax.stop_gradient(w["expert_bias"])  # a buffer: chooses, takes no gradient
     _, chosen = jax.lax.top_k(scores + bias, spec.num_experts_per_tok)
@@ -286,19 +649,25 @@ def moe_ffn(
 
 
 def block(spec: BackboneSpec, op: str, ffn: str, w: Dict, h: jnp.ndarray, active=None):
-    """One pre-norm residual block; returns ``(h, counts)`` with
-    ``counts = (routed, pairs_here)`` of a routed block, else None.
-    ``active``: as :func:`moe_ffn`."""
+    """One pre-norm residual block; returns ``(h, counts, selection)``
+    with ``counts = (routed, pairs_here)`` of a routed block and
+    ``selection = (objective, (keys_selected, keys_causal))`` of a
+    ``sparse_attention`` block, else None. ``active``: as
+    :func:`moe_ffn`."""
     normed = rms_norm(h, w["operator_norm"], spec.norm_eps)
+    selection = None
     if op == "conv":
         h = h + short_conv(spec, w["conv"], normed)
+    elif op == "sparse_attention":
+        out, objective, keys = sparse_attention(spec, w["attn"], w["indexer"], normed, active)
+        h, selection = h + out, (objective, keys)
     else:
         h = h + gqa_attention(spec, w["attn"], normed)
     normed = rms_norm(h, w["ffn_norm"], spec.norm_eps)
     if ffn == "dense":
-        return h + dense_ffn(w["ffn"], normed), None
+        return h + dense_ffn(w["ffn"], normed), None, selection
     out, routed, pairs_here = moe_ffn(spec, w["moe"], normed, active)
-    return h + out, (routed, pairs_here)
+    return h + out, (routed, pairs_here), selection
 
 
 def _param_bytes(params: Dict) -> int:
@@ -314,10 +683,14 @@ def forward_backbone_aux(
 ):
     """
     Windows ``x [batch, lookback, n_features]`` -> ``(output [batch,
-    n_features_out], penalty=0, aux)``; ``aux`` holds, a row per expert
+    n_features_out], penalty, aux)``; ``aux`` holds, a row per expert
     layer, ``router_tokens [layers, num_experts]`` (tokens routed to
     each published expert), ``pairs_here [layers]`` (pairs whose expert
-    is held here) and ``pairs_total [layers]`` (tokens x k).
+    is held here) and ``pairs_total [layers]`` (tokens x k); and, a row
+    per ``sparse_attention`` layer, float32 ``keys_selected`` and
+    ``keys_causal`` (query-key pairs kept and possible) and
+    ``indexer_kl`` (the layer's term of the indexer's objective).
+    ``penalty`` is the sum of those terms, 0 without such a layer.
 
     ``remat``: rematerialise each block in the backward pass; None
     decides from the bytes of ``params`` (module docstring).
@@ -330,18 +703,21 @@ def forward_backbone_aux(
     if remat is None:
         remat = _param_bytes(params) >= REMAT_MIN_PARAM_BYTES
     h = x.astype(dtype) @ params["embed"]["W"].astype(dtype) + params["embed"]["b"].astype(dtype)
-    routed_rows, pairs_rows = [], []
+    routed_rows, pairs_rows, selections = [], [], []
     for i, (op, ffn) in enumerate(zip(spec.layer_ops, spec.layer_ffns)):
         run = lambda w, h, a, _op=op, _ffn=ffn: block(spec, _op, _ffn, w, h, a)  # noqa: E731
         if remat:
+            saved = SAVED_PRODUCTS + ((SAVED_SELECTION,) if op == "sparse_attention" else ())
             run = jax.checkpoint(
-                run, policy=jax.checkpoint_policies.save_only_these_names(*SAVED_PRODUCTS)
+                run, policy=jax.checkpoint_policies.save_only_these_names(*saved)
             )
         with jax.named_scope(f"layer_{i}"):  # one scope a layer, as in params
-            h, counts = run(params[f"layer_{i}"], h, active)
+            h, counts, selection = run(params[f"layer_{i}"], h, active)
         if counts is not None:
             routed_rows.append(counts[0])
             pairs_rows.append(counts[1])
+        if selection is not None:
+            selections.append(selection)
     last = rms_norm(h[:, -1], params["head"]["norm"], spec.norm_eps)
     out = last @ params["head"]["W"].astype(dtype) + params["head"]["b"].astype(dtype)
     aux = None
@@ -354,11 +730,21 @@ def forward_backbone_aux(
                 (len(routed_rows),), windows * x.shape[1] * spec.num_experts_per_tok, jnp.int32
             ),
         }
-    return out.astype(jnp.float32), jnp.zeros((), jnp.float32), aux
+    penalty = jnp.zeros((), jnp.float32)
+    if selections:
+        objectives = jnp.stack([objective for objective, _ in selections])
+        penalty = jnp.sum(objectives)
+        aux = {
+            **(aux or {}),
+            "keys_selected": jnp.stack([keys[0] for _, keys in selections]),
+            "keys_causal": jnp.stack([keys[1] for _, keys in selections]),
+            "indexer_kl": objectives,
+        }
+    return out.astype(jnp.float32), penalty, aux
 
 
 def forward_backbone(spec: BackboneSpec, params: Dict, x: jnp.ndarray):
-    """``(output, penalty=0)``: :func:`forward_backbone_aux` without its
+    """``(output, penalty)``: :func:`forward_backbone_aux` without its
     counters, the signature every spec's forward has."""
     out, penalty, _ = forward_backbone_aux(spec, params, x)
     return out, penalty

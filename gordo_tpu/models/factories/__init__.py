@@ -1,5 +1,5 @@
 from . import backbone, feedforward_autoencoder, lstm_autoencoder  # noqa: F401  (registration)
-from .backbone import lfm2_moe
+from .backbone import keye_vl2, lfm2_moe
 from .feedforward_autoencoder import (
     feedforward_hourglass,
     feedforward_model,
@@ -15,4 +15,5 @@ __all__ = [
     "lstm_symmetric",
     "lstm_hourglass",
     "lfm2_moe",
+    "keye_vl2",
 ]

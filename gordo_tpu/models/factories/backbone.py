@@ -77,3 +77,127 @@ def lfm2_moe(
         compute_dtype=compute_dtype,
         precision=precision,
     )
+
+
+#: Kwai-Keye/Keye-VL-2.0-30B-A3B config.json (``model_type: KeyeVL2``):
+#: the language model's keys, every one. The factory's defaults are
+#: these; the keys it does not take say nothing it can act on (the
+#: vocabulary is replaced by the sensor projections, positions are a
+#: window's, ``mrope_section`` gives each rotary frequency one of three
+#: position ids that are equal where no image enters: one-dimensional
+#: rotary, exactly) or name what it refuses to be told otherwise.
+KEYE_VL2_30B_A3B_CONFIG: Dict[str, Any] = {
+    "attention_bias": False,
+    "decoder_sparse_step": 1,
+    "head_dim": 128,
+    "hidden_act": "silu",
+    "hidden_size": 2048,
+    "intermediate_size": 6144,
+    "max_position_embeddings": 262144,
+    "max_window_layers": 48,
+    "mlp_only_layers": [],
+    "model_type": "KeyeVL2",
+    "moe_intermediate_size": 768,
+    "norm_topk_prob": True,
+    "num_attention_heads": 32,
+    "num_experts": 128,
+    "num_experts_per_tok": 8,
+    "num_hidden_layers": 48,
+    "num_key_value_heads": 4,
+    "num_local_experts": 128,
+    "rms_norm_eps": 1e-06,
+    "rope_scaling": {"mrope_section": [16, 24, 24], "rope_type": "default", "type": "default"},
+    "rope_theta": 10000000,
+    "sa_config": {
+        "indexer_head_dim": 64,
+        "indexer_num_heads": 16,
+        "indexer_num_kv_heads": 1,
+        "kv_chunk_size": 512,
+        "q_chunk_size": 512,
+        "topk": 2048,
+    },
+    "sliding_window": None,
+    "tie_word_embeddings": False,
+    "use_sliding_window": False,
+    "vocab_size": 151936,
+}
+_KEYE = KEYE_VL2_30B_A3B_CONFIG
+#: what the layers here cannot be told otherwise: every layer is sparse
+#: attention and routed experts, without bias, gated by silu, the chosen
+#: experts' probabilities renormalised
+_KEYE_FIXED = (
+    "attention_bias", "decoder_sparse_step", "hidden_act", "mlp_only_layers", "norm_topk_prob",
+    "use_sliding_window",
+)
+
+
+@register_model_builder(type="JaxBackboneForecast")
+def keye_vl2(
+    n_features: int,
+    n_features_out: Optional[int] = None,
+    lookback_window: int = 8192,
+    num_hidden_layers: int = _KEYE["num_hidden_layers"],
+    hidden_size: int = _KEYE["hidden_size"],
+    head_dim: int = _KEYE["head_dim"],
+    num_attention_heads: int = _KEYE["num_attention_heads"],
+    num_key_value_heads: int = _KEYE["num_key_value_heads"],
+    moe_intermediate_size: int = _KEYE["moe_intermediate_size"],
+    num_experts: int = _KEYE["num_experts"],
+    experts_held: Optional[int] = None,
+    expert_offset: int = 0,
+    num_experts_per_tok: int = _KEYE["num_experts_per_tok"],
+    rope_theta: float = _KEYE["rope_theta"],
+    rms_norm_eps: float = _KEYE["rms_norm_eps"],
+    sa_config: Optional[Dict[str, int]] = None,
+    optimizer: Union[str, OptimizerSpec] = "Adam",
+    optimizer_kwargs: Optional[Dict[str, Any]] = None,
+    compile_kwargs: Optional[Dict[str, Any]] = None,
+    compute_dtype: str = "float32",
+    precision: str = "",
+    **kwargs,
+) -> BackboneSpec:
+    """``model_type: KeyeVL2`` (defaults: Keye-VL-2.0-30B-A3B's language
+    model; the vision tower is left out: no image enters a sensor
+    model). ``num_hidden_layers`` layers, all alike: sparse attention
+    (grouped-query attention over the ``sa_config.topk`` keys a learned
+    indexer selects for each query, computed in tiles of ``q_chunk_size``
+    queries by ``kv_chunk_size`` keys, which have to be equal), then the
+    routed experts under a softmax router, of which this holder keeps
+    ``experts_held`` (default: all) from ``expert_offset``. Keys of
+    ``sa_config`` that are left out keep their published values."""
+    for key in _KEYE_FIXED:
+        if key in kwargs and kwargs[key] != _KEYE[key]:
+            raise ValueError(f"keye_vl2 runs {key}={_KEYE[key]!r} only; got {kwargs[key]!r}")
+    sparse = {**_KEYE["sa_config"], **(sa_config or {})}
+    if sparse["indexer_num_kv_heads"] != 1:
+        raise ValueError("keye_vl2's indexer has one key head")
+    if sparse["q_chunk_size"] != sparse["kv_chunk_size"]:
+        raise ValueError("keye_vl2 computes square tiles: q_chunk_size has to equal kv_chunk_size")
+    compile_kwargs = compile_kwargs or {}
+    return BackboneSpec(
+        n_features=n_features,
+        n_features_out=n_features_out or n_features,
+        lookback_window=lookback_window,
+        layer_ops=("sparse_attention",) * num_hidden_layers,
+        layer_ffns=("moe",) * num_hidden_layers,
+        hidden_size=hidden_size,
+        attention_head_dim=head_dim,
+        num_attention_heads=num_attention_heads,
+        num_key_value_heads=num_key_value_heads,
+        moe_intermediate_size=moe_intermediate_size,
+        num_experts=num_experts,
+        experts_held=num_experts if experts_held is None else experts_held,
+        expert_offset=expert_offset,
+        num_experts_per_tok=num_experts_per_tok,
+        router="softmax",
+        rope_theta=float(rope_theta),
+        norm_eps=float(rms_norm_eps),
+        index_n_heads=sparse["indexer_num_heads"],
+        index_head_dim=sparse["indexer_head_dim"],
+        index_topk=sparse["topk"],
+        index_chunk=sparse["q_chunk_size"],
+        optimizer=OptimizerSpec.from_config(optimizer, optimizer_kwargs),
+        loss=compile_kwargs.get("loss", "mse"),
+        compute_dtype=compute_dtype,
+        precision=precision,
+    )

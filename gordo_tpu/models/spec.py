@@ -275,8 +275,13 @@ class LSTMSpec(ModelSpec):
 
 
 #: layer kinds of a :class:`BackboneSpec`
-OPERATORS = ("conv", "full_attention")
+OPERATORS = ("conv", "full_attention", "sparse_attention")
 FFNS = ("dense", "moe")
+#: how a routed layer scores its experts: ``sigmoid_bias`` (sigmoid
+#: scores, the ``k`` largest ``score + bias`` chosen, a bias buffer) or
+#: ``softmax`` (softmax over all logits, the ``k`` largest renormalised
+#: to sum 1, no bias)
+ROUTERS = ("sigmoid_bias", "softmax")
 
 
 @dataclass(frozen=True)
@@ -296,6 +301,13 @@ class BackboneSpec(ModelSpec):
     of them; this holder computes the part of the result that experts
     ``expert_offset .. expert_offset + experts_held - 1`` give. With
     ``experts_held == num_experts`` that is the whole layer.
+
+    ``sparse_attention`` is grouped-query attention over the keys a
+    learned indexer selects (DeepSeek-Sparse-Attention): ``index_n_heads``
+    heads of ``index_head_dim`` score every causal key, a query attends
+    to its ``index_topk`` best, computed in square tiles of
+    ``index_chunk`` queries by ``index_chunk`` keys. The indexer
+    learns from its own objective, the ``penalty`` of the forward.
     """
 
     n_features: int
@@ -320,6 +332,14 @@ class BackboneSpec(ModelSpec):
     loss: str = "mse"
     compute_dtype: str = "float32"
     precision: str = ""
+    #: width of an attention head where the config states it; 0: the
+    #: family's ``hidden_size // num_attention_heads``
+    attention_head_dim: int = 0
+    router: str = "sigmoid_bias"
+    index_n_heads: int = 16
+    index_head_dim: int = 64
+    index_topk: int = 2048
+    index_chunk: int = 512
 
     windowed = True
 
@@ -329,8 +349,18 @@ class BackboneSpec(ModelSpec):
         unknown = (set(self.layer_ops) - set(OPERATORS)) | (set(self.layer_ffns) - set(FFNS))
         if unknown:
             raise ValueError(f"unknown layer kinds {sorted(unknown)}")
-        if self.hidden_size % self.num_attention_heads:
+        if not self.attention_head_dim and self.hidden_size % self.num_attention_heads:
             raise ValueError("hidden_size must divide into num_attention_heads")
+        if self.router not in ROUTERS:
+            raise ValueError(f"unknown router {self.router!r}; known: {ROUTERS}")
+        if self.head_dim % 2 or self.head_dim <= 0:
+            raise ValueError("the rotary embedding needs an even head width")
+        if "sparse_attention" in self.layer_ops and (
+            min(self.index_n_heads, self.index_topk, self.index_chunk) < 1
+            or self.index_head_dim < 2
+            or self.index_head_dim % 2
+        ):
+            raise ValueError("the indexer needs heads, an even head width, a top-k and blocks")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
         if not (
@@ -347,7 +377,14 @@ class BackboneSpec(ModelSpec):
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+        return self.attention_head_dim or self.hidden_size // self.num_attention_heads
+
+    @property
+    def indexer_param_count(self) -> int:
+        """The indexer of one ``sparse_attention`` layer: its query and
+        key projections, the key's LayerNorm, the head weights."""
+        h, width = self.hidden_size, self.index_head_dim
+        return h * self.index_n_heads * width + h * width + 2 * width + h * self.index_n_heads
 
     @property
     def member_axis(self) -> bool:
@@ -372,13 +409,17 @@ class BackboneSpec(ModelSpec):
 
     def fit_counter_attrs(self, counters: Dict[str, Any]) -> Dict[str, Any]:
         """The router counts, with which of the published experts are
-        held here: who reads ``router_tokens`` needs the three."""
-        return {
+        held here: who reads ``router_tokens`` needs the three; beside
+        the selection's counts, how many keys a query may keep."""
+        attrs = {
             **super().fit_counter_attrs(counters),
             "num_experts": self.num_experts,
             "experts_held": self.experts_held,
             "expert_offset": self.expert_offset,
         }
+        if "sparse_attention" in self.layer_ops:
+            attrs["index_topk"] = self.index_topk
+        return attrs
 
     def layer_param_count(self, op: str, ffn: str) -> int:
         """One block: two norms, its operator, its feed-forward (the
@@ -389,7 +430,9 @@ class BackboneSpec(ModelSpec):
         if op == "conv":
             total += h * 3 * h + h * self.conv_L_cache + h * h
         else:
-            total += 2 * h * h + 2 * h * kv + 2 * self.head_dim
+            total += 2 * h * self.num_attention_heads * self.head_dim + 2 * h * kv + 2 * self.head_dim
+        if op == "sparse_attention":
+            total += self.indexer_param_count
         if ffn == "dense":
             return total + 3 * h * self.intermediate_size
         return total + h * self.num_experts + self.experts_held * 3 * h * self.moe_intermediate_size
@@ -406,17 +449,27 @@ class BackboneSpec(ModelSpec):
 
     def flops_per_sample(self) -> float:
         """One window of ``lookback_window`` tokens: products only,
-        causal attention at its useful half, the expert layer at the
-        pairs this holder expects under even routing."""
+        causal attention at its useful half (sparse attention at the
+        keys a query keeps, its indexer over every causal key), the
+        expert layer at the pairs this holder expects under even routing."""
         h, t = self.hidden_size, self.lookback_window
         kv = self.num_key_value_heads * self.head_dim
+        qo = self.num_attention_heads * self.head_dim
+        index = self.index_n_heads * self.index_head_dim
+        # keys a query keeps, on average over a window: min(t + 1, top-k)
+        kept = min(t, self.index_topk)
+        kept_mean = (kept * (kept + 1) / 2.0 + (t - kept) * self.index_topk) / t
         per_token = 2.0 * self.n_features * h
         local_pairs = self.num_experts_per_tok * self.experts_held / self.num_experts
         for op, ffn in zip(self.layer_ops, self.layer_ffns):
             if op == "conv":
                 per_token += 2.0 * h * 3 * h + 2.0 * h * h + 2.0 * self.conv_L_cache * h
+            elif op == "full_attention":
+                per_token += 2.0 * h * (2 * qo + 2 * kv) + 2.0 * t * qo
             else:
-                per_token += 2.0 * h * (2 * h + 2 * kv) + 2.0 * t * h
+                per_token += 2.0 * h * (2 * qo + 2 * kv) + 4.0 * kept_mean * qo
+                per_token += 2.0 * h * (index + self.index_head_dim + self.index_n_heads)
+                per_token += (t + 1.0) * index  # 2 x index a causal pair, (t + 1) / 2 pairs
             if ffn == "dense":
                 per_token += 6.0 * h * self.intermediate_size
             else:

@@ -184,12 +184,15 @@ def _fit_counter_attrs(spec: ModelSpec, counters, members: int) -> Dict[str, Any
     and named in ``fit_counters``, by which the builder copies them into
     ``build_status.json``. Read by the chip benchmark's backbone readers."""
     counters = fetch_to_host(counters)  # inside the caller's program span
-    attrs = spec.fit_counter_attrs(
-        {
-            name: np.asarray(value)[:members].astype(np.int64).sum(axis=(0, 1))
-            for name, value in counters.items()
-        }
-    )
+
+    def total(value):
+        # counts add up in int64; a counter that is a float32 (a sum of
+        # losses, a count past int32) stays a float
+        value = np.asarray(value)[:members]
+        wide = np.int64 if np.issubdtype(value.dtype, np.integer) else np.float64
+        return value.astype(wide).sum(axis=(0, 1))
+
+    attrs = spec.fit_counter_attrs({name: total(value) for name, value in counters.items()})
     return {**attrs, "fit_counters": sorted(attrs)}
 
 

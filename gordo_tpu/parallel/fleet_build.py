@@ -56,8 +56,10 @@ from ..machine.metadata import (
     TrainingSummaryMetadata,
 )
 from ..models.anomaly.diff import (
+    THRESHOLD_RUN,
     DiffBasedAnomalyDetector,
     DiffBasedKFCVAnomalyDetector,
+    threshold_run,
 )
 from ..models.estimators import JaxBaseEstimator, JaxWindowedBaseEstimator
 from ..models.training import FitConfig, fit_config_from_kwargs, split_fit_kwargs
@@ -2026,9 +2028,16 @@ class FleetBuilder:
                 (np.asarray(test_rows), scaled_mse, abs_err)
             )
         else:
-            state["aggregate_threshold"] = cls._rolling_min_max(scaled_mse, 6)
+            run = state["threshold_run_rows"] = threshold_run(len(scaled_mse))
+            if run < THRESHOLD_RUN:
+                logger.warning(
+                    "%s: fold %d scored %d rows, fewer than the %d a threshold's "
+                    "run takes; its thresholds are the minimum over those rows",
+                    plan.machine.name, fold_idx, len(scaled_mse), THRESHOLD_RUN,
+                )
+            state["aggregate_threshold"] = cls._rolling_min_max(scaled_mse, run)
             tag_thresholds = pd.Series(
-                cls._rolling_min_max(abs_err, 6), name=f"fold-{fold_idx}"
+                cls._rolling_min_max(abs_err, run), name=f"fold-{fold_idx}"
             )
             state.setdefault("feature_folds", {})[f"fold-{fold_idx}"] = tag_thresholds
             state.setdefault("agg_folds", {})[f"fold-{fold_idx}"] = state[
@@ -2086,6 +2095,7 @@ class FleetBuilder:
             folds_df.columns = feature_names
             detector.feature_thresholds_per_fold_ = folds_df
             detector.aggregate_thresholds_per_fold_ = state["agg_folds"]
+            detector.threshold_run_rows_ = state["threshold_run_rows"]
             last = folds_df.iloc[-1]
             last.name = folds_df.index[-1]
             detector.feature_thresholds_ = last

@@ -96,10 +96,15 @@ WINDOWED_SCORING_BATCH = 256
 #: the same for a member that trains alone: 256 of its windows are a fit
 #: step's activations eight times over, so it scores at a fit's batch
 ALONE_SCORING_BATCH = 32
+#: ... and no more rows a step than 32 windows of 512 hold: a step's
+#: activations follow its rows, and a window of 8,192 is sixteen of those
+ALONE_SCORING_ROWS = 32 * 512
 
 
 def windowed_scoring_batch(spec: ModelSpec) -> int:
-    return ALONE_SCORING_BATCH if trains_alone(spec) else WINDOWED_SCORING_BATCH
+    if not trains_alone(spec):
+        return WINDOWED_SCORING_BATCH
+    return max(1, min(ALONE_SCORING_BATCH, ALONE_SCORING_ROWS // spec.lookback_window))
 
 
 def _round_up_pow2(n: int, batch_size: int) -> int:

@@ -7,6 +7,9 @@ with the reference computed in blocks of windows so that it fits.
 
     python3 scripts/backbone_parity.py [--config <configs/*.json>] [--seed N] [--block 4]
 
+(``--config benchmarks/chip/configs/keye-vl2-30b-a3b-50tag-lb8192.json
+--block 1`` for the sparse-attention backbone: a block is whole windows.)
+
 Prints one JSON object (and writes it to ``chiprun_out/backbone_parity.json``):
 the worst fraction of scale of the forward, the loss of both sides, and
 the gradient's global and per-leaf norms' relative differences. A
@@ -95,6 +98,9 @@ def main(argv=None) -> int:
     result["program_seconds"] = round(time.time() - started, 3)
     result["router_tokens"] = np.asarray(aux["router_tokens"]).tolist() if aux else None
     result["pairs_here"] = np.asarray(aux["pairs_here"]).tolist() if aux else None
+    for name in ("keys_selected", "keys_causal", "indexer_kl"):  # a sparse-attention backbone's
+        if aux and name in aux:
+            result[name] = np.asarray(aux[name]).tolist()
 
     # the reference, in blocks of windows
     class Artifact:
@@ -107,10 +113,14 @@ def main(argv=None) -> int:
     result["forward_worst_fraction_of_scale"] = float(np.max(np.abs(out - expected))) / scale
     result["forward_scale"] = scale
     if aux:
-        counts = np.sum(
-            [reference.router_counts(layers, x[i : i + args.block]) for i in range(0, batch, args.block)],
-            axis=0,
-        )
+        blocks = [x[i : i + args.block] for i in range(0, batch, args.block)]
+        if hasattr(reference, "router_counts"):
+            counts = np.sum([reference.router_counts(layers, b) for b in blocks], axis=0)
+        else:  # a reference that counts its selection beside its routing
+            found = [reference.counters(layers, b) for b in blocks]
+            counts = np.sum([f["routed"] for f in found], axis=0)
+            result["reference_keys_selected"] = np.sum([f["kept"] for f in found], axis=0).tolist()
+            result["reference_indexer_kl"] = float(np.mean(np.concatenate([f["kl"] for f in found])))
         moved = np.abs(counts - np.asarray(aux["router_tokens"])).sum(axis=1) / 2
         result["router_pairs_moved_share"] = (moved / counts.sum(axis=1)).tolist()
 
@@ -151,6 +161,11 @@ def main(argv=None) -> int:
         grad_norm_relative_difference=readings["grad_norm"],
         worst_leaf_norm_relative_difference=readings["leaf"], worst_leaf=readings["worst_leaf"],
     )
+    if "indexer_leaf" in readings:
+        result.update(
+            worst_indexer_leaf_norm_relative_difference=readings["indexer_leaf"],
+            worst_indexer_leaf=readings["worst_indexer_leaf"],
+        )
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "backbone_parity.json"), "w") as f:
         json.dump(result, f, indent=1)

@@ -128,3 +128,28 @@ def test_get_metadata_structure(frame):
     assert "feature-thresholds" in meta
     assert "aggregate-threshold" in meta
     assert "feature-thresholds-per-fold" in meta
+
+
+@pytest.mark.parametrize("rows, run", [(17, 4), (24, 6), (300, 6)])
+def test_a_fold_that_scores_fewer_than_six_rows_takes_its_thresholds_over_those(frame, rows, run):
+    """``rolling(6).min().max()`` of a fold of four rows is no value at
+    all: such a fold takes the minimum over the rows it has
+    (``threshold_run``), a fold of six or more the reference's run."""
+    from gordo_tpu.models.anomaly.diff import threshold_run
+
+    short = frame.iloc[:rows]
+    det = DiffBasedAnomalyDetector(base_estimator=LinearRegression(), scaler=MinMaxScaler())
+    det.cross_validate(X=short, y=short)
+    assert threshold_run(rows // 4) == run
+    # a model whose thresholds come from a shorter run says so, no other does
+    meta = det.get_metadata()
+    assert meta.get("thresholds-degraded", False) == (run < 6)
+    assert meta.get("threshold-run-rows", 6) == run
+    assert np.isfinite(det.aggregate_threshold_) and np.isfinite(det.feature_thresholds_).all()
+    assert np.isfinite(det.feature_thresholds_per_fold_.to_numpy()).all()
+    # the last fold's errors, by hand
+    test = short.iloc[-(rows // 4):]
+    fold_model = DiffBasedAnomalyDetector(base_estimator=LinearRegression(), scaler=MinMaxScaler())
+    fold_model.fit(short.iloc[: -(rows // 4)], short.iloc[: -(rows // 4)])
+    mae = (test - fold_model.predict(test)).abs()
+    np.testing.assert_allclose(det.feature_thresholds_, mae.rolling(run).min().max(), rtol=1e-6, atol=1e-12)

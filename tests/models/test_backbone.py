@@ -13,7 +13,7 @@ import pytest
 
 from gordo_tpu.models import BackboneSpec, JaxBackboneForecast, register_model_builder
 from gordo_tpu.models import backbone
-from gordo_tpu.models.factories import lfm2_moe
+from gordo_tpu.models.factories import keye_vl2, lfm2_moe
 from gordo_tpu.models.factories.backbone import LFM2_8B_A1B_LAYER_TYPES
 from gordo_tpu.models.nn import forward_fn_for, init_fn_for
 from gordo_tpu.models.spec import FeedForwardSpec, LSTMSpec, ModelSpec
@@ -23,13 +23,23 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 TOLERANCE = 1e-4  # of scale: both sides compute in float32 on the CPU
 
 
-@pytest.fixture(scope="module")
-def reference():
-    path = os.path.join(ROOT, "benchmarks", "chip", "reference", "lfm2_moe_backbone.py")
-    spec = importlib.util.spec_from_file_location("reference_lfm2_moe_backbone", path)
+def load_reference(name):
+    path = os.path.join(ROOT, "benchmarks", "chip", "reference", f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"reference_{name}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return load_reference("lfm2_moe_backbone")
+
+
+@pytest.fixture(scope="module")
+def references(reference):
+    """The plain reference of each backbone kind."""
+    return {"lfm2_moe": reference, "keye_vl2": load_reference("keye_sparse_backbone")}
 
 
 def toy(**overrides) -> BackboneSpec:
@@ -41,6 +51,23 @@ def toy(**overrides) -> BackboneSpec:
     )
     sizes.update(overrides)
     return lfm2_moe(5, **sizes)
+
+
+def sparse_toy(**overrides) -> BackboneSpec:
+    """``kind: keye_vl2`` at toy widths: heads of 16 at hidden 32, an
+    indexer that keeps 6 of 12 rows, a softmax router
+    (tests/models/test_sparse_backbone.py has the operator's own tests)."""
+    sizes = dict(
+        lookback_window=12, num_hidden_layers=2, hidden_size=32, head_dim=16,
+        num_attention_heads=4, num_key_value_heads=2, moe_intermediate_size=24, num_experts=8,
+        experts_held=2, expert_offset=2, num_experts_per_tok=2,
+        sa_config=dict(indexer_head_dim=8, indexer_num_heads=8, topk=6, q_chunk_size=8, kv_chunk_size=8),
+    )
+    sizes.update(overrides)
+    return keye_vl2(5, **sizes)
+
+
+TOYS = {"lfm2_moe": toy, "keye_vl2": sparse_toy}
 
 
 class Artifact:
@@ -117,7 +144,8 @@ def test_a_spec_that_cannot_be_is_refused(bad):
     FeedForwardSpec(4, 4, (3,), ("tanh",)),
     LSTMSpec(4, 4, 5, (3,), ("tanh",)),
     toy(),
-], ids=lambda s: type(s).__name__)
+    sparse_toy(),
+], ids=["FeedForwardSpec", "LSTMSpec", "BackboneSpec", "BackboneSpec-keye_vl2"])
 def test_every_spec_answers_for_itself(spec):
     params = init_fn_for(spec)(jax.random.PRNGKey(0), spec)
     leaves = sum(
@@ -216,8 +244,11 @@ def test_loss_and_every_gradient_leaf_against_the_reference(seeded, reference):
         close(a, b, "remat")
 
 
-def test_layers_are_rematerialised_from_the_bytes_of_the_state(monkeypatch, seeded):
-    spec, params, _, x, _ = seeded
+@pytest.mark.parametrize("kind", ["lfm2_moe", "keye_vl2"])
+def test_layers_are_rematerialised_from_the_bytes_of_the_state(monkeypatch, seeded, kind):
+    x = seeded[3]
+    spec = TOYS[kind]()
+    params = backbone.init_backbone(jax.random.PRNGKey(7), spec)
 
     def checkpoints(p):
         jaxpr = jax.make_jaxpr(
@@ -226,32 +257,39 @@ def test_layers_are_rematerialised_from_the_bytes_of_the_state(monkeypatch, seed
         text = str(jaxpr)
         return text.count("checkpoint") + text.count("remat")
 
-    assert checkpoints(params) == 0  # a few kilobytes of state: nothing to save
+    # a few kilobytes of state: no layer is rematerialised (sparse
+    # attention has its own derivative rule, which keeps no score)
+    small = checkpoints(params)
+    assert small == 0
     monkeypatch.setattr(backbone, "REMAT_MIN_PARAM_BYTES", 1024)
-    assert checkpoints(params) >= len(spec.layer_ops)
+    assert checkpoints(params) >= small + len(spec.layer_ops)
 
 
-def test_the_four_shares_add_up_to_the_uncut_layer(reference):
-    """8 experts of which 2 held: the shares at offsets 0, 2, 4, 6, each
-    with its own slice of the uncut layer's expert weights, add up to
-    what the uncut layer gives (program and reference alike)."""
-    whole = toy(experts_held=8, expert_offset=0)
-    params = backbone.init_backbone(jax.random.PRNGKey(11), whole)["layer_2"]["moe"]
+@pytest.mark.parametrize("kind, held", [("lfm2_moe", 2), ("keye_vl2", 1)])
+def test_the_shares_add_up_to_the_uncut_layer(references, kind, held):
+    """8 experts of which ``held`` a share (four shares under the sigmoid
+    router with its bias, eight under the softmax router): the shares,
+    each with its own slice of the uncut layer's expert weights, add up
+    to what the uncut layer gives (program and reference alike)."""
+    reference, make = references[kind], TOYS[kind]
+    whole = make(experts_held=8, expert_offset=0)
+    layer = len(whole.layer_ops) - 1
+    params = backbone.init_backbone(jax.random.PRNGKey(11), whole)[f"layer_{layer}"]["moe"]
+    assert ("expert_bias" in params) == (kind == "lfm2_moe")
     u = jnp.asarray(np.random.RandomState(2).normal(size=(4, 12, 32)).astype(np.float32))
     uncut, routed, pairs = backbone.moe_ffn(whole, params, u)
     assert int(pairs) == int(routed.sum()) == 4 * 12 * 2
+    _, weights = backbone.route(whole, params, u.reshape(-1, 32))
+    np.testing.assert_allclose(np.asarray(weights).sum(axis=1), 1.0, rtol=1e-5)  # normalised
     total = np.zeros_like(np.asarray(uncut))
-    for offset in (0, 2, 4, 6):
-        share = toy(experts_held=2, expert_offset=offset)
-        held = {
-            **params,
-            **{k: params[k][offset : offset + 2] for k in ("w1", "w3", "w2")},
-        }
-        out, routed_s, pairs_s = backbone.moe_ffn(share, held, u)
+    for offset in range(0, 8, held):
+        share = make(experts_held=held, expert_offset=offset)
+        slices = {k: params[k][offset : offset + held] for k in ("w1", "w3", "w2")}
+        out, routed_s, pairs_s = backbone.moe_ffn(share, {**params, **slices}, u)
         assert np.array_equal(routed_s, routed)  # the router is the whole model's
-        assert int(pairs_s) == int(routed[offset : offset + 2].sum())
+        assert int(pairs_s) == int(routed[offset : offset + held].sum())
         sizes = {key: getattr(share, key) for key in reference.SIZES}
-        want, _ = reference.moe_ffn(u, jax.tree_util.tree_map(np.asarray, held), sizes)
+        want, _ = reference.moe_ffn(u, jax.tree_util.tree_map(np.asarray, {**params, **slices}), sizes)
         close(out, want, f"share at {offset}")
         total += np.asarray(out)
     close(total, uncut, "sum of the shares")
@@ -280,12 +318,14 @@ def test_no_pair_is_dropped_when_one_expert_takes_every_token(reference, favoure
         assert np.all(np.any(np.asarray(out) != 0, axis=-1))  # no token left out
 
 
-def test_bfloat16_compute_keeps_float32_parameters_and_output(seeded):
-    spec, params, _, x, _ = seeded
-    half = toy(compute_dtype="bfloat16")
-    out, _ = backbone.forward_backbone(half, params, x)
+@pytest.mark.parametrize("kind", ["lfm2_moe", "keye_vl2"])
+def test_bfloat16_compute_keeps_float32_parameters_and_output(seeded, kind):
+    x = seeded[3]
+    spec, half = TOYS[kind](), TOYS[kind](compute_dtype="bfloat16")
+    params = backbone.init_backbone(jax.random.PRNGKey(7), spec)
+    out, penalty = backbone.forward_backbone(half, params, x)
     full, _ = backbone.forward_backbone(spec, params, x)
-    assert out.dtype == jnp.float32
+    assert out.dtype == jnp.float32 and penalty.dtype == jnp.float32
     assert float(np.max(np.abs(np.asarray(out) - np.asarray(full)))) < 0.2
 
 

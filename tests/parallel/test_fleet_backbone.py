@@ -38,13 +38,16 @@ TAGS = [f"bb-{i}" for i in range(4)]
 MACHINES = ("compressor-a", "compressor-b")
 
 
-@pytest.fixture(scope="module")
-def built(tmp_path_factory):
-    """Two toy machines of one configuration built by the ``build-fleet``
-    command: 145 rows, 45 windows of 100 rows, three folds and the final
-    fit each. One configuration is one spec, and a spec without a member
-    axis shares no program: every fit and every score runs one machine."""
-    root = tmp_path_factory.mktemp("backbone") / REVISION
+#: ``kind: keye_vl2`` at toy widths: an indexer that keeps 24 of 100 rows
+TOY_SPARSE = dict(
+    kind="keye_vl2", lookback_window=LOOKBACK, num_hidden_layers=2, hidden_size=32, head_dim=16,
+    num_attention_heads=4, num_key_value_heads=2, moe_intermediate_size=24, num_experts=8,
+    experts_held=2, num_experts_per_tok=2, epochs=2, batch_size=32,
+    sa_config=dict(indexer_head_dim=8, indexer_num_heads=8, topk=24, q_chunk_size=32, kv_chunk_size=32),
+)
+
+
+def build_fleet(root, estimator, machines):
     document = {
         "project_name": PROJECT,
         "machines": [{
@@ -52,7 +55,7 @@ def built(tmp_path_factory):
             "model": {"gordo_tpu.models.anomaly.diff.DiffBasedAnomalyDetector": {
                 "base_estimator": {"sklearn.pipeline.Pipeline": {"steps": [
                     "sklearn.preprocessing.MinMaxScaler",
-                    {"gordo_tpu.models.JaxBackboneForecast": dict(TOY)},
+                    {"gordo_tpu.models.JaxBackboneForecast": dict(estimator)},
                 ]}}
             }},
             "dataset": {
@@ -63,7 +66,7 @@ def built(tmp_path_factory):
                 "resolution": "10min",
                 "tag_list": TAGS,
             },
-        } for name in MACHINES],
+        } for name in machines],
     }
     config_path = str(root.parent / "machines.yaml")
     with open(config_path, "w") as f:
@@ -74,6 +77,15 @@ def built(tmp_path_factory):
     except SystemExit as exc:
         code = int(exc.code or 0)
     return code, str(root)
+
+
+@pytest.fixture(scope="module")
+def built(tmp_path_factory):
+    """Two toy machines of one configuration built by the ``build-fleet``
+    command: 145 rows, 45 windows of 100 rows, three folds and the final
+    fit each. One configuration is one spec, and a spec without a member
+    axis shares no program: every fit and every score runs one machine."""
+    return build_fleet(tmp_path_factory.mktemp("backbone") / REVISION, TOY, MACHINES)
 
 
 def rows(n):
@@ -179,6 +191,57 @@ def test_the_server_answers_prediction_from_it(built):
             np.testing.assert_allclose(got.to_numpy(), want.to_numpy(), rtol=1e-5)
 
 
+def test_a_sparse_attention_backbone_takes_the_same_path(tmp_path_factory):
+    """``kind: keye_vl2`` through ``build-fleet``, the artifact and the
+    server, as ``lfm2_moe`` goes: its fits carry the selection's counters
+    beside the router's, and the indexer's objective is in their loss."""
+    from tests.server.conftest import temp_env_vars
+
+    code, root = build_fleet(tmp_path_factory.mktemp("sparse") / REVISION, TOY_SPARSE, ("turbine-k",))
+    assert code == 0
+    with open(os.path.join(root, "build_status.json")) as f:
+        status = json.load(f)
+    assert status["state"] == "complete" and status["machines"]["completed"] == 1
+    assert not any(status["robustness"].values())
+    counters = status["fit_counters"]
+    assert len(counters) == 4 and all(c["members"] == 1 for c in counters)
+    kept = sum(min(t + 1, 24) for t in range(LOOKBACK))
+    for c, windows in zip(sorted(counters, key=lambda c: c["pairs_total"][0]), (12, 23, 34, 45)):
+        assert c["index_topk"] == 24 and c["pairs_total"] == [2 * windows * LOOKBACK * 2] * 2
+        assert c["keys_selected"] == [2.0 * windows * kept] * 2  # two epochs, a layer each
+        assert c["keys_causal"] == [2.0 * windows * LOOKBACK * (LOOKBACK + 1) / 2] * 2
+        assert len(c["indexer_kl"]) == 2 and all(0 < kl < 10 * c["steps_run"] for kl in c["indexer_kl"])
+        assert c["pairs_here"] == [sum(layer[:2]) for layer in c["router_tokens"]]
+    with open(os.path.join(root, "build_trace.jsonl")) as f:
+        spans = [json.loads(line) for line in f]
+    fits = [s["attributes"] for s in spans
+            if s["name"] == "device_program" and "fit" in s["attributes"]["program"]]
+    assert len(fits) == 4 and sum(bool(a["compile"]) for a in fits) == 1
+    assert all(set(a["fit_counters"]) >= {"keys_selected", "keys_causal", "indexer_kl", "index_topk"} for a in fits)
+    model = serializer.load(os.path.join(root, "turbine-k"))
+    X = rows(LOOKBACK + 6)
+    prediction = np.asarray(model.predict(X))
+    assert prediction.shape == (6, len(TAGS)) and np.isfinite(prediction).all()
+    estimator = model.base_estimator.steps[-1][1]
+    assert estimator.spec_.layer_ops == ("sparse_attention",) * 2 and estimator.spec_.router == "softmax"
+    assert "expert_bias" not in estimator.params_["layer_0"]["moe"]
+    loss, norms = estimator.training_loss_and_grad_norms(
+        model.base_estimator.steps[0][1].transform(X), X.to_numpy()
+    )
+    assert np.isfinite(loss) and norms["layer_1"]["indexer"]["wq"] > 0
+    values = {tag: {ts.isoformat(): float(v) for ts, v in X[tag].items()} for tag in TAGS}
+    with temp_env_vars(MODEL_COLLECTION_DIR=root):
+        client = Client(build_app())
+        alone = client.post(f"/gordo/v0/{PROJECT}/turbine-k/prediction", json={"X": values})
+        assert alone.status_code == 200, alone.text
+        want = pd.DataFrame(json.loads(alone.data)["data"]["model-output"])
+        np.testing.assert_allclose(want.to_numpy(), prediction, rtol=1e-4, atol=1e-5)
+        fleet = client.post(f"/gordo/v0/{PROJECT}/prediction/fleet", json={"X": {"turbine-k": values}})
+        assert fleet.status_code == 200, fleet.text
+        got = pd.DataFrame(json.loads(fleet.data)["data"]["turbine-k"]["model-output"])
+        np.testing.assert_allclose(got.to_numpy(), want.to_numpy(), rtol=1e-5)
+
+
 def series(n=150, f=4, seed=0):
     return np.random.RandomState(seed).rand(n, f).astype(np.float32)
 
@@ -255,7 +318,13 @@ def test_an_lstm_with_a_short_history_takes_the_same_rule_and_a_long_one_keeps_r
     """The rule is the windowed path's, not the backbone's: an LSTM with
     a lookback of 100 over 145 rows has its folds over the target rows
     (and says so in its metadata and in the log); the same model over 600
-    rows, where a row fold of 150 holds 51 windows, keeps row folds."""
+    rows, where a row fold of 150 holds 51 windows, keeps row folds. And
+    over 117 rows, 17 windows, a fold scores 4 rows, fewer than the six a
+    threshold's run takes: its thresholds are the minimum over the four,
+    said in the log, and not the NaN that ``rolling(6)`` of four rows is."""
+    from gordo_tpu.models.anomaly.diff import threshold_run
+
+    assert [threshold_run(n) for n in (0, 1, 4, 6, 7, 345)] == [1, 1, 4, 6, 6, 6]
     lstm = {"gordo_tpu.models.JaxLSTMForecast": dict(
         kind="lstm_model", lookback_window=LOOKBACK, encoding_dim=[4], encoding_func=["tanh"],
         decoding_dim=[4], decoding_func=["tanh"], epochs=1, batch_size=32,
@@ -280,6 +349,7 @@ def test_an_lstm_with_a_short_history_takes_the_same_rule_and_a_long_one_keeps_r
     document = {"project_name": PROJECT, "machines": [
         machine("short", 145, "2020-01-02T00:00:00+00:00"),
         machine("long", 600, "2020-01-05T03:50:00+00:00"),
+        machine("brief", 117, "2020-01-01T19:20:00+00:00"),
     ]}
     config_path = str(tmp_path / "machines.yaml")
     with open(config_path, "w") as f:
@@ -290,8 +360,18 @@ def test_an_lstm_with_a_short_history_takes_the_same_rule_and_a_long_one_keeps_r
     cvs = {
         name: serializer.load_metadata(os.path.join(root, name))["metadata"]["build_metadata"][
             "model"]["cross_validation"]
-        for name in ("short", "long")
+        for name in ("short", "long", "brief")
     }
+    brief = serializer.load_metadata(os.path.join(root, "brief"))["metadata"]["build_metadata"]["model"]["model_meta"]
+    assert np.isfinite(brief["aggregate-threshold"]) and np.all(np.isfinite(brief["feature-thresholds"]))
+    assert cvs["brief"]["splits"]["folds-over"] == "target-rows"
+    # ... and the metadata of the model that serves them says so; no other model's does
+    assert (brief["thresholds-degraded"], brief["threshold-run-rows"]) == (True, 4)
+    for name in ("short", "long"):
+        meta = serializer.load_metadata(os.path.join(root, name))["metadata"]["build_metadata"]["model"]["model_meta"]
+        assert "thresholds-degraded" not in meta and "threshold-run-rows" not in meta
+    said = [r.getMessage() for r in caplog.records if "fewer than the 6" in r.getMessage()]
+    assert len(said) == 3 and all(m.startswith("brief: fold") and "scored 4 rows" in m for m in said)
     assert cvs["short"]["splits"]["folds-over"] == "target-rows"
     assert "folds-over" not in cvs["long"]["splits"]
     assert [r for r in caplog.records if "short: no CV fold" in r.getMessage()]
@@ -301,7 +381,7 @@ def test_an_lstm_with_a_short_history_takes_the_same_rule_and_a_long_one_keeps_r
         assert all(np.isfinite(v["fold-mean"]) for v in cv["scores"].values()), name
         starts = [pd.Timestamp(v) for k, v in cv["splits"].items() if k.endswith("train-start")]
         # row folds train from the first row, target folds from the first target row
-        assert all(s == (first_target if name == "short" else pd.Timestamp("2020-01-01T00:00:00+00:00")) for s in starts)
+        assert all(s == (pd.Timestamp("2020-01-01T00:00:00+00:00") if name == "long" else first_target) for s in starts)
 
 
 def test_the_lstm_fit_is_bit_for_bit_what_the_masked_loop_gave():
