@@ -37,6 +37,7 @@ from jax.errors import JaxRuntimeError
 from jax.sharding import Mesh
 
 from .. import telemetry
+from ..models.anomaly.diff import THRESHOLD_RUN
 from ..models.nn import forward_fn_for, init_fn_for
 from ..models.spec import ModelSpec
 from ..models.training import (
@@ -146,6 +147,20 @@ class FleetResult:
     #: degradation policy (FleetBuilder falls back to the sequential
     #: ModelBuilder path)
     error: Optional[BaseException] = None
+
+
+@dataclass
+class FoldScoring:
+    """What the scoring half of a predict program reads, stacked over a
+    bucket's fold models (:func:`fold_scores` has the arithmetic). A
+    scaler is its fitted transform a tag, ``(shift, mul, div, add)``:
+    ``((x - shift) * mul) / div + add``."""
+
+    y_true: np.ndarray  # [M, N, T] targets of the rows predicted
+    rows: np.ndarray  # [M] rows that count; the rest of a block is padding
+    metric_scaler: np.ndarray  # [M, 4, T] the machine's scoring scaler
+    error_scaler: np.ndarray  # [M, 4, T] the detector's, fitted to the fold's training rows
+    window: Optional[int] = None  # the detectors' smoothing window (static)
 
 
 def _bucket_nbytes(bucket) -> int:
@@ -350,6 +365,118 @@ def _over_members(spec: ModelSpec, fn):
     return one_member
 
 
+def _scaled(x, scaler):
+    """``x[T, N]`` through a scaler's ``[4, T]`` parameters
+    (:class:`FoldScoring`): sklearn's own steps in its order, so a
+    ``MinMaxScaler``'s ``x * scale + min`` comes out bit for bit."""
+    shift, mul, div, add = (scaler[k][:, None] for k in range(4))
+    return (x - shift) * mul / div + add
+
+
+def _explained_share(numerator, denominator):
+    """``1 - numerator / denominator`` under sklearn's rule for a zero
+    (``_assemble_fraction_of_explained_deviance``, ``force_finite``): a
+    numerator of zero scores 1, else a denominator of zero scores 0."""
+    defined = (numerator != 0) & (denominator != 0)
+    share = 1.0 - numerator / jnp.where(defined, denominator, 1.0)
+    return jnp.where(defined, share, jnp.where(numerator != 0, 0.0, 1.0))
+
+
+def _over_run(values, run: int, combine, fill):
+    """``combine`` over every ``values[..., t : t + run]`` by doubling;
+    what lies past the end reads ``fill``."""
+    covered = 1
+    while covered < run:
+        step = min(covered, run - covered)
+        tail = jnp.full(values.shape[:-1] + (step,), fill, values.dtype)
+        shifted = jnp.concatenate([values[..., step:], tail], axis=-1)
+        values = combine(values, shifted)
+        covered += step
+    return values
+
+
+def _run_min_max(values, usable, run: int):
+    """``pd.rolling(run).min().max()`` along the last axis of
+    ``values[K, N]``: the maximum over the complete runs of ``run`` usable
+    values of the run's minimum. A run that holds a value that is not
+    usable (a NaN, a row of padding) is skipped; no complete run, NaN."""
+    if run > values.shape[-1]:
+        return jnp.full(values.shape[:-1], jnp.nan, values.dtype)
+    lows = _over_run(jnp.where(usable, values, jnp.inf), run, jnp.minimum, jnp.inf)
+    spoiled = _over_run(~usable, run, jnp.logical_or, True)
+    highest = jnp.max(jnp.where(spoiled, -jnp.inf, lows), axis=-1)
+    return jnp.where(jnp.all(spoiled, axis=-1), jnp.nan, highest)
+
+
+def fold_scores(y_true, y_pred, rows, metric_scaler, error_scaler, window=None):
+    """
+    One fold model's evaluation where its predictions are: what
+    ``FleetBuilder._accumulate_metric_scores`` and
+    ``_accumulate_thresholds`` reckon on the host from ``y_true[:rows]``
+    and ``y_pred[:rows]`` (``[N, T]`` blocks; rows past ``rows`` are
+    padding), in the same float32 arithmetic.
+
+    - the four default metrics a tag (``[T]`` under sklearn's function
+      names, its rule for a zero denominator and for R2 of one row
+      included) of targets and predictions through ``metric_scaler``;
+    - ``aggregate_threshold`` and ``feature_thresholds[T]``: of the mean
+      over tags of the squared difference under ``error_scaler``, and of
+      ``|y_true - y_pred|`` a tag, the maximum over time of the minimum
+      over every complete run of ``threshold_run(rows)`` rows; with a
+      ``window``, the same over that window under ``smooth_*``;
+    - ``unscorable``: 1.0 where sklearn's metrics would refuse the fold
+      (no row, or a value that is not finite), else 0.0.
+    """
+    truth, predicted = y_true.T, y_pred.T  # [T, N]: the rows along the lanes
+    live = jnp.arange(truth.shape[-1]) < rows
+    count = jnp.maximum(rows, 1).astype(truth.dtype)
+
+    def total(values):
+        return jnp.sum(jnp.where(live, values, 0.0), axis=-1)
+
+    def mean(values):
+        return total(values) / count
+
+    truth_s, predicted_s = _scaled(truth, metric_scaler), _scaled(predicted, metric_scaler)
+    diff = truth_s - predicted_s
+    centred = truth_s - mean(truth_s)[:, None]
+    residual, spread = total(diff * diff), total(centred * centred)
+    unexplained = mean(jnp.square(diff - mean(diff)[:, None]))
+    finite = jnp.isfinite(truth_s) & jnp.isfinite(predicted_s)
+    scores = {
+        "explained_variance_score": _explained_share(unexplained, spread / count),
+        "r2_score": jnp.where(rows < 2, jnp.nan, _explained_share(residual, spread)),
+        "mean_squared_error": residual / count,
+        "mean_absolute_error": mean(jnp.abs(diff)),
+        "unscorable": ((rows < 1) | jnp.any(live & ~finite)).astype(truth.dtype),
+    }
+
+    error = _scaled(predicted, error_scaler) - _scaled(truth, error_scaler)
+    errors = jnp.concatenate(  # [T + 1, N]: a tag's absolute error, then the scaled MSE
+        [jnp.abs(truth - predicted), jnp.mean(error * error, axis=0)[None]]
+    )
+    usable = live & ~jnp.isnan(errors)
+    # a fold of fewer rows than a run takes all it has as its one run
+    # (models/anomaly/diff.threshold_run)
+    one_run = jnp.where(
+        jnp.all(usable | ~live, axis=-1),
+        jnp.min(jnp.where(live, errors, jnp.inf), axis=-1),
+        jnp.nan,
+    )
+    thresholds = jnp.where(
+        rows < THRESHOLD_RUN, one_run, _run_min_max(errors, usable, THRESHOLD_RUN)
+    )
+    scores["feature_thresholds"], scores["aggregate_threshold"] = (
+        thresholds[:-1], thresholds[-1],
+    )
+    if window is not None:
+        smooth = _run_min_max(errors, usable, window)
+        scores["smooth_feature_thresholds"], scores["smooth_aggregate_threshold"] = (
+            smooth[:-1], smooth[-1],
+        )
+    return scores
+
+
 @lru_cache(maxsize=None)
 def _fleet_fit_program(spec: ModelSpec, config: FitConfig):
     """jit(vmap) of the raw fused fit over a leading model axis."""
@@ -371,17 +498,9 @@ def _fleet_windowed_fit_program(spec: ModelSpec, config: FitConfig):
     )
 
 
-@lru_cache(maxsize=None)
-def fleet_windowed_predict_program(spec: ModelSpec, batch_size: int):
-    """
-    jit(vmap) forward for windowed members: windows gathered from the raw
-    series per scan step, so prediction memory stays bounded like training.
-
-    ``(stacked params, series[M, n, F], order[M, nv]) -> [M, nv, F_out]``
-    (``nv`` must be a multiple of ``batch_size``).
-    """
-    import jax.numpy as jnp
-
+def _windowed_predict_fn(spec: ModelSpec, batch_size: int):
+    """One windowed member's forward, its windows gathered from the raw
+    series a scan step: ``(params, series[n, F], order[nv]) -> [nv, F_out]``."""
     forward = forward_fn_for(spec)
     lookback = spec.lookback_window
 
@@ -398,18 +517,75 @@ def fleet_windowed_predict_program(spec: ModelSpec, batch_size: int):
         )
         return outs.reshape(steps * batch_size, -1)
 
-    return _jit_named("fleet_windowed_predict", _over_members(spec, predict_one))
+    return predict_one
 
 
-@lru_cache(maxsize=None)
-def fleet_predict_program(spec: ModelSpec):
-    """jit(vmap) forward: (stacked params, X[M, N, ...]) -> [M, N, out]."""
+def _predict_fn(spec: ModelSpec):
+    """One dense member's forward: ``(params, X[N, ...]) -> [N, out]``."""
     forward = forward_fn_for(spec)
 
     def predict(params, X):
         return forward(spec, params, X)[0]
 
-    return _jit_named("fleet_predict", jax.vmap(predict))
+    return predict
+
+
+def _scored(predict_one, window: Optional[int]):
+    """``predict_one`` with :func:`fold_scores` of its predictions: the
+    model's inputs, then a member's slice of a :class:`FoldScoring`."""
+
+    def predict_and_score(*args):
+        *inputs, y_true, rows, metric_scaler, error_scaler = args
+        predictions = predict_one(*inputs)
+        return predictions, fold_scores(
+            y_true, predictions, rows, metric_scaler, error_scaler, window
+        )
+
+    return predict_and_score
+
+
+@lru_cache(maxsize=None)
+def fleet_windowed_predict_program(spec: ModelSpec, batch_size: int):
+    """
+    jit(vmap) forward for windowed members: windows gathered from the raw
+    series per scan step, so prediction memory stays bounded like training.
+
+    ``(stacked params, series[M, n, F], order[M, nv]) -> [M, nv, F_out]``
+    (``nv`` must be a multiple of ``batch_size``).
+    """
+    return _jit_named(
+        "fleet_windowed_predict",
+        _over_members(spec, _windowed_predict_fn(spec, batch_size)),
+    )
+
+
+@lru_cache(maxsize=None)
+def fleet_predict_program(spec: ModelSpec):
+    """jit(vmap) forward: (stacked params, X[M, N, ...]) -> [M, N, out]."""
+    return _jit_named("fleet_predict", jax.vmap(_predict_fn(spec)))
+
+
+@lru_cache(maxsize=None)
+def _fleet_windowed_predict_score_program(
+    spec: ModelSpec, batch_size: int, window: Optional[int]
+):
+    """:func:`fleet_windowed_predict_program` and the fold scores of what
+    it predicts in one program: ``(..., y_true[M, nv, F_out], rows[M],
+    metric_scaler[M, 4, F_out], error_scaler[M, 4, F_out]) ->
+    (predictions, scores)``."""
+    return _jit_named(
+        "fleet_windowed_predict_score",
+        _over_members(spec, _scored(_windowed_predict_fn(spec, batch_size), window)),
+    )
+
+
+@lru_cache(maxsize=None)
+def _fleet_predict_score_program(spec: ModelSpec, window: Optional[int]):
+    """:func:`fleet_predict_program` and the fold scores of what it
+    predicts in one program."""
+    return _jit_named(
+        "fleet_predict_score", jax.vmap(_scored(_predict_fn(spec), window))
+    )
 
 
 @lru_cache(maxsize=None)
@@ -953,10 +1129,55 @@ class FleetTrainer:
 
     # -- prediction ---------------------------------------------------------
 
+    def _put_scoring(
+        self, mesh: Mesh, scoring: FoldScoring, m_total: int, n_total: int, rows_sharding
+    ):
+        """``scoring``'s arrays padded to the program's block (a member of
+        padding counts no rows) and put on the mesh beside the inputs:
+        ``y_true`` like the predictions it is held against."""
+        y_true = scoring.y_true
+        m, n = y_true.shape[:2]
+        if (m, n) != (m_total, n_total):
+            y_true = np.zeros((m_total, n_total, y_true.shape[2]), np.float32)
+            y_true[:m, :n] = scoring.y_true
+        rows = np.zeros(m_total, np.int32)
+        rows[:m] = scoring.rows
+        scalers = []
+        for scaler in (scoring.metric_scaler, scoring.error_scaler):
+            padded = np.ones((m_total,) + scaler.shape[1:], np.float32)
+            padded[:m] = scaler
+            scalers.append(padded)
+        per_member = model_sharding(mesh, extra_dims=2)
+        return jax.device_put(
+            (y_true, rows, *scalers),
+            (rows_sharding, model_sharding(mesh), per_member, per_member),
+        )
+
+    @staticmethod
+    def _collect_predictions(out, scoring: Optional[FoldScoring], m: int, n: int):
+        """What a predict program hands back: the predictions on the
+        host, or, of a program that scored them (``scoring``), the
+        predictions where they are and the scores of the ``m`` members
+        on the host."""
+        with telemetry.part_span("collect"):
+            if scoring is None:
+                return np.asarray(fetch_to_host(out))[:m, :n]
+            predictions, scores = out
+            scores = fetch_to_host(scores)
+        return predictions, {k: np.asarray(v)[:m] for k, v in scores.items()}
+
     def predict_bucket(
-        self, spec: ModelSpec, stacked_params, X: np.ndarray
-    ) -> np.ndarray:
-        """Forward the whole bucket: X[M, N, ...] -> [M, N, out]."""
+        self,
+        spec: ModelSpec,
+        stacked_params,
+        X: np.ndarray,
+        scoring: Optional[FoldScoring] = None,
+    ):
+        """Forward the whole bucket: X[M, N, ...] -> [M, N, out]. With
+        ``scoring``, the same program goes on to :func:`fold_scores`: the
+        predictions stay on the device (``[M', N', out]``, the program's
+        padded block) and come back beside the scores, ``[M, ...]`` arrays
+        on the host."""
         with telemetry.part_span("h2d"):  # pad to the mesh, then transfer
             X = np.asarray(X, np.float32)
             m = X.shape[0]
@@ -982,17 +1203,23 @@ class FleetTrainer:
             X = jax.device_put(
                 X, model_data_sharding(self.mesh, extra_dims=X.ndim - 2)
             )
+            program, name, scored = fleet_predict_program(spec), "fleet_predict", ()
+            if scoring is not None:
+                program = _fleet_predict_score_program(spec, scoring.window)
+                name = "fleet_predict_score"
+                scored = self._put_scoring(
+                    self.mesh, scoring, m_total, n_total,
+                    model_data_sharding(self.mesh, extra_dims=1),
+                )
         with telemetry.program_span(
-            "fleet_predict",
-            (spec, X.shape),
+            name,
+            (spec, X.shape, scoring and scoring.window),
             members=m,
             shape=str(tuple(X.shape)),
             spec=type(spec).__name__,
         ):
-            out = _traced_outputs(fleet_predict_program(spec)(stacked_params, X))
-            with telemetry.part_span("collect"):
-                out = np.asarray(fetch_to_host(out))
-        return out[:m, :n]
+            out = _traced_outputs(program(stacked_params, X, *scored))
+            return self._collect_predictions(out, scoring, m, n)
 
     def predict_windowed_bucket(
         self,
@@ -1001,12 +1228,14 @@ class FleetTrainer:
         series: np.ndarray,
         order: np.ndarray,
         batch_size: int = 256,
-    ) -> np.ndarray:
+        scoring: Optional[FoldScoring] = None,
+    ):
         """
         Forward a windowed bucket with on-device window gathering, sharded
         over the mesh's model axis like :meth:`predict_bucket`:
         ``series[M, n, F]`` + ``order[M, nv]`` → ``[M, nv, F_out]``
         (``nv`` is padded to a whole number of ``batch_size`` batches here).
+        ``scoring`` as in :meth:`predict_bucket`.
         """
         mesh = self._mesh_for(spec)
         with telemetry.part_span("h2d"):  # pad to the mesh, then transfer
@@ -1037,21 +1266,29 @@ class FleetTrainer:
             order = jax.device_put(
                 order, model_sharding(mesh, extra_dims=1)
             )
+            program = fleet_windowed_predict_program(spec, batch_size)
+            name, scored = "fleet_windowed_predict", ()
+            if scoring is not None:
+                program = _fleet_windowed_predict_score_program(
+                    spec, batch_size, scoring.window
+                )
+                name = "fleet_windowed_predict_score"
+                scored = self._put_scoring(mesh, scoring, m_total, nv_pad, ms2)
         with telemetry.program_span(
-            "fleet_windowed_predict",
-            (spec, batch_size, series.shape, order.shape),
+            name,
+            (spec, batch_size, series.shape, order.shape, scoring and scoring.window),
             members=m,
             shape=str(tuple(series.shape)),
             spec=type(spec).__name__,
         ):
-            out = _traced_outputs(
-                fleet_windowed_predict_program(spec, batch_size)(
-                    stacked_params, series, order
-                )
-            )
-            with telemetry.part_span("collect"):
-                out = np.asarray(fetch_to_host(out))
-        return out[:m, :nv]
+            out = _traced_outputs(program(stacked_params, series, order, *scored))
+            return self._collect_predictions(out, scoring, m, nv)
+
+
+def fetch_members(stacked, members: Sequence[int]) -> np.ndarray:
+    """The ``members`` of a stacked device array on the host (the fold
+    models whose predictions the host scores), and no other's."""
+    return np.asarray(fetch_to_host(stacked[np.asarray(members, np.int32)]))
 
 
 def stack_member_params(results: Sequence[FleetResult]):

@@ -38,6 +38,7 @@ import pandas as pd
 from sklearn.base import clone as sklearn_clone
 from sklearn.model_selection import KFold, TimeSeriesSplit
 from sklearn.pipeline import Pipeline
+from sklearn.preprocessing import MinMaxScaler, RobustScaler, StandardScaler
 
 import gordo_tpu
 from .. import serializer, telemetry
@@ -72,7 +73,9 @@ from ..utils.retry import retry_call
 from .fleet import (
     FleetMember,
     FleetTrainer,
+    FoldScoring,
     WindowedFleetMember,
+    fetch_members,
     is_device_error,
     stack_member_params,
 )
@@ -123,6 +126,9 @@ class _Plan:
     # baked into BuildMetadata.model.training at assembly.
     training_summary: Optional[TrainingSummaryMetadata] = None
     _scoring_setup_cache: Any = None  # (metrics, fitted scoring scaler)
+    # (the scoring scaler's parameters for a predict program, or None
+    # where the host scores the machine,): FleetBuilder._device_scoring
+    _device_scoring_cache: Any = None
 
 
 class FleetBuildError(RuntimeError):
@@ -136,6 +142,75 @@ FIT_PROGRAM_KEYS = (
     "program", "phase", "members", "params", "epochs", "stacked_samples",
     "tokens_per_step",
 )
+
+
+#: the metrics a predict program scores where the predictions are
+#: (parallel/fleet.fold_scores): the callables of a default evaluation
+DEVICE_METRICS = tuple(ModelBuilder.metrics_from_list())
+
+
+def _per_tag_affine(scaler) -> bool:
+    """Whether ``scaler``'s transform is four numbers a tag
+    (:func:`_scaler_parameters`): none at all, or one of the three
+    per-tag affine scalers itself. A subclass, a pipeline, another
+    transformer or a ``MinMaxScaler`` that clips may do what four
+    numbers cannot say."""
+    return (
+        scaler is None
+        or type(scaler) in (StandardScaler, RobustScaler)
+        or (type(scaler) is MinMaxScaler and not scaler.clip)
+    )
+
+
+def _scaler_parameters(scaler, tags: int) -> np.ndarray:
+    """A fitted :func:`_per_tag_affine` scaler's transform as
+    ``FoldScoring`` takes it: float32 ``[4, tags]`` rows ``(shift, mul,
+    div, add)`` of ``((x - shift) * mul) / div + add``, the steps sklearn
+    takes in the order it takes them; no scaler is the identity."""
+    parameters = np.empty((4, tags), np.float32)
+    parameters[:] = [[0.0], [1.0], [1.0], [0.0]]
+    if isinstance(scaler, MinMaxScaler):
+        parameters[1], parameters[3] = scaler.scale_, scaler.min_
+    elif isinstance(scaler, StandardScaler):
+        if scaler.with_mean:
+            parameters[0] = scaler.mean_
+        if scaler.with_std:
+            parameters[2] = scaler.scale_
+    elif isinstance(scaler, RobustScaler):
+        if scaler.with_centering:
+            parameters[0] = scaler.center_
+        if scaler.with_scaling:
+            parameters[2] = scaler.scale_
+    return parameters
+
+
+def _fold_scaler_parameters(scaler, y_train: np.ndarray) -> np.ndarray:
+    """:func:`_scaler_parameters` of a clone of ``scaler`` fitted to a
+    fold's training rows. A ``MinMaxScaler`` (the reference's detectors
+    have no other) is fitted here, by the arithmetic of its
+    ``partial_fit`` without the input validation that is most of a
+    ``clone().fit`` on a fold's few thousand rows."""
+    tags = y_train.shape[1]
+    if not isinstance(scaler, MinMaxScaler):
+        return _scaler_parameters(sklearn_clone(scaler).fit(y_train), tags)
+    low, high = (y_train.dtype.type(bound) for bound in scaler.feature_range)
+    data_min = np.nanmin(y_train, axis=0)
+    data_range = np.nanmax(y_train, axis=0) - data_min
+    data_range[data_range < 10 * np.finfo(data_range.dtype).eps] = 1.0
+    parameters = _scaler_parameters(None, tags)
+    parameters[1] = (high - low) / data_range
+    parameters[3] = low - data_min * parameters[1]
+    return parameters
+
+
+def _take_rows(array: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    """``array[rows]``, as a view where ``rows`` is one ascending run (a
+    ``TimeSeriesSplit``'s folds are): a copy of a fold's training rows
+    costs more than the minimum and maximum taken of it."""
+    first = int(rows[0]) if len(rows) else 0
+    if np.array_equal(rows, np.arange(first, first + len(rows))):
+        return array[first : first + len(rows)]
+    return array[rows]
 
 
 def _cv_chunk_bytes() -> int:
@@ -1811,8 +1886,13 @@ class FleetBuilder:
         """
         Score trained fold models: ``fold_items`` is ``[(plan, fold_idx)]``
         in fold-major order (every fold of every machine of one fit
-        config). One batched forward per (spec, geometry) group — all
-        folds of all machines of an architecture predict in one dispatch.
+        config). One batched forward per (spec, geometry, detector window)
+        group — all folds of all machines of an architecture predict in
+        one dispatch, and the same program scores them where its
+        predictions are (``parallel/fleet.fold_scores``), for every
+        machine whose evaluation it can express (:meth:`_device_scoring`);
+        the predictions of the others come to the host and are scored
+        there, a machine-fold at a time.
         Windowed (LSTM) plans predict through the on-device window-gather
         scan; dense plans through the stacked forward.
         """
@@ -1822,8 +1902,21 @@ class FleetBuilder:
             geometry = (
                 ("windowed",) if plan.windows is None else plan.windows.shape[1:]
             )
-            groups.setdefault((plan.spec, geometry), []).append((plan, fold_idx))
-        for (spec, geometry), group in groups.items():
+            window = getattr(plan.detector, "window", None)
+            groups.setdefault((plan.spec, geometry, window), []).append(
+                (plan, fold_idx)
+            )
+        for (spec, geometry, window), group in groups.items():
+            with self._phase("cv_score"), self._part("stack"):
+                # per item: (train_rows, window_idx, target_rows)
+                fold_rows = []
+                for plan, fold_idx in group:
+                    train_rows, test_rows = per_plan_folds[plan.machine.name][
+                        fold_idx
+                    ]
+                    window_idx, target_rows = self._test_window_rows(plan, test_rows)
+                    fold_rows.append((train_rows, window_idx, target_rows))
+                scoring = self._fold_scoring(group, fold_rows, window)
             with self._phase("cv_predict"):
                 with self._part("stack"):
                     stacked = stack_member_params(
@@ -1832,22 +1925,13 @@ class FleetBuilder:
                             for p, k in group
                         ]
                     )
-                    # per item: (train_rows, window_idx, target_rows)
-                    fold_rows = []
-                    for plan, fold_idx in group:
-                        train_rows, test_rows = per_plan_folds[
-                            plan.machine.name
-                        ][fold_idx]
-                        window_idx, target_rows = self._test_window_rows(
-                            plan, test_rows
-                        )
-                        fold_rows.append((train_rows, window_idx, target_rows))
                 if geometry == ("windowed",):
-                    predictions = self._predict_windowed_group(
+                    predicted = self._predict_windowed_group(
                         spec,
                         stacked,
                         [p for p, _ in group],
                         [wi for _, wi, _ in fold_rows],
+                        scoring,
                     )
                 else:
                     with self._part("stack"):
@@ -1858,30 +1942,118 @@ class FleetBuilder:
                         )
                         for i, (p, _) in enumerate(group):
                             X[i, : len(fold_rows[i][1])] = p.windows[fold_rows[i][1]]
-                    predictions = self.trainer.predict_bucket(spec, stacked, X)
+                    predicted = self.trainer.predict_bucket(
+                        spec, stacked, X, scoring=scoring
+                    )
             with self._phase("cv_score"):
-                # per machine-fold work, timed here and recorded as two
-                # spans a group: a span each would be 2 x members lines
-                clock = time.perf_counter
-                metric_seconds = threshold_seconds = 0.0
-                for i, (plan, fold_idx) in enumerate(group):
-                    train_rows, window_idx, target_rows = fold_rows[i]
-                    y_true = plan.y_arr[target_rows]
-                    y_pred = predictions[i, : len(window_idx)]
-                    state = fold_state[plan.machine.name]
-                    began = clock()
-                    self._accumulate_metric_scores(plan, y_true, y_pred, fold_idx)
-                    scored = clock()
-                    metric_seconds += scored - began
-                    if plan.detector is not None:
-                        self._accumulate_thresholds(
-                            plan, y_true, y_pred, fold_idx, state,
-                            y_train=plan.y_arr[train_rows],
-                            test_rows=target_rows,
-                        )
-                        threshold_seconds += clock() - scored
-                self._record_part("metric_scores", metric_seconds, len(group))
-                self._record_part("thresholds", threshold_seconds, len(group))
+                self._adopt_fold_scores(group, fold_rows, scoring, predicted, fold_state)
+
+    def _fold_scoring(self, group, fold_rows, window) -> Optional[FoldScoring]:
+        """What the group's predict program needs to score its fold
+        models (``FoldScoring``): targets, rows and scalers of every
+        member :meth:`_device_scoring` takes; a member the host scores
+        counts no rows there. ``None`` where the host scores them all."""
+        metric_scalers = [self._device_scoring(plan) for plan, _ in group]
+        if all(scaler is None for scaler in metric_scalers):
+            return None
+        tags = group[0][0].y_arr.shape[1]
+        n_max = max(len(target_rows) for _, _, target_rows in fold_rows)
+        scoring = FoldScoring(
+            y_true=np.zeros((len(group), n_max, tags), np.float32),
+            rows=np.zeros(len(group), np.int32),
+            metric_scaler=np.ones((len(group), 4, tags), np.float32),
+            error_scaler=np.ones((len(group), 4, tags), np.float32),
+            window=window,
+        )
+        for i, ((plan, _), (train_rows, _, target_rows)) in enumerate(
+            zip(group, fold_rows)
+        ):
+            if metric_scalers[i] is None:
+                continue
+            scoring.y_true[i, : len(target_rows)] = _take_rows(plan.y_arr, target_rows)
+            scoring.rows[i] = len(target_rows)
+            scoring.metric_scaler[i] = metric_scalers[i]
+            # the fold model's scaler is fit on the fold-TRAIN targets, as
+            # in _accumulate_thresholds
+            scoring.error_scaler[i] = (
+                _fold_scaler_parameters(
+                    plan.detector.scaler, _take_rows(plan.y_arr, train_rows)
+                )
+                if plan.detector is not None
+                else metric_scalers[i]
+            )
+        return scoring
+
+    def _adopt_fold_scores(self, group, fold_rows, scoring, predicted, fold_state):
+        """A group's fold scores into ``plan.cv_scores`` and the
+        machines' ``fold_state``: what the predict program scored
+        (``predicted`` is then its predictions on the device and its
+        scores), and, through the host's own arithmetic, every member it
+        did not: one :meth:`_device_scoring` leaves to the host, and one
+        the program found ``unscorable``, so that the host's code says
+        what is wrong with it. Parts: ``device_scores`` counts the
+        machine-folds scored on the device, ``metric_scores`` and
+        ``thresholds`` those scored here."""
+        clock = time.perf_counter
+        began = clock()
+        on_host = list(range(len(group)))
+        predictions = predicted
+        if scoring is not None:
+            predictions, scores = predicted
+            on_host = [
+                i for i in on_host if not scoring.rows[i] or scores["unscorable"][i]
+            ]
+            metrics = {
+                metric: (scores[metric.__name__], scores[metric.__name__].mean(axis=-1))
+                for metric in DEVICE_METRICS
+            }
+            thresholds = ["aggregate_threshold", "feature_thresholds"]
+            if scoring.window is not None:
+                thresholds += ["smooth_aggregate_threshold", "smooth_feature_thresholds"]
+            left_to_host = set(on_host)
+            for i, (plan, fold_idx) in enumerate(group):
+                if i in left_to_host:
+                    continue
+                for metric in self._scoring_setup(plan)[0]:
+                    per_tag, aggregate = metrics[metric]
+                    self._record_metric_scores(
+                        plan, fold_idx, metric, per_tag[i], aggregate[i]
+                    )
+                if plan.detector is not None:
+                    # float64 as the host's _rolling_min_max hands them on
+                    # (of float32 minima and maxima: the same numbers)
+                    self._record_thresholds(
+                        plan, fold_idx, fold_state[plan.machine.name],
+                        int(scoring.rows[i]),
+                        *(scores[name][i].astype(np.float64) for name in thresholds),
+                    )
+            if on_host:
+                with self._part("collect"):
+                    predictions = fetch_members(predictions, on_host)
+        self._record_part(
+            "device_scores", clock() - began, len(group) - len(on_host)
+        )
+        # per machine-fold work, timed here and recorded as two
+        # spans a group: a span each would be 2 x members lines
+        metric_seconds = threshold_seconds = 0.0
+        for i, y_pred in zip(on_host, predictions):
+            plan, fold_idx = group[i]
+            train_rows, window_idx, target_rows = fold_rows[i]
+            y_true = plan.y_arr[target_rows]
+            y_pred = y_pred[: len(window_idx)]
+            began = clock()
+            self._accumulate_metric_scores(plan, y_true, y_pred, fold_idx)
+            scored = clock()
+            metric_seconds += scored - began
+            if plan.detector is not None:
+                self._accumulate_thresholds(
+                    plan, y_true, y_pred, fold_idx, fold_state[plan.machine.name],
+                    y_train=plan.y_arr[train_rows],
+                    test_rows=target_rows,
+                )
+                threshold_seconds += clock() - scored
+        self._record_part("metric_scores", metric_seconds, len(on_host))
+        self._record_part("thresholds", threshold_seconds, len(on_host))
 
     def _predict_windowed_group(
         self,
@@ -1889,11 +2061,13 @@ class FleetBuilder:
         stacked,
         group: List[_Plan],
         window_idx: List[np.ndarray],
-    ) -> np.ndarray:
+        scoring: Optional[FoldScoring] = None,
+    ):
         """Predictions for windowed plans, windows gathered on device (scan
         over ``planner.packing.windowed_scoring_batch`` windows a step), model-axis sharded over the
         trainer's mesh like the dense scoring path. ``window_idx`` gives
-        each plan's window positions to predict (the fold-test windows)."""
+        each plan's window positions to predict (the fold-test windows);
+        ``scoring`` as ``FleetTrainer.predict_bucket`` takes it."""
         orders = window_idx
         with self._part("stack"):
             nv_max = max(len(o) for o in orders)
@@ -1906,7 +2080,8 @@ class FleetBuilder:
                 series[i, : len(p.X_arr)] = p.X_arr
                 order[i, : len(orders[i])] = orders[i]
         return self.trainer.predict_windowed_bucket(
-            spec, stacked, series, order, batch_size=windowed_scoring_batch(spec)
+            spec, stacked, series, order,
+            batch_size=windowed_scoring_batch(spec), scoring=scoring,
         )
 
     @staticmethod
@@ -1933,16 +2108,55 @@ class FleetBuilder:
         plan._scoring_setup_cache = (metrics_list, scaler)
         return plan._scoring_setup_cache
 
+    @classmethod
+    def _device_scoring(cls, plan: _Plan) -> Optional[np.ndarray]:
+        """Whether the group's predict program can score this machine's
+        folds, read off what the plan holds: every metric is one of the
+        default four themselves (a user's callable cannot be traced), the
+        scoring scaler and the detector's are per-tag affine
+        (:func:`_per_tag_affine`), the detector is none or a
+        ``DiffBasedAnomalyDetector`` itself (the KFCV detector stitches
+        scattered test rows before it smooths). Then the scoring
+        scaler's parameters, else ``None``: the host scores the machine."""
+        cached = plan._device_scoring_cache
+        if cached is None:
+            metrics_list, scaler = cls._scoring_setup(plan)
+            detector = plan.detector
+            able = (
+                all(any(metric is known for known in DEVICE_METRICS) for metric in metrics_list)
+                and _per_tag_affine(scaler)
+                and (
+                    detector is None
+                    or (
+                        type(detector) is DiffBasedAnomalyDetector
+                        and _per_tag_affine(detector.scaler)
+                    )
+                )
+            )
+            cached = plan._device_scoring_cache = (
+                _scaler_parameters(scaler, plan.y_arr.shape[1]) if able else None,
+            )
+        return cached[0]
+
+    @staticmethod
+    def _record_metric_scores(plan, fold_idx, metric, per_tag, aggregate):
+        """One metric of one fold into ``plan.cv_scores``: a value a tag
+        and the aggregate over the tags."""
+        name = metric.__name__.replace("_", "-")
+        fold_key = f"fold-{fold_idx + 1}"
+        for tag, value in zip(plan.y.columns, per_tag):
+            key = f"{name}-{str(tag).replace(' ', '-')}"
+            plan.cv_scores.setdefault(key, {})[fold_key] = float(value)
+        plan.cv_scores.setdefault(name, {})[fold_key] = float(aggregate)
+
     def _accumulate_metric_scores(self, plan, y_true, y_pred, fold_idx):
         metrics_list, scaler = self._scoring_setup(plan)
         if scaler is not None:
             y_true_s, y_pred_s = scaler.transform(y_true), scaler.transform(y_pred)
         else:
             y_true_s, y_pred_s = y_true, y_pred
-        tags = [str(c) for c in plan.y.columns]
-        fold_key = f"fold-{fold_idx + 1}"
+        tags = range(y_true.shape[1])
         for metric in metrics_list:
-            name = metric.__name__.replace("_", "-")
             per_tag = None
             vectorized = False
             try:
@@ -1960,19 +2174,14 @@ class FleetBuilder:
                 # the kwarg and return something else entirely; only trust
                 # a correctly-shaped per-tag vector.
                 per_tag = np.asarray(
-                    [
-                        metric(y_true_s[:, i], y_pred_s[:, i])
-                        for i in range(len(tags))
-                    ]
+                    [metric(y_true_s[:, i], y_pred_s[:, i]) for i in tags]
                 )
-            for i, tag in enumerate(tags):
-                key = f"{name}-{tag.replace(' ', '-')}"
-                plan.cv_scores.setdefault(key, {})[fold_key] = float(per_tag[i])
             # sklearn regression metrics aggregate with multioutput=
             # "uniform_average" — the plain mean of the raw_values vector —
             # so when the vectorized call succeeded the aggregate is free.
-            plan.cv_scores.setdefault(name, {})[fold_key] = float(
-                np.mean(per_tag) if vectorized else metric(y_true_s, y_pred_s)
+            self._record_metric_scores(
+                plan, fold_idx, metric, per_tag,
+                np.mean(per_tag) if vectorized else metric(y_true_s, y_pred_s),
             )
 
     @staticmethod
@@ -2028,33 +2237,47 @@ class FleetBuilder:
                 (np.asarray(test_rows), scaled_mse, abs_err)
             )
         else:
-            run = state["threshold_run_rows"] = threshold_run(len(scaled_mse))
-            if run < THRESHOLD_RUN:
-                logger.warning(
-                    "%s: fold %d scored %d rows, fewer than the %d a threshold's "
-                    "run takes; its thresholds are the minimum over those rows",
-                    plan.machine.name, fold_idx, len(scaled_mse), THRESHOLD_RUN,
-                )
-            state["aggregate_threshold"] = cls._rolling_min_max(scaled_mse, run)
-            tag_thresholds = pd.Series(
-                cls._rolling_min_max(abs_err, run), name=f"fold-{fold_idx}"
+            run = threshold_run(len(scaled_mse))
+            cls._record_thresholds(
+                plan, fold_idx, state, len(scaled_mse),
+                cls._rolling_min_max(scaled_mse, run),
+                cls._rolling_min_max(abs_err, run),
+                *(
+                    (
+                        cls._rolling_min_max(scaled_mse, detector.window),
+                        cls._rolling_min_max(abs_err, detector.window),
+                    )
+                    if detector.window is not None
+                    else ()
+                ),
             )
-            state.setdefault("feature_folds", {})[f"fold-{fold_idx}"] = tag_thresholds
-            state.setdefault("agg_folds", {})[f"fold-{fold_idx}"] = state[
-                "aggregate_threshold"
-            ]
-            if detector.window is not None:
-                smooth_agg = cls._rolling_min_max(scaled_mse, detector.window)
-                smooth_tags = pd.Series(
-                    cls._rolling_min_max(abs_err, detector.window),
-                    name=f"fold-{fold_idx}",
-                )
-                state["smooth_aggregate_threshold"] = smooth_agg
-                state["smooth_feature_thresholds"] = smooth_tags
-                state.setdefault("smooth_feature_folds", {})[
-                    f"fold-{fold_idx}"
-                ] = smooth_tags
-                state.setdefault("smooth_agg_folds", {})[f"fold-{fold_idx}"] = smooth_agg
+
+    @staticmethod
+    def _record_thresholds(
+        plan, fold_idx, state, rows, aggregate, features,
+        smooth_aggregate=None, smooth_features=None,
+    ):
+        """One fold's thresholds (a float and a value a tag, of ``rows``
+        scored rows; the ``smooth_*`` pair where the detector has a
+        window) into the machine's ``fold_state``, as ``_finalize_cv``
+        reads them."""
+        fold = f"fold-{fold_idx}"
+        run = state["threshold_run_rows"] = threshold_run(rows)
+        if run < THRESHOLD_RUN:
+            logger.warning(
+                "%s: fold %d scored %d rows, fewer than the %d a threshold's "
+                "run takes; its thresholds are the minimum over those rows",
+                plan.machine.name, fold_idx, rows, THRESHOLD_RUN,
+            )
+        state["aggregate_threshold"] = float(aggregate)
+        state.setdefault("feature_folds", {})[fold] = pd.Series(features, name=fold)
+        state.setdefault("agg_folds", {})[fold] = float(aggregate)
+        if smooth_features is not None:
+            smooth_tags = pd.Series(smooth_features, name=fold)
+            state["smooth_aggregate_threshold"] = float(smooth_aggregate)
+            state["smooth_feature_thresholds"] = smooth_tags
+            state.setdefault("smooth_feature_folds", {})[fold] = smooth_tags
+            state.setdefault("smooth_agg_folds", {})[fold] = float(smooth_aggregate)
 
     def _finalize_cv(self, plan: _Plan, state: Dict[str, Any]):
         # fold-stat summary rows (fold-mean/std/min/max) like the reference

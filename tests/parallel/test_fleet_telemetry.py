@@ -106,9 +106,7 @@ def test_span_stream_covers_every_phase_and_attributes_compile(tmp_path):
         assert attrs["program"]
         assert attrs["members"] >= 1
         assert attrs["shape"].startswith("(")
-        assert attrs.get("bytes", 0) > 0 or attrs["program"].endswith(
-            "predict"
-        )
+        assert attrs.get("bytes", 0) > 0 or "predict" in attrs["program"]
     # compile-vs-run attribution within one build: the FIRST occurrence
     # of each (program, stacked-shape) signature is the compile, every
     # later one a steady-state run. (Under the test mesh the CV and

@@ -41,7 +41,7 @@ REQUIRED_PARTS = {
     "cv_train": {"stack", "h2d", "init", "collect"},
     "final_fit": {"stack", "h2d", "init", "collect"},
     "cv_predict": {"stack", "collect"},
-    "cv_score": {"metric_scores", "thresholds"},
+    "cv_score": {"stack", "device_scores", "metric_scores", "thresholds"},
     "dump": {"serialize", "write"},
 }
 
@@ -285,10 +285,62 @@ def test_status_holds_the_parts_of_each_phase(built, phase):
     assert set(entry["parts"]) >= REQUIRED_PARTS[phase]
     workers = builder.data_workers if phase == "data_fetch" else 8
     for part, measured in entry["parts"].items():
-        assert measured["count"] >= 1 and measured["seconds"] >= 0.0
+        # the parts of the host's scoring count the machine-folds that
+        # fell back to it: none of a default evaluation's
+        fell_back = phase == "cv_score" and part in ("metric_scores", "thresholds")
+        assert measured["count"] >= (0 if fell_back else 1)
+        assert measured["seconds"] >= 0.0
         # thread-seconds: no more than the phase's wall seconds on
         # every worker at once
         assert measured["seconds"] <= entry["seconds"] * workers + 1e-3, part
+
+
+def test_cv_score_counts_the_machine_folds_scored_on_the_device(built):
+    """A default evaluation is scored by the predict program: the device
+    part counts machines x folds, the host's parts none; the program's
+    name holds no ``fit`` (the chip benchmark holds every program so
+    named to the cell's epochs and samples)."""
+    _, spans, status = built
+    parts = status["phases"]["cv_score"]["parts"]
+    assert parts["device_scores"]["count"] == 2 * 3
+    assert parts["metric_scores"]["count"] == parts["thresholds"]["count"] == 0
+    programs = [
+        s["attributes"]["program"] for s in spans if s["name"] == "device_program"
+    ]
+    assert programs == ["fleet_fit", "fleet_predict_score", "fleet_fit"]
+    assert "fit" not in programs[1]
+
+
+def test_cv_score_counts_the_machine_folds_that_fell_back(tmp_path):
+    """A metric the program cannot express sends that machine's folds to
+    the host's code, its neighbour's stay on the device; a second build
+    in the same process compiles nothing new for scoring."""
+    from gordo_tpu.parallel.fleet import _fleet_predict_score_program
+
+    def machines():
+        odd = make_machine("bp-odd")
+        odd.evaluation = {**odd.evaluation, "metrics": ["median_absolute_error"]}
+        return [make_machine("bp-plain"), odd]
+
+    compiled = []
+    for attempt in ("first", "second"):
+        out = str(tmp_path / attempt)
+        results = FleetBuilder(machines()).build(output_dir=out)
+        assert len(results) == 2
+        parts = load_status(out)["phases"]["cv_score"]["parts"]
+        assert parts["device_scores"]["count"] == 3
+        assert parts["metric_scores"]["count"] == parts["thresholds"]["count"] == 3
+        with open(os.path.join(out, BUILD_TRACE_FILE)) as f:
+            spans = [json.loads(line) for line in f]
+        (predict,) = [
+            s for s in spans
+            if s["name"] == "device_program"
+            and s["attributes"]["program"] == "fleet_predict_score"
+        ]
+        program = _fleet_predict_score_program(results[0][0].base_estimator.spec_, None)
+        compiled.append((predict["attributes"]["compile"], program, program._cache_size()))
+    (_, first, size), (compile_flag, second, size_after) = compiled
+    assert compile_flag is False and second is first and size_after == size == 1
 
 
 def test_a_phase_without_parts_is_written_as_before(built):
@@ -424,7 +476,7 @@ def test_phases_parts_and_programs_are_annotations_in_a_profiler_session(
     assert "dataset:provider_read" in names  # the dataset's own, by the same door
     assert "build_part:cv_train/init" in names  # the trainer's
     assert "device_program:fleet_fit" in names
-    assert "device_program:fleet_predict" in names
+    assert "device_program:fleet_predict_score" in names
 
 
 # -- the compile path's counters -------------------------------------------------
