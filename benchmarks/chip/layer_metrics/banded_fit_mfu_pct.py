@@ -1,0 +1,33 @@
+"""Useful forward-and-backward FLOPs of the traced job's training of a
+banded-attention backbone (``flops_banded_backbone.job_useful_fit_flops``:
+projections and gates from shapes at each layer's own head count,
+attention over the pairs inside the mask that the program counted, the
+routed experts from its pairs counter, the shared expert and the dense
+layer from tokens; pairs a tile multiplies outside the mask, padding,
+skipped steps and rematerialised work are no useful work) over what the
+chip could do at the bf16 peak in the device time the fit modules took
+(the same time as ``backbone_fit_step_ms``): the share of the whole
+step's roofline. None where the fit programs carry no band counters (a
+program without the operator) or the traced slice holds no whole fit
+module."""
+
+import flops_banded_backbone
+from harness.data import history_rows
+from harness.evidence import fit_seconds_and_steps
+
+
+def read(evidence):
+    job = next(
+        (j for j in evidence.get("jobs", []) if j["index"] == evidence.get("traced_job")),
+        None,
+    )
+    if job is None or not flops_banded_backbone.fit_counters(job.get("programs", [])):
+        return None
+    found = fit_seconds_and_steps(evidence)
+    if found is None:
+        return None
+    useful = flops_banded_backbone.job_useful_fit_flops(
+        evidence["config"], history_rows(evidence["traffic"]["history_days"]), job["programs"]
+    )
+    peak = evidence["device"]["peaks"]["bf16_flops_per_s"] * evidence["cell"]["chips"]
+    return 100.0 * useful / (found[0] * peak)

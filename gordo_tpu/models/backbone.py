@@ -1,9 +1,10 @@
 """
 Init and forward of :class:`~gordo_tpu.models.spec.BackboneSpec`: the
-layer kinds of the LFM2-MoE family (HF ``modeling_lfm2_moe``) and of
+layer kinds of the LFM2-MoE family (HF ``modeling_lfm2_moe``), of
 ``kind: keye_vl2`` (the ``qwen3_moe`` shape with DeepSeek-Sparse-
-Attention's indexer) as pure functions over an explicit parameter tree,
-like :mod:`.nn`.
+Attention's indexer) and of ``kind: laguna`` (window and full attention
+mixed, gated heads, a shared expert) as pure functions over an explicit
+parameter tree, like :mod:`.nn`.
 
 ``u`` is the ``[batch, T, hidden]`` sequence of a batch of windows.
 
@@ -13,8 +14,21 @@ like :mod:`.nn`.
   ``z = B * X``, ``c_t = sum_k w[:, k] * z_{t-(L-1)+k}`` (depthwise,
   causal, zeros before the window), ``y = (C * c) W_out``.
 - ``full_attention``: grouped-query causal attention with an RMSNorm
-  over each head of ``q`` and ``k`` and a rotary embedding in the
-  half-rotation layout at positions ``0..T-1``.
+  over each head of ``q`` and ``k`` (``spec.qk_norm``) and a rotary
+  embedding in the half-rotation layout at positions ``0..T-1``
+  (``spec.rope_of``: plain, or YaRN's frequencies over the leading part
+  of a head). A layer has its own number of query heads (the width of
+  its ``wq``). With ``spec.attention_gate`` each head's output is
+  multiplied by ``sigmoid(u Wg)`` before ``wo``.
+- ``sliding_attention``: the same under the mask ``s <= t and t - s <
+  sliding_window``. It, and ``full_attention`` over a window longer
+  than :data:`ATTENTION_TILE` rows, run in square tiles under a
+  running softmax (:func:`_banded_attention`): a block of queries
+  visits the tiles its mask reaches and no other (all up to the
+  diagonal, or the diagonal tile and ``ceil((sliding_window - 1) /
+  tile)`` before it), with its own derivative rule, so no score
+  outlives its tile. A shorter ``full_attention`` window holds every
+  score at once (:func:`gqa_attention`), as it always did.
 - ``sparse_attention`` (:func:`sparse_attention`): the same q, k, v,
   but query ``t`` attends to ``S_t``, the ``min(t + 1, index_topk)``
   causal keys of largest index score ``I[t, s] = sum_j w[t, j] *
@@ -36,7 +50,9 @@ like :mod:`.nn`.
   router scores all published experts (sigmoid), the ``k`` largest
   ``score + bias`` are chosen, the chosen scores, normalised, weigh
   (``router: softmax``: a softmax over all logits, its ``k`` largest
-  renormalised to sum 1, no bias).
+  renormalised to sum 1, no bias). A shared expert
+  (``spec.shared_expert_intermediate_size``) is a dense feed-forward
+  that every token takes beside them: every holder computes it whole.
   This holder keeps the (token, expert) pairs whose expert it holds,
   sorts them by expert, runs the three products as grouped products
   (``jax.lax.ragged_dot``; XLA:TPU lowers it to a tiled grouped kernel
@@ -62,11 +78,13 @@ the bytes of ``params``, :data:`REMAT_MIN_PARAM_BYTES`. A routed layer
 then still keeps :data:`SAVED_PRODUCTS`.
 """
 
+import math
 from functools import partial
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from .spec import BackboneSpec
@@ -82,6 +100,11 @@ EXPERTS_SCOPE = "moe_experts"
 INDEX_SCOPE = "sparse_index"
 SELECT_SCOPE = "sparse_select"
 SPARSE_ATTENTION_SCOPE = "sparse_attention"
+#: ... of attention in tiles under a mask by position, by operator, of
+#: the gate on its heads and of the shared expert
+TILES_SCOPES = {"full_attention": "full_attention_tiles", "sliding_attention": "sliding_attention_tiles"}
+GATE_SCOPE = "attention_gate"
+SHARED_SCOPE = "moe_shared"
 
 #: what a rematerialised routed layer keeps for its backward pass: the two
 #: grouped products that feed the gate. They are the part of a step whose
@@ -102,6 +125,13 @@ SAVED_SELECTION = "sparse_selection"
 #: the backward pass: below it a step's saved activations are small
 #: beside the chip's memory, above it they are what runs it out
 REMAT_MIN_PARAM_BYTES = 1 << 30
+
+#: rows of a square tile of attention under a mask by position
+#: (:func:`banded_attention`), and the longest window whose
+#: ``full_attention`` still holds every score at once
+#: (:func:`gqa_attention`): blocks of the computation, not of a model
+#: (any tile gives the same numbers)
+ATTENTION_TILE = 512
 
 
 def _mxu_operand_dtype(dtype):
@@ -126,10 +156,13 @@ def init_backbone(rng: jax.Array, spec: BackboneSpec) -> Dict:
     fan-in (the config states no initialiser), norm gains one, the conv
     taps uniform in +-1/sqrt(L), the expert bias a small seeded buffer."""
     h, dh = spec.hidden_size, spec.head_dim
-    kv, qo = spec.num_key_value_heads * dh, spec.num_attention_heads * dh
+    kv = spec.num_key_value_heads * dh
     # a split of another length is other keys: the kinds that were here
-    # before the indexer keep their 8 a layer, and so their weights
+    # before the indexer keep their 8 a layer, and so their weights; those
+    # before the gate and the shared expert their 8 or 12
     a_layer = 12 if "sparse_attention" in spec.layer_ops else 8
+    if spec.attention_gate or spec.shared_expert_intermediate_size:
+        a_layer = 16
     keys = iter(jax.random.split(rng, 2 + a_layer * len(spec.layer_ops)))
     ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
     params: Dict = {
@@ -140,6 +173,8 @@ def init_backbone(rng: jax.Array, spec: BackboneSpec) -> Dict:
     }
     for i, (op, ffn) in enumerate(zip(spec.layer_ops, spec.layer_ffns)):
         layer: Dict = {"operator_norm": ones(h), "ffn_norm": ones(h)}
+        heads = spec.heads_by_layer[i]
+        qo = heads * dh
         if op == "conv":
             bound = 1.0 / jnp.sqrt(float(spec.conv_L_cache))
             layer["conv"] = {
@@ -155,9 +190,11 @@ def init_backbone(rng: jax.Array, spec: BackboneSpec) -> Dict:
                 "wk": _normal(next(keys), (h, kv), h),
                 "wv": _normal(next(keys), (h, kv), h),
                 "wo": _normal(next(keys), (qo, h), qo),
-                "q_norm": ones(dh),
-                "k_norm": ones(dh),
             }
+            if spec.qk_norm:
+                layer["attn"].update(q_norm=ones(dh), k_norm=ones(dh))
+            if spec.attention_gate:
+                layer["attn"]["gate"] = _normal(next(keys), (h, heads), h)
         if op == "sparse_attention":
             heads, width = spec.index_n_heads, spec.index_head_dim
             layer["indexer"] = {
@@ -185,6 +222,13 @@ def init_backbone(rng: jax.Array, spec: BackboneSpec) -> Dict:
                 w3=_normal(next(keys), (held, h, width), h),
                 w2=_normal(next(keys), (held, width, h), width),
             )
+            if spec.shared_expert_intermediate_size:
+                width = spec.shared_expert_intermediate_size
+                layer["moe"]["shared"] = {
+                    "w1": _normal(next(keys), (h, width), h),
+                    "w3": _normal(next(keys), (h, width), h),
+                    "w2": _normal(next(keys), (width, h), width),
+                }
         params[f"layer_{i}"] = layer
     params["head"] = {
         "norm": ones(h),
@@ -231,36 +275,100 @@ def rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _heads(spec: BackboneSpec, w: Dict, u: jnp.ndarray):
+def yarn_inverse_frequencies(rope: Dict[str, Any], rotated: int) -> np.ndarray:
+    """YaRN's ``rotated // 2`` inverse frequencies (``transformers``'
+    ``_compute_yarn_parameters``): those of ``rope_theta`` as they are
+    (extrapolated) for the fast dimensions before the ramp, divided by
+    ``factor`` (interpolated) for the slow ones after it, blended
+    linearly between the two correction dimensions: those that turn
+    ``beta_fast`` and ``beta_slow`` times over
+    ``original_max_position_embeddings`` positions, rounded outwards.
+    Computed in float64 and rounded to float32 once."""
+    base, factor = float(rope["rope_theta"]), float(rope["factor"])
+    positions = float(rope["original_max_position_embeddings"])
+
+    def correction(rotations):
+        return rotated * math.log(positions / (rotations * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(correction(rope.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction(rope.get("beta_slow", 1))), rotated - 1)
+    high = high + 0.001 if high == low else high
+    plain = 1.0 / base ** (np.arange(0, rotated, 2, dtype=np.float64) / rotated)
+    ramp = np.clip((np.arange(rotated // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    return ((plain / factor) * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def scaled_rotary(x: jnp.ndarray, rope: Dict[str, Any]) -> jnp.ndarray:
+    """:func:`rotary` of the leading ``partial_rotary_factor`` of each
+    head of ``x [B, T, heads, d]``, the rest as it is; under ``rope_type:
+    yarn`` at :func:`yarn_inverse_frequencies`, ``cos`` and ``sin``
+    multiplied by ``attention_factor`` (so the rotated part of ``q`` and
+    of ``k`` alone is scaled)."""
+    rotated = int(x.shape[-1] * rope["partial_rotary_factor"])
+    half = rotated // 2
+    if rope["rope_type"] == "yarn":
+        inv_freq = jnp.asarray(yarn_inverse_frequencies(rope, rotated))
+        factor = float(rope["attention_factor"])
+    else:
+        inv_freq = 1.0 / (rope["rope_theta"] ** (jnp.arange(half, dtype=jnp.float32) / half))
+        factor = 1.0
+    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = (jnp.cos(angles) * factor)[None, :, None, :].astype(x.dtype)
+    sin = (jnp.sin(angles) * factor)[None, :, None, :].astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:rotated]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., rotated:]], axis=-1)
+
+
+def _heads(spec: BackboneSpec, w: Dict, u: jnp.ndarray, op: str = "full_attention"):
     """``u [B, T, hidden]`` -> ``(q [B, T, heads, dh], k, v [B, T,
-    kv_heads, dh])``: the projections, the per-head RMSNorm of ``q`` and
-    ``k`` and their rotary embedding, as both attentions take them."""
+    kv_heads, dh])``: the projections (``heads`` is the layer's own: the
+    width of its ``wq``), the per-head RMSNorm of ``q`` and ``k`` where
+    the spec has one, and the rotary embedding of operator ``op``, as
+    every attention takes them."""
     dtype = u.dtype
     batch, length, _ = u.shape
-    heads, kv_heads, dh = spec.num_attention_heads, spec.num_key_value_heads, spec.head_dim
-    q = (u @ w["wq"].astype(dtype)).reshape(batch, length, heads, dh)
+    kv_heads, dh = spec.num_key_value_heads, spec.head_dim
+    q = (u @ w["wq"].astype(dtype)).reshape(batch, length, -1, dh)
     k = (u @ w["wk"].astype(dtype)).reshape(batch, length, kv_heads, dh)
     v = (u @ w["wv"].astype(dtype)).reshape(batch, length, kv_heads, dh)
-    q = rotary(rms_norm(q, w["q_norm"], spec.norm_eps), spec.rope_theta)
-    k = rotary(rms_norm(k, w["k_norm"], spec.norm_eps), spec.rope_theta)
-    return q, k, v
+    rope = spec.rope_of(op)
+    plain = rope["rope_type"] == "default" and rope["partial_rotary_factor"] == 1
+
+    def placed(x, gain):
+        if spec.qk_norm:
+            x = rms_norm(x, w[gain], spec.norm_eps)
+        return rotary(x, rope["rope_theta"]) if plain else scaled_rotary(x, rope)
+
+    return placed(q, "q_norm"), placed(k, "k_norm"), v
+
+
+def _gated(spec: BackboneSpec, w: Dict, u: jnp.ndarray, out: jnp.ndarray) -> jnp.ndarray:
+    """``out [B, T, ..., dh]``, the heads' outputs of the attention over
+    ``u``: each head times ``sigmoid(u Wg)`` where the spec gates its
+    heads, then ``wo``."""
+    dtype = u.dtype
+    batch, length, _ = u.shape
+    if spec.attention_gate:
+        with jax.named_scope(GATE_SCOPE):
+            gate = jax.nn.sigmoid(u @ w["gate"].astype(dtype))
+            out = out.reshape(batch, length, -1, out.shape[-1]) * gate[..., None]
+    return out.reshape(batch, length, -1) @ w["wo"].astype(dtype)
 
 
 def gqa_attention(spec: BackboneSpec, w: Dict, u: jnp.ndarray) -> jnp.ndarray:
+    """``full_attention`` with every score of a window held at once."""
     dtype = u.dtype
     batch, length, _ = u.shape
-    heads, kv_heads, dh = spec.num_attention_heads, spec.num_key_value_heads, spec.head_dim
-    group = heads // kv_heads
+    kv_heads, dh = spec.num_key_value_heads, spec.head_dim
     with jax.named_scope(ATTENTION_SCOPE):
         q, k, v = _heads(spec, w, u)
         # each key/value head serves `group` query heads: no repeat of k, v
-        q = q.reshape(batch, length, kv_heads, group, dh)
+        q = q.reshape(batch, length, kv_heads, -1, dh)
         scores = jnp.einsum("bqngd,bknd->bngqk", q, k) * (1.0 / jnp.sqrt(float(dh))).astype(dtype)
         causal = jnp.tril(jnp.ones((length, length), bool))
         scores = jnp.where(causal, scores.astype(jnp.float32), -jnp.inf)
         weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
-        out = jnp.einsum("bngqk,bknd->bqngd", weights, v)
-        return out.reshape(batch, length, heads * dh) @ w["wo"].astype(dtype)
+        return _gated(spec, w, u, jnp.einsum("bngqk,bknd->bqngd", weights, v))
 
 
 def layer_norm(x, gain, bias, eps):
@@ -321,6 +429,29 @@ def _unpack_bits(packed: jnp.ndarray, chunk: int) -> jnp.ndarray:
 
 def _tile(a: jnp.ndarray, j, axis: int = 0) -> jnp.ndarray:
     return jax.lax.dynamic_index_in_dim(a, j, axis, keepdims=False)
+
+
+def _add_tile(total: jnp.ndarray, j, tile: jnp.ndarray) -> jnp.ndarray:
+    """``total`` with ``tile`` added to its tile ``j`` (a backward pass's
+    running ``d_k``, ``d_v``)."""
+    return jax.lax.dynamic_update_index_in_dim(total, _tile(total, j) + tile, j, 0)
+
+
+def _tile_gradients(j, q_i, k_j, v_j, d_out_i, weights, delta, scale, d_q, d_k, d_v):
+    """Tile ``j`` of keys' part of a block of queries' gradients, from
+    the tile's recomputed softmax ``weights [n, g, q, k]`` and ``delta``,
+    the block's sum over the keys of weights x their cotangent: the
+    running ``(d_q, d_k, d_v)`` with the tile's terms added. The one
+    body of both tiled attentions' backward loops."""
+    dtype = q_i.dtype
+    d_v = _add_tile(d_v, j, jnp.einsum(
+        "ngqk,qngd->knd", weights.astype(dtype), d_out_i
+    ).astype(jnp.float32))
+    d_weights = jnp.einsum("qngd,knd->ngqk", d_out_i, v_j).astype(jnp.float32)
+    d_scores = (weights * (d_weights - delta[..., None])).astype(dtype) * scale.astype(dtype)
+    d_q = d_q + jnp.einsum("ngqk,knd->qngd", d_scores, k_j).astype(jnp.float32)
+    d_k = _add_tile(d_k, j, jnp.einsum("ngqk,qngd->knd", d_scores, q_i).astype(jnp.float32))
+    return d_q, d_k, d_v
 
 
 def select_keys(spec: BackboneSpec, qi, ki, wi) -> jnp.ndarray:
@@ -471,9 +602,6 @@ def _selected_attention_bwd(length, kept, cotangents):
     blocks, chunk, kv_heads, group, dh = q.shape
     scale = 1.0 / jnp.sqrt(jnp.float32(dh))
 
-    def add_tile(total, j, tile):
-        return jax.lax.dynamic_update_index_in_dim(total, _tile(total, j) + tile, j, 0)
-
     def block_of(carry, i):
         q_i, qi_i, wi_i, selected_i = _tile(q, i), _tile(qi, i), _tile(wi, i), _tile(selected, i)
         d_out_i = _tile(d_out, i)
@@ -490,13 +618,7 @@ def _selected_attention_bwd(length, kept, cotangents):
             k_j, v_j, ki_j = _tile(k, j), _tile(v, j), _tile(ki, j)
             with jax.named_scope(SPARSE_ATTENTION_SCOPE):
                 weights = jnp.exp(_tile_scores(q_i, k_j, keep, scale) - log_z_b[..., None])
-                d_v = add_tile(d_v, j, jnp.einsum(
-                    "ngqk,qngd->knd", weights.astype(q.dtype), d_out_i
-                ).astype(jnp.float32))
-                d_weights = jnp.einsum("qngd,knd->ngqk", d_out_i, v_j).astype(jnp.float32)
-                d_scores = (weights * (d_weights - delta[..., None])).astype(q.dtype) * scale.astype(q.dtype)
-                d_q = d_q + jnp.einsum("ngqk,knd->qngd", d_scores, k_j).astype(jnp.float32)
-                d_k = add_tile(d_k, j, jnp.einsum("ngqk,qngd->knd", d_scores, q_i).astype(jnp.float32))
+                d_q, d_k, d_v = _tile_gradients(j, q_i, k_j, v_j, d_out_i, weights, delta, scale, d_q, d_k, d_v)
             with jax.named_scope(INDEX_SCOPE):
                 # d KL / d I[t, s] = r[t, s] sum_s' p[t, s'] - p[t, s]
                 counted = keep & valid
@@ -504,7 +626,7 @@ def _selected_attention_bwd(length, kept, cotangents):
                 index, back = jax.vjp(index_scores, qi_i, ki_j, wi_i)
                 r = jnp.where(counted, jnp.exp(jnp.where(counted, index, -jnp.inf) - log_z_i_b[:, None]), 0.0)
                 add_qi, add_ki, add_wi = back(d_kl * (r * p_sum_b[:, None] - p))
-            return d_q, d_qi + add_qi, d_wi + add_wi, d_k, d_v, add_tile(d_ki, j, add_ki)
+            return d_q, d_qi + add_qi, d_wi + add_wi, d_k, d_v, _add_tile(d_ki, j, add_ki)
 
         d_k, d_v, d_ki = carry
         d_q, d_qi, d_wi, d_k, d_v, d_ki = jax.lax.fori_loop(
@@ -533,10 +655,11 @@ def sparse_attention(
     8,192 rows fit an int32, an epoch's do not."""
     dtype = u.dtype
     batch, length, _ = u.shape
-    heads, kv_heads, dh = spec.num_attention_heads, spec.num_key_value_heads, spec.head_dim
+    kv_heads, dh = spec.num_key_value_heads, spec.head_dim
+    heads = w["wq"].shape[1] // dh
     blocked = jax.vmap(lambda a: _blocked(a, spec.index_chunk))
     with jax.named_scope(SPARSE_ATTENTION_SCOPE):
-        q, k, v = _heads(spec, w, u)
+        q, k, v = _heads(spec, w, u, "sparse_attention")
         q = q.reshape(batch, length, kv_heads, heads // kv_heads, dh)
         q, k, v = blocked(q), blocked(k), blocked(v)
     with jax.named_scope(INDEX_SCOPE):
@@ -564,6 +687,143 @@ def sparse_attention(
         jnp.sum(counted) * (length * (length + 1) / 2.0),
     )
     return out, objective, counts
+
+
+def _band_tiles(chunk: int, window: int, i):
+    """``(first, stop)``: block ``i`` of queries visits the tiles of keys
+    ``first .. stop - 1``, up to its diagonal one from the tile that its
+    earliest query ``i * chunk`` sees back to, row ``i * chunk - (window
+    - 1)``. The bounds of the forward's and the backward's loops, and
+    what ``pairs_multiplied`` counts."""
+    back = -(-(window - 1) // chunk)
+    return jnp.maximum(i - back, 0), i + 1
+
+
+def _band_mask(chunk: int, window: int, i, j) -> jnp.ndarray:
+    """Tile ``(i, j)`` of the mask by position: ``s <= t and t - s < window``."""
+    t = (i * chunk + jnp.arange(chunk))[:, None]
+    s = (j * chunk + jnp.arange(chunk))[None, :]
+    return (s <= t) & (t - s < window)
+
+
+def band_pairs(length: int, window: int, chunk: int):
+    """Of one window of ``length`` rows: the (query, key) pairs inside
+    the mask, ``sum over t of min(t + 1, window)``, and the pairs of the
+    tiles :func:`_banded_attention` visits for them (float32: the loops'
+    own bounds, summed over the blocks)."""
+    reach = min(length, window)
+    attended = reach * (reach + 1) / 2.0 + (length - reach) * reach
+    first, stop = _band_tiles(chunk, window, jnp.arange(-(-length // chunk)))
+    return attended, jnp.sum(stop - first).astype(jnp.float32) * (chunk * chunk)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _banded_attention(scope: str, window: int, q, k, v):
+    """One window's causal attention limited to the ``window`` rows up
+    to the query (``window`` at least the length: every causal key),
+    blocked inputs (:func:`_blocked`: ``q [blocks, chunk, n, g, dh]``,
+    ``k``, ``v [blocks, chunk, n, dh]``) -> ``out`` as ``q``. A block of
+    queries at a time against the tiles of keys that
+    :func:`_band_tiles` names under a running softmax, in
+    loops of one body whatever the length and the mask. Rows of padding
+    after the window's own lie after every key they could hide: they
+    are computed, and mean nothing. Its own derivative rule, as
+    :func:`_selected_attention`'s: the backward pass recomputes a
+    tile's weights from the normalisers kept."""
+    return _banded_attention_fwd(scope, window, q, k, v)[0]
+
+
+def _banded_attention_fwd(scope, window, q, k, v):
+    blocks, chunk, kv_heads, group, dh = q.shape
+    scale = 1.0 / jnp.sqrt(jnp.float32(dh))
+
+    def block_of(i):
+        q_i = _tile(q, i)
+
+        def attend(j, carry):
+            top, total, acc = carry
+            with jax.named_scope(scope):
+                scores = _tile_scores(q_i, _tile(k, j), _band_mask(chunk, window, i, j), scale)
+                top, total, weights, rescale = _log_sum_exp_step(top, total, scores)
+                acc = acc * rescale[..., None] + jnp.einsum(
+                    "ngqk,knd->ngqd", weights.astype(q.dtype), _tile(v, j)
+                ).astype(jnp.float32)
+            return top, total, acc
+
+        rows = (kv_heads, group, chunk)
+        top, total, acc = jax.lax.fori_loop(
+            *_band_tiles(chunk, window, i), attend,
+            (jnp.full(rows, -jnp.inf, jnp.float32), jnp.zeros(rows, jnp.float32),
+             jnp.zeros(rows + (dh,), jnp.float32)),
+        )
+        # every query sees itself: no row's total is 0
+        out = jnp.transpose(acc / total[..., None], (2, 0, 1, 3)).astype(q.dtype)
+        return out, top + jnp.log(total)
+
+    out, log_z = jax.lax.map(block_of, jnp.arange(blocks))
+    return out, (q, k, v, out, log_z)
+
+
+def _banded_attention_bwd(scope, window, kept, d_out):
+    q, k, v, out, log_z = kept
+    blocks, chunk, kv_heads, group, dh = q.shape
+    scale = 1.0 / jnp.sqrt(jnp.float32(dh))
+
+    def block_of(carry, i):
+        q_i, d_out_i, log_z_i = _tile(q, i), _tile(d_out, i), _tile(log_z, i)
+        # sum over the keys of weights x their cotangent, from the output
+        delta = jnp.einsum(
+            "qngd,qngd->ngq", d_out_i.astype(jnp.float32), _tile(out, i).astype(jnp.float32)
+        )
+
+        def tile_of(j, carry):
+            d_q, d_k, d_v = carry
+            k_j, v_j = _tile(k, j), _tile(v, j)
+            with jax.named_scope(scope):
+                scores = _tile_scores(q_i, k_j, _band_mask(chunk, window, i, j), scale)
+                weights = jnp.exp(scores - log_z_i[..., None])
+                d_q, d_k, d_v = _tile_gradients(j, q_i, k_j, v_j, d_out_i, weights, delta, scale, d_q, d_k, d_v)
+            return d_q, d_k, d_v
+
+        d_q, d_k, d_v = jax.lax.fori_loop(
+            *_band_tiles(chunk, window, i), tile_of, (jnp.zeros(q_i.shape, jnp.float32),) + carry
+        )
+        return (d_k, d_v), d_q
+
+    zeros = (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32))
+    (d_k, d_v), d_q = jax.lax.scan(block_of, zeros, jnp.arange(blocks))
+    return d_q.astype(q.dtype), d_k.astype(k.dtype), d_v.astype(v.dtype)
+
+
+_banded_attention.defvjp(_banded_attention_fwd, _banded_attention_bwd)
+
+
+def banded_attention(
+    spec: BackboneSpec, op: str, w: Dict, u: jnp.ndarray, active: Optional[jnp.ndarray] = None
+):
+    """``full_attention`` or ``sliding_attention`` over ``u [B, T,
+    hidden]`` in tiles of :data:`ATTENTION_TILE` rows (module docstring):
+    ``(output, counts)``, ``counts`` float32 ``(pairs_attended,
+    pairs_multiplied)`` of the windows that count (``active``; None:
+    all): the pairs inside the mask and the pairs of the tiles visited
+    (:func:`band_pairs`: the mask is by position, the tiles are the
+    loops' bounds)."""
+    batch, length, _ = u.shape
+    kv_heads, dh = spec.num_key_value_heads, spec.head_dim
+    chunk = min(ATTENTION_TILE, length)
+    window = min(spec.sliding_window, length) if op == "sliding_attention" else length
+    scope = TILES_SCOPES[op]
+    blocked = jax.vmap(lambda a: _blocked(a, chunk))
+    with jax.named_scope(scope):
+        q, k, v = _heads(spec, w, u, op)
+        q = q.reshape(batch, length, kv_heads, -1, dh)
+        q, k, v = blocked(q), blocked(k), blocked(v)
+    out = jax.lax.map(lambda one: _banded_attention(scope, window, *one), (q, k, v))
+    with jax.named_scope(scope):
+        out = _gated(spec, w, u, out.reshape((batch, -1) + out.shape[3:])[:, :length])
+    windows = jnp.float32(batch) if active is None else jnp.sum(active.astype(jnp.float32))
+    attended, multiplied = band_pairs(length, window, chunk)
+    return out, (windows * attended, windows * multiplied)
 
 
 def dense_ffn(w: Dict, u: jnp.ndarray) -> jnp.ndarray:
@@ -649,25 +909,32 @@ def moe_ffn(
 
 
 def block(spec: BackboneSpec, op: str, ffn: str, w: Dict, h: jnp.ndarray, active=None):
-    """One pre-norm residual block; returns ``(h, counts, selection)``
-    with ``counts = (routed, pairs_here)`` of a routed block and
+    """One pre-norm residual block; returns ``(h, counts, selection,
+    band)`` with ``counts = (routed, pairs_here)`` of a routed block,
     ``selection = (objective, (keys_selected, keys_causal))`` of a
-    ``sparse_attention`` block, else None. ``active``: as
-    :func:`moe_ffn`."""
+    ``sparse_attention`` block and ``band = (pairs_attended,
+    pairs_multiplied)`` of an attention computed in tiles under a mask
+    by position, else None. ``active``: as :func:`moe_ffn`."""
     normed = rms_norm(h, w["operator_norm"], spec.norm_eps)
-    selection = None
+    selection = band = None
     if op == "conv":
         h = h + short_conv(spec, w["conv"], normed)
     elif op == "sparse_attention":
         out, objective, keys = sparse_attention(spec, w["attn"], w["indexer"], normed, active)
         h, selection = h + out, (objective, keys)
-    else:
+    elif op == "full_attention" and h.shape[1] <= ATTENTION_TILE:
         h = h + gqa_attention(spec, w["attn"], normed)
+    else:
+        out, band = banded_attention(spec, op, w["attn"], normed, active)
+        h = h + out
     normed = rms_norm(h, w["ffn_norm"], spec.norm_eps)
     if ffn == "dense":
-        return h + dense_ffn(w["ffn"], normed), None, selection
+        return h + dense_ffn(w["ffn"], normed), None, selection, band
     out, routed, pairs_here = moe_ffn(spec, w["moe"], normed, active)
-    return h + out, (routed, pairs_here), selection
+    if "shared" in w["moe"]:
+        with jax.named_scope(SHARED_SCOPE):
+            out = out + dense_ffn(w["moe"]["shared"], normed)
+    return h + out, (routed, pairs_here), selection, band
 
 
 def _param_bytes(params: Dict) -> int:
@@ -689,7 +956,10 @@ def forward_backbone_aux(
     is held here) and ``pairs_total [layers]`` (tokens x k); and, a row
     per ``sparse_attention`` layer, float32 ``keys_selected`` and
     ``keys_causal`` (query-key pairs kept and possible) and
-    ``indexer_kl`` (the layer's term of the indexer's objective).
+    ``indexer_kl`` (the layer's term of the indexer's objective); and,
+    a row per attention layer computed in tiles under a mask by
+    position, float32 ``pairs_attended`` and ``pairs_multiplied``
+    (query-key pairs inside the mask and of the tiles visited).
     ``penalty`` is the sum of those terms, 0 without such a layer.
 
     ``remat``: rematerialise each block in the backward pass; None
@@ -703,7 +973,7 @@ def forward_backbone_aux(
     if remat is None:
         remat = _param_bytes(params) >= REMAT_MIN_PARAM_BYTES
     h = x.astype(dtype) @ params["embed"]["W"].astype(dtype) + params["embed"]["b"].astype(dtype)
-    routed_rows, pairs_rows, selections = [], [], []
+    routed_rows, pairs_rows, selections, bands = [], [], [], []
     for i, (op, ffn) in enumerate(zip(spec.layer_ops, spec.layer_ffns)):
         run = lambda w, h, a, _op=op, _ffn=ffn: block(spec, _op, _ffn, w, h, a)  # noqa: E731
         if remat:
@@ -712,12 +982,14 @@ def forward_backbone_aux(
                 run, policy=jax.checkpoint_policies.save_only_these_names(*saved)
             )
         with jax.named_scope(f"layer_{i}"):  # one scope a layer, as in params
-            h, counts, selection = run(params[f"layer_{i}"], h, active)
+            h, counts, selection, band = run(params[f"layer_{i}"], h, active)
         if counts is not None:
             routed_rows.append(counts[0])
             pairs_rows.append(counts[1])
         if selection is not None:
             selections.append(selection)
+        if band is not None:
+            bands.append(band)
     last = rms_norm(h[:, -1], params["head"]["norm"], spec.norm_eps)
     out = last @ params["head"]["W"].astype(dtype) + params["head"]["b"].astype(dtype)
     aux = None
@@ -739,6 +1011,12 @@ def forward_backbone_aux(
             "keys_selected": jnp.stack([keys[0] for _, keys in selections]),
             "keys_causal": jnp.stack([keys[1] for _, keys in selections]),
             "indexer_kl": objectives,
+        }
+    if bands:
+        aux = {
+            **(aux or {}),
+            "pairs_attended": jnp.stack([attended for attended, _ in bands]),
+            "pairs_multiplied": jnp.stack([multiplied for _, multiplied in bands]),
         }
     return out.astype(jnp.float32), penalty, aux
 

@@ -201,3 +201,140 @@ def keye_vl2(
         compute_dtype=compute_dtype,
         precision=precision,
     )
+
+
+#: poolside/Laguna-XS.2 config.json (``model_type: laguna``), every key of
+#: the catalog's row. The factory's defaults are these; the keys it does
+#: not take say nothing it can act on (the vocabulary is replaced by the
+#: sensor projections, positions are a window's) or name what it refuses
+#: to be told otherwise.
+LAGUNA_XS2_CONFIG: Dict[str, Any] = {
+    "model_type": "laguna",
+    "vocab_size": 100352,
+    "hidden_size": 2048,
+    "intermediate_size": 8192,
+    "num_hidden_layers": 40,
+    "num_attention_heads": 48,
+    "num_key_value_heads": 8,
+    "head_dim": 128,
+    "max_position_embeddings": 262144,
+    "attention_bias": False,
+    "rms_norm_eps": 1e-06,
+    "num_experts": 256,
+    "num_experts_per_tok": 8,
+    "moe_intermediate_size": 512,
+    "shared_expert_intermediate_size": 512,
+    "tie_word_embeddings": False,
+    "gating": True,
+    "sliding_window": 512,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_theta": 500000,
+            "rope_type": "yarn",
+            "factor": 64,
+            "original_max_position_embeddings": 4096,
+            "beta_slow": 1,
+            "beta_fast": 64,
+            "attention_factor": 1.4158883083359672,
+            "partial_rotary_factor": 0.5,
+        },
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000, "partial_rotary_factor": 1},
+        "original_max_position_embeddings": 4096,
+    },
+    "layer_types": ["full_attention", "sliding_attention", "sliding_attention", "sliding_attention"] * 10,
+    "moe_apply_router_weight_on_input": False,
+    "partial_rotary_factor": 0.5,
+    "mlp_layer_types": ["dense"] + ["sparse"] * 39,
+    "moe_routed_scaling_factor": 2.5,
+    "num_attention_heads_per_layer": [48, 64, 64, 64] * 10,
+}
+_LAGUNA = LAGUNA_XS2_CONFIG
+#: what the layers here cannot be told otherwise: no bias, gated heads,
+#: the router's weights on the experts' outputs
+_LAGUNA_FIXED = ("attention_bias", "gating", "moe_apply_router_weight_on_input")
+
+
+@register_model_builder(type="JaxBackboneForecast")
+def laguna(
+    n_features: int,
+    n_features_out: Optional[int] = None,
+    lookback_window: int = 8192,
+    num_hidden_layers: int = _LAGUNA["num_hidden_layers"],
+    layer_types: Sequence[str] = tuple(_LAGUNA["layer_types"]),
+    mlp_layer_types: Sequence[str] = tuple(_LAGUNA["mlp_layer_types"]),
+    num_attention_heads_per_layer: Sequence[int] = tuple(_LAGUNA["num_attention_heads_per_layer"]),
+    hidden_size: int = _LAGUNA["hidden_size"],
+    head_dim: int = _LAGUNA["head_dim"],
+    num_key_value_heads: int = _LAGUNA["num_key_value_heads"],
+    intermediate_size: int = _LAGUNA["intermediate_size"],
+    moe_intermediate_size: int = _LAGUNA["moe_intermediate_size"],
+    shared_expert_intermediate_size: int = _LAGUNA["shared_expert_intermediate_size"],
+    num_experts: int = _LAGUNA["num_experts"],
+    experts_held: Optional[int] = None,
+    expert_offset: int = 0,
+    num_experts_per_tok: int = _LAGUNA["num_experts_per_tok"],
+    moe_routed_scaling_factor: float = _LAGUNA["moe_routed_scaling_factor"],
+    sliding_window: int = _LAGUNA["sliding_window"],
+    rope_parameters: Optional[Dict[str, Any]] = None,
+    rms_norm_eps: float = _LAGUNA["rms_norm_eps"],
+    optimizer: Union[str, OptimizerSpec] = "Adam",
+    optimizer_kwargs: Optional[Dict[str, Any]] = None,
+    compile_kwargs: Optional[Dict[str, Any]] = None,
+    compute_dtype: str = "float32",
+    precision: str = "",
+    **kwargs,
+) -> BackboneSpec:
+    """``model_type: laguna`` (defaults: Laguna-XS.2). The first
+    ``num_hidden_layers`` entries of ``layer_types`` (``full_attention``
+    or ``sliding_attention``: a query sees the ``sliding_window`` rows up
+    to itself), of ``mlp_layer_types`` (``dense`` or ``sparse``: the
+    routed experts under a sigmoid router scaled by
+    ``moe_routed_scaling_factor``, of which this holder keeps
+    ``experts_held`` (default: all) from ``expert_offset``, beside a
+    shared expert) and of ``num_attention_heads_per_layer`` are the
+    layers held; every head is gated; ``rope_parameters`` gives each
+    layer type its rotary embedding (keys left out keep their published
+    values)."""
+    for key in _LAGUNA_FIXED:
+        if key in kwargs and kwargs[key] != _LAGUNA[key]:
+            raise ValueError(f"laguna runs {key}={_LAGUNA[key]!r} only; got {kwargs[key]!r}")
+    if min(len(layer_types), len(mlp_layer_types), len(num_attention_heads_per_layer)) < num_hidden_layers:
+        raise ValueError("laguna needs a layer type, an mlp type and a head count for every layer held")
+    unknown = set(mlp_layer_types) - {"dense", "sparse"}
+    if unknown:
+        raise ValueError(f"unknown mlp_layer_types {sorted(unknown)}")
+    ropes = {
+        op: {**_LAGUNA["rope_parameters"][op], **(rope_parameters or {}).get(op, {})}
+        for op in ("full_attention", "sliding_attention")
+    }
+    compile_kwargs = compile_kwargs or {}
+    return BackboneSpec(
+        n_features=n_features,
+        n_features_out=n_features_out or n_features,
+        lookback_window=lookback_window,
+        layer_ops=tuple(layer_types[:num_hidden_layers]),
+        layer_ffns=tuple("dense" if kind == "dense" else "moe" for kind in mlp_layer_types[:num_hidden_layers]),
+        layer_heads=tuple(int(heads) for heads in num_attention_heads_per_layer[:num_hidden_layers]),
+        hidden_size=hidden_size,
+        attention_head_dim=head_dim,
+        num_attention_heads=int(num_attention_heads_per_layer[0]),
+        num_key_value_heads=num_key_value_heads,
+        intermediate_size=intermediate_size,
+        moe_intermediate_size=moe_intermediate_size,
+        shared_expert_intermediate_size=shared_expert_intermediate_size,
+        num_experts=num_experts,
+        experts_held=num_experts if experts_held is None else experts_held,
+        expert_offset=expert_offset,
+        num_experts_per_tok=num_experts_per_tok,
+        routed_scaling_factor=float(moe_routed_scaling_factor),
+        rope_theta=float(ropes["sliding_attention"]["rope_theta"]),
+        rope_parameters=tuple((op, tuple(sorted(rope.items()))) for op, rope in sorted(ropes.items())),
+        qk_norm=False,
+        attention_gate=True,
+        sliding_window=sliding_window,
+        norm_eps=float(rms_norm_eps),
+        optimizer=OptimizerSpec.from_config(optimizer, optimizer_kwargs),
+        loss=compile_kwargs.get("loss", "mse"),
+        compute_dtype=compute_dtype,
+        precision=precision,
+    )

@@ -275,7 +275,9 @@ class LSTMSpec(ModelSpec):
 
 
 #: layer kinds of a :class:`BackboneSpec`
-OPERATORS = ("conv", "full_attention", "sparse_attention")
+OPERATORS = ("conv", "full_attention", "sparse_attention", "sliding_attention")
+#: the operators that project to query, key and value heads
+ATTENTIONS = tuple(op for op in OPERATORS if op != "conv")
 FFNS = ("dense", "moe")
 #: how a routed layer scores its experts: ``sigmoid_bias`` (sigmoid
 #: scores, the ``k`` largest ``score + bias`` chosen, a bias buffer) or
@@ -308,6 +310,19 @@ class BackboneSpec(ModelSpec):
     to its ``index_topk`` best, computed in square tiles of
     ``index_chunk`` queries by ``index_chunk`` keys. The indexer
     learns from its own objective, the ``penalty`` of the forward.
+
+    ``sliding_attention`` is grouped-query attention limited by
+    distance: a query sees itself and the ``sliding_window - 1`` rows
+    before it. It and a ``full_attention`` layer over a window longer
+    than a tile (``backbone.ATTENTION_TILE`` rows) are computed in
+    square tiles, a block of queries against the tiles its mask
+    reaches. ``layer_heads`` gives each layer its own number of
+    query heads (empty: ``num_attention_heads`` in every layer),
+    ``rope_parameters`` each operator its own rotary embedding (an
+    operator it does not name: ``rope_theta`` over the whole head),
+    ``attention_gate`` a sigmoid gate on each head's output,
+    ``shared_expert_intermediate_size`` an expert that every token
+    takes beside the routed ones (0: none).
     """
 
     n_features: int
@@ -340,6 +355,14 @@ class BackboneSpec(ModelSpec):
     index_head_dim: int = 64
     index_topk: int = 2048
     index_chunk: int = 512
+    layer_heads: Tuple[int, ...] = ()
+    #: ``((operator, ((key, value), ...)), ...)``: HF ``rope_parameters``
+    #: by layer type, as plain sorted pairs (:meth:`rope_of` reads them)
+    rope_parameters: Tuple[Tuple[str, Tuple[Tuple[str, Any], ...]], ...] = ()
+    qk_norm: bool = True
+    attention_gate: bool = False
+    sliding_window: int = 0
+    shared_expert_intermediate_size: int = 0
 
     windowed = True
 
@@ -361,8 +384,21 @@ class BackboneSpec(ModelSpec):
             or self.index_head_dim % 2
         ):
             raise ValueError("the indexer needs heads, an even head width, a top-k and blocks")
-        if self.num_attention_heads % self.num_key_value_heads:
+        if self.layer_heads and len(self.layer_heads) != len(self.layer_ops):
+            raise ValueError("layer_heads needs one entry a layer")
+        if any(heads % self.num_key_value_heads for heads in self.heads_by_layer):
             raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+        if "sliding_attention" in self.layer_ops and self.sliding_window < 1:
+            raise ValueError("sliding_attention needs a sliding_window of at least one row")
+        if self.attention_gate and "sparse_attention" in self.layer_ops:
+            raise ValueError("sparse_attention has no gate on its heads")
+        for op, _ in self.rope_parameters:
+            rope = self.rope_of(op)
+            rotated = int(self.head_dim * rope["partial_rotary_factor"])
+            if op not in ATTENTIONS or rope["rope_type"] not in ("default", "yarn"):
+                raise ValueError(f"rope_parameters of {op!r}: unknown operator or rope_type")
+            if rotated < 2 or rotated % 2:
+                raise ValueError("the rotary embedding needs an even number of rotated dimensions")
         if not (
             0 < self.experts_held
             and 0 <= self.expert_offset
@@ -378,6 +414,23 @@ class BackboneSpec(ModelSpec):
     @property
     def head_dim(self) -> int:
         return self.attention_head_dim or self.hidden_size // self.num_attention_heads
+
+    @property
+    def heads_by_layer(self) -> Tuple[int, ...]:
+        """Query heads of each layer (what a ``conv`` layer's says is unused)."""
+        return self.layer_heads or (self.num_attention_heads,) * len(self.layer_ops)
+
+    def rope_of(self, op: str) -> Dict[str, Any]:
+        """The rotary embedding of operator ``op``, HF's keys:
+        ``rope_theta``, ``partial_rotary_factor`` (the leading share of a
+        head that is rotated), ``rope_type`` (``default`` or ``yarn``)
+        and, for YaRN, ``factor``, ``original_max_position_embeddings``,
+        ``beta_fast``, ``beta_slow``, ``attention_factor``."""
+        stated = dict(dict(self.rope_parameters).get(op, ()))
+        return {
+            "rope_theta": self.rope_theta, "partial_rotary_factor": 1.0, "rope_type": "default",
+            **stated,
+        }
 
     @property
     def indexer_param_count(self) -> int:
@@ -421,27 +474,31 @@ class BackboneSpec(ModelSpec):
             attrs["index_topk"] = self.index_topk
         return attrs
 
-    def layer_param_count(self, op: str, ffn: str) -> int:
-        """One block: two norms, its operator, its feed-forward (the
-        expert bias is a buffer, not a parameter)."""
+    def layer_param_count(self, op: str, ffn: str, heads: Optional[int] = None) -> int:
+        """One block of ``heads`` query heads (None: ``num_attention_heads``):
+        two norms, its operator, its feed-forward (the expert bias is a
+        buffer, not a parameter)."""
         h = self.hidden_size
+        heads = self.num_attention_heads if heads is None else heads
         kv = self.num_key_value_heads * self.head_dim
         total = 2 * h
         if op == "conv":
             total += h * 3 * h + h * self.conv_L_cache + h * h
         else:
-            total += 2 * h * self.num_attention_heads * self.head_dim + 2 * h * kv + 2 * self.head_dim
+            total += 2 * h * heads * self.head_dim + 2 * h * kv
+            total += 2 * self.head_dim * self.qk_norm + h * heads * self.attention_gate
         if op == "sparse_attention":
             total += self.indexer_param_count
         if ffn == "dense":
             return total + 3 * h * self.intermediate_size
-        return total + h * self.num_experts + self.experts_held * 3 * h * self.moe_intermediate_size
+        shared = 3 * h * self.shared_expert_intermediate_size
+        return total + h * self.num_experts + self.experts_held * 3 * h * self.moe_intermediate_size + shared
 
     def param_count(self) -> int:
         h = self.hidden_size
         layers = sum(
-            self.layer_param_count(op, ffn)
-            for op, ffn in zip(self.layer_ops, self.layer_ffns)
+            self.layer_param_count(op, ffn, heads)
+            for op, ffn, heads in zip(self.layer_ops, self.layer_ffns, self.heads_by_layer)
         )
         embed = self.n_features * h + h
         head = h + h * self.n_features_out + self.n_features_out
@@ -449,32 +506,40 @@ class BackboneSpec(ModelSpec):
 
     def flops_per_sample(self) -> float:
         """One window of ``lookback_window`` tokens: products only,
-        causal attention at its useful half (sparse attention at the
-        keys a query keeps, its indexer over every causal key), the
-        expert layer at the pairs this holder expects under even routing."""
+        causal attention at its useful half (sliding attention at the
+        keys inside its window, sparse attention at the keys a query
+        keeps, its indexer over every causal key), the expert layer at
+        the pairs this holder expects under even routing."""
         h, t = self.hidden_size, self.lookback_window
         kv = self.num_key_value_heads * self.head_dim
-        qo = self.num_attention_heads * self.head_dim
         index = self.index_n_heads * self.index_head_dim
         # keys a query keeps, on average over a window: min(t + 1, top-k)
         kept = min(t, self.index_topk)
         kept_mean = (kept * (kept + 1) / 2.0 + (t - kept) * self.index_topk) / t
         per_token = 2.0 * self.n_features * h
         local_pairs = self.num_experts_per_tok * self.experts_held / self.num_experts
-        for op, ffn in zip(self.layer_ops, self.layer_ffns):
+        for op, ffn, heads in zip(self.layer_ops, self.layer_ffns, self.heads_by_layer):
+            qo = heads * self.head_dim
             if op == "conv":
                 per_token += 2.0 * h * 3 * h + 2.0 * h * h + 2.0 * self.conv_L_cache * h
+                attended = 0.0
             elif op == "full_attention":
-                per_token += 2.0 * h * (2 * qo + 2 * kv) + 2.0 * t * qo
+                attended = t / 2.0
+            elif op == "sliding_attention":
+                reach = min(t, self.sliding_window)  # keys of a query: min(t + 1, window)
+                attended = (reach * (reach + 1) / 2.0 + (t - reach) * reach) / t
             else:
-                per_token += 2.0 * h * (2 * qo + 2 * kv) + 4.0 * kept_mean * qo
+                attended = kept_mean
                 per_token += 2.0 * h * (index + self.index_head_dim + self.index_n_heads)
                 per_token += (t + 1.0) * index  # 2 x index a causal pair, (t + 1) / 2 pairs
+            if op != "conv":  # q, k, v, o and the gate; a score and a value a pair
+                per_token += 2.0 * h * (2 * qo + 2 * kv + heads * self.attention_gate) + 4.0 * attended * qo
             if ffn == "dense":
                 per_token += 6.0 * h * self.intermediate_size
             else:
                 per_token += 2.0 * h * self.num_experts
                 per_token += local_pairs * 6.0 * h * self.moe_intermediate_size
+                per_token += 6.0 * h * self.shared_expert_intermediate_size
         return per_token * t + 2.0 * h * self.n_features_out
 
 

@@ -8,7 +8,9 @@ with the reference computed in blocks of windows so that it fits.
     python3 scripts/backbone_parity.py [--config <configs/*.json>] [--seed N] [--block 4]
 
 (``--config benchmarks/chip/configs/keye-vl2-30b-a3b-50tag-lb8192.json
---block 1`` for the sparse-attention backbone: a block is whole windows.)
+--block 1`` for the sparse-attention backbone, ``--config
+benchmarks/chip/configs/laguna-xs2-50tag-lb8192.json --block 1`` for the
+banded one: a block is whole windows.)
 
 Prints one JSON object (and writes it to ``chiprun_out/backbone_parity.json``):
 the worst fraction of scale of the forward, the loss of both sides, and
@@ -98,7 +100,8 @@ def main(argv=None) -> int:
     result["program_seconds"] = round(time.time() - started, 3)
     result["router_tokens"] = np.asarray(aux["router_tokens"]).tolist() if aux else None
     result["pairs_here"] = np.asarray(aux["pairs_here"]).tolist() if aux else None
-    for name in ("keys_selected", "keys_causal", "indexer_kl"):  # a sparse-attention backbone's
+    # a sparse-attention backbone's, a banded one's
+    for name in ("keys_selected", "keys_causal", "indexer_kl", "pairs_attended", "pairs_multiplied"):
         if aux and name in aux:
             result[name] = np.asarray(aux[name]).tolist()
 
@@ -116,11 +119,14 @@ def main(argv=None) -> int:
         blocks = [x[i : i + args.block] for i in range(0, batch, args.block)]
         if hasattr(reference, "router_counts"):
             counts = np.sum([reference.router_counts(layers, b) for b in blocks], axis=0)
-        else:  # a reference that counts its selection beside its routing
+        else:  # a reference that counts its selection, or its mask, beside its routing
             found = [reference.counters(layers, b) for b in blocks]
             counts = np.sum([f["routed"] for f in found], axis=0)
-            result["reference_keys_selected"] = np.sum([f["kept"] for f in found], axis=0).tolist()
-            result["reference_indexer_kl"] = float(np.mean(np.concatenate([f["kl"] for f in found])))
+            if "kept" in found[0]:
+                result["reference_keys_selected"] = np.sum([f["kept"] for f in found], axis=0).tolist()
+                result["reference_indexer_kl"] = float(np.mean(np.concatenate([f["kl"] for f in found])))
+            else:
+                result["reference_pairs_attended"] = np.sum([f["attended"] for f in found], axis=0).tolist()
         moved = np.abs(counts - np.asarray(aux["router_tokens"])).sum(axis=1) / 2
         result["router_pairs_moved_share"] = (moved / counts.sum(axis=1)).tolist()
 

@@ -47,6 +47,17 @@ TOY_SPARSE = dict(
 )
 
 
+#: ``kind: laguna`` at toy widths: full, sliding, sliding, full; a window
+#: of 24 of 100 rows in tiles of 32, heads of two counts, a shared expert
+TOY_BANDED = dict(
+    kind="laguna", lookback_window=LOOKBACK, num_hidden_layers=4,
+    layer_types=["full_attention", "sliding_attention", "sliding_attention", "full_attention"],
+    num_attention_heads_per_layer=[6, 8, 8, 6], hidden_size=32, head_dim=16, num_key_value_heads=2,
+    intermediate_size=48, moe_intermediate_size=24, shared_expert_intermediate_size=20, num_experts=8,
+    experts_held=2, num_experts_per_tok=2, sliding_window=24, epochs=2, batch_size=32,
+)
+
+
 def build_fleet(root, estimator, machines):
     document = {
         "project_name": PROJECT,
@@ -240,6 +251,58 @@ def test_a_sparse_attention_backbone_takes_the_same_path(tmp_path_factory):
         assert fleet.status_code == 200, fleet.text
         got = pd.DataFrame(json.loads(fleet.data)["data"]["turbine-k"]["model-output"])
         np.testing.assert_allclose(got.to_numpy(), want.to_numpy(), rtol=1e-5)
+
+
+def test_a_banded_attention_backbone_takes_the_same_path(tmp_path_factory, monkeypatch):
+    """``kind: laguna`` through ``build-fleet``, the artifact and the
+    server, as the other two go: its fits carry the band's counters
+    beside the router's, a row a layer."""
+    from gordo_tpu.models import backbone
+    from tests.server.conftest import temp_env_vars
+
+    monkeypatch.setattr(backbone, "ATTENTION_TILE", 32)  # the program's constant is 512 rows: a toy's 100 take 32
+    code, root = build_fleet(tmp_path_factory.mktemp("banded") / REVISION, TOY_BANDED, ("pump-l",))
+    assert code == 0
+    with open(os.path.join(root, "build_status.json")) as f:
+        status = json.load(f)
+    assert status["state"] == "complete" and status["machines"]["completed"] == 1
+    assert not any(status["robustness"].values())
+    counters = status["fit_counters"]
+    assert len(counters) == 4 and all(c["members"] == 1 for c in counters)
+    full, sliding = LOOKBACK * (LOOKBACK + 1) / 2, sum(min(t + 1, 24) for t in range(LOOKBACK))
+    for c, windows in zip(sorted(counters, key=lambda c: c["pairs_total"][0]), (12, 23, 34, 45)):
+        trained = 2.0 * windows  # two epochs
+        assert c["pairs_total"] == [2 * windows * LOOKBACK * 2] * 3 and "index_topk" not in c
+        assert c["pairs_attended"] == [trained * full, trained * sliding, trained * sliding, trained * full]
+        # tiles of 32 over 100 rows: 10 up to the diagonal, 7 in a band that reaches one tile back
+        assert c["pairs_multiplied"] == [trained * n * 32 * 32 for n in (10, 7, 7, 10)]
+        assert c["pairs_here"] == [sum(layer[:2]) for layer in c["router_tokens"]]
+    with open(os.path.join(root, "build_trace.jsonl")) as f:
+        spans = [json.loads(line) for line in f]
+    fits = [s["attributes"] for s in spans
+            if s["name"] == "device_program" and "fit" in s["attributes"]["program"]]
+    assert len(fits) == 4 and sum(bool(a["compile"]) for a in fits) == 1
+    assert all(set(a["fit_counters"]) >= {"pairs_attended", "pairs_multiplied", "pairs_here"} for a in fits)
+    model = serializer.load(os.path.join(root, "pump-l"))
+    X = rows(LOOKBACK + 6)
+    prediction = np.asarray(model.predict(X))
+    assert prediction.shape == (6, len(TAGS)) and np.isfinite(prediction).all()
+    estimator = model.base_estimator.steps[-1][1]
+    assert estimator.spec_.layer_ops == tuple(TOY_BANDED["layer_types"])
+    assert estimator.spec_.layer_heads == (6, 8, 8, 6) and estimator.spec_.routed_scaling_factor == 2.5
+    assert estimator.params_["layer_1"]["attn"]["gate"].shape == (32, 8)
+    assert estimator.params_["layer_3"]["moe"]["shared"]["w1"].shape == (32, 20)
+    loss, norms = estimator.training_loss_and_grad_norms(
+        model.base_estimator.steps[0][1].transform(X), X.to_numpy()
+    )
+    assert np.isfinite(loss) and norms["layer_2"]["attn"]["gate"] > 0 and norms["layer_3"]["moe"]["shared"]["w2"] > 0
+    values = {tag: {ts.isoformat(): float(v) for ts, v in X[tag].items()} for tag in TAGS}
+    with temp_env_vars(MODEL_COLLECTION_DIR=root):
+        client = Client(build_app())
+        alone = client.post(f"/gordo/v0/{PROJECT}/pump-l/prediction", json={"X": values})
+        assert alone.status_code == 200, alone.text
+        want = pd.DataFrame(json.loads(alone.data)["data"]["model-output"])
+        np.testing.assert_allclose(want.to_numpy(), prediction, rtol=1e-4, atol=1e-5)
 
 
 def series(n=150, f=4, seed=0):
