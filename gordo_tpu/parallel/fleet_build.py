@@ -428,7 +428,9 @@ class FleetBuilder:
             **attributes,
         )
 
-    def _record_part(self, name: str, seconds: float, count: int) -> None:
+    def _record_part(
+        self, name: str, seconds: float, count: int, **attributes: Any
+    ) -> None:
         """``count`` pieces of one kind of work as ONE ``build_part``
         span of their summed seconds: per machine-fold or per artifact
         they would cost more lines than they are worth (docs/
@@ -439,6 +441,7 @@ class FleetBuilder:
             phase=self._current_phase,
             part=name,
             count=count,
+            **attributes,
         )
 
     def _record_phase(self, name: str, seconds: float) -> None:
@@ -1043,9 +1046,10 @@ class FleetBuilder:
         kill-injection site, so a death right after machine N leaves N
         resumable machines."""
 
-        # (metadata seconds, artifact seconds) a machine, from the pool's
-        # threads (list.append is atomic); two spans a build, not a machine
-        timings: List[Tuple[float, float]] = []
+        # (metadata seconds, artifact seconds, what went into model.pkl)
+        # a machine, from the pool's threads (list.append is atomic); two
+        # spans a build, not a machine
+        timings: List[Tuple[float, float, serializer.Written]] = []
         clock = time.perf_counter
 
         def dump_one(item):
@@ -1054,8 +1058,8 @@ class FleetBuilder:
             began = clock()
             metadata = machine.to_dict()
             serialized = clock()
-            serializer.dump_atomic(model, path, metadata=metadata)
-            timings.append((serialized - began, clock() - serialized))
+            written = serializer.dump_atomic(model, path, metadata=metadata)
+            timings.append((serialized - began, clock() - serialized, written))
             if self._journal is not None:
                 # Record the hash too: cache-hit machines skip the planning
                 # pass (where it is normally journaled), and resume needs it.
@@ -1096,9 +1100,18 @@ class FleetBuilder:
         finally:
             pool.shutdown(wait=True)
         # serialize: the machine and its build metadata to a plain dict;
-        # write: pickle + JSON + checksum into the staging dir, renamed
+        # write: the pickle, hashed as it is written, + JSON into the
+        # staging dir, renamed
         self._record_part("serialize", sum(t[0] for t in timings), len(timings))
-        self._record_part("write", sum(t[1] for t in timings), len(timings))
+        self._record_part(
+            "write",
+            sum(t[1] for t in timings),
+            len(timings),
+            bytes=sum(t[2].bytes for t in timings),
+            bytes_hashed_beside_write=sum(
+                t[2].bytes_hashed_beside_write for t in timings
+            ),
+        )
         saved = []
         for (model, machine), exc in zip(to_dump, outcomes):
             if exc is not None:

@@ -18,9 +18,10 @@ import os
 import pickle
 import re
 import shutil
+import threading
 import uuid
 from os import path
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 from ..telemetry.aggregate import ROLLUP_DIR, is_worker_variant
 from ..telemetry.fleet_health import FLEET_HEALTH_FILE, FLEET_HEALTH_SHARD_DIR
@@ -35,6 +36,21 @@ logger = logging.getLogger(__name__)
 MODEL_FILE = "model.pkl"
 METADATA_FILE = "metadata.json"
 INFO_FILE = "info.json"
+
+#: ``model.pkl``'s pickle protocol: numpy hands a contiguous leaf to a
+#: protocol-5 pickler as a ``PickleBuffer``, and the C pickler passes a
+#: buffer larger than its 64 KiB frame straight to ``write()``, so a
+#: backbone's leaf reaches the file as a view of the array's own memory
+#: (in-band: one file, no ``buffer_callback``). Every Python this
+#: package supports reads it, and artifacts of earlier builds
+#: (protocol 4) load unchanged.
+MODEL_PICKLE_PROTOCOL = 5
+
+#: a buffer at least this large is hashed on a helper thread while the
+#: calling thread writes it (``hashlib`` and file writes both release
+#: the GIL); a smaller one (the pickle's frames, every byte of a dense
+#: member's few KB) is not worth a thread and is hashed in line
+HASH_BESIDE_WRITE_MIN_BYTES = 1 << 20
 
 
 def dumps(model) -> bytes:
@@ -62,24 +78,77 @@ def _file_checksum(file_path: str) -> str:
     return digest.hexdigest()
 
 
-def dump(obj, dest_dir: str, metadata: Optional[dict] = None, info: Optional[dict] = None):
+class Written(NamedTuple):
+    """What one :func:`dump` put into ``model.pkl``."""
+
+    #: the file's size
+    bytes: int
+    #: the part of it that took the helper thread
+    bytes_hashed_beside_write: int
+
+
+class _HashingWriter:
+    """The pickler's file: every buffer it is handed goes to the file
+    and to the md5 once, so the digest is that of the file's bytes with
+    no read-back. A large buffer is hashed on a helper thread while this
+    one writes it; the helper is joined before ``write`` returns, so
+    order is kept and the view outlives no call."""
+
+    def __init__(self, file):
+        self._file = file
+        self.digest = hashlib.md5()
+        self.bytes = 0
+        self.bytes_hashed_beside_write = 0
+
+    def write(self, data) -> int:
+        size = memoryview(data).nbytes
+        if size < HASH_BESIDE_WRITE_MIN_BYTES:
+            self.digest.update(data)
+            self._file.write(data)
+        else:
+            failed = []
+
+            def hash_it():
+                try:
+                    self.digest.update(data)
+                except BaseException as exc:  # re-raised below, on the caller
+                    failed.append(exc)
+
+            helper = threading.Thread(target=hash_it, name="model-pkl-md5")
+            helper.start()
+            try:
+                self._file.write(data)
+            finally:
+                helper.join()
+            if failed:
+                raise failed[0]
+            self.bytes_hashed_beside_write += size
+        self.bytes += size
+        return size
+
+
+def dump(
+    obj, dest_dir: str, metadata: Optional[dict] = None, info: Optional[dict] = None
+) -> Written:
     """
     Serialize ``obj`` into ``dest_dir`` as ``model.pkl`` (+ optional
     ``metadata.json`` / ``info.json``; info always records the model
-    checksum).
+    checksum: the md5 of ``model.pkl``'s bytes, computed while they are
+    written).
     """
     os.makedirs(dest_dir, exist_ok=True)
-    model_path = path.join(dest_dir, MODEL_FILE)
-    with open(model_path, "wb") as f:
-        pickle.dump(obj, f)
+    with open(path.join(dest_dir, MODEL_FILE), "wb") as f:
+        writer = _HashingWriter(f)
+        pickle.dump(obj, writer, protocol=MODEL_PICKLE_PROTOCOL)
     if metadata is not None:
         with open(path.join(dest_dir, METADATA_FILE), "w") as f:
             simplejson.dump(metadata, f, default=str, ignore_nan=True)
-    full_info = {"checksum": _file_checksum(model_path)}
+    full_info = {"checksum": writer.digest.hexdigest()}
     if info:
         full_info.update(info)
     with open(path.join(dest_dir, INFO_FILE), "w") as f:
         simplejson.dump(full_info, f, default=str)
+    return Written(writer.bytes, writer.bytes_hashed_beside_write)
 
 
 TMP_DIR_MARKER = ".tmp-"
@@ -174,7 +243,7 @@ def dump_atomic(
     dest_dir: str,
     metadata: Optional[dict] = None,
     info: Optional[dict] = None,
-):
+) -> Written:
     """
     Crash-safe :func:`dump`: artifacts are written into a
     ``.<name>.tmp-*`` sibling staging dir and ``os.replace``-renamed
@@ -207,7 +276,7 @@ def dump_atomic(
         except FileExistsError:  # pragma: no cover - 2^32 collision
             continue
     try:
-        dump(obj, staging, metadata=metadata, info=info)
+        written = dump(obj, staging, metadata=metadata, info=info)
         fault_point("dump_artifact", name)
         if path.isdir(dest_dir) and not set(os.listdir(dest_dir)) <= _ARTIFACT_FILES:
             # Mixed-content dest: move each artifact file in (file-level
@@ -215,13 +284,14 @@ def dump_atomic(
             for entry in os.listdir(staging):
                 os.replace(path.join(staging, entry), path.join(dest_dir, entry))
             os.rmdir(staging)
-            return
+            return written
         if path.isdir(dest_dir):
             # rename(2) cannot replace a non-empty dir; a complete prior
             # artifact (e.g. a re-build into the same output dir) is
             # swapped out the pre-rename instant before the new one lands.
             shutil.rmtree(dest_dir)
         os.replace(staging, dest_dir)
+        return written
     except BaseException:
         shutil.rmtree(staging, ignore_errors=True)
         raise

@@ -14,7 +14,7 @@ import pytest
 
 from gordo_tpu import serializer, telemetry
 from gordo_tpu.machine import Machine
-from gordo_tpu.parallel import FleetBuilder
+from gordo_tpu.parallel import FleetBuilder, journal
 from gordo_tpu.utils import faults
 from gordo_tpu.utils.faults import FaultRule, inject
 
@@ -306,3 +306,27 @@ def test_serving_store_ignores_telemetry_files(tmp_path):
     assert RevisionFleet(str(out)).warm() == ["srv-m"]
     assert serializer.is_builder_dropping("build_status.json")
     assert serializer.is_builder_dropping("build_trace.jsonl")
+
+
+def test_the_dump_phase_says_what_it_wrote(tmp_path):
+    """The ``write`` part of ``dump`` carries the bytes of the
+    ``model.pkl`` files it wrote and the part of them hashed on the
+    helper thread: none of a dense member's few KB."""
+    names = ["dw-a", "dw-b"]
+    out = tmp_path / "out"
+    FleetBuilder([make_machine(n) for n in names]).build(output_dir=str(out))
+
+    parts = telemetry.load_status(str(out))["phases"]["dump"]["parts"]
+    assert parts["write"]["count"] == 2 and parts["write"]["seconds"] > 0.0
+    written = [
+        s["attributes"]
+        for s in read_trace(str(out))
+        if s["name"] == "build_part" and s["attributes"]["part"] == "write"
+    ]
+    assert len(written) == 1 and written[0]["phase"] == "dump"
+    assert written[0]["bytes"] == sum(
+        os.path.getsize(out / n / serializer.MODEL_FILE) for n in names
+    )
+    assert written[0]["bytes_hashed_beside_write"] == 0
+    for n in names:
+        assert journal.artifact_complete(str(out / n))
