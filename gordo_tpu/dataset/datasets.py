@@ -246,6 +246,13 @@ class TimeSeriesDataset(GordoBaseDataset):
         #: ``resample_join``, ``row_filter``): the fleet builder puts them
         #: on the machine's ``machine_fetch`` span
         self.fetch_seconds: Dict[str, float] = {}
+        #: the same parts on the calling thread's CPU clock
+        #: (``time.thread_time()``): what the fetch computed, free of
+        #: its waits for the source, for the GIL, for a core. Kept only
+        #: where ``fetch_cpu_timed`` is set (the fleet builder sets it
+        #: where it records spans): a fetch nobody records reads one clock
+        self.fetch_cpu_seconds: Dict[str, float] = {}
+        self.fetch_cpu_timed = False
 
     def _load_and_join(self) -> pd.DataFrame:
         all_tags = unique_tag_names(list(self.tag_list) + list(self.target_tag_list))
@@ -266,12 +273,21 @@ class TimeSeriesDataset(GordoBaseDataset):
     @contextlib.contextmanager
     def _timed(self, part: str):
         """Add the enclosed block's seconds to ``fetch_seconds[part]``
-        (and name it in a profiler session's host plane)."""
+        and, where ``fetch_cpu_timed``, the calling thread's CPU seconds
+        to ``fetch_cpu_seconds[part]`` (and name it in a profiler
+        session's host plane)."""
         started = time.perf_counter()
+        cpu_started = time.thread_time() if self.fetch_cpu_timed else None
         try:
             with annotate(f"dataset:{part}"):
                 yield
         finally:
+            if cpu_started is not None:
+                self.fetch_cpu_seconds[part] = (
+                    self.fetch_cpu_seconds.get(part, 0.0)
+                    + time.thread_time()
+                    - cpu_started
+                )
             self.fetch_seconds[part] = (
                 self.fetch_seconds.get(part, 0.0) + time.perf_counter() - started
             )
@@ -413,7 +429,7 @@ class TimeSeriesDataset(GordoBaseDataset):
         return data
 
     def get_data(self) -> Tuple[pd.DataFrame, pd.DataFrame]:
-        self.fetch_seconds = {}
+        self.fetch_seconds, self.fetch_cpu_seconds = {}, {}
         data = self._load_and_join()
         with self._timed("row_filter"):
             data = self._apply_filters(data)

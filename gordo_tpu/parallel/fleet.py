@@ -26,6 +26,7 @@ are independent of bucket composition and deterministic per seed.
 """
 
 import logging
+import time
 from dataclasses import dataclass, replace
 from functools import lru_cache
 from typing import Any, Dict, List, Optional, Sequence
@@ -161,6 +162,39 @@ class FoldScoring:
     metric_scaler: np.ndarray  # [M, 4, T] the machine's scoring scaler
     error_scaler: np.ndarray  # [M, 4, T] the detector's, fitted to the fold's training rows
     window: Optional[int] = None  # the detectors' smoothing window (static)
+
+    @property
+    def nbytes(self) -> int:
+        return tree_nbytes(
+            self.y_true, self.rows, self.metric_scaler, self.error_scaler
+        )
+
+
+def tree_nbytes(*trees, on_host: bool = False) -> int:
+    """Bytes of the arrays in ``trees`` (a None holds none): what a
+    ``build_part`` span says it moved. ``on_host``: of the leaves that
+    are not on a device yet, which is what a transfer moves."""
+    return sum(
+        int(leaf.nbytes)
+        for leaf in jax.tree_util.tree_leaves(trees)
+        if not (on_host and isinstance(leaf, jax.Array))
+    )
+
+
+def _fetch_for(span, tree):
+    """:func:`fetch_to_host` inside the caller's ``collect`` span, which
+    is told what came back (``bytes``) and the seconds of the fetch alone
+    (``d2h_seconds``): the rest of a ``collect`` is the host's own
+    unstacking. A span nobody records is told nothing, and nothing is
+    measured for it."""
+    if not span.recording:
+        return fetch_to_host(tree)
+    began = time.perf_counter()
+    host = fetch_to_host(tree)
+    span.set(
+        d2h_seconds=round(time.perf_counter() - began, 6), bytes=tree_nbytes(host)
+    )
+    return host
 
 
 def _bucket_nbytes(bucket) -> int:
@@ -870,7 +904,7 @@ class FleetTrainer:
         planner pads sibling HBM-split buckets to one shared rung so they
         reuse a single compiled program).
         """
-        with telemetry.part_span("stack"):
+        with telemetry.part_span("stack") as span:
             model_axis = self.mesh.devices.shape[0]
             data_axis = self.mesh.devices.shape[1] if self.mesh.devices.ndim > 1 else 1
             m_floor = max(len(bucket), m_padded or 0)
@@ -902,12 +936,14 @@ class FleetTrainer:
             wval = np.zeros((m_total, n_padded), np.float32)
             for i, member in enumerate(bucket):
                 _fill_weight_row(wtr, wval, i, member.n, member, config)
+            if span.recording:  # the blocks filled
+                span.set(bytes=tree_nbytes(X, y, wtr, wval))
             validation_slots, wval, Xval, yval = validation_inputs(
                 wval, X, y, axis=1
             )
 
             rngs = host_prng_keys([m.seed for m in bucket] + [0] * (m_total - len(bucket)))
-        with telemetry.part_span("h2d"):
+        with telemetry.part_span("h2d") as span:
             w_sharding = model_data_sharding(self.mesh)
 
             def put(a):
@@ -921,6 +957,11 @@ class FleetTrainer:
             Xval_dev, yval_dev = (
                 (X_dev, y_dev) if validation_slots else (put(Xval), put(yval))
             )
+            if span.recording:
+                span.set(
+                    bytes=tree_nbytes(X, y, wtr, wval, rngs)
+                    + (0 if validation_slots else tree_nbytes(Xval, yval))
+                )
             wtr, wval, rngs = jax.device_put(
                 (wtr, wval, rngs),
                 (w_sharding, w_sharding, model_sharding(self.mesh, extra_dims=1)),
@@ -957,11 +998,10 @@ class FleetTrainer:
             params, _, losses, val_losses, epochs_ran = _traced_outputs(
                 fit(params, opt_state, *data, rngs)
             )
-        with telemetry.part_span("collect"):
-            return self._collect_results(
-                bucket, params, losses, val_losses, epochs_ran, config,
-                steps=n_padded // config.batch_size,
-            )
+        return self._collect_results(
+            bucket, params, losses, val_losses, epochs_ran, config,
+            steps=n_padded // config.batch_size,
+        )
 
     def _init_bucket_params(self, spec: ModelSpec, rngs):
         """Per-member init mirroring fit_single's derivation exactly so a
@@ -999,7 +1039,7 @@ class FleetTrainer:
         virtual window axis (order + weights) shards over ``data``.
         """
         mesh = self._mesh_for(spec)
-        with telemetry.part_span("stack"):
+        with telemetry.part_span("stack") as span:
             model_axis = mesh.devices.shape[0]
             data_axis = mesh.devices.shape[1] if mesh.devices.ndim > 1 else 1
             m_floor = max(len(bucket), m_padded or 0)
@@ -1023,13 +1063,17 @@ class FleetTrainer:
                     member.order if member.order is not None else np.arange(nv)
                 )
                 _fill_weight_row(wtr, wval, i, nv, member, config)
+            if span.recording:
+                span.set(bytes=tree_nbytes(series, ytgt, order, wtr, wval))
             validation_slots, wval = validation_inputs(wval, axis=1)
 
             rngs = host_prng_keys(
                 [m.seed for m in bucket] + [0] * (m_total - len(bucket))
             )
-        with telemetry.part_span("h2d"):
+        with telemetry.part_span("h2d") as span:
             md = model_data_sharding(mesh)
+            if span.recording:
+                span.set(bytes=tree_nbytes(series, ytgt, order, wtr, wval, rngs))
             arrays = jax.device_put(
                 (series, ytgt, order, wtr, wval, rngs),
                 (
@@ -1079,53 +1123,57 @@ class FleetTrainer:
             )
             if counters:
                 span.set(**_fit_counter_attrs(spec, counters[0], len(bucket)))
-        with telemetry.part_span("collect"):
-            return self._collect_results(
-                bucket, params, losses, val_losses, epochs_ran, config,
-                steps=order.shape[1] // config.batch_size,
-            )
+        return self._collect_results(
+            bucket, params, losses, val_losses, epochs_ran, config,
+            steps=order.shape[1] // config.batch_size,
+        )
 
     def _collect_results(
         self, bucket, params, losses, val_losses, epochs_ran, config, steps
     ) -> List[FleetResult]:
-        host_params, losses, val_losses, epochs_ran = fetch_to_host(
-            (params, losses, val_losses, epochs_ran)
-        )
-        losses = np.asarray(losses)
-        val_losses = np.asarray(val_losses)
-        epochs_ran = np.asarray(epochs_ran)
+        """A fit program's results on the host, a member each: the
+        ``collect`` part, whose span says the bytes fetched and the
+        fetch's own seconds (``_fetch_for``); the rest of it is the loop
+        below."""
+        with telemetry.part_span("collect") as span:
+            host_params, losses, val_losses, epochs_ran = _fetch_for(
+                span, (params, losses, val_losses, epochs_ran)
+            )
+            losses = np.asarray(losses)
+            val_losses = np.asarray(val_losses)
+            epochs_ran = np.asarray(epochs_ran)
 
-        results = []
-        for i, member in enumerate(bucket):
-            ran = int(epochs_ran[i])
-            history = {"loss": [float(l) for l in losses[i][:ran]]}
-            member_val = val_losses[i][:ran]
-            # NaN marks "no validation rows for this member" (see
-            # weighted_mean_loss); only members with real validation data
-            # get a val_loss history.
-            if ran and not np.all(np.isnan(member_val)):
-                history["val_loss"] = [float(l) for l in member_val]
-            member_params = jax.tree_util.tree_map(
-                lambda a: np.asarray(a[i]), host_params
-            )
-            results.append(
-                FleetResult(
-                    name=member.name,
-                    seed=member.seed,
-                    params=member_params,
-                    history=History(
-                        history=history,
-                        params={
-                            "epochs": config.epochs,
-                            "steps": steps,
-                            "verbose": 0,
-                            "metrics": list(history),
-                        },
-                        epoch=list(range(ran)),
-                    ),
+            results = []
+            for i, member in enumerate(bucket):
+                ran = int(epochs_ran[i])
+                history = {"loss": [float(l) for l in losses[i][:ran]]}
+                member_val = val_losses[i][:ran]
+                # NaN marks "no validation rows for this member" (see
+                # weighted_mean_loss); only members with real validation
+                # data get a val_loss history.
+                if ran and not np.all(np.isnan(member_val)):
+                    history["val_loss"] = [float(l) for l in member_val]
+                member_params = jax.tree_util.tree_map(
+                    lambda a: np.asarray(a[i]), host_params
                 )
-            )
-        return results
+                results.append(
+                    FleetResult(
+                        name=member.name,
+                        seed=member.seed,
+                        params=member_params,
+                        history=History(
+                            history=history,
+                            params={
+                                "epochs": config.epochs,
+                                "steps": steps,
+                                "verbose": 0,
+                                "metrics": list(history),
+                            },
+                            epoch=list(range(ran)),
+                        ),
+                    )
+                )
+            return results
 
     # -- prediction ---------------------------------------------------------
 
@@ -1159,11 +1207,11 @@ class FleetTrainer:
         host, or, of a program that scored them (``scoring``), the
         predictions where they are and the scores of the ``m`` members
         on the host."""
-        with telemetry.part_span("collect"):
+        with telemetry.part_span("collect") as span:
             if scoring is None:
-                return np.asarray(fetch_to_host(out))[:m, :n]
+                return np.asarray(_fetch_for(span, out))[:m, :n]
             predictions, scores = out
-            scores = fetch_to_host(scores)
+            scores = _fetch_for(span, scores)
         return predictions, {k: np.asarray(v)[:m] for k, v in scores.items()}
 
     def predict_bucket(
@@ -1178,7 +1226,7 @@ class FleetTrainer:
         predictions stay on the device (``[M', N', out]``, the program's
         padded block) and come back beside the scores, ``[M, ...]`` arrays
         on the host."""
-        with telemetry.part_span("h2d"):  # pad to the mesh, then transfer
+        with telemetry.part_span("h2d") as span:  # pad to the mesh, then transfer
             X = np.asarray(X, np.float32)
             m = X.shape[0]
             model_axis = self.mesh.devices.shape[0]
@@ -1199,6 +1247,12 @@ class FleetTrainer:
                     if m_total != m
                     else np.asarray(a),
                     stacked_params,
+                )
+            if span.recording:
+                # the parameters go with the program's call, host arrays too
+                span.set(
+                    bytes=tree_nbytes(X, stacked_params, on_host=True)
+                    + (scoring.nbytes if scoring is not None else 0)
                 )
             X = jax.device_put(
                 X, model_data_sharding(self.mesh, extra_dims=X.ndim - 2)
@@ -1238,7 +1292,7 @@ class FleetTrainer:
         ``scoring`` as in :meth:`predict_bucket`.
         """
         mesh = self._mesh_for(spec)
-        with telemetry.part_span("h2d"):  # pad to the mesh, then transfer
+        with telemetry.part_span("h2d") as span:  # pad to the mesh, then transfer
             series = np.asarray(series, np.float32)
             order = np.asarray(order, np.int32)
             m = series.shape[0]
@@ -1262,6 +1316,11 @@ class FleetTrainer:
                     stacked_params,
                 )
             ms2 = model_sharding(mesh, extra_dims=2)
+            if span.recording:
+                span.set(
+                    bytes=tree_nbytes(series, order, stacked_params, on_host=True)
+                    + (scoring.nbytes if scoring is not None else 0)
+                )
             series = jax.device_put(series, ms2)
             order = jax.device_put(
                 order, model_sharding(mesh, extra_dims=1)

@@ -31,7 +31,7 @@ import time
 import types
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 import pandas as pd
@@ -78,6 +78,7 @@ from .fleet import (
     fetch_members,
     is_device_error,
     stack_member_params,
+    tree_nbytes,
 )
 from .journal import BuildJournal, clean_staging_dirs
 
@@ -255,6 +256,12 @@ def _fold_member_name(machine_name: str, fold_idx: int) -> str:
     return f"{machine_name}::fold{fold_idx}"
 
 
+def _no_clock() -> float:
+    """The CPU clock of a build that records nothing
+    (``FleetBuilder._cpu_clock``)."""
+    return 0.0
+
+
 def _try_call(fn, *args):
     """Run ``fn``; return the exception instead of raising (thread-pool
     safe capture for failFast:false semantics). Interpreter-shutdown
@@ -393,8 +400,17 @@ class FleetBuilder:
         previous = (self._current_phase, self._phase_span_id)
         try:
             with self.recorder.span(
-                "build_phase", phase=name, machines=len(self.machines)
+                "build_phase",
+                cpu_clock=True,
+                phase=name,
+                machines=len(self.machines),
             ) as handle:
+                # every thread's CPU between the phase's two ends, beside
+                # the span's own thread's: over the phase's seconds, the
+                # cores the phase kept busy
+                process_cpu_started = (
+                    time.process_time() if handle.recording else None
+                )
                 # the phase pays for its own status write and device
                 # sample: nothing of a build lies between two phases
                 if self.progress is not None:
@@ -404,6 +420,12 @@ class FleetBuilder:
                     yield
                 finally:
                     self._sample_device(name)
+                    if process_cpu_started is not None:
+                        handle.set(
+                            process_cpu_seconds=round(
+                                time.process_time() - process_cpu_started, 6
+                            )
+                        )
         finally:
             self._enter_phase(*previous)
             self.phase_seconds[name] += time.perf_counter() - started
@@ -423,21 +445,37 @@ class FleetBuilder:
         return self.recorder.span(
             "build_part",
             parent_id=self._phase_span_id,
+            cpu_clock=True,
             phase=self._current_phase,
             part=name,
             **attributes,
         )
 
+    def _cpu_clock(self) -> Callable[[], float]:
+        """``time.thread_time`` for work that is timed in place and
+        handed to :meth:`_record_part`; where nothing is recorded, a
+        clock that stands still: a build without telemetry reads no
+        second clock."""
+        return time.thread_time if self.recorder.enabled else _no_clock
+
     def _record_part(
-        self, name: str, seconds: float, count: int, **attributes: Any
+        self,
+        name: str,
+        seconds: float,
+        count: int,
+        cpu_seconds: Optional[float] = None,
+        **attributes: Any,
     ) -> None:
         """``count`` pieces of one kind of work as ONE ``build_part``
-        span of their summed seconds: per machine-fold or per artifact
-        they would cost more lines than they are worth (docs/
-        observability.md, the span budget)."""
+        span of their summed seconds (and, where the threads that did
+        them read ``time.thread_time()`` too, of their summed CPU
+        seconds): per machine-fold or per artifact they would cost more
+        lines than they are worth (docs/observability.md, the span
+        budget)."""
         self.recorder.record(
             "build_part",
             seconds,
+            cpu_seconds=cpu_seconds,
             phase=self._current_phase,
             part=name,
             count=count,
@@ -471,11 +509,12 @@ class FleetBuilder:
         return None
 
     def _sample_device(self, phase: str) -> None:
-        """Emit a ``device_utilization`` event (HBM in-use/peak +
-        compile-cache counters) at the end of device-heavy phases,
+        """Sample what the process holds (the devices' memory, the
+        host's peak resident set) at the end of device-heavy phases,
         time-throttled so a thousand-chunk CV loop costs a handful of
         samples, not a thousand. Tracks the build's max observed HBM
-        peak for the plan-accuracy join."""
+        peak for the plan-accuracy join, and hands the newest sample to
+        ``build_status.json["resources"]``."""
         if phase not in self._DEVICE_SAMPLED_PHASES:
             return
         now = time.time()
@@ -483,17 +522,21 @@ class FleetBuilder:
             return
         self._last_device_sample = now
         try:
-            snapshot = telemetry.emit_device_utilization(
-                self.recorder, phase=phase
-            )
+            sample = telemetry.sample_resources()
         except Exception as exc:  # noqa: BLE001 - device telemetry is advisory
-            logger.debug("device utilization not sampled: %r", exc)
+            logger.debug("resources not sampled: %r", exc)
             return
+        snapshot = sample.pop("memory")
         if snapshot and snapshot.get("available"):
             self._device_peak_bytes = max(
                 self._device_peak_bytes,
                 int(snapshot.get("max_peak_bytes_in_use") or 0),
             )
+        if self.progress is not None:
+            self.progress.resources = {
+                "hbm_peak_bytes": self._device_peak_bytes or None,
+                **sample,
+            }
 
     def _fail(self, name: str, exc: BaseException):
         if self._journal is not None:
@@ -932,10 +975,23 @@ class FleetBuilder:
         if name == "build_part" and self.progress is not None:
             phase = str(attrs.get("phase", ""))
             self.progress.add_part(
-                phase, str(attrs.get("part", "")), seconds, int(attrs.get("count", 1))
+                phase,
+                str(attrs.get("part", "")),
+                seconds,
+                int(attrs.get("count", 1)),
+                **{key: attrs.get(key) for key in telemetry.PART_SUMS},
             )
+            nested_cpu = telemetry.nested_part_cpu_seconds(attrs)
             for part, nested in telemetry.nested_part_seconds(attrs).items():
-                self.progress.add_part(phase, part, nested)
+                self.progress.add_part(
+                    phase, part, nested, cpu_seconds=nested_cpu.get(part)
+                )
+        if name == "build_phase" and self.progress is not None:
+            self.progress.add_phase_cpu(
+                str(attrs.get("phase", "")),
+                cpu_seconds=attrs.get("cpu_seconds"),
+                process_cpu_seconds=attrs.get("process_cpu_seconds"),
+            )
         self._feed_health_ledger(name, attrs)
         try:
             from ..server.prometheus import metrics as prom
@@ -1046,20 +1102,31 @@ class FleetBuilder:
         kill-injection site, so a death right after machine N leaves N
         resumable machines."""
 
-        # (metadata seconds, artifact seconds, what went into model.pkl)
-        # a machine, from the pool's threads (list.append is atomic); two
-        # spans a build, not a machine
-        timings: List[Tuple[float, float, serializer.Written]] = []
-        clock = time.perf_counter
+        # (metadata seconds, artifact seconds, the same two of the
+        # thread's CPU clock, what went into model.pkl) a machine, from
+        # the pool's threads (list.append is atomic); two spans a build,
+        # not a machine
+        timings: List[Tuple[float, float, float, float, serializer.Written]] = []
+        clock, cpu_clock = time.perf_counter, self._cpu_clock()
 
         def dump_one(item):
             model, machine = item
             path = os.path.join(output_dir, machine.name)
-            began = clock()
+            began, cpu_began = clock(), cpu_clock()
             metadata = machine.to_dict()
-            serialized = clock()
-            written = serializer.dump_atomic(model, path, metadata=metadata)
-            timings.append((serialized - began, clock() - serialized, written))
+            serialized, cpu_serialized = clock(), cpu_clock()
+            written = serializer.dump_atomic(
+                model, path, metadata=metadata, cpu_clock=cpu_clock
+            )
+            timings.append(
+                (
+                    serialized - began,
+                    clock() - serialized,
+                    cpu_serialized - cpu_began,
+                    cpu_clock() - cpu_serialized,
+                    written,
+                )
+            )
             if self._journal is not None:
                 # Record the hash too: cache-hit machines skip the planning
                 # pass (where it is normally journaled), and resume needs it.
@@ -1102,14 +1169,22 @@ class FleetBuilder:
         # serialize: the machine and its build metadata to a plain dict;
         # write: the pickle, hashed as it is written, + JSON into the
         # staging dir, renamed
-        self._record_part("serialize", sum(t[0] for t in timings), len(timings))
+        # (the helper threads that hash beside the write count their CPU
+        # into ``write``)
+        self._record_part(
+            "serialize",
+            sum(t[0] for t in timings),
+            len(timings),
+            cpu_seconds=sum(t[2] for t in timings),
+        )
         self._record_part(
             "write",
             sum(t[1] for t in timings),
             len(timings),
-            bytes=sum(t[2].bytes for t in timings),
+            cpu_seconds=sum(t[3] + t[4].hash_cpu_seconds for t in timings),
+            bytes=sum(t[4].bytes for t in timings),
             bytes_hashed_beside_write=sum(
-                t[2].bytes_hashed_beside_write for t in timings
+                t[4].bytes_hashed_beside_write for t in timings
             ),
         )
         saved = []
@@ -1426,6 +1501,8 @@ class FleetBuilder:
         config errors (insufficient data, bad tags) are not retried."""
         from ..dataset.exceptions import ConfigException, InsufficientDataError
 
+        cpu_clock = self._cpu_clock()
+
         def load(plan: _Plan):
             start = time.time()
 
@@ -1452,13 +1529,20 @@ class FleetBuilder:
             # main thread, which only waits, writes the span: written
             # from here, among sixteen threads contending for the GIL,
             # a span cost the phase about a millisecond. The dataset's
-            # own parts ride on it as <part>_s attributes.
+            # own parts ride on it as <part>_s attributes, their CPU
+            # seconds as <part>_cpu_seconds; this thread's own CPU clock
+            # says how much of the fetch's seconds it computed and how
+            # much it waited (for the GIL, for the source). Both CPU
+            # clocks are read only where the span will be written.
             attrs = {
                 "phase": "data_fetch",
                 "part": "machine_fetch",
                 "machine": plan.machine.name,
             }
+            if self.recorder.enabled:
+                plan.dataset.fetch_cpu_timed = True  # TimeSeriesDataset._timed
             began = time.perf_counter()
+            cpu_began = cpu_clock()
             try:
                 with self._annotation("build_part", attrs):
                     X, y = retry_call(
@@ -1474,8 +1558,13 @@ class FleetBuilder:
                     plan.dataset, "fetch_seconds", {}
                 ).items():
                     attrs[f"{name}_s"] = round(seconds, 6)
+                for name, seconds in getattr(
+                    plan.dataset, "fetch_cpu_seconds", {}
+                ).items():
+                    attrs[f"{name}_cpu_seconds"] = round(seconds, 6)
             finally:
                 attrs["retries"] = plan.data_retries
+                attrs["cpu_seconds"] = cpu_clock() - cpu_began
                 fetched[plan.machine.name] = (
                     time.perf_counter() - began, start, attrs
                 )
@@ -1920,7 +2009,7 @@ class FleetBuilder:
                 (plan, fold_idx)
             )
         for (spec, geometry, window), group in groups.items():
-            with self._phase("cv_score"), self._part("stack"):
+            with self._phase("cv_score"), self._part("stack") as span:
                 # per item: (train_rows, window_idx, target_rows)
                 fold_rows = []
                 for plan, fold_idx in group:
@@ -1930,14 +2019,18 @@ class FleetBuilder:
                     window_idx, target_rows = self._test_window_rows(plan, test_rows)
                     fold_rows.append((train_rows, window_idx, target_rows))
                 scoring = self._fold_scoring(group, fold_rows, window)
+                if scoring is not None and span.recording:
+                    span.set(bytes=scoring.nbytes)
             with self._phase("cv_predict"):
-                with self._part("stack"):
+                with self._part("stack") as span:
                     stacked = stack_member_params(
                         [
                             by_name[_fold_member_name(p.machine.name, k)]
                             for p, k in group
                         ]
                     )
+                    if span.recording:
+                        span.set(bytes=tree_nbytes(stacked))
                 if geometry == ("windowed",):
                     predicted = self._predict_windowed_group(
                         spec,
@@ -1947,7 +2040,7 @@ class FleetBuilder:
                         scoring,
                     )
                 else:
-                    with self._part("stack"):
+                    with self._part("stack") as span:
                         n_max = max(len(wi) for _, wi, _ in fold_rows)
                         X = np.zeros(
                             (len(group), n_max) + group[0][0].windows.shape[1:],
@@ -1955,6 +2048,7 @@ class FleetBuilder:
                         )
                         for i, (p, _) in enumerate(group):
                             X[i, : len(fold_rows[i][1])] = p.windows[fold_rows[i][1]]
+                        span.set(bytes=X.nbytes)
                     predicted = self.trainer.predict_bucket(
                         spec, stacked, X, scoring=scoring
                     )
@@ -2007,8 +2101,8 @@ class FleetBuilder:
         what is wrong with it. Parts: ``device_scores`` counts the
         machine-folds scored on the device, ``metric_scores`` and
         ``thresholds`` those scored here."""
-        clock = time.perf_counter
-        began = clock()
+        clock, cpu_clock = time.perf_counter, self._cpu_clock()
+        began, cpu_began = clock(), cpu_clock()
         on_host = list(range(len(group)))
         predictions = predicted
         if scoring is not None:
@@ -2041,23 +2135,29 @@ class FleetBuilder:
                         *(scores[name][i].astype(np.float64) for name in thresholds),
                     )
             if on_host:
-                with self._part("collect"):
+                with self._part("collect") as span:
                     predictions = fetch_members(predictions, on_host)
+                    span.set(bytes=predictions.nbytes)
         self._record_part(
-            "device_scores", clock() - began, len(group) - len(on_host)
+            "device_scores",
+            clock() - began,
+            len(group) - len(on_host),
+            cpu_seconds=cpu_clock() - cpu_began,
         )
-        # per machine-fold work, timed here and recorded as two
-        # spans a group: a span each would be 2 x members lines
+        # per machine-fold work, timed here (both clocks) and recorded as
+        # two spans a group: a span each would be 2 x members lines
         metric_seconds = threshold_seconds = 0.0
+        metric_cpu = threshold_cpu = 0.0
         for i, y_pred in zip(on_host, predictions):
             plan, fold_idx = group[i]
             train_rows, window_idx, target_rows = fold_rows[i]
             y_true = plan.y_arr[target_rows]
             y_pred = y_pred[: len(window_idx)]
-            began = clock()
+            began, cpu_began = clock(), cpu_clock()
             self._accumulate_metric_scores(plan, y_true, y_pred, fold_idx)
-            scored = clock()
+            scored, cpu_scored = clock(), cpu_clock()
             metric_seconds += scored - began
+            metric_cpu += cpu_scored - cpu_began
             if plan.detector is not None:
                 self._accumulate_thresholds(
                     plan, y_true, y_pred, fold_idx, fold_state[plan.machine.name],
@@ -2065,8 +2165,13 @@ class FleetBuilder:
                     test_rows=target_rows,
                 )
                 threshold_seconds += clock() - scored
-        self._record_part("metric_scores", metric_seconds, len(on_host))
-        self._record_part("thresholds", threshold_seconds, len(on_host))
+                threshold_cpu += cpu_clock() - cpu_scored
+        self._record_part(
+            "metric_scores", metric_seconds, len(on_host), cpu_seconds=metric_cpu
+        )
+        self._record_part(
+            "thresholds", threshold_seconds, len(on_host), cpu_seconds=threshold_cpu
+        )
 
     def _predict_windowed_group(
         self,
@@ -2082,7 +2187,7 @@ class FleetBuilder:
         each plan's window positions to predict (the fold-test windows);
         ``scoring`` as ``FleetTrainer.predict_bucket`` takes it."""
         orders = window_idx
-        with self._part("stack"):
+        with self._part("stack") as span:
             nv_max = max(len(o) for o in orders)
             n_series_max = max(len(p.X_arr) for p in group)
             series = np.zeros(
@@ -2092,6 +2197,7 @@ class FleetBuilder:
             for i, p in enumerate(group):
                 series[i, : len(p.X_arr)] = p.X_arr
                 order[i, : len(orders[i])] = orders[i]
+            span.set(bytes=series.nbytes + order.nbytes)
         return self.trainer.predict_windowed_bucket(
             spec, stacked, series, order,
             batch_size=windowed_scoring_batch(spec), scoring=scoring,

@@ -21,7 +21,7 @@ import shutil
 import threading
 import uuid
 from os import path
-from typing import Any, NamedTuple, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 from ..telemetry.aggregate import ROLLUP_DIR, is_worker_variant
 from ..telemetry.fleet_health import FLEET_HEALTH_FILE, FLEET_HEALTH_SHARD_DIR
@@ -85,6 +85,11 @@ class Written(NamedTuple):
     bytes: int
     #: the part of it that took the helper thread
     bytes_hashed_beside_write: int
+    #: the CPU seconds of the helper threads that hashed it, which the
+    #: caller's own thread clock does not see: on the ``cpu_clock`` the
+    #: caller gave (``time.thread_time``, read on each helper), 0.0
+    #: where it gave none
+    hash_cpu_seconds: float
 
 
 class _HashingWriter:
@@ -92,13 +97,16 @@ class _HashingWriter:
     and to the md5 once, so the digest is that of the file's bytes with
     no read-back. A large buffer is hashed on a helper thread while this
     one writes it; the helper is joined before ``write`` returns, so
-    order is kept and the view outlives no call."""
+    order is kept and the view outlives no call. A helper reads
+    ``cpu_clock`` at its two ends where the caller gave one."""
 
-    def __init__(self, file):
+    def __init__(self, file, cpu_clock: Optional[Callable[[], float]] = None):
         self._file = file
+        self._cpu_clock = cpu_clock
         self.digest = hashlib.md5()
         self.bytes = 0
         self.bytes_hashed_beside_write = 0
+        self.hash_cpu_seconds = 0.0
 
     def write(self, data) -> int:
         size = memoryview(data).nbytes
@@ -109,10 +117,14 @@ class _HashingWriter:
             failed = []
 
             def hash_it():
+                began = self._cpu_clock() if self._cpu_clock else None
                 try:
                     self.digest.update(data)
                 except BaseException as exc:  # re-raised below, on the caller
                     failed.append(exc)
+                if began is not None:
+                    # joined before the next helper starts: no two add at once
+                    self.hash_cpu_seconds += self._cpu_clock() - began
 
             helper = threading.Thread(target=hash_it, name="model-pkl-md5")
             helper.start()
@@ -128,17 +140,22 @@ class _HashingWriter:
 
 
 def dump(
-    obj, dest_dir: str, metadata: Optional[dict] = None, info: Optional[dict] = None
+    obj,
+    dest_dir: str,
+    metadata: Optional[dict] = None,
+    info: Optional[dict] = None,
+    cpu_clock: Optional[Callable[[], float]] = None,
 ) -> Written:
     """
     Serialize ``obj`` into ``dest_dir`` as ``model.pkl`` (+ optional
     ``metadata.json`` / ``info.json``; info always records the model
     checksum: the md5 of ``model.pkl``'s bytes, computed while they are
-    written).
+    written). ``cpu_clock``: a thread's CPU clock for
+    ``Written.hash_cpu_seconds``, from a caller that records it.
     """
     os.makedirs(dest_dir, exist_ok=True)
     with open(path.join(dest_dir, MODEL_FILE), "wb") as f:
-        writer = _HashingWriter(f)
+        writer = _HashingWriter(f, cpu_clock)
         pickle.dump(obj, writer, protocol=MODEL_PICKLE_PROTOCOL)
     if metadata is not None:
         with open(path.join(dest_dir, METADATA_FILE), "w") as f:
@@ -148,7 +165,9 @@ def dump(
         full_info.update(info)
     with open(path.join(dest_dir, INFO_FILE), "w") as f:
         simplejson.dump(full_info, f, default=str)
-    return Written(writer.bytes, writer.bytes_hashed_beside_write)
+    return Written(
+        writer.bytes, writer.bytes_hashed_beside_write, writer.hash_cpu_seconds
+    )
 
 
 TMP_DIR_MARKER = ".tmp-"
@@ -243,6 +262,7 @@ def dump_atomic(
     dest_dir: str,
     metadata: Optional[dict] = None,
     info: Optional[dict] = None,
+    cpu_clock: Optional[Callable[[], float]] = None,
 ) -> Written:
     """
     Crash-safe :func:`dump`: artifacts are written into a
@@ -276,7 +296,9 @@ def dump_atomic(
         except FileExistsError:  # pragma: no cover - 2^32 collision
             continue
     try:
-        written = dump(obj, staging, metadata=metadata, info=info)
+        written = dump(
+            obj, staging, metadata=metadata, info=info, cpu_clock=cpu_clock
+        )
         fault_point("dump_artifact", name)
         if path.isdir(dest_dir) and not set(os.listdir(dest_dir)) <= _ARTIFACT_FILES:
             # Mixed-content dest: move each artifact file in (file-level

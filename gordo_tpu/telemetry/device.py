@@ -10,8 +10,9 @@ closes both gaps:
 - :func:`memory_snapshot` reads ``Device.memory_stats()`` off every
   local device (``bytes_in_use`` / ``peak_bytes_in_use`` / the backend's
   limit) and aggregates them into one JSON-able dict. The fleet builder
-  emits it as a ``device_utilization`` event at phase boundaries (the
-  measured counterpart of the FleetPlan's predicted HBM), and the
+  samples it at phase boundaries beside the host's own numbers
+  (:func:`sample_resources`: the measured counterpart of the FleetPlan's
+  predicted HBM, and ``build_status.json["resources"]``), and the
   Prometheus device collector reads it at scrape time. Backends without
   the stats (the CPU platform) answer ``{"available": False}`` —
   callers never branch on platform.
@@ -45,6 +46,7 @@ without an accelerator stack.
 # work) on hosts without jax
 
 import os
+import resource
 import threading
 from typing import Any, Dict, Optional
 
@@ -348,8 +350,8 @@ def memory_snapshot() -> Optional[Dict[str, Any]]:
 def utilization_snapshot() -> Dict[str, Any]:
     """The full device-telemetry document: device identity + memory +
     compile-cache counters + persistent-cache inventory (each section
-    None/absent when unavailable). This is what the
-    ``device_utilization`` events and the fleet-status surface carry."""
+    None/absent when unavailable). This is what the fleet-status
+    surface carries."""
     doc: Dict[str, Any] = {"compile_cache": program_cache_counters()}
     identity = device_identity()
     if identity is not None:
@@ -363,21 +365,17 @@ def utilization_snapshot() -> Dict[str, Any]:
     return doc
 
 
-def emit_device_utilization(recorder: Any, **attributes: Any) -> Optional[dict]:
-    """Emit one ``device_utilization`` event onto ``recorder`` (memory +
-    cache counters flattened to event attributes) and return the
-    snapshot, or None when sampling is off/unavailable. The fleet
-    builder calls this at phase boundaries — a handful of samples per
-    build, not per program."""
-    memory = memory_snapshot()
-    if memory is None:
-        return None
-    counters = program_cache_counters().get("build") or {}
-    recorder.event(
-        "device_utilization",
-        **attributes,
-        **{f"memory_{k}": v for k, v in memory.items()},
-        compiles=counters.get("compiles", 0),
-        cache_hits=counters.get("cache_hits", 0),
-    )
-    return memory
+def sample_resources() -> Dict[str, Any]:
+    """What the process holds, where a build samples it (the end of a
+    device-heavy phase: a handful of samples a build, not one a
+    program): ``memory``, the :func:`memory_snapshot` (None when sampling
+    is off or unavailable); ``host_rss_peak_bytes``, the largest resident
+    set the process has had so far (``getrusage``'s ``ru_maxrss``, which
+    Linux counts in KiB); and ``host_cpu_count``, the cores it may run
+    on, which is what its CPU seconds are to be held against."""
+    return {
+        "memory": memory_snapshot(),
+        "host_rss_peak_bytes": resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
+        * 1024,
+        "host_cpu_count": len(os.sched_getaffinity(0)),
+    }

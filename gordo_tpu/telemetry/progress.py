@@ -32,7 +32,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from .recorder import _iso
+from .recorder import PART_SUMS, PHASE_SUMS, _iso, add_sums
 
 logger = logging.getLogger(__name__)
 
@@ -102,10 +102,19 @@ class BuildProgress:
 
             heartbeat_seconds = env_float(HEARTBEAT_ENV, DEFAULT_HEARTBEAT_SECONDS)
         self.heartbeat_seconds = max(0.0, heartbeat_seconds)
-        #: phase -> part -> [seconds, count]: what ``build_part`` spans
-        #: measured inside a phase, summed over the threads that did it
-        #: (so a pooled phase's parts can exceed its wall ``seconds``)
-        self._parts: Dict[str, Dict[str, List[float]]] = {}
+        #: phase -> part -> {seconds, count, ...}: what ``build_part``
+        #: spans measured inside a phase, summed over the threads that
+        #: did it (so a pooled phase's parts can exceed its wall
+        #: ``seconds``); beside the two, each of ``PART_SUMS`` a span gave
+        self._parts: Dict[str, Dict[str, Dict[str, float]]] = {}
+        #: phase -> {cpu_seconds, process_cpu_seconds}: the CPU seconds
+        #: of the thread that ran the phase and of the whole process
+        #: between the phase's two ends (``add_phase_cpu``)
+        self._phase_cpu: Dict[str, Dict[str, float]] = {}
+        #: the newest resource sample (``hbm_peak_bytes``,
+        #: ``host_rss_peak_bytes``, ``host_cpu_count``), set by the
+        #: builder where it samples the device
+        self.resources: Optional[Dict[str, Any]] = None
         #: what the build spent tracing, lowering, compiling and loading
         #: programs (``telemetry.device.compile_path_counters`` deltas),
         #: set by the builder at build end
@@ -139,14 +148,27 @@ class BuildProgress:
             self.write(min_interval=self.PHASE_REENTRY_INTERVAL)
 
     def add_part(
-        self, phase: str, part: str, seconds: float, count: int = 1
+        self, phase: str, part: str, seconds: float, count: int = 1, **sums: float
     ) -> None:
-        """Fold one ``build_part`` span into ``phases[phase]["parts"]``;
-        nothing is written until the next heartbeat or phase entry."""
+        """Fold one ``build_part`` span into ``phases[phase]["parts"]``:
+        its seconds and ``count``, and of ``PART_SUMS`` (``cpu_seconds``,
+        ``bytes``, ``d2h_seconds``) what the span gave, so an entry has
+        such a key only where a span had it. Nothing is written until
+        the next heartbeat or phase entry."""
         with self._lock:
-            entry = self._parts.setdefault(phase, {}).setdefault(part, [0.0, 0])
-            entry[0] += seconds
-            entry[1] += count
+            entry = self._parts.setdefault(phase, {}).setdefault(
+                part, {"seconds": 0.0, "count": 0}
+            )
+            entry["seconds"] += seconds
+            entry["count"] += count
+            add_sums(entry, PART_SUMS, sums)
+
+    def add_phase_cpu(self, phase: str, **cpu: Optional[float]) -> None:
+        """Fold one ``build_phase`` span's ``cpu_seconds`` (its own
+        thread's) and ``process_cpu_seconds`` (every thread's) into the
+        phase's entry, summed over its re-entries."""
+        with self._lock:
+            add_sums(self._phase_cpu.setdefault(phase, {}), PHASE_SUMS, cpu)
 
     def add_fit_counters(self, counters: Dict[str, Any]) -> None:
         """Keep one fit program's own counters (what its
@@ -184,12 +206,27 @@ class BuildProgress:
                 }
                 for name in self._phase_order
             }
+            for name, cpu in self._phase_cpu.items():
+                if name in phases:
+                    phases[name].update(
+                        {key: round(value, 6) for key, value in cpu.items()}
+                    )
             for name, parts in self._parts.items():
                 if name in phases:
                     phases[name]["parts"] = {
-                        part: {"seconds": round(seconds, 6), "count": int(count)}
-                        for part, (seconds, count) in parts.items()
+                        part: {
+                            key: int(value)
+                            if key in ("count", "bytes")
+                            else round(value, 6)
+                            for key, value in entry.items()
+                        }
+                        for part, entry in parts.items()
                     }
+            resources = (
+                {"resources": dict(self.resources)}
+                if self.resources is not None
+                else {}
+            )
             compile_path = (
                 {"compile": dict(self.compile)} if self.compile is not None else {}
             )
@@ -216,6 +253,7 @@ class BuildProgress:
                 "robustness": {k: int(v) for k, v in self.robustness.items()},
                 "device": self.device,
                 "phases": phases,
+                **resources,
                 **compile_path,
                 **fit_counters,
             }
@@ -294,6 +332,56 @@ def eta_seconds(doc: Dict[str, Any]) -> Optional[float]:
     return remaining * elapsed / completed
 
 
+def _gigabytes(count: float) -> str:
+    return f"{float(count) / 1e9:.2f} GB"
+
+
+def part_rates_text(measured: Dict[str, Any]) -> str:
+    """What a part's ``cpu_seconds``, ``bytes`` and ``d2h_seconds`` say
+    beside its seconds (``build-status`` and ``gordo-tpu trace`` print
+    it): the share of its seconds a CPU was computing for it (the rest
+    it waited: for the GIL, for I/O, for the device, for a core), its
+    GB/s where it moved bytes, and a ``collect``'s fetch alone. Empty
+    where the part carries none of them."""
+    seconds = float(measured.get("seconds") or 0.0)
+    shown = []
+    if "cpu_seconds" in measured and seconds > 0:
+        shown.append(f"cpu {100.0 * float(measured['cpu_seconds']) / seconds:.0f}%")
+    if measured.get("bytes"):
+        if seconds > 0:
+            shown.append(f"{float(measured['bytes']) / 1e9 / seconds:.2f} GB/s")
+        else:
+            shown.append(_gigabytes(measured["bytes"]))
+    if measured.get("d2h_seconds"):
+        rate = (
+            f" at {float(measured['bytes']) / 1e9 / measured['d2h_seconds']:.2f} GB/s"
+            if measured.get("bytes")
+            else ""
+        )
+        shown.append(f"d2h {float(measured['d2h_seconds']):.2f} s{rate}")
+    return f"  [{', '.join(shown)}]" if shown else ""
+
+
+def cores_busy_text(entry: Dict[str, Any]) -> str:
+    """What a phase's two CPU clocks say beside its seconds
+    (``build-status`` and ``gordo-tpu trace`` print it): the cores it
+    kept busy (the process's CPU seconds between its two ends over its
+    wall seconds) and the share of its seconds its own thread computed.
+    A phase whose own thread reads near 100% is bound by the builder's
+    one thread; a low share beside many cores is a pool or the runtime
+    at work; both low, the host waited (for the device, for a lock).
+    Empty where the phase has neither."""
+    seconds = float(entry.get("seconds") or 0.0)
+    if seconds <= 0:
+        return ""
+    shown = []
+    if "process_cpu_seconds" in entry:
+        shown.append(f"{float(entry['process_cpu_seconds']) / seconds:.2f} cores busy")
+    if "cpu_seconds" in entry:
+        shown.append(f"own thread cpu {100.0 * float(entry['cpu_seconds']) / seconds:.0f}%")
+    return f"  [{', '.join(shown)}]" if shown else ""
+
+
 def render_status(doc: Dict[str, Any]) -> str:
     """Human rendering of a build-status document (the ``build-status``
     CLI's output): header, progress bar + ETA, per-phase table."""
@@ -349,14 +437,25 @@ def render_status(doc: Dict[str, Any]) -> str:
             lines.append(
                 f"  {name.ljust(name_width)}  "
                 f"{float(entry.get('seconds', 0.0)):9.2f}  "
-                f"{entry.get('status', '')}"
+                f"{entry.get('status', '')}{cores_busy_text(entry)}"
             )
             for part, measured in (entry.get("parts") or {}).items():
                 lines.append(
                     f"    {part.ljust(max(0, name_width - 2))}  "
                     f"{float(measured.get('seconds', 0.0)):9.2f}  "
                     f"x{measured.get('count', 0)} (thread-seconds)"
+                    f"{part_rates_text(measured)}"
                 )
+    resources = doc.get("resources")
+    if resources:
+        lines.append(
+            "Resources: "
+            + ", ".join(
+                f"{key}={_gigabytes(value)}" if key.endswith("_bytes") else f"{key}={value}"
+                for key, value in resources.items()
+                if value is not None
+            )
+        )
     compile_path = doc.get("compile")
     if compile_path:
         lines.append(

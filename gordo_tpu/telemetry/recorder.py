@@ -57,6 +57,24 @@ KEEP_ENV = "GORDO_TPU_TELEMETRY_KEEP"
 DEFAULT_MAX_BYTES = 256 * 1024 * 1024
 DEFAULT_KEEP = 3
 
+#: what a ``build_part`` span may carry beside its seconds and ``count``,
+#: summed into its entry of ``build_status.json``: the CPU seconds of the
+#: thread (or, recorded as a sum, the threads) that ran it, the bytes it
+#: moved, and inside ``collect`` the seconds of the device-to-host fetch
+#: alone
+PART_SUMS = ("cpu_seconds", "bytes", "d2h_seconds")
+#: and a ``build_phase`` span beside its seconds: its own thread's CPU
+#: seconds and the whole process's between its two ends
+PHASE_SUMS = ("cpu_seconds", "process_cpu_seconds")
+
+
+def add_sums(entry: Dict[str, Any], keys, given: Dict[str, Any]) -> None:
+    """Add to ``entry`` each of ``keys`` that ``given`` has (and is not
+    None): an entry gets such a key only where a span gave it."""
+    for key in keys:
+        if given.get(key) is not None:
+            entry[key] = entry.get(key, 0) + given[key]
+
 #: per-process sink split: when on, process-owned telemetry sinks
 #: (``serve_trace.jsonl``, ``fleet_health.json``) get a ``-<pid>``
 #: suffix so N gunicorn workers stop clobbering one shared path — the
@@ -133,6 +151,11 @@ class SpanHandle:
 
     __slots__ = ("attributes", "links", "trace_id", "span_id")
 
+    #: False on the null recorder's handle: a caller that would compute
+    #: something only to ``set`` it (a walk over a tree for its bytes, a
+    #: second clock) asks first
+    recording = True
+
     def __init__(
         self,
         attributes: Dict[str, Any],
@@ -163,6 +186,14 @@ class SpanHandle:
         return self
 
 
+class NullHandle(SpanHandle):
+    """What a span of the null recorder yields: takes what it is given
+    and keeps it for nobody."""
+
+    __slots__ = ()
+    recording = False
+
+
 class NullRecorder:
     """The do-nothing recorder: spans yield a throwaway handle and
     record nothing. Shared process-wide default."""
@@ -174,14 +205,25 @@ class NullRecorder:
     annotate = None
 
     @contextlib.contextmanager
-    def span(self, name: str, parent_id: Optional[str] = None, **attributes):
-        yield SpanHandle({})
+    def span(
+        self,
+        name: str,
+        parent_id: Optional[str] = None,
+        cpu_clock: bool = False,
+        **attributes,
+    ):
+        yield NullHandle({})
 
     def event(self, name: str, **attributes) -> None:
         pass
 
     def record(
-        self, name: str, seconds: float, start: Optional[float] = None, **attributes
+        self,
+        name: str,
+        seconds: float,
+        start: Optional[float] = None,
+        cpu_seconds: Optional[float] = None,
+        **attributes,
     ) -> None:
         pass
 
@@ -304,13 +346,25 @@ class SpanRecorder:
         return stack
 
     @contextlib.contextmanager
-    def span(self, name: str, parent_id: Optional[str] = None, **attributes):
+    def span(
+        self,
+        name: str,
+        parent_id: Optional[str] = None,
+        cpu_clock: bool = False,
+        **attributes,
+    ):
         """Record the enclosed block as one span; exceptions mark the
         span ``ERROR`` (with the exception repr) and propagate. The
         parent is ``parent_id`` where given, else the span enclosing
         this one on the calling thread. The start stamp is wall time;
         the duration is taken from ``time.perf_counter()``, which no
-        clock step can stretch."""
+        clock step can stretch. With ``cpu_clock`` (the build path's
+        spans ask for it: :func:`part_span`, :func:`program_span`, the
+        fleet builder's phases and parts; a serving span pays no second
+        clock) the span also carries ``cpu_seconds``: what
+        ``time.thread_time()`` moved by on the calling thread, that is
+        the CPU this thread used (C code that released the GIL
+        included), not what other threads used meanwhile."""
         span_id = rand_hex(16)
         handle = SpanHandle(dict(attributes), self.trace_id, span_id)
         stack = self._stack()
@@ -322,6 +376,7 @@ class SpanRecorder:
             else None
         )
         stack.append(span_id)
+        cpu_started = time.thread_time() if cpu_clock else None
         start = time.time()
         started = time.perf_counter()
         error: Optional[BaseException] = None
@@ -336,6 +391,10 @@ class SpanRecorder:
             raise
         finally:
             end = start + (time.perf_counter() - started)
+            if cpu_started is not None:
+                handle.attributes["cpu_seconds"] = round(
+                    time.thread_time() - cpu_started, 6
+                )
             stack.pop()
             self._record(
                 self._span_dict(
@@ -368,10 +427,17 @@ class SpanRecorder:
         )
 
     def record(
-        self, name: str, seconds: float, start: Optional[float] = None, **attributes
+        self,
+        name: str,
+        seconds: float,
+        start: Optional[float] = None,
+        cpu_seconds: Optional[float] = None,
+        **attributes,
     ) -> None:
         """An externally-timed interval as a finished span: ending now,
-        or beginning at the wall-clock stamp ``start``.
+        or beginning at the wall-clock stamp ``start``; with
+        ``cpu_seconds`` where the thread that did the work also read its
+        CPU clock (``time.thread_time()``) around it.
 
         For durations measured on ANOTHER thread's clock — e.g. a
         request handler folding the micro-batcher's shared stack/device
@@ -383,6 +449,8 @@ class SpanRecorder:
         seconds = max(0.0, seconds)
         end = time.time() if start is None else start + seconds
         stack = self._stack()
+        if cpu_seconds is not None:
+            attributes["cpu_seconds"] = round(max(0.0, cpu_seconds), 6)
         self._record(
             self._span_dict(
                 name,
@@ -734,16 +802,28 @@ def reset_seen_programs() -> None:
         _seen_programs.clear()
 
 
+def _suffixed(attributes: Dict[str, Any], suffix: str) -> Dict[str, float]:
+    """``<part><suffix>`` numeric attributes as part -> value."""
+    return {
+        key[: -len(suffix)]: float(value)
+        for key, value in attributes.items()
+        if key.endswith(suffix) and isinstance(value, (int, float))
+    }
+
+
 def nested_part_seconds(attributes: Dict[str, Any]) -> Dict[str, float]:
     """The parts a ``build_part`` span carries as attributes instead of
     child spans: ``<part>_s`` -> seconds. ``machine_fetch`` carries the
     dataset's own parts so, because a span each, written from sixteen
     pool threads, cost more than it told (PERF.md, PR 24)."""
-    return {
-        key[:-2]: float(value)
-        for key, value in attributes.items()
-        if key.endswith("_s") and isinstance(value, (int, float))
-    }
+    return _suffixed(attributes, "_s")
+
+
+def nested_part_cpu_seconds(attributes: Dict[str, Any]) -> Dict[str, float]:
+    """The CPU seconds of those nested parts, the pair of each
+    ``<part>_s``: ``<part>_cpu_seconds`` -> seconds (a spelling
+    :func:`nested_part_seconds` does not take for a part of its own)."""
+    return _suffixed(attributes, "_cpu_seconds")
 
 
 def part_span(part: str, **attributes):
@@ -754,7 +834,9 @@ def part_span(part: str, **attributes):
     ``FleetBuilder._part`` is the same span with the phase's span as
     explicit parent, for pool threads."""
     recorder = get_recorder()
-    return recorder.span("build_part", phase=recorder.phase, part=part, **attributes)
+    return recorder.span(
+        "build_part", cpu_clock=True, phase=recorder.phase, part=part, **attributes
+    )
 
 
 def program_span(program: str, key: Hashable, **attributes):
@@ -773,5 +855,9 @@ def program_span(program: str, key: Hashable, **attributes):
 
     note_program_execution(compile_flag, kind="build")
     return get_recorder().span(
-        "device_program", program=program, compile=compile_flag, **attributes
+        "device_program",
+        cpu_clock=True,
+        program=program,
+        compile=compile_flag,
+        **attributes,
     )

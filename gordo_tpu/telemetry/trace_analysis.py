@@ -462,7 +462,12 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     program run in the phase is listed as ``program <name>``, a fit
     program with the ``validation_slots`` of its buckets summed and, for
     the dense fit, the widest ``shuffle_columns`` among them) and its
-    ``self_seconds``, the wall time no part or program covers. A part
+    ``self_seconds``, the wall time no part or program covers. Where its
+    spans carry them, a phase also has ``cpu_seconds`` (its own thread's)
+    and ``process_cpu_seconds`` (every thread's between its two ends),
+    and a part ``cpu_seconds``, ``bytes`` and, a ``collect``, the
+    ``d2h_seconds`` of its fetch alone: an attribute of the ``collect``
+    entry and no part of its own, so nothing counts it twice. A part
     covers its own interval (overlapping parts count once); a part
     recorded as a sum over many pieces (``count`` attribute) covers its
     seconds. Only parts whose parent is the phase cover it: a part
@@ -474,7 +479,13 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     """
     from .aggregate import parse_span_time
     from .device import COMPILE_PATH_KEYS
-    from .recorder import nested_part_seconds
+    from .recorder import (
+        PART_SUMS,
+        PHASE_SUMS,
+        add_sums,
+        nested_part_cpu_seconds,
+        nested_part_seconds,
+    )
 
     phases: Dict[str, Dict[str, Any]] = {}
     phase_spans: Dict[str, Tuple[str, float, float]] = {}
@@ -491,6 +502,7 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
             )
             entry["entries"] += 1
             entry["seconds"] += seconds
+            add_sums(entry, PHASE_SUMS, attributes)
             start = parse_span_time(span.get("start_time")) or 0.0
             span_id = (span.get("context") or {}).get("span_id", "")
             phase_spans[span_id] = (phase, start, start + seconds)
@@ -505,10 +517,11 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     covering: Dict[str, List[Tuple[float, float]]] = {}
     summed: Dict[str, float] = {}
 
-    def add(phase: str, label: str, seconds: float, count: int) -> None:
+    def add(phase: str, label: str, seconds: float, count: int, sums) -> None:
         part = phases[phase]["parts"].setdefault(label, {"seconds": 0.0, "count": 0})
         part["seconds"] += seconds
         part["count"] += count
+        add_sums(part, PART_SUMS, sums)
 
     for span in parts:
         attributes = span.get("attributes") or {}
@@ -522,7 +535,14 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
             phase = str(attributes.get("phase", ""))
             label = str(attributes.get("part", ""))
         if phase in phases:
-            add(phase, label, seconds, int(attributes.get("count", 1)))
+            sums = (
+                attributes
+                if span["name"] == "build_part"
+                # a program's ``bytes`` are its bucket's staged size, not
+                # what the span moved
+                else {"cpu_seconds": attributes.get("cpu_seconds")}
+            )
+            add(phase, label, seconds, int(attributes.get("count", 1)), sums)
             part = phases[phase]["parts"][label]
             if "validation_slots" in attributes:  # a fit program's span
                 part["validation_slots"] = part.get("validation_slots", 0) + int(
@@ -533,8 +553,12 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
                     part.get("shuffle_columns", 0), int(attributes["shuffle_columns"])
                 )
             if span["name"] == "build_part":
+                nested_cpu = nested_part_cpu_seconds(attributes)
                 for nested, nested_seconds in nested_part_seconds(attributes).items():
-                    add(phase, nested, nested_seconds, 1)
+                    add(
+                        phase, nested, nested_seconds, 1,
+                        {"cpu_seconds": nested_cpu.get(nested)},
+                    )
         if parent not in phase_spans:
             continue
         if "count" in attributes:
@@ -549,10 +573,13 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
         covered = _covered_seconds(covering.get(span_id, [])) + summed.get(span_id, 0.0)
         phases[phase]["self_seconds"] += max(0.0, (end - start) - covered)
     for entry in phases.values():
-        entry["seconds"] = round(entry["seconds"], 6)
-        entry["self_seconds"] = round(entry["self_seconds"], 6)
+        for key in ("seconds", "self_seconds") + PHASE_SUMS:
+            if key in entry:
+                entry[key] = round(entry[key], 6)
         for part in entry["parts"].values():
-            part["seconds"] = round(part["seconds"], 6)
+            for key in ("seconds", "cpu_seconds", "d2h_seconds"):
+                if key in part:
+                    part[key] = round(part[key], 6)
     return {"phases": phases, "compile": compile_path}
 
 
@@ -803,14 +830,20 @@ def render_analysis(doc: Dict[str, Any]) -> str:
 
     build = doc.get("build_breakdown")
     if build:
+        from .progress import cores_busy_text, part_rates_text
+
         out.append(
             "\nBuild phases (seconds; self = wall time no part covers; "
-            "parts in thread-seconds):"
+            "parts in thread-seconds; cpu = the share of a part's seconds "
+            "its threads computed):"
         )
         rows: List[List[Any]] = []
         for phase, entry in build["phases"].items():
             rows.append(
-                [phase, entry["entries"], entry["seconds"], entry["self_seconds"]]
+                [
+                    phase, entry["entries"], entry["seconds"], entry["self_seconds"],
+                    cores_busy_text(entry).strip(),
+                ]
             )
             for part, measured in entry["parts"].items():
                 counters = [
@@ -821,9 +854,14 @@ def render_analysis(doc: Dict[str, Any]) -> str:
                 if counters:
                     part += f" [{', '.join(counters)}]"
                 rows.append(
-                    [f"  {part}", measured["count"], measured["seconds"], ""]
+                    [
+                        f"  {part}", measured["count"], measured["seconds"], "",
+                        part_rates_text(measured).strip(),
+                    ]
                 )
-        out.append(_table(rows, ["phase / part", "count", "seconds", "self"]))
+        out.append(
+            _table(rows, ["phase / part", "count", "seconds", "self", "cpu, rate"])
+        )
         if build.get("compile"):
             out.append(
                 "compile path: "

@@ -330,3 +330,66 @@ def test_the_dump_phase_says_what_it_wrote(tmp_path):
     assert written[0]["bytes_hashed_beside_write"] == 0
     for n in names:
         assert journal.artifact_complete(str(out / n))
+
+
+# -- what the host computed, moved and waited for (PR 37) ---------------------
+
+
+@pytest.fixture(scope="module")
+def accounted(tmp_path_factory):
+    """One tiny CPU build of two machines: its status and its spans."""
+    out = str(tmp_path_factory.mktemp("accounted") / "out")
+    FleetBuilder([make_machine("acc-a"), make_machine("acc-b")]).build(output_dir=out)
+    return telemetry.load_status(out), read_trace(out)
+
+
+#: every key PR 37 added to ``build_status.json``, as a path into it
+NEW_STATUS_KEYS = [
+    ("resources", "hbm_peak_bytes"),
+    ("resources", "host_rss_peak_bytes"),
+    ("resources", "host_cpu_count"),
+    ("phases", "data_fetch", "cpu_seconds"),
+    ("phases", "data_fetch", "process_cpu_seconds"),
+    ("phases", "cv_train", "process_cpu_seconds"),
+    ("phases", "data_fetch", "parts", "machine_fetch", "cpu_seconds"),
+    ("phases", "data_fetch", "parts", "resample_join", "cpu_seconds"),
+    ("phases", "data_fetch", "parts", "provider_read", "cpu_seconds"),
+    ("phases", "cv_train", "parts", "stack", "bytes"),
+    ("phases", "cv_train", "parts", "stack", "cpu_seconds"),
+    ("phases", "cv_train", "parts", "h2d", "bytes"),
+    ("phases", "cv_train", "parts", "collect", "bytes"),
+    ("phases", "cv_train", "parts", "collect", "d2h_seconds"),
+    ("phases", "final_fit", "parts", "stack", "bytes"),
+    ("phases", "final_fit", "parts", "collect", "d2h_seconds"),
+    ("phases", "cv_predict", "parts", "collect", "bytes"),
+    ("phases", "dump", "parts", "write", "cpu_seconds"),
+    ("phases", "dump", "parts", "write", "bytes"),
+    ("phases", "dump", "parts", "serialize", "cpu_seconds"),
+]
+
+
+@pytest.mark.parametrize("path", NEW_STATUS_KEYS, ids="/".join)
+def test_a_tiny_build_yields_every_key_of_the_hosts_accounting(accounted, path):
+    node, _ = accounted
+    for key in path:
+        assert key in node, path
+        node = node[key]
+    if path[-1] != "hbm_peak_bytes":  # the CPU backend reports no memory
+        assert isinstance(node, (int, float)) and node >= 0
+
+
+def test_the_accounting_adds_no_line_and_no_span_name(accounted):
+    """The spans a job wrote before carry the new attributes; the
+    ``device_utilization`` events went (their numbers are in the
+    status's ``resources``), and nothing else was added."""
+    _, spans = accounted
+    assert {s["name"] for s in spans} == {
+        "fleet_build", "build_phase", "build_part", "device_program",
+        "member_trained", "machine_built", "fleet_plan", "fleet_plan_accuracy",
+    }
+    collects = [
+        s["attributes"] for s in spans
+        if s["name"] == "build_part" and s["attributes"]["part"] == "collect"
+    ]
+    assert len(collects) == 3  # cv fit, cv predict, final fit
+    assert all(a["d2h_seconds"] > 0 and a["bytes"] > 0 for a in collects)

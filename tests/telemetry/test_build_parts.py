@@ -4,12 +4,14 @@ they leave in ``build_status.json``, the compile path's counters, the
 profiler annotations on the spans, and what all of it may cost in lines.
 """
 
+import ast
 import concurrent.futures
 import glob
 import json
 import os
 import time
 
+import numpy as np
 import pytest
 
 from gordo_tpu import telemetry
@@ -34,6 +36,13 @@ MODEL = {
         }
     }
 }
+
+
+#: what two reads of two clocks may differ by where nothing ran between
+#: ("to clock resolution"): microseconds on plain Linux, but a kernel
+#: that accounts CPU by scheduler ticks steps ``time.thread_time()`` by
+#: 10 ms (the chip's host does: PERF.md 6, PR 37), so two ticks
+CLOCK_SLACK = 0.025
 
 #: parts a dense job must record, by phase
 REQUIRED_PARTS = {
@@ -190,12 +199,130 @@ def test_part_span_stamps_the_recorders_phase():
         with telemetry.part_span("stack", rows=3):
             pass
     (span,) = rec.finished("build_part")
+    cpu = span["attributes"].pop("cpu_seconds")  # the recorder's, on every part
+    assert 0.0 <= cpu <= span["duration_ms"] / 1000.0 + CLOCK_SLACK
     assert span["attributes"] == {"phase": "cv_train", "part": "stack", "rows": 3}
     # without a build the same call records nothing and costs nothing
     with telemetry.part_span("stack"):
         pass
     assert telemetry.get_recorder() is telemetry.NULL_RECORDER
     assert telemetry.NULL_RECORDER.phase == ""
+
+
+
+# -- the second clock -------------------------------------------------------------
+
+
+def _spin(cpu_seconds):
+    """Compute until this thread has used ``cpu_seconds`` of CPU."""
+    began = time.thread_time()
+    while time.thread_time() - began < cpu_seconds:
+        pass
+
+
+def test_a_part_that_spins_reads_cpu_near_its_wall_and_one_that_sleeps_near_none():
+    rec = SpanRecorder()
+    with rec.span("build_part", cpu_clock=True, part="spin"):
+        _spin(0.1)
+    with rec.span("build_part", cpu_clock=True, part="sleep"):
+        time.sleep(0.1)
+    spin, sleep = rec.finished("build_part")
+    wall = spin["duration_ms"] / 1000.0
+    # all of its seconds but what the host took this thread off the core for
+    assert 0.1 <= spin["attributes"]["cpu_seconds"] <= wall + CLOCK_SLACK
+    assert spin["attributes"]["cpu_seconds"] >= 0.2 * wall
+    assert sleep["duration_ms"] >= 100.0
+    assert sleep["attributes"]["cpu_seconds"] <= CLOCK_SLACK
+
+
+def test_only_a_span_that_asks_reads_the_cpu_clock_and_the_build_paths_ask():
+    rec = SpanRecorder()
+    with rec.span("serve_batch"):
+        pass
+    with rec.span("serve_batch", cpu_clock=True):
+        pass
+    plain, timed = rec.finished("serve_batch")
+    assert "cpu_seconds" not in plain["attributes"]
+    assert timed["attributes"].keys() == {"cpu_seconds"}
+    # the build path's four ways to open a span all ask
+    builder = FleetBuilder([make_machine("cpu-b")])
+    builder.recorder = rec
+    with telemetry.activate(rec):
+        with builder._phase("stage"):
+            with builder._part("on-builder"):
+                pass
+            with telemetry.part_span("on-trainer"):
+                pass
+            with telemetry.program_span("fleet_fit", ("cpu-clock-test",)):
+                pass
+    asked = rec.finished("build_phase") + rec.finished("build_part") + rec.finished("device_program")
+    assert len(asked) == 4 and all("cpu_seconds" in s["attributes"] for s in asked)
+    assert "process_cpu_seconds" in rec.finished("build_phase")[0]["attributes"]
+    # work timed in place hands its own reading over, rounded, never negative
+    rec.record("build_part", 1.0, cpu_seconds=0.25000049, part="write", count=3)
+    rec.record("build_part", 1.0, cpu_seconds=-1e-9, part="serialize")
+    rec.record("build_part", 1.0, part="plain")
+    write, serialize, plain = rec.finished("build_part")[2:]
+    assert write["attributes"] == {"part": "write", "count": 3, "cpu_seconds": 0.25}
+    assert serialize["attributes"]["cpu_seconds"] == 0.0
+    assert "cpu_seconds" not in plain["attributes"]
+
+
+def test_a_build_that_records_nothing_reads_no_second_clock_and_walks_no_tree(monkeypatch):
+    """Under the null recorder a span's handle says it records nothing,
+    so the sites that would compute something only to ``set`` it (a
+    tree's bytes, the fetch's seconds) skip it, and work timed in place
+    is timed on a clock that stands still."""
+    import gordo_tpu.parallel.fleet as fleet
+
+    with telemetry.NULL_RECORDER.span("build_part", cpu_clock=True) as handle:
+        assert handle.recording is False
+        handle.set(bytes=1)  # taken, kept for nobody
+    with SpanRecorder().span("build_part") as handle:
+        assert handle.recording is True
+    builder = FleetBuilder([make_machine("cpu-c")])
+    assert builder.recorder is telemetry.NULL_RECORDER
+    clock = builder._cpu_clock()
+    assert clock() == clock() == 0.0
+    builder.recorder = SpanRecorder()
+    assert builder._cpu_clock() is time.thread_time
+
+    def no_walk(*trees, **kwargs):
+        raise AssertionError("a tree was walked for a span nobody records")
+
+    monkeypatch.setattr(fleet, "tree_nbytes", no_walk)
+    tree = {"w": np.ones((2, 3), np.float32)}
+    with telemetry.part_span("collect") as span:
+        host = fleet._fetch_for(span, tree)
+    np.testing.assert_array_equal(host["w"], tree["w"])
+
+
+def test_a_pool_of_four_spinning_parts_sums_four_threads_cpu(tmp_path):
+    """Under the GIL the four take turns, so each part's wall seconds are
+    about the four's CPU together; the CPU clock is each thread's own."""
+    builder = FleetBuilder([make_machine("cpu-a")])
+    builder.recorder = rec = SpanRecorder()
+    rec.add_listener(builder._export_span)
+    builder.progress = BuildProgress(str(tmp_path), project="p", total=1)
+
+    def work(_):
+        with builder._part("spin"):
+            _spin(0.1)
+
+    with builder._phase("dump"):
+        with concurrent.futures.ThreadPoolExecutor(4) as pool:
+            list(pool.map(work, range(4)))
+    spans = [s for s in rec.finished("build_part")]
+    assert len(spans) == 4
+    for span in spans:
+        assert 0.1 <= span["attributes"]["cpu_seconds"] <= span["duration_ms"] / 1000.0 + CLOCK_SLACK
+    builder.progress.write(force=True)
+    phase = load_status(str(tmp_path))["phases"]["dump"]
+    folded = phase["parts"]["spin"]
+    assert folded["count"] == 4 and 0.4 <= folded["cpu_seconds"] <= folded["seconds"] + 4 * CLOCK_SLACK
+    # the phase's own thread only waited; the process computed the four's sum
+    assert phase["cpu_seconds"] < 0.1
+    assert phase["process_cpu_seconds"] >= 0.4
 
 
 # -- the builder ----------------------------------------------------------------
@@ -212,6 +339,8 @@ def test_builder_part_from_a_pool_thread_hangs_under_the_running_phase():
     (phase,) = rec.finished("build_phase")
     parts = {s["attributes"]["part"]: s for s in rec.finished("build_part")}
     assert parts["on-main"]["parent_id"] == parts["whole"]["parent_id"] == phase["context"]["span_id"]
+    cpu = parts["whole"]["attributes"].pop("cpu_seconds")  # every part's, from its own thread
+    assert 0.0 <= cpu <= parts["whole"]["duration_ms"] / 1000.0 + CLOCK_SLACK
     assert parts["whole"]["attributes"] == {"phase": "dump", "part": "whole"}
     assert rec.phase == "" and builder._phase_span_id is None  # restored at the phase's end
 
@@ -265,13 +394,23 @@ def test_machine_fetch_carries_machine_rows_retries_and_the_datasets_parts(built
     for span in fetches:
         attributes = span["attributes"]
         assert set(attributes) == {
-            "phase", "part", "machine", "rows", "retries",
+            "phase", "part", "machine", "rows", "retries", "cpu_seconds",
             "provider_read_s", "resample_join_s", "row_filter_s",
+            "provider_read_cpu_seconds", "resample_join_cpu_seconds",
+            "row_filter_cpu_seconds",
         }
         assert attributes["rows"] > 0 and attributes["retries"] == 0
         nested = telemetry.nested_part_seconds(attributes)
         assert set(nested) == {"provider_read", "resample_join", "row_filter"}
         assert 0 < sum(nested.values()) <= span["duration_ms"] / 1000.0
+        # the pool thread read its CPU clock where it read the wall clock:
+        # the folding rule takes no CPU attribute for a part of its own
+        nested_cpu = telemetry.nested_part_cpu_seconds(attributes)
+        assert set(nested_cpu) == set(nested)
+        for part, seconds in nested_cpu.items():
+            assert 0.0 <= seconds <= nested[part] + CLOCK_SLACK
+        assert sum(nested_cpu.values()) <= attributes["cpu_seconds"] + CLOCK_SLACK
+        assert attributes["cpu_seconds"] <= span["duration_ms"] / 1000.0 + CLOCK_SLACK
     assert not [
         s for s in spans
         if s["name"] == "build_part" and s["attributes"]["part"] == "provider_read"
@@ -340,13 +479,102 @@ def test_cv_score_counts_the_machine_folds_that_fell_back(tmp_path):
         program = _fleet_predict_score_program(results[0][0].base_estimator.spec_, None)
         compiled.append((predict["attributes"]["compile"], program, program._cache_size()))
     (_, first, size), (compile_flag, second, size_after) = compiled
-    assert compile_flag is False and second is first and size_after == size == 1
+    # (>= 1: another test file's builds in this process may have compiled
+    # the program for their shapes before)
+    assert compile_flag is False and second is first and size_after == size >= 1
+
+
+def test_no_part_written_by_one_thread_computed_longer_than_it_took(built):
+    """``cpu_seconds <= seconds`` on every ``build_part`` span but the
+    sums over a pool's threads (``count``), and on every phase's own
+    thread; the fetch alone is no longer than its ``collect``."""
+    _, spans, status = built
+    parts = [s for s in spans if s["name"] == "build_part"]
+    assert all("cpu_seconds" in s["attributes"] for s in parts)
+    for span in parts:
+        if "count" not in span["attributes"]:
+            assert (
+                0.0 <= span["attributes"]["cpu_seconds"]
+                <= span["duration_ms"] / 1000.0 + CLOCK_SLACK
+            ), span["attributes"]
+    for span in spans:
+        if span["name"] == "build_phase" and "cpu_seconds" in span["attributes"]:
+            assert span["attributes"]["cpu_seconds"] <= span["duration_ms"] / 1000.0 + CLOCK_SLACK
+            assert span["attributes"]["process_cpu_seconds"] >= 0.0
+    collects = [s for s in parts if s["attributes"]["part"] == "collect"]
+    assert len(collects) == 3  # cv fit, cv predict, final fit
+    for span in collects:
+        assert 0.0 < span["attributes"]["d2h_seconds"] <= span["duration_ms"] / 1000.0
+        assert span["attributes"]["bytes"] > 0
+
+
+PARTS_WITH_BYTES = [
+    ("cv_train", "stack"), ("cv_train", "h2d"), ("cv_train", "collect"),
+    ("final_fit", "stack"), ("final_fit", "h2d"), ("final_fit", "collect"),
+    ("cv_predict", "stack"), ("cv_predict", "h2d"), ("cv_predict", "collect"),
+    ("cv_score", "stack"), ("dump", "write"),
+]
+
+
+@pytest.mark.parametrize("phase,part", PARTS_WITH_BYTES)
+def test_status_keeps_the_bytes_and_the_cpu_of_a_part_that_moved_data(built, phase, part):
+    _, _, status = built
+    measured = status["phases"][phase]["parts"][part]
+    assert isinstance(measured["bytes"], int) and measured["bytes"] > 0
+    assert measured["cpu_seconds"] >= 0.0
+    if part == "collect":
+        # the fetch alone rides on the entry: the entry's seconds are the
+        # whole part still, and no part of the phase is the fetch again
+        assert 0.0 < measured["d2h_seconds"] <= measured["seconds"]
+        assert not {"d2h", "d2h_seconds"} & set(status["phases"][phase]["parts"])
+    else:
+        assert "d2h_seconds" not in measured
+
+
+def test_status_has_a_key_only_where_a_span_gave_it(built):
+    _, _, status = built
+    phases = status["phases"]
+    for phase, part in (("cv_train", "init"), ("dump", "serialize"), ("data_fetch", "machine_fetch")):
+        assert set(phases[phase]["parts"][part]) == {"seconds", "count", "cpu_seconds"}
+    # the dataset's parts have their pool threads' CPU, by the paired attribute
+    fetch = phases["data_fetch"]["parts"]
+    nested = ("provider_read", "resample_join", "row_filter")
+    for part in nested:
+        assert set(fetch[part]) == {"seconds", "count", "cpu_seconds"}
+        assert fetch[part]["cpu_seconds"] <= fetch[part]["seconds"] + 2 * CLOCK_SLACK
+    assert sum(fetch[p]["cpu_seconds"] for p in nested) <= (
+        fetch["machine_fetch"]["cpu_seconds"] + 2 * CLOCK_SLACK
+    )
+    # the blocks a dense stack fills: X of two tags and two weight planes
+    # over the members' padded rows, float32 (no second array of targets)
+    (fit,) = [
+        s["attributes"] for s in built[1]
+        if s["name"] == "device_program" and s["attributes"].get("members") == 2
+    ]
+    members, rows, tags = ast.literal_eval(fit["shape"])
+    assert phases["final_fit"]["parts"]["stack"]["bytes"] == members * rows * (tags + 2) * 4
+
+
+def test_the_newest_resource_sample_reaches_the_status_and_no_event_is_written(built):
+    _, spans, status = built
+    resources = status["resources"]
+    assert set(resources) == {"hbm_peak_bytes", "host_rss_peak_bytes", "host_cpu_count"}
+    assert resources["host_rss_peak_bytes"] > 50e6  # a process that imported JAX
+    assert resources["host_cpu_count"] == len(os.sched_getaffinity(0))
+    # the CPU backend reports no memory: not measured, not zero
+    assert resources["hbm_peak_bytes"] is None
+    assert not [s for s in spans if s["name"] == "device_utilization"]
+    assert "Resources: host_rss_peak_bytes=" in telemetry.render_status(status)
 
 
 def test_a_phase_without_parts_is_written_as_before(built):
     _, _, status = built
     for phase in ("plan", "stage", "assemble", "cv_finalize"):
-        assert set(status["phases"][phase]) == {"seconds", "status"}
+        assert set(status["phases"][phase]) == {
+            "seconds", "status", "cpu_seconds", "process_cpu_seconds",
+        }
+    # timed by the command before the build had a recorder: wall seconds alone
+    assert set(status["phases"]["config_load"]) == {"seconds", "status"}
 
 
 def test_config_load_and_report_are_phases_and_complete_is_the_last_write(built):
@@ -551,6 +779,84 @@ def test_progress_parts_and_compile_only_where_recorded(tmp_path):
     assert "Compile path: trace_s=0.5, programs=2" in rendered
 
 
+def test_progress_sums_cpu_bytes_and_d2h_where_given_and_renders_the_rates(tmp_path):
+    seconds = {"cv_train": 8.0}
+    progress = BuildProgress(str(tmp_path), project="p", total=1, phase_seconds=seconds)
+    progress.phase("cv_train")
+    progress.add_part("cv_train", "collect", 1.5, cpu_seconds=0.5, bytes=10**9, d2h_seconds=0.5)
+    progress.add_part("cv_train", "collect", 0.5, cpu_seconds=0.25, bytes=10**9, d2h_seconds=0.5)
+    progress.add_part("cv_train", "init", 0.25, cpu_seconds=None, bytes=None)
+    progress.add_part("cv_train", "stack", 0.5, bytes=2 * 10**9, unknown=7)
+    progress.add_phase_cpu("cv_train", cpu_seconds=1.0, process_cpu_seconds=12.0)
+    progress.add_phase_cpu("cv_train", cpu_seconds=0.5, process_cpu_seconds=None)
+    progress.add_phase_cpu("never_entered", cpu_seconds=1.0)
+    progress.resources = {
+        "hbm_peak_bytes": 5 * 10**9, "host_rss_peak_bytes": 2 * 10**9, "host_cpu_count": 13,
+    }
+    progress.write(force=True)
+    doc = load_status(str(tmp_path))
+    assert doc["phases"]["cv_train"] == {
+        "seconds": 8.0, "status": "running",
+        "cpu_seconds": 1.5, "process_cpu_seconds": 12.0,
+        "parts": {
+            "collect": {
+                "seconds": 2.0, "count": 2, "cpu_seconds": 0.75,
+                "bytes": 2 * 10**9, "d2h_seconds": 1.0,
+            },
+            "init": {"seconds": 0.25, "count": 1},
+            "stack": {"seconds": 0.5, "count": 1, "bytes": 2 * 10**9},
+        },
+    }
+    assert doc["resources"]["host_cpu_count"] == 13
+    rendered = telemetry.render_status(doc)
+    assert "[1.50 cores busy, own thread cpu 19%]" in rendered  # 12.0 and 1.5 of 8.0 s
+    assert "[cpu 38%, 1.00 GB/s, d2h 1.00 s at 2.00 GB/s]" in rendered  # collect
+    assert "x1 (thread-seconds)  [4.00 GB/s]" in rendered  # stack: bytes, no CPU
+    assert "Resources: hbm_peak_bytes=5.00 GB, host_rss_peak_bytes=2.00 GB, host_cpu_count=13" in rendered
+
+
+@pytest.mark.parametrize(
+    "entry,shown",
+    [
+        # a pool at work: many cores, the phase's own thread waiting on it
+        ({"seconds": 8.0, "process_cpu_seconds": 20.0, "cpu_seconds": 0.4},
+         "  [2.50 cores busy, own thread cpu 5%]"),
+        # the builder's one thread computing
+        ({"seconds": 2.0, "process_cpu_seconds": 2.0, "cpu_seconds": 1.9},
+         "  [1.00 cores busy, own thread cpu 95%]"),
+        # the host waiting for the device
+        ({"seconds": 10.0, "process_cpu_seconds": 0.5, "cpu_seconds": 0.25},
+         "  [0.05 cores busy, own thread cpu 2%]"),
+        ({"seconds": 4.0, "process_cpu_seconds": 6.0}, "  [1.50 cores busy]"),
+        ({"seconds": 4.0, "cpu_seconds": 1.0}, "  [own thread cpu 25%]"),
+        # an older program's phase, and one too short to have seconds
+        ({"seconds": 4.0, "status": "done"}, ""),
+        ({"seconds": 0.0, "process_cpu_seconds": 1.0, "cpu_seconds": 1.0}, ""),
+    ],
+)
+def test_a_phases_line_says_the_cores_busy_and_what_its_own_thread_computed(entry, shown):
+    assert telemetry.progress.cores_busy_text(entry) == shown
+
+
+@pytest.mark.parametrize(
+    "measured,shown",
+    [
+        ({"seconds": 2.0, "count": 1, "cpu_seconds": 0.5}, "  [cpu 25%]"),
+        ({"seconds": 2.0, "count": 1, "bytes": 10**9}, "  [0.50 GB/s]"),
+        # a fetch that is its part whole, and one that is a fifth of it
+        ({"seconds": 2.0, "count": 3, "cpu_seconds": 1.0, "bytes": 4 * 10**9, "d2h_seconds": 2.0},
+         "  [cpu 50%, 2.00 GB/s, d2h 2.00 s at 2.00 GB/s]"),
+        ({"seconds": 2.0, "count": 3, "bytes": 4 * 10**9, "d2h_seconds": 0.4},
+         "  [2.00 GB/s, d2h 0.40 s at 10.00 GB/s]"),
+        # bytes on a part too short to have seconds: the size alone
+        ({"seconds": 0.0, "count": 1, "cpu_seconds": 0.0, "bytes": 5 * 10**8}, "  [0.50 GB]"),
+        ({"seconds": 2.0, "count": 1}, ""),
+    ],
+)
+def test_a_parts_line_says_its_cpu_share_its_rate_and_its_fetch(measured, shown):
+    assert telemetry.progress.part_rates_text(measured) == shown
+
+
 def test_concurrent_parts_lose_no_update(tmp_path):
     import sys
 
@@ -623,6 +929,50 @@ def test_build_breakdown_self_time_is_what_no_part_covers():
     assert train["parts"] == {"program fleet_fit": {"seconds": 1.25, "count": 1}}
     assert found["compile"] == {"trace_s": 0.25, "programs": 2}
     assert build_breakdown([]) is None
+
+
+def test_build_breakdown_keeps_cpu_and_bytes_and_counts_the_fetch_once():
+    """A ``collect`` that says how long its fetch alone took covers its
+    phase once: ``d2h_seconds`` is a key of its entry, no part."""
+    spans = synthetic_build_spans()
+    rec = SpanRecorder()
+    t0 = 1_700_000_000.0
+    spans.append(
+        rec._span_dict(
+            "build_part", "4" * 16, "1" * 16, t0 + 8.75, t0 + 9.0,
+            {"phase": "cv_train", "part": "collect", "cpu_seconds": 0.125,
+             "bytes": 10**9, "d2h_seconds": 0.2},
+            None,
+        )
+    )
+    for span in spans:
+        if span["attributes"].get("part") == "machine_fetch":
+            span["attributes"].update(cpu_seconds=0.5, provider_read_cpu_seconds=0.125)
+        if span["name"] == "build_phase" and span["attributes"]["phase"] == "cv_train":
+            span["attributes"].update(cpu_seconds=0.25, process_cpu_seconds=2.5)
+        if span["name"] == "device_program":
+            span["attributes"].update(cpu_seconds=0.5, bytes=123)
+    found = build_breakdown(spans)
+    train = found["phases"]["cv_train"]
+    assert train["self_seconds"] == 1.0  # 1.25 before the collect's 0.25 s
+    assert train["cpu_seconds"] == 0.5 and train["process_cpu_seconds"] == 5.0  # two entries
+    assert train["parts"] == {
+        "program fleet_fit": {"seconds": 1.25, "count": 1, "cpu_seconds": 0.5},
+        "collect": {
+            "seconds": 0.25, "count": 1, "cpu_seconds": 0.125,
+            "bytes": 10**9, "d2h_seconds": 0.2,
+        },
+    }
+    fetch = found["phases"]["data_fetch"]
+    assert fetch["self_seconds"] == 1.0 and "cpu_seconds" not in fetch
+    assert fetch["parts"]["machine_fetch"] == {"seconds": 4.0, "count": 2, "cpu_seconds": 1.0}
+    assert fetch["parts"]["provider_read"] == {"seconds": 1.0, "count": 2, "cpu_seconds": 0.25}
+    rendered = render_analysis(
+        {"trace": "t", "spans_read": len(spans), "build_breakdown": found}
+    )
+    assert "[2.00 cores busy, own thread cpu 20%]" in rendered  # 5.0 and 0.5 of 2.5 s
+    assert "[cpu 50%, 4.00 GB/s, d2h 0.20 s at 5.00 GB/s]" in rendered
+    assert "[cpu 25%]" in rendered  # machine_fetch: a quarter computing, the rest waiting
 
 
 def test_build_breakdown_sums_and_renders_a_fit_programs_validation_slots():

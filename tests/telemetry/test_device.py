@@ -1,7 +1,7 @@
 """
 Device-utilization telemetry (PR 9): the compile-cache hit counters,
 their ``program_span`` / serving wiring, the memory snapshot's degrade
-contract, and the ``device_utilization`` event schema.
+contract, and the resource sample's schema.
 """
 
 import pytest
@@ -121,21 +121,25 @@ def test_persistent_cache_info_counts_entries(tmp_path, monkeypatch):
     assert device.persistent_cache_info() is None
 
 
-def test_emit_device_utilization_event_schema():
-    """When memory stats exist the event carries flattened memory_*
-    attributes + the build counters; when they don't, nothing is
-    emitted (callers treat None as 'not measurable')."""
-    recorder = telemetry.SpanRecorder()
-    snapshot = device.emit_device_utilization(recorder, phase="final_fit")
-    events = recorder.finished("device_utilization")
-    if snapshot is None:
-        assert events == []
-        return
-    assert len(events) == 1
-    attrs = events[0]["attributes"]
-    assert attrs["phase"] == "final_fit"
-    assert "compiles" in attrs and "cache_hits" in attrs
-    assert attrs["memory_devices"] == snapshot["devices"]
+def test_the_resource_sample_has_the_devices_memory_and_the_hosts_own_numbers():
+    """What the fleet builder samples at the end of a device-heavy phase
+    and hands to ``build_status.json["resources"]``: the memory snapshot
+    as :func:`memory_snapshot` gives it (None when sampling is off), the
+    process's peak resident set in bytes and the cores it may run on.
+    No event is written: the status key is the sample's reader."""
+    import os
+    import resource
+
+    sample = device.sample_resources()
+    assert set(sample) == {"memory", "host_rss_peak_bytes", "host_cpu_count"}
+    assert sample["memory"] == device.memory_snapshot()
+    assert sample["host_cpu_count"] == len(os.sched_getaffinity(0))
+    peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+    assert 0 < sample["host_rss_peak_bytes"] <= peak  # a peak only grows
+    ballast = bytearray(64 << 20)  # touched pages: the peak moves with the process
+    assert device.sample_resources()["host_rss_peak_bytes"] >= sample["host_rss_peak_bytes"]
+    del ballast
+    assert not hasattr(device, "emit_device_utilization")
 
 
 @pytest.mark.precision
