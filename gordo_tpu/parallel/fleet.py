@@ -139,7 +139,9 @@ class WindowedFleetMember:
 @dataclass
 class FleetResult:
     name: str
-    params: Any  # host numpy pytree (None when ``error`` is set)
+    #: host numpy pytree; None when ``error`` is set, and when the fit
+    #: left its parameters on the device (``block``)
+    params: Any
     history: History
     seed: int = 0  # the RNG seed this member actually trained with
     retries: int = 0  # diverged-member reseed retries that led to this result
@@ -148,6 +150,16 @@ class FleetResult:
     #: degradation policy (FleetBuilder falls back to the sequential
     #: ModelBuilder path)
     error: Optional[BaseException] = None
+    #: of a fit whose parameters stayed on the device
+    #: (``FleetTrainer.train(params_on_device=True)``): its bucket's
+    #: stacked parameters as the fit program returned them (padded,
+    #: sharded over ``models``; every member of the bucket refers to the
+    #: same tree) and this member's ``row`` in them. Nothing fetches
+    #: them: ``FleetTrainer.device_params`` hands them to a predict
+    #: program, and the device's memory is theirs until the last result
+    #: that refers to them goes.
+    block: Any = None
+    row: int = 0
 
 
 @dataclass
@@ -640,6 +652,30 @@ def _optimizer_init_program(spec: ModelSpec):
     )
 
 
+def _block_params(stacked_params, m: int, m_total: int):
+    """``stacked_params`` for a predict program's block of ``m_total``
+    members: parameters that come ``m_total`` long are the block already
+    (a fit's, on the device: :meth:`FleetTrainer.device_params`) and
+    pass as they are; ``m`` members' are padded on the host by repeating
+    the first."""
+    if jax.tree_util.tree_leaves(stacked_params)[0].shape[0] == m_total:
+        return stacked_params
+    return jax.tree_util.tree_map(
+        lambda a: np.concatenate(
+            [a, np.repeat(np.asarray(a)[:1], m_total - m, axis=0)]
+        ),
+        stacked_params,
+    )
+
+
+def _resident_members(stacked_params, m: int) -> int:
+    """Of a predict program's ``m`` members, those whose parameters it
+    takes from the device: all where no leaf is a host array, else none
+    (the ``params_resident_members`` of its span)."""
+    leaves = jax.tree_util.tree_leaves(stacked_params)
+    return m if all(isinstance(leaf, jax.Array) for leaf in leaves) else 0
+
+
 class FleetTrainer:
     """
     Trains homogeneous-spec buckets of models as single device programs.
@@ -711,6 +747,7 @@ class FleetTrainer:
         config: FitConfig,
         initial_params: Optional[Any] = None,
         retry_failed: int = 1,
+        params_on_device: bool = False,
     ) -> List[FleetResult]:
         """
         Train all members (auto-bucketed); returns one FleetResult per
@@ -722,6 +759,14 @@ class FleetTrainer:
         this many times — the chip-level analog of the reference DAG's
         per-pod retryStrategy (SURVEY.md §2.9 elasticity row).
 
+        ``params_on_device``: the fits' parameters stay where the fit
+        programs left them and only the histories come to the host: a
+        result then has ``params`` None and refers to its bucket's
+        ``block`` and its ``row`` in it (:meth:`device_params` stacks
+        such results for a predict program). For models that exist to
+        predict and go, a CV fold's; a fit whose parameters are the
+        artifact takes them to the host (the default).
+
         CONTRACT: a member whose device program fails in ISOLATION (after
         bucket bisection of a ``JaxRuntimeError``/``RESOURCE_EXHAUSTED``)
         does NOT raise — it returns a ``FleetResult`` with ``params=None``
@@ -730,7 +775,7 @@ class FleetTrainer:
         degrades such machines to the sequential builder). Host-side
         exceptions still raise for the whole call, as before.
         """
-        results = self._train_once(members, config)
+        results = self._train_once(members, config, params_on_device)
         for attempt in range(1, retry_failed + 1):
             failed_idx = [
                 i
@@ -752,7 +797,7 @@ class FleetTrainer:
                     members[i], seed=members[i].seed + 7919 * attempt
                 )
                 retry_members.append(member)
-            retried = self._train_once(retry_members, config)
+            retried = self._train_once(retry_members, config, params_on_device)
             for i, result in zip(failed_idx, retried):
                 result.retries = attempt
                 result.history.params["fleet_retry"] = {
@@ -763,7 +808,7 @@ class FleetTrainer:
         return results
 
     def _train_once(
-        self, members: Sequence[Any], config: FitConfig
+        self, members: Sequence[Any], config: FitConfig, params_on_device: bool = False
     ) -> List[FleetResult]:
         by_name: Dict[str, FleetResult] = {}
         failures: Dict[str, BaseException] = {}
@@ -795,6 +840,7 @@ class FleetTrainer:
                     lambda b, _p=pb: self._train_windowed_bucket(
                         _p.spec, _p.n_padded, _p.offset, b, config,
                         m_padded=bucket_m_padded(_p, b),
+                        params_on_device=params_on_device,
                     ),
                     bucket,
                     by_name,
@@ -812,6 +858,7 @@ class FleetTrainer:
                 lambda b, _p=pb: self._train_bucket(
                     _p.spec, _p.n_padded, b, config,
                     m_padded=bucket_m_padded(_p, b),
+                    params_on_device=params_on_device,
                 ),
                 bucket,
                 by_name,
@@ -975,6 +1022,7 @@ class FleetTrainer:
         bucket: List[FleetMember],
         config: FitConfig,
         m_padded: Optional[int] = None,
+        params_on_device: bool = False,
     ) -> List[FleetResult]:
         (*data, rngs), validation_slots = self._stack_bucket(
             spec, n_padded, bucket, config, m_padded=m_padded
@@ -1001,6 +1049,7 @@ class FleetTrainer:
         return self._collect_results(
             bucket, params, losses, val_losses, epochs_ran, config,
             steps=n_padded // config.batch_size,
+            params_on_device=params_on_device,
         )
 
     def _init_bucket_params(self, spec: ModelSpec, rngs):
@@ -1095,6 +1144,7 @@ class FleetTrainer:
         bucket: List[WindowedFleetMember],
         config: FitConfig,
         m_padded: Optional[int] = None,
+        params_on_device: bool = False,
     ) -> List[FleetResult]:
         (series, ytgt, order, wtr, wval, rngs), validation_slots = (
             self._stack_windowed_bucket(
@@ -1126,18 +1176,24 @@ class FleetTrainer:
         return self._collect_results(
             bucket, params, losses, val_losses, epochs_ran, config,
             steps=order.shape[1] // config.batch_size,
+            params_on_device=params_on_device,
         )
 
     def _collect_results(
-        self, bucket, params, losses, val_losses, epochs_ran, config, steps
+        self, bucket, params, losses, val_losses, epochs_ran, config, steps,
+        params_on_device: bool = False,
     ) -> List[FleetResult]:
-        """A fit program's results on the host, a member each: the
-        ``collect`` part, whose span says the bytes fetched and the
-        fetch's own seconds (``_fetch_for``); the rest of it is the loop
-        below."""
+        """A fit program's results, a member each: the ``collect`` part,
+        whose span says the bytes fetched and the fetch's own seconds
+        (``_fetch_for``); the rest of it is the loop below. The
+        histories come to the host, and so do the parameters unless
+        ``params_on_device``: then they are not touched, and every
+        result refers to ``params`` itself, the program's block, and to
+        its row in it."""
         with telemetry.part_span("collect") as span:
             host_params, losses, val_losses, epochs_ran = _fetch_for(
-                span, (params, losses, val_losses, epochs_ran)
+                span,
+                (None if params_on_device else params, losses, val_losses, epochs_ran),
             )
             losses = np.asarray(losses)
             val_losses = np.asarray(val_losses)
@@ -1161,6 +1217,8 @@ class FleetTrainer:
                         name=member.name,
                         seed=member.seed,
                         params=member_params,
+                        block=params if params_on_device else None,
+                        row=i,
                         history=History(
                             history=history,
                             params={
@@ -1176,6 +1234,49 @@ class FleetTrainer:
             return results
 
     # -- prediction ---------------------------------------------------------
+
+    def device_params(self, spec: ModelSpec, results: Sequence[FleetResult]):
+        """
+        The stacked parameters of fits that left them on the device
+        (``train(params_on_device=True)``), for :meth:`predict_bucket` /
+        :meth:`predict_windowed_bucket`: ``results``' members in order,
+        then padding up to the mesh's model axis, sharded over ``models``.
+
+        Where ``results`` are one bucket's rows from its first on, and
+        the bucket's block is as long as the predict program's (the usual
+        case: a group of fold models is the bucket that trained them),
+        that is the block itself and nothing moves. Otherwise (a group
+        out of several buckets, a reseeded retry's, part of a bucket) the
+        rows are gathered on the device. No leaf comes to the host.
+        """
+        mesh = self._mesh_for(spec)
+        model_axis = mesh.devices.shape[0]
+        m_total = -(-len(results) // model_axis) * model_axis
+        first = results[0].block
+        length = jax.tree_util.tree_leaves(first)[0].shape[0]
+        if (
+            length == m_total
+            and all(r.block is first for r in results)
+            and [r.row for r in results] == list(range(len(results)))
+        ):
+            return first
+        # runs of rows out of one block each; the padding repeats the
+        # first member, as the host's does (_block_params)
+        padded = list(results) + [results[0]] * (m_total - len(results))
+        runs: List[Any] = []
+        for result in padded:
+            if runs and runs[-1][0] is result.block:
+                runs[-1][1].append(result.row)
+            else:
+                runs.append((result.block, [result.row]))
+        rows = [np.asarray(run_rows, np.int32) for _, run_rows in runs]
+        stacked = jax.tree_util.tree_map(
+            lambda *leaves: jnp.concatenate(
+                [jnp.take(leaf, r, axis=0) for leaf, r in zip(leaves, rows)]
+            ),
+            *[block for block, _ in runs],
+        )
+        return jax.device_put(stacked, model_sharding(mesh, extra_dims=0))
 
     def _put_scoring(
         self, mesh: Mesh, scoring: FoldScoring, m_total: int, n_total: int, rows_sharding
@@ -1221,7 +1322,10 @@ class FleetTrainer:
         X: np.ndarray,
         scoring: Optional[FoldScoring] = None,
     ):
-        """Forward the whole bucket: X[M, N, ...] -> [M, N, out]. With
+        """Forward the whole bucket: X[M, N, ...] -> [M, N, out].
+        ``stacked_params``: the ``M`` members' on the host (padded here
+        to the program's block), or the block itself on the device
+        (:meth:`device_params`), which goes to the program as it is. With
         ``scoring``, the same program goes on to :func:`fold_scores`: the
         predictions stay on the device (``[M', N', out]``, the program's
         padded block) and come back beside the scores, ``[M, ...]`` arrays
@@ -1240,14 +1344,7 @@ class FleetTrainer:
                 padded = np.zeros((m_total, n_total) + X.shape[2:], X.dtype)
                 padded[:m, :n] = X
                 X = padded
-                stacked_params = jax.tree_util.tree_map(
-                    lambda a: np.concatenate(
-                        [a, np.repeat(np.asarray(a)[:1], m_total - m, axis=0)]
-                    )
-                    if m_total != m
-                    else np.asarray(a),
-                    stacked_params,
-                )
+            stacked_params = _block_params(stacked_params, m, m_total)
             if span.recording:
                 # the parameters go with the program's call, host arrays too
                 span.set(
@@ -1265,10 +1362,12 @@ class FleetTrainer:
                     self.mesh, scoring, m_total, n_total,
                     model_data_sharding(self.mesh, extra_dims=1),
                 )
+        resident = _resident_members(stacked_params, m)
         with telemetry.program_span(
             name,
-            (spec, X.shape, scoring and scoring.window),
+            (spec, X.shape, scoring and scoring.window, bool(resident)),
             members=m,
+            params_resident_members=resident,
             shape=str(tuple(X.shape)),
             spec=type(spec).__name__,
         ):
@@ -1289,7 +1388,7 @@ class FleetTrainer:
         over the mesh's model axis like :meth:`predict_bucket`:
         ``series[M, n, F]`` + ``order[M, nv]`` → ``[M, nv, F_out]``
         (``nv`` is padded to a whole number of ``batch_size`` batches here).
-        ``scoring`` as in :meth:`predict_bucket`.
+        ``stacked_params`` and ``scoring`` as in :meth:`predict_bucket`.
         """
         mesh = self._mesh_for(spec)
         with telemetry.part_span("h2d") as span:  # pad to the mesh, then transfer
@@ -1307,14 +1406,7 @@ class FleetTrainer:
                 padded_order = np.zeros((m_total, nv_pad), np.int32)
                 padded_order[:m, :nv] = order
                 order = padded_order
-                stacked_params = jax.tree_util.tree_map(
-                    lambda a: np.concatenate(
-                        [a, np.repeat(np.asarray(a)[:1], m_total - m, axis=0)]
-                    )
-                    if m_total != m
-                    else np.asarray(a),
-                    stacked_params,
-                )
+            stacked_params = _block_params(stacked_params, m, m_total)
             ms2 = model_sharding(mesh, extra_dims=2)
             if span.recording:
                 span.set(
@@ -1333,10 +1425,15 @@ class FleetTrainer:
                 )
                 name = "fleet_windowed_predict_score"
                 scored = self._put_scoring(mesh, scoring, m_total, nv_pad, ms2)
+        resident = _resident_members(stacked_params, m)
         with telemetry.program_span(
             name,
-            (spec, batch_size, series.shape, order.shape, scoring and scoring.window),
+            (
+                spec, batch_size, series.shape, order.shape,
+                scoring and scoring.window, bool(resident),
+            ),
             members=m,
+            params_resident_members=resident,
             shape=str(tuple(series.shape)),
             spec=type(spec).__name__,
         ):

@@ -28,6 +28,7 @@ import datetime
 import logging
 import os
 import time
+import traceback
 import types
 from collections import defaultdict
 from dataclasses import dataclass, field
@@ -77,8 +78,6 @@ from .fleet import (
     WindowedFleetMember,
     fetch_members,
     is_device_error,
-    stack_member_params,
-    tree_nbytes,
 )
 from .journal import BuildJournal, clean_staging_dirs
 
@@ -1919,7 +1918,13 @@ class FleetBuilder:
             return
         try:
             with self._phase("cv_train"):
-                fold_results = self.trainer.train(members, config)
+                # a fold model exists to predict its test rows: its
+                # parameters stay on the device, where the group's
+                # predict program takes them (_score_folds), and go with
+                # this call's results
+                fold_results = self.trainer.train(
+                    members, config, params_on_device=True
+                )
         except Exception as exc:
             # CV chunks split on ANY exception — unlike _train_final_group,
             # which gates on device errors. The asymmetry is deliberate:
@@ -1929,30 +1934,34 @@ class FleetBuilder:
             # O(N log N) retrain cost in the worst chunk-wide case),
             # while the final fit keeps its original fail-the-group
             # semantics for deterministic host errors.
-            if len(members) > 1:
-                logger.warning(
-                    "CV chunk of %d fold-members failed (%s); splitting",
-                    len(members),
-                    exc,
-                )
-                self.robustness["bucket_bisects"] += 1
-                for plan, _ in fold_items:
-                    plan.bucket_bisects += 1
-                mid = len(members) // 2
-                self._train_and_score_folds(
-                    members[:mid], fold_items[:mid], config,
-                    per_plan_folds, fold_state,
-                )
-                self._train_and_score_folds(
-                    members[mid:], fold_items[mid:], config,
-                    per_plan_folds, fold_state,
-                )
+            if len(members) == 1:
+                plan = fold_items[0][0]
+                if is_device_error(exc):
+                    self._degrade(plan, exc)
+                else:
+                    self._fail(plan.machine.name, exc)
                 return
-            plan = fold_items[0][0]
-            if is_device_error(exc):
-                self._degrade(plan, exc)
-            else:
-                self._fail(plan.machine.name, exc)
+            logger.warning(
+                "CV chunk of %d fold-members failed (%s); splitting",
+                len(members),
+                exc,
+            )
+            fold_results = None
+        if fold_results is None:
+            # outside the handler: the failed call's traceback, and the
+            # device arrays its frames hold, are gone before a half trains
+            self.robustness["bucket_bisects"] += 1
+            for plan, _ in fold_items:
+                plan.bucket_bisects += 1
+            mid = len(members) // 2
+            self._train_and_score_folds(
+                members[:mid], fold_items[:mid], config,
+                per_plan_folds, fold_state,
+            )
+            self._train_and_score_folds(
+                members[mid:], fold_items[mid:], config,
+                per_plan_folds, fold_state,
+            )
             return
         # The trainer's own bucket bisection reports members that failed
         # in ISOLATION as error-results instead of raising: degrade those
@@ -1981,6 +1990,13 @@ class FleetBuilder:
                 scorable_items, scorable_results, per_plan_folds, fold_state
             )
         except Exception as exc:
+            # the error is kept with its machines, and its traceback
+            # keeps the frames it came through (this one too, as their
+            # caller), whose locals hold the fold models' parameters on
+            # the device. Those go with this chunk, not with the build:
+            # the frames below are cleared, and this one lets go by name
+            traceback.clear_frames(exc.__traceback__)
+            del fold_results, scorable_results, result
             for plan, _ in scorable_items:
                 self._fail(plan.machine.name, exc)
 
@@ -2022,15 +2038,16 @@ class FleetBuilder:
                 if scoring is not None and span.recording:
                     span.set(bytes=scoring.nbytes)
             with self._phase("cv_predict"):
-                with self._part("stack") as span:
-                    stacked = stack_member_params(
+                with self._part("stack"):
+                    # on the device: the bucket's own block where the
+                    # group is the bucket, else a gather of its rows
+                    stacked = self.trainer.device_params(
+                        spec,
                         [
                             by_name[_fold_member_name(p.machine.name, k)]
                             for p, k in group
-                        ]
+                        ],
                     )
-                    if span.recording:
-                        span.set(bytes=tree_nbytes(stacked))
                 if geometry == ("windowed",):
                     predicted = self._predict_windowed_group(
                         spec,
@@ -2138,6 +2155,10 @@ class FleetBuilder:
                 with self._part("collect") as span:
                     predictions = fetch_members(predictions, on_host)
                     span.set(bytes=predictions.nbytes)
+            else:
+                # they stay where they are (across processes not even
+                # addressable from here): nothing below reads one
+                predictions = ()
         self._record_part(
             "device_scores",
             clock() - began,
@@ -2501,7 +2522,10 @@ class FleetBuilder:
             return
         try:
             with self._phase("final_fit"):
-                results = self.trainer.train(members, config)
+                # these parameters are the artifact: they come to the host
+                results = self.trainer.train(
+                    members, config, params_on_device=False
+                )
         except Exception as exc:
             # Split-retry DEVICE errors only (the trainer's own rule): a
             # host-side exception is deterministic and would fail every

@@ -461,7 +461,9 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     count: a pooled phase's parts can exceed its wall seconds; a device
     program run in the phase is listed as ``program <name>``, a fit
     program with the ``validation_slots`` of its buckets summed and, for
-    the dense fit, the widest ``shuffle_columns`` among them) and its
+    the dense fit, the widest ``shuffle_columns`` among them; a predict
+    program with its ``members`` and, of them, those whose parameters
+    it took from the device, ``params_resident_members``) and its
     ``self_seconds``, the wall time no part or program covers. Where its
     spans carry them, a phase also has ``cpu_seconds`` (its own thread's)
     and ``process_cpu_seconds`` (every thread's between its two ends),
@@ -552,6 +554,9 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
                 part["shuffle_columns"] = max(
                     part.get("shuffle_columns", 0), int(attributes["shuffle_columns"])
                 )
+            if "params_resident_members" in attributes:  # a predict program's span
+                for key in ("members", "params_resident_members"):
+                    part[key] = part.get(key, 0) + int(attributes.get(key) or 0)
             if span["name"] == "build_part":
                 nested_cpu = nested_part_cpu_seconds(attributes)
                 for nested, nested_seconds in nested_part_seconds(attributes).items():
@@ -848,7 +853,10 @@ def render_analysis(doc: Dict[str, Any]) -> str:
             for part, measured in entry["parts"].items():
                 counters = [
                     f"{key}={measured[key]}"
-                    for key in ("validation_slots", "shuffle_columns")
+                    for key in (
+                        "validation_slots", "shuffle_columns",
+                        "members", "params_resident_members",
+                    )
                     if key in measured
                 ]
                 if counters:

@@ -186,11 +186,11 @@ def test_fleet_retries_diverged_members():
     calls = []
     original = trainer._train_once
 
-    def fake_train_once(ms, cfg):
+    def fake_train_once(ms, cfg, *on_device):
         calls.append([m.name for m in ms])
         if len(calls) == 1:
             return poisoned
-        return original(ms, cfg)
+        return original(ms, cfg, *on_device)
 
     with mock.patch.object(trainer, "_train_once", side_effect=fake_train_once):
         results = trainer.train(members, config)

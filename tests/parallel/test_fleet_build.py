@@ -283,8 +283,8 @@ def test_final_fit_divergence_retry_counts_into_metadata(monkeypatch):
     real = FleetTrainer._train_once
     state = {"poisoned": False}
 
-    def poison_first_final_fit(self, members, config):
-        results = real(self, members, config)
+    def poison_first_final_fit(self, members, config, *on_device):
+        results = real(self, members, config, *on_device)
         # poison exactly one result once: the final-fit members carry the
         # machine name itself (CV fold members are name::foldN)
         if not state["poisoned"] and any(r.name == "retry-meta" for r in results):

@@ -268,11 +268,11 @@ def test_over_packed_bucket_resolves_by_splitting():
     big_bucket_failures = {"n": 0}
     real = FleetTrainer._train_bucket
 
-    def oom_on_big_buckets(self, spec, n_padded, bucket, config, m_padded=None):
+    def oom_on_big_buckets(self, spec, n_padded, bucket, config, m_padded=None, **where):
         if len(bucket) > 2:
             big_bucket_failures["n"] += 1
             raise RuntimeError("RESOURCE_EXHAUSTED: out of memory (injected)")
-        return real(self, spec, n_padded, bucket, config, m_padded=m_padded)
+        return real(self, spec, n_padded, bucket, config, m_padded=m_padded, **where)
 
     FleetTrainer._train_bucket = oom_on_big_buckets
     try:

@@ -135,11 +135,11 @@ def test_planned_m_padded_bucket_still_bisects_on_oom(monkeypatch):
     calls = []
     real = FleetTrainer._train_bucket
 
-    def oom_at_planned_rung(self, spec, n_padded, bucket, config, m_padded=None):
+    def oom_at_planned_rung(self, spec, n_padded, bucket, config, m_padded=None, **where):
         calls.append((len(bucket), m_padded))
         if m_padded is not None:
             raise RuntimeError("RESOURCE_EXHAUSTED: out of memory (injected)")
-        return real(self, spec, n_padded, bucket, config, m_padded=m_padded)
+        return real(self, spec, n_padded, bucket, config, m_padded=m_padded, **where)
 
     monkeypatch.setattr(FleetTrainer, "_train_bucket", oom_at_planned_rung)
     members = [_member(f"mp{i}", 128, i) for i in range(4)]

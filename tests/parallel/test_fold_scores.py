@@ -29,7 +29,7 @@ from gordo_tpu.models.anomaly.diff import (
 from gordo_tpu.models.nn import init_fn_for
 from gordo_tpu.models.training import History
 from gordo_tpu.parallel import FleetBuilder, fleet_build
-from gordo_tpu.parallel.fleet import FleetResult, fold_scores
+from gordo_tpu.parallel.fleet import FleetResult, fold_scores, stack_member_params
 from gordo_tpu.parallel.fleet_build import (
     _Plan,
     _fold_scaler_parameters,
@@ -368,10 +368,13 @@ def folds_of(rows):
 def seeded_train(poison=()):
     """``FleetTrainer.train`` without the training: every fold model is
     its spec's seeded initial weights, so two builders score the same
-    predictions; a member named in ``poison`` predicts NaN."""
+    predictions; a member named in ``poison`` predicts NaN. As the
+    builder asks of its fold models, the parameters are left on the
+    device: one block a spec, a member a row."""
 
-    def train(members, config):
-        results = []
+    def train(members, config, params_on_device=False):
+        assert params_on_device
+        results, by_spec = [], {}
         for member in members:
             params = init_fn_for(member.spec)(
                 jax.random.PRNGKey(member.seed), member.spec
@@ -385,6 +388,11 @@ def seeded_train(poison=()):
                     history=History(history={"loss": [0.0]}, params={}, epoch=[0]),
                 )
             )
+            by_spec.setdefault(member.spec, []).append(results[-1])
+        for of_spec in by_spec.values():
+            block = jax.device_put(stack_member_params(of_spec))
+            for row, result in enumerate(of_spec):
+                result.params, result.block, result.row = None, block, row
         return results
 
     return train
