@@ -2,9 +2,12 @@
 Init and forward of :class:`~gordo_tpu.models.spec.BackboneSpec`: the
 layer kinds of the LFM2-MoE family (HF ``modeling_lfm2_moe``), of
 ``kind: keye_vl2`` (the ``qwen3_moe`` shape with DeepSeek-Sparse-
-Attention's indexer) and of ``kind: laguna`` (window and full attention
-mixed, gated heads, a shared expert) as pure functions over an explicit
-parameter tree, like :mod:`.nn`.
+Attention's indexer), of ``kind: laguna`` (window and full attention
+mixed, gated heads, a shared expert) and of ``kind: smallthinker``
+(attention without positions and window attention with rotary mixed, a
+router that reads the layer's input before its attention, experts
+gated by ``relu``) as pure functions over an explicit parameter tree,
+like :mod:`.nn`.
 
 ``u`` is the ``[batch, T, hidden]`` sequence of a batch of windows.
 
@@ -17,7 +20,9 @@ parameter tree, like :mod:`.nn`.
   over each head of ``q`` and ``k`` (``spec.qk_norm``) and a rotary
   embedding in the half-rotation layout at positions ``0..T-1``
   (``spec.rope_of``: plain, or YaRN's frequencies over the leading part
-  of a head). A layer has its own number of query heads (the width of
+  of a head, or none at all: ``rope_type: none``, an operator whose
+  ``q`` and ``k`` carry no position). A layer has its own number of
+  query heads (the width of
   its ``wq``). With ``spec.attention_gate`` each head's output is
   multiplied by ``sigmoid(u Wg)`` before ``wo``.
 - ``sliding_attention``: the same under the mask ``s <= t and t - s <
@@ -45,14 +50,22 @@ parameter tree, like :mod:`.nn`.
   the softmax of ``I`` over ``S_t``: the forward's ``penalty``. The
   forecast loss gives the indexer no gradient and the penalty gives
   nothing else any (:func:`_selected_attention` has the derivative rule).
-- dense feed-forward ``W_2(silu(u W_1) * (u W_3))``.
+- dense feed-forward ``W_2(silu(u W_1) * (u W_3))``; an expert is the
+  same at its own width, its gate ``spec.expert_activation`` (``silu``
+  or ``relu``).
 - routed experts (:func:`moe_ffn`): the guide's *share* layer. The
   router scores all published experts (sigmoid), the ``k`` largest
   ``score + bias`` are chosen, the chosen scores, normalised, weigh
   (``router: softmax``: a softmax over all logits, its ``k`` largest
-  renormalised to sum 1, no bias). A shared expert
+  renormalised to sum 1, no bias; ``router: softmax_of_chosen``: the
+  ``k`` largest logits, a softmax over those). A shared expert
   (``spec.shared_expert_intermediate_size``) is a dense feed-forward
   that every token takes beside them: every holder computes it whole.
+  The router reads the normed tensor the experts read, or, under
+  ``spec.router_input: layer_input``, the block's input before its
+  operator and its norm: :func:`block` then makes the routing plan
+  (:func:`routing_plan`: the choice, the sort, the groups) first, and
+  nothing the operator computes enters it.
   This holder keeps the (token, expert) pairs whose expert it holds,
   sorts them by expert, runs the three products as grouped products
   (``jax.lax.ragged_dot``; XLA:TPU lowers it to a tiled grouped kernel
@@ -337,6 +350,8 @@ def _heads(spec: BackboneSpec, w: Dict, u: jnp.ndarray, op: str = "full_attentio
     def placed(x, gain):
         if spec.qk_norm:
             x = rms_norm(x, w[gain], spec.norm_eps)
+        if rope["rope_type"] == "none":  # no position encoding: the projection as it is
+            return x
         return rotary(x, rope["rope_theta"]) if plain else scaled_rotary(x, rope)
 
     return placed(q, "q_norm"), placed(k, "k_norm"), v
@@ -844,6 +859,9 @@ def route(spec: BackboneSpec, w: Dict, tokens: jnp.ndarray):
     if spec.router == "softmax":
         picked, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), spec.num_experts_per_tok)
         return chosen, picked / jnp.sum(picked, axis=-1, keepdims=True)
+    if spec.router == "softmax_of_chosen":
+        picked, chosen = jax.lax.top_k(logits, spec.num_experts_per_tok)
+        return chosen, jax.nn.softmax(picked, axis=-1)
     scores = jax.nn.sigmoid(logits)
     bias = jax.lax.stop_gradient(w["expert_bias"])  # a buffer: chooses, takes no gradient
     _, chosen = jax.lax.top_k(scores + bias, spec.num_experts_per_tok)
@@ -852,16 +870,17 @@ def route(spec: BackboneSpec, w: Dict, tokens: jnp.ndarray):
     return chosen, weights * spec.routed_scaling_factor
 
 
-def moe_ffn(
-    spec: BackboneSpec, w: Dict, u: jnp.ndarray, active: Optional[jnp.ndarray] = None
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """The held experts' share of the routed layer over ``u [B, T,
-    hidden]``: ``(output, tokens routed to each published expert
-    [num_experts], pairs computed here)``. ``active [B]`` (None: all):
-    the windows that are tokens of this step; the others are padding,
-    route nothing and get no expert's output."""
-    dtype = u.dtype
-    tokens = u.reshape(-1, u.shape[-1])
+def routing_plan(
+    spec: BackboneSpec, w: Dict, tokens: jnp.ndarray, rows: int, active: Optional[jnp.ndarray] = None
+) -> Dict[str, jnp.ndarray]:
+    """What a routed layer does with ``tokens [N, hidden]`` (windows of
+    ``rows`` tokens) before any expert runs: the choice, the pairs sorted
+    by held expert, the groups' sizes. ``token_of [N * k]`` (the token of
+    each sorted pair), ``pair_weight [N * k]`` (its weight; 0 for a pair
+    of an absent expert), ``group_sizes [experts_held]``, ``pairs_here``
+    and ``routed [num_experts]`` (tokens to each published expert).
+    ``active``: as :func:`moe_ffn`. The tensor the router reads need not
+    be the experts' (``spec.router_input``)."""
     k, held, offset = spec.num_experts_per_tok, spec.experts_held, spec.expert_offset
     with jax.named_scope(ROUTE_SCOPE):
         chosen, weights = route(spec, w, tokens)
@@ -871,7 +890,7 @@ def moe_ffn(
         if active is None:
             routed = jnp.zeros((spec.num_experts,), jnp.int32).at[flat].add(1)
         else:
-            pair_active = jnp.repeat(active, u.shape[1] * k)
+            pair_active = jnp.repeat(active, rows * k)
             routed = jnp.zeros((spec.num_experts,), jnp.int32).at[flat].add(
                 pair_active.astype(jnp.int32)
             )
@@ -882,6 +901,36 @@ def moe_ffn(
         pairs_here = jnp.sum(group_sizes)
         token_of = order // k
         pair_weight = jnp.where(is_local, weights.reshape(-1), 0.0)[order]
+    return {
+        "token_of": token_of, "pair_weight": pair_weight, "group_sizes": group_sizes,
+        "pairs_here": pairs_here, "routed": routed,
+    }
+
+
+def moe_ffn(
+    spec: BackboneSpec,
+    w: Dict,
+    u: jnp.ndarray,
+    active: Optional[jnp.ndarray] = None,
+    plan: Optional[Dict[str, jnp.ndarray]] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Optional[Tuple[jnp.ndarray, jnp.ndarray]]]:
+    """The held experts' share of the routed layer over ``u [B, T,
+    hidden]``: ``(output, tokens routed to each published expert
+    [num_experts], pairs computed here, gate)``. ``active [B]`` (None:
+    all): the windows that are tokens of this step; the others are
+    padding, route nothing and get no expert's output. ``plan``: the
+    :func:`routing_plan` of these tokens, made before from another
+    tensor; None: made here from ``u``. ``gate``: under a ``relu`` gate
+    float32 ``(gate_active, gate_total)``, the gate units of the pairs
+    computed here that are above zero and all of them; else None."""
+    dtype = u.dtype
+    tokens = u.reshape(-1, u.shape[-1])
+    k = spec.num_experts_per_tok
+    if plan is None:
+        plan = routing_plan(spec, w, tokens, u.shape[1], active)
+    token_of, pair_weight, group_sizes = plan["token_of"], plan["pair_weight"], plan["group_sizes"]
+    pairs_here = plan["pairs_here"]
+    relu = spec.expert_activation == "relu"
     with jax.named_scope(EXPERTS_SCOPE):
         # rows past the last group belong to no expert held here. A
         # grouped product writes the rows of its groups only: the rest
@@ -902,19 +951,31 @@ def moe_ffn(
         x = jnp.where(valid, tokens[token_of], 0.0)  # [N * k, hidden]: the worst case, nothing dropped
         h1 = checkpoint_name(jnp.where(valid, grouped(x, w["w1"]), 0.0), SAVED_PRODUCTS[0])
         h3 = checkpoint_name(jnp.where(valid, grouped(x, w["w3"]), 0.0), SAVED_PRODUCTS[1])
-        y = grouped(jax.nn.silu(h1) * h3, w["w2"])
+        y = grouped((jax.nn.relu(h1) if relu else jax.nn.silu(h1)) * h3, w["w2"])
         y = jnp.where(valid, y, 0.0) * pair_weight[:, None].astype(dtype)
         out = jnp.zeros_like(tokens).at[token_of].add(y)
-    return out.reshape(u.shape), routed, pairs_here
+        gate = None
+        if relu:  # the zeroed rows of no group are not above zero
+            gate = (
+                jnp.sum(jax.lax.stop_gradient(h1) > 0).astype(jnp.float32),
+                pairs_here.astype(jnp.float32) * h1.shape[1],
+            )
+    return out.reshape(u.shape), plan["routed"], pairs_here, gate
 
 
 def block(spec: BackboneSpec, op: str, ffn: str, w: Dict, h: jnp.ndarray, active=None):
     """One pre-norm residual block; returns ``(h, counts, selection,
-    band)`` with ``counts = (routed, pairs_here)`` of a routed block,
-    ``selection = (objective, (keys_selected, keys_causal))`` of a
-    ``sparse_attention`` block and ``band = (pairs_attended,
-    pairs_multiplied)`` of an attention computed in tiles under a mask
-    by position, else None. ``active``: as :func:`moe_ffn`."""
+    band)`` with ``counts = (routed, pairs_here, gate)`` of a routed
+    block (:func:`moe_ffn`'s), ``selection = (objective, (keys_selected,
+    keys_causal))`` of a ``sparse_attention`` block and ``band =
+    (pairs_attended, pairs_multiplied)`` of an attention computed in
+    tiles under a mask by position, else None. ``active``: as
+    :func:`moe_ffn`. Where the router reads the layer's input
+    (``spec.router_input``) the routing plan is made first, from ``h``
+    as it comes in: nothing the operator computes enters it."""
+    plan = None
+    if ffn == "moe" and spec.router_input == "layer_input":
+        plan = routing_plan(spec, w["moe"], h.reshape(-1, h.shape[-1]), h.shape[1], active)
     normed = rms_norm(h, w["operator_norm"], spec.norm_eps)
     selection = band = None
     if op == "conv":
@@ -930,11 +991,11 @@ def block(spec: BackboneSpec, op: str, ffn: str, w: Dict, h: jnp.ndarray, active
     normed = rms_norm(h, w["ffn_norm"], spec.norm_eps)
     if ffn == "dense":
         return h + dense_ffn(w["ffn"], normed), None, selection, band
-    out, routed, pairs_here = moe_ffn(spec, w["moe"], normed, active)
+    out, *counts = moe_ffn(spec, w["moe"], normed, active, plan)
     if "shared" in w["moe"]:
         with jax.named_scope(SHARED_SCOPE):
             out = out + dense_ffn(w["moe"]["shared"], normed)
-    return h + out, (routed, pairs_here), selection, band
+    return h + out, tuple(counts), selection, band
 
 
 def _param_bytes(params: Dict) -> int:
@@ -959,7 +1020,10 @@ def forward_backbone_aux(
     ``indexer_kl`` (the layer's term of the indexer's objective); and,
     a row per attention layer computed in tiles under a mask by
     position, float32 ``pairs_attended`` and ``pairs_multiplied``
-    (query-key pairs inside the mask and of the tiles visited).
+    (query-key pairs inside the mask and of the tiles visited); and, a
+    row per expert layer whose gate is a ``relu``, float32
+    ``gate_active`` and ``gate_total`` (the gate units of the pairs
+    computed here that are above zero, and all of them).
     ``penalty`` is the sum of those terms, 0 without such a layer.
 
     ``remat``: rematerialise each block in the backward pass; None
@@ -973,7 +1037,7 @@ def forward_backbone_aux(
     if remat is None:
         remat = _param_bytes(params) >= REMAT_MIN_PARAM_BYTES
     h = x.astype(dtype) @ params["embed"]["W"].astype(dtype) + params["embed"]["b"].astype(dtype)
-    routed_rows, pairs_rows, selections, bands = [], [], [], []
+    routed_rows, pairs_rows, gates, selections, bands = [], [], [], [], []
     for i, (op, ffn) in enumerate(zip(spec.layer_ops, spec.layer_ffns)):
         run = lambda w, h, a, _op=op, _ffn=ffn: block(spec, _op, _ffn, w, h, a)  # noqa: E731
         if remat:
@@ -986,6 +1050,8 @@ def forward_backbone_aux(
         if counts is not None:
             routed_rows.append(counts[0])
             pairs_rows.append(counts[1])
+            if counts[2] is not None:
+                gates.append(counts[2])
         if selection is not None:
             selections.append(selection)
         if band is not None:
@@ -1002,6 +1068,9 @@ def forward_backbone_aux(
                 (len(routed_rows),), windows * x.shape[1] * spec.num_experts_per_tok, jnp.int32
             ),
         }
+    if gates:
+        aux["gate_active"] = jnp.stack([active_units for active_units, _ in gates])
+        aux["gate_total"] = jnp.stack([units for _, units in gates])
     penalty = jnp.zeros((), jnp.float32)
     if selections:
         objectives = jnp.stack([objective for objective, _ in selections])

@@ -1,5 +1,5 @@
 from . import backbone, feedforward_autoencoder, lstm_autoencoder  # noqa: F401  (registration)
-from .backbone import keye_vl2, laguna, lfm2_moe
+from .backbone import keye_vl2, laguna, lfm2_moe, smallthinker
 from .feedforward_autoencoder import (
     feedforward_hourglass,
     feedforward_model,
@@ -17,4 +17,5 @@ __all__ = [
     "lfm2_moe",
     "keye_vl2",
     "laguna",
+    "smallthinker",
 ]

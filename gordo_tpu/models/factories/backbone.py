@@ -338,3 +338,122 @@ def laguna(
         compute_dtype=compute_dtype,
         precision=precision,
     )
+
+
+#: PowerInfer/SmallThinker-21BA3B-Instruct config.json, every key of the
+#: catalog's row. The factory's defaults are these; the keys it does not
+#: take say nothing it can act on (the vocabulary is replaced by the
+#: sensor projections, positions are a window's, the model's name) or
+#: name what it refuses to be told otherwise.
+SMALLTHINKER_21B_A3B_CONFIG: Dict[str, Any] = {
+    "head_dim": 128,
+    "hidden_size": 2560,
+    "max_position_embeddings": 16384,
+    "model_name": "smallthinker_21b_instruct",
+    "moe_ffn_hidden_size": 768,
+    "moe_num_active_primary_experts": 6,
+    "moe_num_primary_experts": 64,
+    "moe_primary_router_apply_softmax": True,
+    "norm_topk_prob": True,
+    "num_attention_heads": 28,
+    "num_hidden_layers": 52,
+    "num_key_value_heads": 4,
+    "rms_norm_eps": 1e-06,
+    "rope_layout": [0, 1, 1, 1] * 13,
+    "rope_scaling": None,
+    "rope_theta": 1500000,
+    "sliding_window_layout": [0, 1, 1, 1] * 13,
+    "sliding_window_size": 4096,
+    "tie_word_embeddings": False,
+    "vocab_size": 151936,
+}
+_SMALLTHINKER = SMALLTHINKER_21B_A3B_CONFIG
+#: what the layers here cannot be told otherwise: a softmax over the
+#: chosen experts' logits, renormalised; plain rotary where there is any
+_SMALLTHINKER_FIXED = ("moe_primary_router_apply_softmax", "norm_topk_prob", "rope_scaling")
+
+
+@register_model_builder(type="JaxBackboneForecast")
+def smallthinker(
+    n_features: int,
+    n_features_out: Optional[int] = None,
+    lookback_window: int = 8192,
+    num_hidden_layers: int = _SMALLTHINKER["num_hidden_layers"],
+    rope_layout: Sequence[int] = tuple(_SMALLTHINKER["rope_layout"]),
+    sliding_window_layout: Sequence[int] = tuple(_SMALLTHINKER["sliding_window_layout"]),
+    hidden_size: int = _SMALLTHINKER["hidden_size"],
+    head_dim: int = _SMALLTHINKER["head_dim"],
+    num_attention_heads: int = _SMALLTHINKER["num_attention_heads"],
+    num_key_value_heads: int = _SMALLTHINKER["num_key_value_heads"],
+    moe_ffn_hidden_size: int = _SMALLTHINKER["moe_ffn_hidden_size"],
+    moe_num_primary_experts: int = _SMALLTHINKER["moe_num_primary_experts"],
+    experts_held: Optional[int] = None,
+    expert_offset: int = 0,
+    moe_num_active_primary_experts: int = _SMALLTHINKER["moe_num_active_primary_experts"],
+    sliding_window_size: int = _SMALLTHINKER["sliding_window_size"],
+    rope_theta: float = _SMALLTHINKER["rope_theta"],
+    rms_norm_eps: float = _SMALLTHINKER["rms_norm_eps"],
+    optimizer: Union[str, OptimizerSpec] = "Adam",
+    optimizer_kwargs: Optional[Dict[str, Any]] = None,
+    compile_kwargs: Optional[Dict[str, Any]] = None,
+    compute_dtype: str = "float32",
+    precision: str = "",
+    **kwargs,
+) -> BackboneSpec:
+    """``model_name: smallthinker_*`` (defaults: SmallThinker-21BA3B-
+    Instruct). The first ``num_hidden_layers`` entries of
+    ``sliding_window_layout`` (1: a query sees the ``sliding_window_size``
+    rows up to itself; 0: every causal row) and of ``rope_layout`` (1:
+    plain rotary at ``rope_theta``; 0: no position encoding) are the
+    layers held; the two have to agree a layer, as published (a layer
+    limited by distance rotates, a full one has no positions). Every
+    layer routes: the router reads the layer's input before its
+    attention, the ``moe_num_active_primary_experts`` largest logits are
+    chosen and a softmax over those weighs the experts, which are gated
+    by ``relu`` and of which this holder keeps ``experts_held`` (default:
+    all) from ``expert_offset``."""
+    for key in _SMALLTHINKER_FIXED:
+        if key in kwargs and kwargs[key] != _SMALLTHINKER[key]:
+            raise ValueError(f"smallthinker runs {key}={_SMALLTHINKER[key]!r} only; got {kwargs[key]!r}")
+    if min(len(rope_layout), len(sliding_window_layout)) < num_hidden_layers:
+        raise ValueError("smallthinker needs a rope_layout and a sliding_window_layout entry for every layer held")
+    rotated = tuple(bool(flag) for flag in rope_layout[:num_hidden_layers])
+    limited = tuple(bool(flag) for flag in sliding_window_layout[:num_hidden_layers])
+    if rotated != limited:
+        raise ValueError(
+            "smallthinker gives a rotary embedding by operator: rope_layout has to equal "
+            "sliding_window_layout in every layer held"
+        )
+    ropes = {
+        "full_attention": {"rope_type": "none"},
+        "sliding_attention": {"rope_type": "default", "rope_theta": float(rope_theta), "partial_rotary_factor": 1},
+    }
+    compile_kwargs = compile_kwargs or {}
+    return BackboneSpec(
+        n_features=n_features,
+        n_features_out=n_features_out or n_features,
+        lookback_window=lookback_window,
+        layer_ops=tuple("sliding_attention" if flag else "full_attention" for flag in limited),
+        layer_ffns=("moe",) * num_hidden_layers,
+        hidden_size=hidden_size,
+        attention_head_dim=head_dim,
+        num_attention_heads=num_attention_heads,
+        num_key_value_heads=num_key_value_heads,
+        moe_intermediate_size=moe_ffn_hidden_size,
+        num_experts=moe_num_primary_experts,
+        experts_held=moe_num_primary_experts if experts_held is None else experts_held,
+        expert_offset=expert_offset,
+        num_experts_per_tok=moe_num_active_primary_experts,
+        router="softmax_of_chosen",
+        router_input="layer_input",
+        expert_activation="relu",
+        rope_theta=float(rope_theta),
+        rope_parameters=tuple((op, tuple(sorted(rope.items()))) for op, rope in sorted(ropes.items())),
+        qk_norm=False,
+        sliding_window=sliding_window_size,
+        norm_eps=float(rms_norm_eps),
+        optimizer=OptimizerSpec.from_config(optimizer, optimizer_kwargs),
+        loss=compile_kwargs.get("loss", "mse"),
+        compute_dtype=compute_dtype,
+        precision=precision,
+    )

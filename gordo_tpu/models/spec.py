@@ -280,10 +280,24 @@ OPERATORS = ("conv", "full_attention", "sparse_attention", "sliding_attention")
 ATTENTIONS = tuple(op for op in OPERATORS if op != "conv")
 FFNS = ("dense", "moe")
 #: how a routed layer scores its experts: ``sigmoid_bias`` (sigmoid
-#: scores, the ``k`` largest ``score + bias`` chosen, a bias buffer) or
+#: scores, the ``k`` largest ``score + bias`` chosen, a bias buffer),
 #: ``softmax`` (softmax over all logits, the ``k`` largest renormalised
-#: to sum 1, no bias)
-ROUTERS = ("sigmoid_bias", "softmax")
+#: to sum 1, no bias) or ``softmax_of_chosen`` (the ``k`` largest logits
+#: chosen, a softmax over those: the same weights, and the same choice
+#: wherever the softmax over all does not round two experts to one
+#: value; where logits lie some 90 apart it rounds all but the largest
+#: to zero, and its ``k`` largest are then the largest and whatever a
+#: tie picks)
+ROUTERS = ("sigmoid_bias", "softmax", "softmax_of_chosen")
+#: the tensor a routed layer's router reads: ``ffn_input`` (the normed
+#: tensor its experts read, after the operator) or ``layer_input`` (the
+#: layer's input, before the operator and before its norm: the routing
+#: then depends on nothing the operator computes)
+ROUTER_INPUTS = ("ffn_input", "layer_input")
+#: the gate of an expert: ``act(x W_1) * (x W_3)``
+EXPERT_ACTIVATIONS = ("silu", "relu")
+#: a rotary embedding's ``rope_type``; ``none``: no position encoding
+ROPE_TYPES = ("default", "yarn", "none")
 
 
 @dataclass(frozen=True)
@@ -319,10 +333,15 @@ class BackboneSpec(ModelSpec):
     reaches. ``layer_heads`` gives each layer its own number of
     query heads (empty: ``num_attention_heads`` in every layer),
     ``rope_parameters`` each operator its own rotary embedding (an
-    operator it does not name: ``rope_theta`` over the whole head),
+    operator it does not name: ``rope_theta`` over the whole head;
+    ``rope_type: none``: that operator's ``q`` and ``k`` carry no
+    position at all),
     ``attention_gate`` a sigmoid gate on each head's output,
     ``shared_expert_intermediate_size`` an expert that every token
-    takes beside the routed ones (0: none).
+    takes beside the routed ones (0: none). ``router_input`` says which
+    tensor a routed layer's router reads (``ROUTER_INPUTS``) and
+    ``expert_activation`` the gate of its experts
+    (``EXPERT_ACTIVATIONS``).
     """
 
     n_features: int
@@ -363,6 +382,8 @@ class BackboneSpec(ModelSpec):
     attention_gate: bool = False
     sliding_window: int = 0
     shared_expert_intermediate_size: int = 0
+    router_input: str = "ffn_input"
+    expert_activation: str = "silu"
 
     windowed = True
 
@@ -376,6 +397,12 @@ class BackboneSpec(ModelSpec):
             raise ValueError("hidden_size must divide into num_attention_heads")
         if self.router not in ROUTERS:
             raise ValueError(f"unknown router {self.router!r}; known: {ROUTERS}")
+        if self.router_input not in ROUTER_INPUTS:
+            raise ValueError(f"unknown router_input {self.router_input!r}; known: {ROUTER_INPUTS}")
+        if self.expert_activation not in EXPERT_ACTIVATIONS:
+            raise ValueError(
+                f"unknown expert_activation {self.expert_activation!r}; known: {EXPERT_ACTIVATIONS}"
+            )
         if self.head_dim % 2 or self.head_dim <= 0:
             raise ValueError("the rotary embedding needs an even head width")
         if "sparse_attention" in self.layer_ops and (
@@ -395,7 +422,7 @@ class BackboneSpec(ModelSpec):
         for op, _ in self.rope_parameters:
             rope = self.rope_of(op)
             rotated = int(self.head_dim * rope["partial_rotary_factor"])
-            if op not in ATTENTIONS or rope["rope_type"] not in ("default", "yarn"):
+            if op not in ATTENTIONS or rope["rope_type"] not in ROPE_TYPES:
                 raise ValueError(f"rope_parameters of {op!r}: unknown operator or rope_type")
             if rotated < 2 or rotated % 2:
                 raise ValueError("the rotary embedding needs an even number of rotated dimensions")
@@ -423,8 +450,8 @@ class BackboneSpec(ModelSpec):
     def rope_of(self, op: str) -> Dict[str, Any]:
         """The rotary embedding of operator ``op``, HF's keys:
         ``rope_theta``, ``partial_rotary_factor`` (the leading share of a
-        head that is rotated), ``rope_type`` (``default`` or ``yarn``)
-        and, for YaRN, ``factor``, ``original_max_position_embeddings``,
+        head that is rotated), ``rope_type`` (``default``, ``yarn`` or
+        ``none``: not rotated) and, for YaRN, ``factor``, ``original_max_position_embeddings``,
         ``beta_fast``, ``beta_slow``, ``attention_factor``."""
         stated = dict(dict(self.rope_parameters).get(op, ()))
         return {

@@ -463,7 +463,8 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     program with the ``validation_slots`` of its buckets summed and, for
     the dense fit, the widest ``shuffle_columns`` among them; a predict
     program with its ``members`` and, of them, those whose parameters
-    it took from the device, ``params_resident_members``) and its
+    it took from the device, ``params_resident_members``; a fit of experts
+    gated by ``relu`` with its ``gate_active`` and ``gate_total`` summed) and its
     ``self_seconds``, the wall time no part or program covers. Where its
     spans carry them, a phase also has ``cpu_seconds`` (its own thread's)
     and ``process_cpu_seconds`` (every thread's between its two ends),
@@ -557,6 +558,9 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
             if "params_resident_members" in attributes:  # a predict program's span
                 for key in ("members", "params_resident_members"):
                     part[key] = part.get(key, 0) + int(attributes.get(key) or 0)
+            if "gate_total" in attributes:  # a fit of experts gated by relu: a list a layer
+                for key in ("gate_active", "gate_total"):
+                    part[key] = part.get(key, 0.0) + float(sum(attributes.get(key) or ()))
             if span["name"] == "build_part":
                 nested_cpu = nested_part_cpu_seconds(attributes)
                 for nested, nested_seconds in nested_part_seconds(attributes).items():
@@ -859,6 +863,9 @@ def render_analysis(doc: Dict[str, Any]) -> str:
                     )
                     if key in measured
                 ]
+                if measured.get("gate_total"):
+                    share = 100.0 * measured["gate_active"] / measured["gate_total"]
+                    counters.append(f"gate_active_pct={share:.1f}")
                 if counters:
                     part += f" [{', '.join(counters)}]"
                 rows.append(

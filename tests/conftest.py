@@ -87,14 +87,15 @@ def tiny_model_definition() -> dict:
 #: 5`` for every configuration (the reference's defaults, which the first
 #: three keep); the harness holds a build to the file's ``epochs``, and a
 #: window of 8,192 rows trains 2 a step and one epoch a job (ISSUE 31,
-#: ISSUE 33). ``tests/chipbench/test_laguna_swa_cell.py`` holds both
-#: configurations to every other line of that test.
+#: ISSUE 33, ISSUE 39). ``tests/chipbench/test_smallthinker_cell.py``
+#: holds the three configurations to every other line of that test.
 MANIFEST_CASE_OUTGROWN = (
     "tests/chipbench/test_manifest.py::test_config_entry_and_file[keye-vl2-30b-a3b-50tag-lb8192]"
 )
 MANIFEST_CASES_OUTGROWN = (
     MANIFEST_CASE_OUTGROWN,
     "tests/chipbench/test_manifest.py::test_config_entry_and_file[laguna-xs2-50tag-lb8192]",
+    "tests/chipbench/test_manifest.py::test_config_entry_and_file[smallthinker-21b-a3b-50tag-lb8192]",
 )
 #: ``test_keye_dsa_cell.py`` asserts that its cell and its configuration
 #: are the manifest's last: true of the PR that added them, of no later
@@ -107,9 +108,56 @@ MANIFEST_CASES_OUTGROWN = (
 LAST_ENTRY_CASE_OUTGROWN = (
     "tests/chipbench/test_keye_dsa_cell.py::test_the_manifest_has_no_problems_with_the_cell"
 )
+#: PR 33's own copies of those two tests said in their turn that
+#: ``laguna_swa_build`` and its configuration are the manifest's last,
+#: that a cell's own readers list it alone, and that this table holds
+#: exactly PR 33's three cases: true of that PR, of no later one (ISSUE
+#: 39). ``tests/chipbench/test_smallthinker_cell.py`` states every other
+#: line of the four cases for the cells they were about, in a form a
+#: later cell leaves true (membership, never position), and holds each
+#: marked case to failing on the named lines alone.
+LAGUNA_CASES_OUTGROWN = {
+    "tests/chipbench/test_laguna_swa_cell.py::test_the_manifest_has_no_problems_with_the_cell"
+    "[laguna_swa_build-laguna-xs2-50tag-lb8192-own0]":
+        "asserts that laguna_swa_build is the manifest's last cell and the only one its readers list",
+    "tests/chipbench/test_laguna_swa_cell.py::test_the_manifest_has_no_problems_with_the_cell"
+    "[keye_dsa_build-keye-vl2-30b-a3b-50tag-lb8192-own1]":
+        "asserts that laguna_swa_build is the manifest's last cell",
+    "tests/chipbench/test_laguna_swa_cell.py::test_the_manifest_cases_expected_to_fail_fail_on_their_last_line_alone"
+    "[keye-vl2-30b-a3b-50tag-lb8192]": "asserts that this table holds exactly PR 33's three cases",
+    "tests/chipbench/test_laguna_swa_cell.py::test_the_manifest_cases_expected_to_fail_fail_on_their_last_line_alone"
+    "[laguna-xs2-50tag-lb8192]": "asserts that this table holds exactly PR 33's three cases",
+}
+#: ``test_host_accounting.py`` asserts, in each of its six cases, that
+#: PR 37's six readers are the last six entries of ``per_layer`` (its own
+#: comment: "appended: the entries that were there are where they
+#: were"). ISSUE 39 asked for its new entry before them for that reason.
+#: The builder's contract of this round says of ``BENCHMARK.json``: "Put
+#: new entries at the end of their lists: one put first or in the middle
+#: reads as a change to what was there", and a PR that changes an entry
+#: that was there is refused; every PR's diff of the file has appended
+#: (``git log -p BENCHMARK.json``). So ``prerouted_fit_mfu_pct`` is the
+#: last entry and the six cases fail on that line; the three whose
+#: readers read the new cell's jobs (``collect_gbps``,
+#: ``host_cores_busy``, ``host_rss_peak_gb``) list it, and fail on their
+#: ``workloads`` by that one appended name besides
+#: (``test_smallthinker_cell.py`` holds every other line of them, and
+#: the entries to the table but for that name). PERF.md 7 (f).
+APPENDED_CASES_OUTGROWN = tuple(
+    f"tests/chipbench/test_host_accounting.py::test_the_manifest_entry_is_the_issues_table[{name}]"
+    for name in (
+        "collect_gbps", "fetch_cpu_parallelism", "fetch_resample_cpu_ms", "host_cores_busy",
+        "host_rss_peak_gb", "stack_gbps",
+    )
+)
 OUTGROWN = {
     **{case: "asserts batch_size 32 and epochs 5 of every configuration" for case in MANIFEST_CASES_OUTGROWN},
     LAST_ENTRY_CASE_OUTGROWN: "asserts that keye_dsa_build is the manifest's last cell",
+    **LAGUNA_CASES_OUTGROWN,
+    **{
+        case: "asserts that PR 37's six readers are the manifest's last and list no later cell"
+        for case in APPENDED_CASES_OUTGROWN
+    },
 }
 
 

@@ -188,7 +188,7 @@ def test_each_layer_kind_against_the_reference(seeded, reference, kind):
         got = backbone.dense_ffn(params["layer_0"]["ffn"], jnp.asarray(u))
         want = reference.dense_ffn(jnp.asarray(u), weights["layer_0"]["ffn"])
     elif kind == "moe_ffn":
-        got, routed, pairs = backbone.moe_ffn(spec, params["layer_2"]["moe"], jnp.asarray(u))
+        got, routed, pairs, _ = backbone.moe_ffn(spec, params["layer_2"]["moe"], jnp.asarray(u))
         want, counts = reference.moe_ffn(jnp.asarray(u), weights["layer_2"]["moe"], sizes)
         assert np.array_equal(routed, counts) and int(routed.sum()) == 3 * 12 * 2
         assert int(pairs) == int(counts[2:4].sum())
@@ -277,7 +277,7 @@ def test_the_shares_add_up_to_the_uncut_layer(references, kind, held):
     params = backbone.init_backbone(jax.random.PRNGKey(11), whole)[f"layer_{layer}"]["moe"]
     assert ("expert_bias" in params) == (kind == "lfm2_moe")
     u = jnp.asarray(np.random.RandomState(2).normal(size=(4, 12, 32)).astype(np.float32))
-    uncut, routed, pairs = backbone.moe_ffn(whole, params, u)
+    uncut, routed, pairs, _ = backbone.moe_ffn(whole, params, u)
     assert int(pairs) == int(routed.sum()) == 4 * 12 * 2
     _, weights = backbone.route(whole, params, u.reshape(-1, 32))
     np.testing.assert_allclose(np.asarray(weights).sum(axis=1), 1.0, rtol=1e-5)  # normalised
@@ -285,7 +285,7 @@ def test_the_shares_add_up_to_the_uncut_layer(references, kind, held):
     for offset in range(0, 8, held):
         share = make(experts_held=held, expert_offset=offset)
         slices = {k: params[k][offset : offset + held] for k in ("w1", "w3", "w2")}
-        out, routed_s, pairs_s = backbone.moe_ffn(share, {**params, **slices}, u)
+        out, routed_s, pairs_s, _ = backbone.moe_ffn(share, {**params, **slices}, u)
         assert np.array_equal(routed_s, routed)  # the router is the whole model's
         assert int(pairs_s) == int(routed[offset : offset + held].sum())
         sizes = {key: getattr(share, key) for key in reference.SIZES}
@@ -306,7 +306,7 @@ def test_no_pair_is_dropped_when_one_expert_takes_every_token(reference, favoure
     params = backbone.init_backbone(jax.random.PRNGKey(13), spec)["layer_2"]["moe"]
     params = {**params, "expert_bias": jnp.zeros(8).at[favoured].set(100.0)}
     u = jnp.asarray(np.random.RandomState(4).normal(size=(5, 12, 32)).astype(np.float32))
-    out, routed, pairs = jax.jit(lambda p, u: backbone.moe_ffn(spec, p, u))(params, u)
+    out, routed, pairs, _ = jax.jit(lambda p, u: backbone.moe_ffn(spec, p, u))(params, u)
     assert int(routed[favoured]) == 5 * 12  # every token
     assert int(pairs) == int(routed[2:4].sum())
     sizes = {key: getattr(spec, key) for key in reference.SIZES}
@@ -353,8 +353,8 @@ def test_a_window_of_padding_routes_nothing_and_changes_no_gradient(seeded, padd
     assert np.all(np.asarray(aux["router_tokens"]) <= np.asarray(aux_all["router_tokens"]))
     # one layer alone: the padding's rows get no expert's output
     u = jnp.asarray(np.random.RandomState(8).normal(size=(4, 12, 32)).astype(np.float32))
-    layer_out, routed, pairs = backbone.moe_ffn(spec, params["layer_2"]["moe"], u, active)
-    whole, routed_all, _ = backbone.moe_ffn(spec, params["layer_2"]["moe"], u)
+    layer_out, routed, pairs, _ = backbone.moe_ffn(spec, params["layer_2"]["moe"], u, active)
+    whole, routed_all, _, _ = backbone.moe_ffn(spec, params["layer_2"]["moe"], u)
     assert not np.any(np.asarray(layer_out)[list(padding)])
     assert int(routed.sum()) == len(kept) * a_window and int(pairs) == int(routed[2:4].sum())
     if kept:

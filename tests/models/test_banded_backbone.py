@@ -429,7 +429,7 @@ def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer
     for share in range(16):
         spec = toy(num_experts=32, experts_held=2, expert_offset=2 * share, num_experts_per_tok=4)
         held = dict(w, **{name: w[name][2 * share : 2 * share + 2] for name in ("w1", "w3", "w2")})
-        out, routed, pairs_here = backbone.moe_ffn(spec, held, u)
+        out, routed, pairs_here, _ = backbone.moe_ffn(spec, held, u)
         total, pairs = total + out, pairs + int(pairs_here)
         assert routed_by_all is None or np.array_equal(routed, routed_by_all)  # every holder routes alike
         routed_by_all = routed

@@ -305,6 +305,69 @@ def test_a_banded_attention_backbone_takes_the_same_path(tmp_path_factory, monke
         np.testing.assert_allclose(want.to_numpy(), prediction, rtol=1e-4, atol=1e-5)
 
 
+#: ``kind: smallthinker`` at toy widths: full without positions, then three
+#: sliding layers with rotary; a band of 30 of 100 rows in tiles of 8, 14
+#: heads over 2 key/value heads, a router on the layer's input, relu experts
+TOY_PREROUTED = dict(
+    kind="smallthinker", lookback_window=LOOKBACK, num_hidden_layers=4, hidden_size=32, head_dim=16,
+    num_attention_heads=14, num_key_value_heads=2, moe_ffn_hidden_size=24, moe_num_primary_experts=8,
+    experts_held=2, moe_num_active_primary_experts=3, sliding_window_size=30, epochs=2, batch_size=32,
+)
+
+
+def test_a_prerouted_backbone_takes_the_same_path(tmp_path_factory, monkeypatch):
+    """``kind: smallthinker`` through ``build-fleet``, the artifact and
+    ``gordo-tpu trace``, as the other three go: its fits carry the band's
+    counters and the gate's beside the router's, a row a layer."""
+    from gordo_tpu.models import backbone
+    from gordo_tpu.telemetry.trace_analysis import build_breakdown, render_analysis
+
+    monkeypatch.setattr(backbone, "ATTENTION_TILE", 8)  # the program's constant is 512 rows: a toy's 100 take 8
+    code, root = build_fleet(tmp_path_factory.mktemp("prerouted") / REVISION, TOY_PREROUTED, ("pump-s",))
+    assert code == 0
+    with open(os.path.join(root, "build_status.json")) as f:
+        status = json.load(f)
+    assert status["state"] == "complete" and status["machines"]["completed"] == 1
+    assert not any(status["robustness"].values())
+    counters = status["fit_counters"]
+    assert len(counters) == 4 and all(c["members"] == 1 for c in counters)
+    full, sliding = LOOKBACK * (LOOKBACK + 1) / 2, sum(min(t + 1, 30) for t in range(LOOKBACK))
+    for c, windows in zip(sorted(counters, key=lambda c: c["pairs_total"][0]), (12, 23, 34, 45)):
+        trained = 2.0 * windows  # two epochs
+        assert c["pairs_total"] == [2 * windows * LOOKBACK * 3] * 4 and "index_topk" not in c
+        assert c["pairs_attended"] == [trained * full] + [trained * sliding] * 3
+        # tiles of 8 over 100 rows: 91 up to the diagonal, 55 in a band that reaches four tiles back
+        assert c["pairs_multiplied"] == [trained * n * 8 * 8 for n in (91, 55, 55, 55)]
+        assert c["pairs_here"] == [sum(layer[:2]) for layer in c["router_tokens"]]
+        assert c["gate_total"] == [24.0 * pairs for pairs in c["pairs_here"]]
+        assert 0 < sum(c["gate_active"]) < sum(c["gate_total"])
+    with open(os.path.join(root, "build_trace.jsonl")) as f:
+        spans = [json.loads(line) for line in f]
+    fits = [s["attributes"] for s in spans
+            if s["name"] == "device_program" and "fit" in s["attributes"]["program"]]
+    assert len(fits) == 4 and sum(bool(a["compile"]) for a in fits) == 1
+    assert all(set(a["fit_counters"]) >= {"gate_active", "gate_total", "pairs_attended", "pairs_here"} for a in fits)
+    found = build_breakdown(spans)
+    rendered = render_analysis({"trace": "t", "spans_read": len(spans), "build_breakdown": found})
+    last = max(fits, key=lambda a: a["pairs_total"][0])  # the final fit, a phase of its own
+    share = 100.0 * sum(last["gate_active"]) / sum(last["gate_total"])
+    assert f"  program fleet_windowed_fit [validation_slots=0, gate_active_pct={share:.1f}]" in rendered
+    model = serializer.load(os.path.join(root, "pump-s"))
+    X = rows(LOOKBACK + 6)
+    prediction = np.asarray(model.predict(X))
+    assert prediction.shape == (6, len(TAGS)) and np.isfinite(prediction).all()
+    estimator = model.base_estimator.steps[-1][1]
+    assert estimator.spec_.layer_ops == ("full_attention",) + ("sliding_attention",) * 3
+    assert (estimator.spec_.router_input, estimator.spec_.expert_activation) == ("layer_input", "relu")
+    assert estimator.params_["layer_1"]["attn"]["wq"].shape == (32, 14 * 16)
+    loss, norms = estimator.training_loss_and_grad_norms(
+        model.base_estimator.steps[0][1].transform(X), X.to_numpy()
+    )
+    # (a layer none of whose pairs came here gives its router nothing: the sum over the layers)
+    assert np.isfinite(loss) and norms["layer_0"]["attn"]["wq"] > 0
+    assert sum(norms[f"layer_{i}"]["moe"]["router"] for i in range(4)) > 0
+
+
 def series(n=150, f=4, seed=0):
     return np.random.RandomState(seed).rand(n, f).astype(np.float32)
 
