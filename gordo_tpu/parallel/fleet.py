@@ -62,6 +62,7 @@ from ..planner.packing import (  # noqa: F401
     plan_train_buckets,
 )
 from ..utils.faults import InjectedDeviceError, fault_point
+from . import host_blocks
 from .mesh import make_mesh, model_data_sharding, model_sharding
 
 logger = logging.getLogger(__name__)
@@ -933,6 +934,7 @@ class FleetTrainer:
         n_padded: int,
         bucket: List[FleetMember],
         config: FitConfig,
+        blocks: host_blocks.Lease,
         m_padded: Optional[int] = None,
     ):
         """Stack + mask a bucket; returns the device-sharded ``(X, y, wtr,
@@ -950,6 +952,10 @@ class FleetTrainer:
         ``m_padded`` raises the member-axis floor further (the packed
         planner pads sibling HBM-split buckets to one shared rung so they
         reuse a single compiled program).
+
+        The host blocks are ``blocks``' (parallel/host_blocks.py): the
+        caller ends that lease when the program that reads the returned
+        arrays has answered, and keeps none of them past it.
         """
         with telemetry.part_span("stack") as span:
             model_axis = self.mesh.devices.shape[0]
@@ -962,15 +968,12 @@ class FleetTrainer:
             n_padded = -(-n_padded // step) * step
 
             def stacked(attr_arrays):
-                # Fill a preallocated block instead of pad-then-np.stack: one
-                # copy per member, zero rows double as sample padding and
+                # Fill one block instead of pad-then-np.stack: one copy per
+                # member, zero rows double as sample padding and
                 # zero-weight dummy models.
-                out = np.zeros(
-                    (m_total, n_padded) + np.shape(attr_arrays[0])[1:], np.float32
+                return blocks.stacked(
+                    (m_total, n_padded) + np.shape(attr_arrays[0])[1:], attr_arrays
                 )
-                for i, a in enumerate(attr_arrays):
-                    out[i, : len(a)] = a
-                return out
 
             X = stacked([m.X for m in bucket])
             # A bare AE trains y == X: the block is staged once and the
@@ -979,12 +982,15 @@ class FleetTrainer:
             # copy of every sample in the rows the steps gather.
             y = None if all(m.y is m.X for m in bucket) else stacked([m.y for m in bucket])
 
-            wtr = np.zeros((m_total, n_padded), np.float32)
-            wval = np.zeros((m_total, n_padded), np.float32)
+            wtr = blocks.zeros((m_total, n_padded))
+            wval = blocks.zeros((m_total, n_padded))
             for i, member in enumerate(bucket):
                 _fill_weight_row(wtr, wval, i, member.n, member, config)
             if span.recording:  # the blocks filled
-                span.set(bytes=tree_nbytes(X, y, wtr, wval))
+                span.set(
+                    bytes=tree_nbytes(X, y, wtr, wval),
+                    bytes_reused=blocks.bytes_reused,
+                )
             validation_slots, wval, Xval, yval = validation_inputs(
                 wval, X, y, axis=1
             )
@@ -1024,33 +1030,37 @@ class FleetTrainer:
         m_padded: Optional[int] = None,
         params_on_device: bool = False,
     ) -> List[FleetResult]:
-        (*data, rngs), validation_slots = self._stack_bucket(
-            spec, n_padded, bucket, config, m_padded=m_padded
-        )
-        X, y, wval = data[0], data[1], data[-1]
-        y_row = None if y is None else y.shape[2:]
-        params, opt_state, rngs = self._init_bucket_params(spec, rngs)
-        fit = _fleet_fit_program(spec, config)
-        with telemetry.program_span(
-            "fleet_fit",
-            (spec, config, X.shape, y_row, wval.shape),
-            members=len(bucket),
-            shape=str(tuple(X.shape)),
-            spec=type(spec).__name__,
-            bytes=_bucket_nbytes(bucket),
-            validation_slots=validation_slots,
-            shuffle_columns=shuffle_columns(config, X.shape[2:], y_row),
-            fit_counters=["shuffle_columns"],
-            **_calibration_attrs(spec, config, X.shape[0], X.shape[1]),
-        ):
-            params, _, losses, val_losses, epochs_ran = _traced_outputs(
-                fit(params, opt_state, *data, rngs)
+        # the lease ends where the fit's results are on the host: the
+        # program has then read every array put from the blocks, and none
+        # of them outlives this frame
+        with host_blocks.lease() as blocks:
+            (*data, rngs), validation_slots = self._stack_bucket(
+                spec, n_padded, bucket, config, blocks, m_padded=m_padded
             )
-        return self._collect_results(
-            bucket, params, losses, val_losses, epochs_ran, config,
-            steps=n_padded // config.batch_size,
-            params_on_device=params_on_device,
-        )
+            X, y, wval = data[0], data[1], data[-1]
+            y_row = None if y is None else y.shape[2:]
+            params, opt_state, rngs = self._init_bucket_params(spec, rngs)
+            fit = _fleet_fit_program(spec, config)
+            with telemetry.program_span(
+                "fleet_fit",
+                (spec, config, X.shape, y_row, wval.shape),
+                members=len(bucket),
+                shape=str(tuple(X.shape)),
+                spec=type(spec).__name__,
+                bytes=_bucket_nbytes(bucket),
+                validation_slots=validation_slots,
+                shuffle_columns=shuffle_columns(config, X.shape[2:], y_row),
+                fit_counters=["shuffle_columns"],
+                **_calibration_attrs(spec, config, X.shape[0], X.shape[1]),
+            ):
+                params, _, losses, val_losses, epochs_ran = _traced_outputs(
+                    fit(params, opt_state, *data, rngs)
+                )
+            return self._collect_results(
+                bucket, params, losses, val_losses, epochs_ran, config,
+                steps=n_padded // config.batch_size,
+                params_on_device=params_on_device,
+            )
 
     def _init_bucket_params(self, spec: ModelSpec, rngs):
         """Per-member init mirroring fit_single's derivation exactly so a
@@ -1075,6 +1085,7 @@ class FleetTrainer:
         offset: int,
         bucket: List[WindowedFleetMember],
         config: FitConfig,
+        blocks: host_blocks.Lease,
         m_padded: Optional[int] = None,
     ):
         """Stack a windowed bucket; series replicated over the data axis.
@@ -1086,6 +1097,7 @@ class FleetTrainer:
         The per-batch window gather indexes arbitrary series rows, so the
         series (and aligned targets) shard over ``models`` only; the
         virtual window axis (order + weights) shards over ``data``.
+        The host blocks are ``blocks``', as in :meth:`_stack_bucket`.
         """
         mesh = self._mesh_for(spec)
         with telemetry.part_span("stack") as span:
@@ -1099,21 +1111,29 @@ class FleetTrainer:
 
             f_in = bucket[0].series.shape[1]
             f_out = bucket[0].targets.shape[1]
-            series = np.zeros((m_total, n_padded, f_in), np.float32)
-            ytgt = np.zeros((m_total, nw_padded, f_out), np.float32)
-            order = np.zeros((m_total, nv_padded), np.int32)
-            wtr = np.zeros((m_total, nv_padded), np.float32)
-            wval = np.zeros((m_total, nv_padded), np.float32)
+            series = blocks.stacked(
+                (m_total, n_padded, f_in), (m.series for m in bucket)
+            )
+            ytgt = blocks.stacked(
+                (m_total, nw_padded, f_out), (m.targets for m in bucket)
+            )
+            order = blocks.stacked(
+                (m_total, nv_padded),
+                (
+                    m.order if m.order is not None else np.arange(m.n_windows)
+                    for m in bucket
+                ),
+                np.int32,
+            )
+            wtr = blocks.zeros((m_total, nv_padded))
+            wval = blocks.zeros((m_total, nv_padded))
             for i, member in enumerate(bucket):
-                series[i, : len(member.series)] = member.series
-                ytgt[i, : member.n_windows] = member.targets
-                nv = member.n_windows
-                order[i, :nv] = (
-                    member.order if member.order is not None else np.arange(nv)
-                )
-                _fill_weight_row(wtr, wval, i, nv, member, config)
+                _fill_weight_row(wtr, wval, i, member.n_windows, member, config)
             if span.recording:
-                span.set(bytes=tree_nbytes(series, ytgt, order, wtr, wval))
+                span.set(
+                    bytes=tree_nbytes(series, ytgt, order, wtr, wval),
+                    bytes_reused=blocks.bytes_reused,
+                )
             validation_slots, wval = validation_inputs(wval, axis=1)
 
             rngs = host_prng_keys(
@@ -1146,38 +1166,39 @@ class FleetTrainer:
         m_padded: Optional[int] = None,
         params_on_device: bool = False,
     ) -> List[FleetResult]:
-        (series, ytgt, order, wtr, wval, rngs), validation_slots = (
-            self._stack_windowed_bucket(
-                spec, n_padded, offset, bucket, config, m_padded=m_padded
-            )
-        )
-        params, opt_state, rngs = self._init_bucket_params(spec, rngs)
-        fit = _fleet_windowed_fit_program(spec, config)
-        with telemetry.program_span(
-            "fleet_windowed_fit",
-            (spec, config, series.shape, order.shape, wval.shape),
-            tokens_per_step=config.batch_size * spec.lookback_window,
-            members=len(bucket),
-            shape=str(tuple(series.shape)),
-            spec=type(spec).__name__,
-            bytes=_bucket_nbytes(bucket),
-            validation_slots=validation_slots,
-            **_calibration_attrs(
-                spec, config, series.shape[0], order.shape[1]
-            ),
-        ) as span:
-            params, _, losses, val_losses, epochs_ran, *counters = (
-                _traced_outputs(
-                    fit(params, opt_state, series, ytgt, order, wtr, wval, rngs)
+        with host_blocks.lease() as blocks:  # as in _train_bucket
+            (series, ytgt, order, wtr, wval, rngs), validation_slots = (
+                self._stack_windowed_bucket(
+                    spec, n_padded, offset, bucket, config, blocks, m_padded=m_padded
                 )
             )
-            if counters:
-                span.set(**_fit_counter_attrs(spec, counters[0], len(bucket)))
-        return self._collect_results(
-            bucket, params, losses, val_losses, epochs_ran, config,
-            steps=order.shape[1] // config.batch_size,
-            params_on_device=params_on_device,
-        )
+            params, opt_state, rngs = self._init_bucket_params(spec, rngs)
+            fit = _fleet_windowed_fit_program(spec, config)
+            with telemetry.program_span(
+                "fleet_windowed_fit",
+                (spec, config, series.shape, order.shape, wval.shape),
+                tokens_per_step=config.batch_size * spec.lookback_window,
+                members=len(bucket),
+                shape=str(tuple(series.shape)),
+                spec=type(spec).__name__,
+                bytes=_bucket_nbytes(bucket),
+                validation_slots=validation_slots,
+                **_calibration_attrs(
+                    spec, config, series.shape[0], order.shape[1]
+                ),
+            ) as span:
+                params, _, losses, val_losses, epochs_ran, *counters = (
+                    _traced_outputs(
+                        fit(params, opt_state, series, ytgt, order, wtr, wval, rngs)
+                    )
+                )
+                if counters:
+                    span.set(**_fit_counter_attrs(spec, counters[0], len(bucket)))
+            return self._collect_results(
+                bucket, params, losses, val_losses, epochs_ran, config,
+                steps=order.shape[1] // config.batch_size,
+                params_on_device=params_on_device,
+            )
 
     def _collect_results(
         self, bucket, params, losses, val_losses, epochs_ran, config, steps,

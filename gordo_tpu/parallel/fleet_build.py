@@ -71,6 +71,7 @@ from ..planner.packing import trains_alone, windowed_scoring_batch
 from ..utils.env import env_float, env_int, env_str
 from ..utils.faults import fault_point
 from ..utils.retry import retry_call
+from . import host_blocks
 from .fleet import (
     FleetMember,
     FleetTrainer,
@@ -728,6 +729,8 @@ class FleetBuilder:
             raise
         finally:
             recorder.close()
+            # the staging pool keeps what this build used, and no more
+            host_blocks.trim()
             if not self._ledger_flushed:
                 # a build that ended early never reached its finish phase
                 self._ledger.flush()
@@ -2025,65 +2028,93 @@ class FleetBuilder:
                 (plan, fold_idx)
             )
         for (spec, geometry, window), group in groups.items():
-            with self._phase("cv_score"), self._part("stack") as span:
-                # per item: (train_rows, window_idx, target_rows)
-                fold_rows = []
-                for plan, fold_idx in group:
-                    train_rows, test_rows = per_plan_folds[plan.machine.name][
-                        fold_idx
-                    ]
-                    window_idx, target_rows = self._test_window_rows(plan, test_rows)
-                    fold_rows.append((train_rows, window_idx, target_rows))
-                scoring = self._fold_scoring(group, fold_rows, window)
-                if scoring is not None and span.recording:
-                    span.set(bytes=scoring.nbytes)
-            with self._phase("cv_predict"):
-                with self._part("stack"):
-                    # on the device: the bucket's own block where the
-                    # group is the bucket, else a gather of its rows
-                    stacked = self.trainer.device_params(
-                        spec,
-                        [
-                            by_name[_fold_member_name(p.machine.name, k)]
-                            for p, k in group
-                        ],
-                    )
-                if geometry == ("windowed",):
-                    predicted = self._predict_windowed_group(
-                        spec,
-                        stacked,
-                        [p for p, _ in group],
-                        [wi for _, wi, _ in fold_rows],
-                        scoring,
-                    )
-                else:
-                    with self._part("stack") as span:
-                        n_max = max(len(wi) for _, wi, _ in fold_rows)
-                        X = np.zeros(
-                            (len(group), n_max) + group[0][0].windows.shape[1:],
-                            np.float32,
+            # the group's staging blocks (parallel/host_blocks.py), a
+            # lease a ``stack`` part, are held until its predict program
+            # has answered: its scores, or its predictions, are then on
+            # the host, and what stays on the device is the program's own
+            # output
+            with host_blocks.lease() as scoring_blocks, host_blocks.lease() as blocks:
+                with self._phase("cv_score"), self._part("stack") as span:
+                    # per item: (train_rows, window_idx, target_rows)
+                    fold_rows = []
+                    for plan, fold_idx in group:
+                        train_rows, test_rows = per_plan_folds[plan.machine.name][
+                            fold_idx
+                        ]
+                        window_idx, target_rows = self._test_window_rows(
+                            plan, test_rows
                         )
-                        for i, (p, _) in enumerate(group):
-                            X[i, : len(fold_rows[i][1])] = p.windows[fold_rows[i][1]]
-                        span.set(bytes=X.nbytes)
-                    predicted = self.trainer.predict_bucket(
-                        spec, stacked, X, scoring=scoring
+                        fold_rows.append((train_rows, window_idx, target_rows))
+                    scoring = self._fold_scoring(
+                        group, fold_rows, window, scoring_blocks
                     )
+                    if scoring is not None and span.recording:
+                        span.set(
+                            bytes=scoring.nbytes,
+                            bytes_reused=scoring_blocks.bytes_reused,
+                        )
+                with self._phase("cv_predict"):
+                    with self._part("stack"):
+                        # on the device: the bucket's own block where the
+                        # group is the bucket, else a gather of its rows
+                        stacked = self.trainer.device_params(
+                            spec,
+                            [
+                                by_name[_fold_member_name(p.machine.name, k)]
+                                for p, k in group
+                            ],
+                        )
+                    if geometry == ("windowed",):
+                        predicted = self._predict_windowed_group(
+                            spec,
+                            stacked,
+                            [p for p, _ in group],
+                            [wi for _, wi, _ in fold_rows],
+                            blocks,
+                            scoring,
+                        )
+                    else:
+                        with self._part("stack") as span:
+                            X = blocks.stacked(
+                                (len(group), max(len(wi) for _, wi, _ in fold_rows))
+                                + group[0][0].windows.shape[1:],
+                                (
+                                    p.windows[wi]
+                                    for (p, _), (_, wi, _) in zip(group, fold_rows)
+                                ),
+                            )
+                            span.set(
+                                bytes=X.nbytes, bytes_reused=blocks.bytes_reused
+                            )
+                        predicted = self.trainer.predict_bucket(
+                            spec, stacked, X, scoring=scoring
+                        )
             with self._phase("cv_score"):
                 self._adopt_fold_scores(group, fold_rows, scoring, predicted, fold_state)
 
-    def _fold_scoring(self, group, fold_rows, window) -> Optional[FoldScoring]:
+    def _fold_scoring(self, group, fold_rows, window, blocks) -> Optional[FoldScoring]:
         """What the group's predict program needs to score its fold
         models (``FoldScoring``): targets, rows and scalers of every
         member :meth:`_device_scoring` takes; a member the host scores
-        counts no rows there. ``None`` where the host scores them all."""
+        counts no rows there. ``None`` where the host scores them all.
+        ``y_true`` is a block of the lease ``blocks``."""
         metric_scalers = [self._device_scoring(plan) for plan, _ in group]
         if all(scaler is None for scaler in metric_scalers):
             return None
         tags = group[0][0].y_arr.shape[1]
         n_max = max(len(target_rows) for _, _, target_rows in fold_rows)
         scoring = FoldScoring(
-            y_true=np.zeros((len(group), n_max, tags), np.float32),
+            y_true=blocks.stacked(
+                (len(group), n_max, tags),
+                (
+                    _take_rows(plan.y_arr, target_rows)
+                    if scaler is not None
+                    else plan.y_arr[:0]  # the host scores it: no row
+                    for (plan, _), (_, _, target_rows), scaler in zip(
+                        group, fold_rows, metric_scalers
+                    )
+                ),
+            ),
             rows=np.zeros(len(group), np.int32),
             metric_scaler=np.ones((len(group), 4, tags), np.float32),
             error_scaler=np.ones((len(group), 4, tags), np.float32),
@@ -2094,7 +2125,6 @@ class FleetBuilder:
         ):
             if metric_scalers[i] is None:
                 continue
-            scoring.y_true[i, : len(target_rows)] = _take_rows(plan.y_arr, target_rows)
             scoring.rows[i] = len(target_rows)
             scoring.metric_scaler[i] = metric_scalers[i]
             # the fold model's scaler is fit on the fold-TRAIN targets, as
@@ -2200,25 +2230,26 @@ class FleetBuilder:
         stacked,
         group: List[_Plan],
         window_idx: List[np.ndarray],
+        blocks: host_blocks.Lease,
         scoring: Optional[FoldScoring] = None,
     ):
         """Predictions for windowed plans, windows gathered on device (scan
         over ``planner.packing.windowed_scoring_batch`` windows a step), model-axis sharded over the
         trainer's mesh like the dense scoring path. ``window_idx`` gives
         each plan's window positions to predict (the fold-test windows);
+        ``blocks`` the lease the series and the positions are stacked in;
         ``scoring`` as ``FleetTrainer.predict_bucket`` takes it."""
-        orders = window_idx
         with self._part("stack") as span:
-            nv_max = max(len(o) for o in orders)
-            n_series_max = max(len(p.X_arr) for p in group)
-            series = np.zeros(
-                (len(group), n_series_max, group[0].X_arr.shape[1]), np.float32
+            series = blocks.stacked(
+                (len(group), max(len(p.X_arr) for p in group), group[0].X_arr.shape[1]),
+                (p.X_arr for p in group),
             )
-            order = np.zeros((len(group), nv_max), np.int32)
-            for i, p in enumerate(group):
-                series[i, : len(p.X_arr)] = p.X_arr
-                order[i, : len(orders[i])] = orders[i]
-            span.set(bytes=series.nbytes + order.nbytes)
+            order = blocks.stacked(
+                (len(group), max(len(o) for o in window_idx)), window_idx, np.int32
+            )
+            span.set(
+                bytes=series.nbytes + order.nbytes, bytes_reused=blocks.bytes_reused
+            )
         return self.trainer.predict_windowed_bucket(
             spec, stacked, series, order,
             batch_size=windowed_scoring_batch(spec), scoring=scoring,

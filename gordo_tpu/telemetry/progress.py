@@ -152,9 +152,9 @@ class BuildProgress:
     ) -> None:
         """Fold one ``build_part`` span into ``phases[phase]["parts"]``:
         its seconds and ``count``, and of ``PART_SUMS`` (``cpu_seconds``,
-        ``bytes``, ``d2h_seconds``) what the span gave, so an entry has
-        such a key only where a span had it. Nothing is written until
-        the next heartbeat or phase entry."""
+        ``bytes``, ``bytes_reused``, ``d2h_seconds``) what the span gave,
+        so an entry has such a key only where a span had it. Nothing is
+        written until the next heartbeat or phase entry."""
         with self._lock:
             entry = self._parts.setdefault(phase, {}).setdefault(
                 part, {"seconds": 0.0, "count": 0}
@@ -337,21 +337,26 @@ def _gigabytes(count: float) -> str:
 
 
 def part_rates_text(measured: Dict[str, Any]) -> str:
-    """What a part's ``cpu_seconds``, ``bytes`` and ``d2h_seconds`` say
-    beside its seconds (``build-status`` and ``gordo-tpu trace`` print
-    it): the share of its seconds a CPU was computing for it (the rest
-    it waited: for the GIL, for I/O, for the device, for a core), its
-    GB/s where it moved bytes, and a ``collect``'s fetch alone. Empty
-    where the part carries none of them."""
+    """What a part's ``cpu_seconds``, ``bytes``, ``bytes_reused`` and
+    ``d2h_seconds`` say beside its seconds (``build-status`` and
+    ``gordo-tpu trace`` print it): the share of its seconds a CPU was
+    computing for it (the rest it waited: for the GIL, for I/O, for the
+    device, for a core), its GB/s where it moved bytes, of a ``stack``'s
+    bytes the share filled into buffers the staging pool already held,
+    and a ``collect``'s fetch alone. Empty where the part carries none of
+    them."""
     seconds = float(measured.get("seconds") or 0.0)
     shown = []
     if "cpu_seconds" in measured and seconds > 0:
         shown.append(f"cpu {100.0 * float(measured['cpu_seconds']) / seconds:.0f}%")
     if measured.get("bytes"):
+        size = float(measured["bytes"])
         if seconds > 0:
-            shown.append(f"{float(measured['bytes']) / 1e9 / seconds:.2f} GB/s")
-        else:
-            shown.append(_gigabytes(measured["bytes"]))
+            shown.append(f"{size / 1e9 / seconds:.2f} GB/s")
+        if seconds <= 0 or "bytes_reused" in measured:
+            shown.append(_gigabytes(size))
+        if "bytes_reused" in measured:
+            shown.append(f"{100.0 * float(measured['bytes_reused']) / size:.0f}% reused")
     if measured.get("d2h_seconds"):
         rate = (
             f" at {float(measured['bytes']) / 1e9 / measured['d2h_seconds']:.2f} GB/s"

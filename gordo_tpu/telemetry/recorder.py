@@ -60,9 +60,10 @@ DEFAULT_KEEP = 3
 #: what a ``build_part`` span may carry beside its seconds and ``count``,
 #: summed into its entry of ``build_status.json``: the CPU seconds of the
 #: thread (or, recorded as a sum, the threads) that ran it, the bytes it
-#: moved, and inside ``collect`` the seconds of the device-to-host fetch
-#: alone
-PART_SUMS = ("cpu_seconds", "bytes", "d2h_seconds")
+#: moved, of a ``stack``'s bytes those filled into buffers the staging
+#: pool already held (parallel/host_blocks.py), and inside ``collect`` the
+#: seconds of the device-to-host fetch alone
+PART_SUMS = ("cpu_seconds", "bytes", "bytes_reused", "d2h_seconds")
 #: and a ``build_phase`` span beside its seconds: its own thread's CPU
 #: seconds and the whole process's between its two ends
 PHASE_SUMS = ("cpu_seconds", "process_cpu_seconds")

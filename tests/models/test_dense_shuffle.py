@@ -41,6 +41,7 @@ from gordo_tpu.models.training import (
     validation_pass,
 )
 from gordo_tpu.ops.losses import resolve_loss, weighted_mean_loss
+from gordo_tpu.parallel import host_blocks
 from gordo_tpu.parallel import FleetMember, FleetTrainer
 
 TAGS, ROWS, BATCH = 3, 64, 16
@@ -322,7 +323,7 @@ def test_a_one_member_bucket_trains_the_plain_fits_bits(target, validation_split
     (result,) = trainer.train([member], config)
 
     (Xs, ys, wtr, Xval, yval, wval, rngs), _ = trainer._stack_bucket(
-        SPEC, 60, [member], config
+        SPEC, 60, [member], config, host_blocks.Lease()
     )
     assert (ys is None) == (yval is None) == (target == "input")
     params, opt_state, rngs = trainer._init_bucket_params(SPEC, rngs)

@@ -23,6 +23,7 @@ from gordo_tpu.models.training import (
     validation_inputs,
 )
 from gordo_tpu.ops.windows import sliding_windows, window_targets
+from gordo_tpu.parallel import host_blocks
 from gordo_tpu.parallel import (
     FleetMember,
     FleetTrainer,
@@ -153,11 +154,16 @@ def test_validation_split_hands_the_program_a_validation_axis(kind):
     in; without it the trainer hands over an axis of length zero."""
     trainer = FleetTrainer()
     stack = (
-        (lambda c: trainer._stack_bucket(DENSE_SPEC, 64, [_member(kind, "m", 1)], c))
+        (
+            lambda c: trainer._stack_bucket(
+                DENSE_SPEC, 64, [_member(kind, "m", 1)], c, host_blocks.Lease()
+            )
+        )
         if kind == "dense"
         else (
             lambda c: trainer._stack_windowed_bucket(
-                LSTM_SPEC, ROWS, LOOKBACK - 1, [_member(kind, "m", 1)], c
+                LSTM_SPEC, ROWS, LOOKBACK - 1, [_member(kind, "m", 1)], c,
+                host_blocks.Lease(),
             )
         )
     )

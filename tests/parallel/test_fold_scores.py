@@ -28,6 +28,7 @@ from gordo_tpu.models.anomaly.diff import (
 )
 from gordo_tpu.models.nn import init_fn_for
 from gordo_tpu.models.training import History
+from gordo_tpu.parallel import host_blocks
 from gordo_tpu.parallel import FleetBuilder, fleet_build
 from gordo_tpu.parallel.fleet import FleetResult, fold_scores, stack_member_params
 from gordo_tpu.parallel.fleet_build import (
@@ -133,7 +134,9 @@ def score_both_ways(make_plans, sizes, window):
             predictions[i, :n] = plan.y_arr[BLOCK : BLOCK + n] + noise
         state = {plan.machine.name: {} for plan in plans}
         if on_device:
-            scoring = builder._fold_scoring(group, fold_rows, window)
+            scoring = builder._fold_scoring(
+                group, fold_rows, window, host_blocks.Lease()
+            )
             program = jax.jit(jax.vmap(functools.partial(fold_scores, window=window)))
             scores = program(
                 scoring.y_true, predictions, scoring.rows,

@@ -848,6 +848,13 @@ def test_a_phases_line_says_the_cores_busy_and_what_its_own_thread_computed(entr
          "  [cpu 50%, 2.00 GB/s, d2h 2.00 s at 2.00 GB/s]"),
         ({"seconds": 2.0, "count": 3, "bytes": 4 * 10**9, "d2h_seconds": 0.4},
          "  [2.00 GB/s, d2h 0.40 s at 10.00 GB/s]"),
+        # a stack of 1.32 GB, all of it into buffers the pool held, and a
+        # process's first, none of it
+        ({"seconds": 0.15, "count": 1, "cpu_seconds": 0.15, "bytes": 1_320_000_000,
+          "bytes_reused": 1_320_000_000},
+         "  [cpu 100%, 8.80 GB/s, 1.32 GB, 100% reused]"),
+        ({"seconds": 1.32, "count": 1, "bytes": 1_320_000_000, "bytes_reused": 0},
+         "  [1.00 GB/s, 1.32 GB, 0% reused]"),
         # bytes on a part too short to have seconds: the size alone
         ({"seconds": 0.0, "count": 1, "cpu_seconds": 0.0, "bytes": 5 * 10**8}, "  [0.50 GB]"),
         ({"seconds": 2.0, "count": 1}, ""),
