@@ -6,8 +6,9 @@ Attention's indexer), of ``kind: laguna`` (window and full attention
 mixed, gated heads, a shared expert) and of ``kind: smallthinker``
 (attention without positions and window attention with rotary mixed, a
 router that reads the layer's input before its attention, experts
-gated by ``relu``) as pure functions over an explicit parameter tree,
-like :mod:`.nn`.
+gated by ``relu``) and of ``kind: kanana`` (``deepseek_v3``'s latent
+attention in every layer, two shared experts as one) as pure functions
+over an explicit parameter tree, like :mod:`.nn`.
 
 ``u`` is the ``[batch, T, hidden]`` sequence of a batch of windows.
 
@@ -25,6 +26,18 @@ like :mod:`.nn`.
   query heads (the width of
   its ``wq``). With ``spec.attention_gate`` each head's output is
   multiplied by ``sigmoid(u Wg)`` before ``wo``.
+- latent attention (``spec.kv_lora_rank`` above 0; :func:`_latent_heads`):
+  ``full_attention`` whose heads come another way. ``q = u W_q``, each
+  head ``[q_nope | q_rope]``; ``[c | r] = u W_kva``, ``c`` the latent,
+  normed (``kv_norm``), ``r`` one rotary key that every head shares,
+  not normed; ``[k_nope | v] = RMSNorm(c) W_kvb`` a head; the rotary
+  embedding turns ``q_rope`` and ``r`` alone (the trailing part of a
+  head, in interleaved pairs under ``spec.rope_interleave``); a head's
+  key is ``[k_nope | r]``. Scores are ``qk_nope_head_dim +
+  qk_rope_head_dim`` wide and scaled by that width, values
+  ``v_head_dim`` wide. Every head has a key and a value of its own
+  (groups of one): the shared key is broadcast into them, and the
+  attention is the one every kind runs, in tiles or in one piece.
 - ``sliding_attention``: the same under the mask ``s <= t and t - s <
   sliding_window``. It, and ``full_attention`` over a window longer
   than :data:`ATTENTION_TILE` rows, run in square tiles under a
@@ -118,6 +131,10 @@ SPARSE_ATTENTION_SCOPE = "sparse_attention"
 TILES_SCOPES = {"full_attention": "full_attention_tiles", "sliding_attention": "sliding_attention_tiles"}
 GATE_SCOPE = "attention_gate"
 SHARED_SCOPE = "moe_shared"
+#: ... and of a latent attention's way to its heads: the projection to
+#: the latent and the shared rotary key, the latent's norm, the
+#: expansion, the rotary parts
+LATENT_SCOPE = "latent_kv"
 
 #: what a rematerialised routed layer keeps for its backward pass: the two
 #: grouped products that feed the gate. They are the part of a step whose
@@ -196,6 +213,15 @@ def init_backbone(rng: jax.Array, spec: BackboneSpec) -> Dict:
                     next(keys), (h, spec.conv_L_cache), jnp.float32, -bound, bound
                 ),
                 "out_proj": _normal(next(keys), (h, h), h),
+            }
+        elif spec.kv_lora_rank:
+            rank = spec.kv_lora_rank
+            layer["attn"] = {
+                "wq": _normal(next(keys), (h, qo), h),
+                "wkv_a": _normal(next(keys), (h, rank + spec.qk_rope_head_dim), h),
+                "kv_norm": ones(rank),
+                "wkv_b": _normal(next(keys), (rank, spec.kv_expanded_dim), rank),
+                "wo": _normal(next(keys), (heads * spec.v_head_dim, h), heads * spec.v_head_dim),
             }
         else:
             layer["attn"] = {
@@ -337,7 +363,11 @@ def _heads(spec: BackboneSpec, w: Dict, u: jnp.ndarray, op: str = "full_attentio
     kv_heads, dh])``: the projections (``heads`` is the layer's own: the
     width of its ``wq``), the per-head RMSNorm of ``q`` and ``k`` where
     the spec has one, and the rotary embedding of operator ``op``, as
-    every attention takes them."""
+    every attention takes them. A latent attention's come the other
+    way (:func:`_latent_heads`): ``k`` and ``q`` of one width, ``v`` of
+    its own."""
+    if spec.kv_lora_rank:
+        return _latent_heads(spec, w, u)
     dtype = u.dtype
     batch, length, _ = u.shape
     kv_heads, dh = spec.num_key_value_heads, spec.head_dim
@@ -355,6 +385,36 @@ def _heads(spec: BackboneSpec, w: Dict, u: jnp.ndarray, op: str = "full_attentio
         return rotary(x, rope["rope_theta"]) if plain else scaled_rotary(x, rope)
 
     return placed(q, "q_norm"), placed(k, "k_norm"), v
+
+
+def interleaved_rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """``x [B, T, heads, d]`` at positions ``0..T-1``, dimensions ``(2i,
+    2i + 1)`` turning together at ``theta ** (-2i / d)``: the pairs
+    parted into their first and their second members, which is
+    :func:`rotary`'s layout (``deepseek_v3`` does the same: a ``q`` and
+    a ``k`` permuted alike give the scores they gave)."""
+    pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    return rotary(jnp.concatenate([pairs[..., 0], pairs[..., 1]], axis=-1), theta)
+
+
+def _latent_heads(spec: BackboneSpec, w: Dict, u: jnp.ndarray):
+    """``u [B, T, hidden]`` -> ``(q, k [B, T, heads, nope + rope], v [B,
+    T, heads, dv])`` of a latent attention (module docstring): the
+    other way to what :func:`_heads` gives. The shared rotary key is
+    broadcast into every head's key."""
+    dtype = u.dtype
+    batch, length, _ = u.shape
+    rank, nope, rope = spec.kv_lora_rank, spec.qk_nope_head_dim, spec.qk_rope_head_dim
+    turn = interleaved_rotary if spec.rope_interleave else rotary
+    with jax.named_scope(LATENT_SCOPE):
+        q = (u @ w["wq"].astype(dtype)).reshape(batch, length, -1, nope + rope)
+        down = u @ w["wkv_a"].astype(dtype)
+        latent = rms_norm(down[..., :rank], w["kv_norm"], spec.norm_eps)  # the shared key is not normed
+        up = (latent @ w["wkv_b"].astype(dtype)).reshape(batch, length, q.shape[2], -1)
+        shared = turn(down[:, :, None, rank:], spec.rope_theta)
+        q = jnp.concatenate([q[..., :nope], turn(q[..., nope:], spec.rope_theta)], axis=-1)
+        k = jnp.concatenate([up[..., :nope], jnp.broadcast_to(shared, q.shape[:3] + (rope,))], axis=-1)
+    return q, k, up[..., nope:]
 
 
 def _gated(spec: BackboneSpec, w: Dict, u: jnp.ndarray, out: jnp.ndarray) -> jnp.ndarray:
@@ -576,7 +636,7 @@ def _selected_attention_fwd(length, q, k, v, qi, ki, wi, selected):
         top, total, acc = jax.lax.fori_loop(
             0, i + 1, attend,
             (jnp.full(rows, -jnp.inf, jnp.float32), jnp.zeros(rows, jnp.float32),
-             jnp.zeros(rows + (dh,), jnp.float32)),
+             jnp.zeros(rows + v.shape[-1:], jnp.float32)),
         )
         out = jnp.transpose(acc / total[..., None], (2, 0, 1, 3)).astype(q.dtype)
         log_z = top + jnp.log(total)
@@ -737,7 +797,9 @@ def _banded_attention(scope: str, window: int, q, k, v):
     """One window's causal attention limited to the ``window`` rows up
     to the query (``window`` at least the length: every causal key),
     blocked inputs (:func:`_blocked`: ``q [blocks, chunk, n, g, dh]``,
-    ``k``, ``v [blocks, chunk, n, dh]``) -> ``out`` as ``q``. A block of
+    ``k [blocks, chunk, n, dh]``, ``v [blocks, chunk, n, dv]``: a head's
+    values have a width of their own) -> ``out [blocks, chunk, n, g,
+    dv]``. A block of
     queries at a time against the tiles of keys that
     :func:`_band_tiles` names under a running softmax, in
     loops of one body whatever the length and the mask. Rows of padding
@@ -769,7 +831,7 @@ def _banded_attention_fwd(scope, window, q, k, v):
         top, total, acc = jax.lax.fori_loop(
             *_band_tiles(chunk, window, i), attend,
             (jnp.full(rows, -jnp.inf, jnp.float32), jnp.zeros(rows, jnp.float32),
-             jnp.zeros(rows + (dh,), jnp.float32)),
+             jnp.zeros(rows + v.shape[-1:], jnp.float32)),
         )
         # every query sees itself: no row's total is 0
         out = jnp.transpose(acc / total[..., None], (2, 0, 1, 3)).astype(q.dtype)

@@ -457,3 +457,140 @@ def smallthinker(
         compute_dtype=compute_dtype,
         precision=precision,
     )
+
+
+#: kakaocorp/kanana-2-30b-a3b-instruct-2601 config.json (``model_type:
+#: deepseek_v3``), every key of the catalog's row. The factory's defaults
+#: are these; the keys it does not take say nothing it can act on (the
+#: vocabulary is replaced by the sensor projections, positions are a
+#: window's, ``num_key_value_heads`` and the top-level ``head_dim`` repeat
+#: what the latent's own keys say: every head has a key and a value, and
+#: 64 is the rotary part) or name what it refuses to be told otherwise.
+KANANA_2_30B_A3B_CONFIG: Dict[str, Any] = {
+    "attention_bias": False,
+    "first_k_dense_replace": 1,
+    "head_dim": 64,
+    "hidden_act": "silu",
+    "hidden_size": 2048,
+    "intermediate_size": 6144,
+    "kv_lora_rank": 512,
+    "max_position_embeddings": 32768,
+    "model_type": "deepseek_v3",
+    "moe_intermediate_size": 768,
+    "moe_layer_freq": 1,
+    "n_group": 1,
+    "n_routed_experts": 128,
+    "n_shared_experts": 2,
+    "norm_topk_prob": True,
+    "num_attention_heads": 32,
+    "num_experts_per_tok": 6,
+    "num_hidden_layers": 48,
+    "num_key_value_heads": 32,
+    "q_lora_rank": None,
+    "qk_head_dim": 192,
+    "qk_nope_head_dim": 128,
+    "qk_rope_head_dim": 64,
+    "rms_norm_eps": 1e-06,
+    "rope_interleave": True,
+    "rope_scaling": None,
+    "rope_theta": 1000000,
+    "routed_scaling_factor": 2.448,
+    "scoring_func": "sigmoid",
+    "tie_word_embeddings": False,
+    "topk_group": 1,
+    "topk_method": "noaux_tc",
+    "v_head_dim": 128,
+    "vocab_size": 128256,
+}
+_KANANA = KANANA_2_30B_A3B_CONFIG
+#: what the layers here cannot be told otherwise: no bias, silu gates,
+#: routed experts in every layer after the leading dense ones, a sigmoid
+#: router whose bias buffer chooses (``noaux_tc``) among all experts as
+#: one group, the chosen scores renormalised, plain rotary, one
+#: projection to the query heads (the family's larger members project
+#: through a normed latent of ``q_lora_rank``: not built, so refused)
+_KANANA_FIXED = (
+    "attention_bias", "hidden_act", "moe_layer_freq", "n_group", "norm_topk_prob", "q_lora_rank",
+    "rope_scaling", "scoring_func", "topk_group", "topk_method",
+)
+
+
+@register_model_builder(type="JaxBackboneForecast")
+def kanana(
+    n_features: int,
+    n_features_out: Optional[int] = None,
+    lookback_window: int = 8192,
+    num_hidden_layers: int = _KANANA["num_hidden_layers"],
+    first_k_dense_replace: int = _KANANA["first_k_dense_replace"],
+    hidden_size: int = _KANANA["hidden_size"],
+    num_attention_heads: int = _KANANA["num_attention_heads"],
+    kv_lora_rank: int = _KANANA["kv_lora_rank"],
+    qk_nope_head_dim: int = _KANANA["qk_nope_head_dim"],
+    qk_rope_head_dim: int = _KANANA["qk_rope_head_dim"],
+    v_head_dim: int = _KANANA["v_head_dim"],
+    rope_interleave: bool = _KANANA["rope_interleave"],
+    intermediate_size: int = _KANANA["intermediate_size"],
+    moe_intermediate_size: int = _KANANA["moe_intermediate_size"],
+    n_routed_experts: int = _KANANA["n_routed_experts"],
+    n_shared_experts: int = _KANANA["n_shared_experts"],
+    experts_held: Optional[int] = None,
+    expert_offset: int = 0,
+    num_experts_per_tok: int = _KANANA["num_experts_per_tok"],
+    routed_scaling_factor: float = _KANANA["routed_scaling_factor"],
+    rope_theta: float = _KANANA["rope_theta"],
+    rms_norm_eps: float = _KANANA["rms_norm_eps"],
+    optimizer: Union[str, OptimizerSpec] = "Adam",
+    optimizer_kwargs: Optional[Dict[str, Any]] = None,
+    compile_kwargs: Optional[Dict[str, Any]] = None,
+    compute_dtype: str = "float32",
+    precision: str = "",
+    **kwargs,
+) -> BackboneSpec:
+    """``model_type: deepseek_v3`` (defaults: kanana-2-30b-a3b-instruct-
+    2601). ``num_hidden_layers`` layers of latent attention (keys and
+    values from a normed latent of ``kv_lora_rank``, expanded to
+    ``qk_nope_head_dim`` of a head's key and ``v_head_dim`` of its
+    value; one rotary key of ``qk_rope_head_dim`` shared by every head;
+    rotary on the trailing ``qk_rope_head_dim`` of ``q`` and on that key
+    alone, in interleaved pairs under ``rope_interleave``); the first
+    ``first_k_dense_replace`` of them carry the dense feed-forward, the
+    rest the routed experts under a sigmoid router scaled by
+    ``routed_scaling_factor``, of which this holder keeps
+    ``experts_held`` (default: all) from ``expert_offset``, beside
+    ``n_shared_experts`` shared experts, built as ``deepseek_v3``
+    builds them: one feed-forward of ``n_shared_experts`` times the
+    experts' width."""
+    for key in _KANANA_FIXED:
+        if key in kwargs and kwargs[key] != _KANANA[key]:
+            raise ValueError(f"kanana runs {key}={_KANANA[key]!r} only; got {kwargs[key]!r}")
+    compile_kwargs = compile_kwargs or {}
+    return BackboneSpec(
+        n_features=n_features,
+        n_features_out=n_features_out or n_features,
+        lookback_window=lookback_window,
+        layer_ops=("full_attention",) * num_hidden_layers,
+        layer_ffns=tuple("dense" if i < first_k_dense_replace else "moe" for i in range(num_hidden_layers)),
+        hidden_size=hidden_size,
+        num_attention_heads=num_attention_heads,
+        num_key_value_heads=num_attention_heads,
+        kv_lora_rank=kv_lora_rank,
+        qk_nope_head_dim=qk_nope_head_dim,
+        qk_rope_head_dim=qk_rope_head_dim,
+        v_head_dim=v_head_dim,
+        rope_interleave=bool(rope_interleave),
+        intermediate_size=intermediate_size,
+        moe_intermediate_size=moe_intermediate_size,
+        shared_expert_intermediate_size=n_shared_experts * moe_intermediate_size,
+        num_experts=n_routed_experts,
+        experts_held=n_routed_experts if experts_held is None else experts_held,
+        expert_offset=expert_offset,
+        num_experts_per_tok=num_experts_per_tok,
+        routed_scaling_factor=float(routed_scaling_factor),
+        rope_theta=float(rope_theta),
+        qk_norm=False,
+        norm_eps=float(rms_norm_eps),
+        optimizer=OptimizerSpec.from_config(optimizer, optimizer_kwargs),
+        loss=compile_kwargs.get("loss", "mse"),
+        compute_dtype=compute_dtype,
+        precision=precision,
+    )

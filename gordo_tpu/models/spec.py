@@ -342,6 +342,17 @@ class BackboneSpec(ModelSpec):
     tensor a routed layer's router reads (``ROUTER_INPUTS``) and
     ``expert_activation`` the gate of its experts
     (``EXPERT_ACTIVATIONS``).
+
+    ``kv_lora_rank`` above 0 makes every attention a latent one
+    (``deepseek_v3``'s): keys and values come from one projection to a
+    latent of that width, normed, and one expansion to ``qk_nope_head_dim``
+    of each head's key and ``v_head_dim`` of its value; the other
+    ``qk_rope_head_dim`` of a key are one rotary key that every head
+    shares, read beside the latent and not normed. A head's scores are
+    ``head_dim = qk_nope_head_dim + qk_rope_head_dim`` wide and its values
+    ``v_head_dim``; the rotary embedding turns the trailing
+    ``qk_rope_head_dim`` of ``q`` and the shared key alone, in
+    interleaved pairs under ``rope_interleave``.
     """
 
     n_features: int
@@ -384,6 +395,11 @@ class BackboneSpec(ModelSpec):
     shared_expert_intermediate_size: int = 0
     router_input: str = "ffn_input"
     expert_activation: str = "silu"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
 
     windowed = True
 
@@ -403,8 +419,23 @@ class BackboneSpec(ModelSpec):
             raise ValueError(
                 f"unknown expert_activation {self.expert_activation!r}; known: {EXPERT_ACTIVATIONS}"
             )
-        if self.head_dim % 2 or self.head_dim <= 0:
+        if not self.kv_lora_rank and (self.head_dim % 2 or self.head_dim <= 0):
             raise ValueError("the rotary embedding needs an even head width")
+        if self.kv_lora_rank:
+            if min(self.kv_lora_rank, self.qk_nope_head_dim, self.v_head_dim) < 1 or (
+                self.qk_rope_head_dim < 2 or self.qk_rope_head_dim % 2
+            ):
+                raise ValueError(
+                    "latent attention needs a latent, a key and a value width of a head "
+                    "and an even width of the shared rotary key"
+                )
+            if set(self.layer_ops) - {"full_attention"} or self.qk_norm or self.attention_gate:
+                raise ValueError(
+                    "latent attention is full_attention in every layer, without a norm of "
+                    "q and k and without a gate on its heads"
+                )
+            if self.layer_heads or self.num_key_value_heads != self.num_attention_heads:
+                raise ValueError("latent attention expands a key and a value for every query head")
         if "sparse_attention" in self.layer_ops and (
             min(self.index_n_heads, self.index_topk, self.index_chunk) < 1
             or self.index_head_dim < 2
@@ -440,7 +471,26 @@ class BackboneSpec(ModelSpec):
 
     @property
     def head_dim(self) -> int:
+        """The width a head's scores are taken over."""
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.attention_head_dim or self.hidden_size // self.num_attention_heads
+
+    @property
+    def kv_expanded_dim(self) -> int:
+        """What a latent expands to: every head's key part and value."""
+        return self.num_attention_heads * (self.qk_nope_head_dim + self.v_head_dim)
+
+    @property
+    def latent_param_count(self) -> int:
+        """One latent attention: ``wq``, the projection to the latent
+        and the shared rotary key, the latent's norm, the expansion to
+        every head's key part and value, ``wo``."""
+        h, heads, rank = self.hidden_size, self.num_attention_heads, self.kv_lora_rank
+        return (
+            h * heads * self.head_dim + h * (rank + self.qk_rope_head_dim) + rank
+            + rank * self.kv_expanded_dim + heads * self.v_head_dim * h
+        )
 
     @property
     def heads_by_layer(self) -> Tuple[int, ...]:
@@ -499,6 +549,13 @@ class BackboneSpec(ModelSpec):
         }
         if "sparse_attention" in self.layer_ops:
             attrs["index_topk"] = self.index_topk
+        if self.kv_lora_rank:  # what a row keeps of itself, and what that expands to
+            attrs.update(
+                kv_lora_rank=self.kv_lora_rank,
+                qk_rope_head_dim=self.qk_rope_head_dim,
+                v_head_dim=self.v_head_dim,
+                kv_expanded_dim=self.kv_expanded_dim,
+            )
         return attrs
 
     def layer_param_count(self, op: str, ffn: str, heads: Optional[int] = None) -> int:
@@ -511,6 +568,8 @@ class BackboneSpec(ModelSpec):
         total = 2 * h
         if op == "conv":
             total += h * 3 * h + h * self.conv_L_cache + h * h
+        elif self.kv_lora_rank:
+            total += self.latent_param_count
         else:
             total += 2 * h * heads * self.head_dim + 2 * h * kv
             total += 2 * self.head_dim * self.qk_norm + h * heads * self.attention_gate
@@ -559,7 +618,10 @@ class BackboneSpec(ModelSpec):
                 attended = kept_mean
                 per_token += 2.0 * h * (index + self.index_head_dim + self.index_n_heads)
                 per_token += (t + 1.0) * index  # 2 x index a causal pair, (t + 1) / 2 pairs
-            if op != "conv":  # q, k, v, o and the gate; a score and a value a pair
+            if self.kv_lora_rank:  # every matrix of the latent attention; a score and a value a pair
+                per_token += 2.0 * (self.latent_param_count - self.kv_lora_rank)
+                per_token += 2.0 * attended * heads * (self.head_dim + self.v_head_dim)
+            elif op != "conv":  # q, k, v, o and the gate; a score and a value a pair
                 per_token += 2.0 * h * (2 * qo + 2 * kv + heads * self.attention_gate) + 4.0 * attended * qo
             if ffn == "dense":
                 per_token += 6.0 * h * self.intermediate_size

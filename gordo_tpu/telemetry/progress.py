@@ -387,6 +387,16 @@ def cores_busy_text(entry: Dict[str, Any]) -> str:
     return f"  [{', '.join(shown)}]" if shown else ""
 
 
+def latent_text(attrs: Dict[str, Any]) -> str:
+    """What a row keeps of itself in a latent attention, of what that
+    expands to for the heads (a fit program's ``kv_lora_rank``,
+    ``qk_rope_head_dim`` and ``kv_expanded_dim``: ``BackboneSpec.fit_counter_attrs``)."""
+    return (
+        f"latent {int(attrs['kv_lora_rank']):,} + {int(attrs['qk_rope_head_dim']):,} "
+        f"of {int(attrs['kv_expanded_dim']):,} floats a row"
+    )
+
+
 def render_status(doc: Dict[str, Any]) -> str:
     """Human rendering of a build-status document (the ``build-status``
     CLI's output): header, progress bar + ETA, per-phase table."""
@@ -451,6 +461,9 @@ def render_status(doc: Dict[str, Any]) -> str:
                     f"x{measured.get('count', 0)} (thread-seconds)"
                     f"{part_rates_text(measured)}"
                 )
+    latent = next((c for c in doc.get("fit_counters") or () if c.get("kv_lora_rank")), None)
+    if latent:
+        lines.append(f"Attention: {latent_text(latent)}")
     resources = doc.get("resources")
     if resources:
         lines.append(

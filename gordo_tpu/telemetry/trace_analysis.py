@@ -464,7 +464,9 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     the dense fit, the widest ``shuffle_columns`` among them; a predict
     program with its ``members`` and, of them, those whose parameters
     it took from the device, ``params_resident_members``; a fit of experts
-    gated by ``relu`` with its ``gate_active`` and ``gate_total`` summed) and its
+    gated by ``relu`` with its ``gate_active`` and ``gate_total`` summed; a
+    fit of latent attention with what a row keeps of itself,
+    ``kv_lora_rank`` + ``qk_rope_head_dim`` of ``kv_expanded_dim``) and its
     ``self_seconds``, the wall time no part or program covers. Where its
     spans carry them, a phase also has ``cpu_seconds`` (its own thread's)
     and ``process_cpu_seconds`` (every thread's between its two ends),
@@ -561,6 +563,9 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
             if "gate_total" in attributes:  # a fit of experts gated by relu: a list a layer
                 for key in ("gate_active", "gate_total"):
                     part[key] = part.get(key, 0.0) + float(sum(attributes.get(key) or ()))
+            if attributes.get("kv_lora_rank"):  # a fit of latent attention: what a row keeps
+                for key in ("kv_lora_rank", "qk_rope_head_dim", "kv_expanded_dim"):
+                    part[key] = int(attributes[key])
             if span["name"] == "build_part":
                 nested_cpu = nested_part_cpu_seconds(attributes)
                 for nested, nested_seconds in nested_part_seconds(attributes).items():
@@ -839,7 +844,7 @@ def render_analysis(doc: Dict[str, Any]) -> str:
 
     build = doc.get("build_breakdown")
     if build:
-        from .progress import cores_busy_text, part_rates_text
+        from .progress import cores_busy_text, latent_text, part_rates_text
 
         out.append(
             "\nBuild phases (seconds; self = wall time no part covers; "
@@ -866,6 +871,8 @@ def render_analysis(doc: Dict[str, Any]) -> str:
                 if measured.get("gate_total"):
                     share = 100.0 * measured["gate_active"] / measured["gate_total"]
                     counters.append(f"gate_active_pct={share:.1f}")
+                if measured.get("kv_lora_rank"):
+                    counters.append(latent_text(measured))
                 if counters:
                     part += f" [{', '.join(counters)}]"
                 rows.append(

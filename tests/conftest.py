@@ -96,6 +96,8 @@ MANIFEST_CASES_OUTGROWN = (
     MANIFEST_CASE_OUTGROWN,
     "tests/chipbench/test_manifest.py::test_config_entry_and_file[laguna-xs2-50tag-lb8192]",
     "tests/chipbench/test_manifest.py::test_config_entry_and_file[smallthinker-21b-a3b-50tag-lb8192]",
+    # ISSUE 41: a new case that never passed; ``tests/chipbench/test_kanana_cell.py`` holds it to every other line
+    "tests/chipbench/test_manifest.py::test_config_entry_and_file[kanana-2-30b-a3b-50tag-lb8192]",
 )
 #: ``test_keye_dsa_cell.py`` asserts that its cell and its configuration
 #: are the manifest's last: true of the PR that added them, of no later
@@ -150,7 +152,27 @@ APPENDED_CASES_OUTGROWN = tuple(
         "host_rss_peak_gb", "stack_gbps",
     )
 )
+#: PR 39's own test of those six cases holds, for the three readers whose
+#: lists the new cell joined, the manifest "less that one appended cell":
+#: its line 306 pops the last name of the reader's ``workloads`` and
+#: holds it to be ``smallthinker_build``. True of PR 39, of no later cell
+#: that joins those lists (ISSUE 41: ``kanana_mla_build`` is appended to
+#: ``collect_gbps``, ``host_cores_busy`` and ``host_rss_peak_gb``).
+#: ``tests/chipbench/test_kanana_cell.py`` states every other line of the
+#: three cases in the form that stays true (the entry equals the issue's
+#: table once the cells appended after it are taken off, whichever they
+#: are) and holds each marked case to failing on that line alone.
+#: PERF.md 7 (f).
+JOINED_CASES_OUTGROWN = tuple(
+    "tests/chipbench/test_smallthinker_cell.py::"
+    f"test_the_six_cases_of_the_appended_readers_fail_on_the_named_lines_alone[{name}]"
+    for name in ("collect_gbps", "host_cores_busy", "host_rss_peak_gb")
+)
 OUTGROWN = {
+    **{
+        case: "asserts that smallthinker_build is the last cell of the three host readers it joined"
+        for case in JOINED_CASES_OUTGROWN
+    },
     **{case: "asserts batch_size 32 and epochs 5 of every configuration" for case in MANIFEST_CASES_OUTGROWN},
     LAST_ENTRY_CASE_OUTGROWN: "asserts that keye_dsa_build is the manifest's last cell",
     **LAGUNA_CASES_OUTGROWN,

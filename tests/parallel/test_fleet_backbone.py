@@ -23,6 +23,7 @@ from gordo_tpu.parallel import FleetTrainer, WindowedFleetMember
 from gordo_tpu.parallel.fleet import _fleet_windowed_fit_program
 from gordo_tpu.planner import packing
 from gordo_tpu.server import build_app
+from gordo_tpu.telemetry.recorder import reset_seen_programs
 
 PROJECT = "backbone-proj"
 REVISION = "1700000000027"
@@ -82,6 +83,13 @@ def build_fleet(root, estimator, machines):
     config_path = str(root.parent / "machines.yaml")
     with open(config_path, "w") as f:
         yaml.safe_dump(document, f)
+    # ``compile`` on a ``device_program`` span is "first seen in this
+    # process" (telemetry/recorder.py:seen_program), and a worker of the
+    # suite may have built the same toy spec in another file before this
+    # one (``test_fold_params_on_device.py`` imports ``TOY``): the tests
+    # here count one compile a build from a clean slate, whatever the
+    # worker ran first
+    reset_seen_programs()
     try:
         gordo_tpu_cli.main(["build-fleet", config_path, str(root)], standalone_mode=False)
         code = 0
