@@ -19,7 +19,7 @@ from typing import Dict, Iterable, List, Optional
 import numpy as np
 import pandas as pd
 
-from ..serializer.import_utils import import_location
+from ..utils.import_utils import import_location
 from ..utils import capture_args
 from .sensor_tag import SensorTag, normalize_sensor_tags
 

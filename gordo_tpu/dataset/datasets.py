@@ -22,7 +22,7 @@ from typing import Any, Dict, List, Optional, Tuple, Union
 import numpy as np
 import pandas as pd
 
-from ..serializer.import_utils import import_location
+from ..utils.import_utils import import_location
 from ..utils import capture_args
 from ..utils.profiling import annotate
 from .data_provider import GordoBaseDataProvider, RandomDataProvider

@@ -44,7 +44,7 @@ from sklearn.preprocessing import MinMaxScaler, RobustScaler, StandardScaler
 import gordo_tpu
 from .. import serializer, telemetry
 from ..builder.build_model import ModelBuilder
-from ..dataset import GordoBaseDataset
+from ..dataset import GordoBaseDataset, fetch_pool
 from ..machine import Machine
 from ..telemetry.device import compile_path_counters, watch_compile_path
 from ..telemetry.progress import BUILD_TRACE_FILE
@@ -409,7 +409,7 @@ class FleetBuilder:
                 # the span's own thread's: over the phase's seconds, the
                 # cores the phase kept busy
                 process_cpu_started = (
-                    time.process_time() if handle.recording else None
+                    self._process_cpu_clock() if handle.recording else None
                 )
                 # the phase pays for its own status write and device
                 # sample: nothing of a build lies between two phases
@@ -423,7 +423,7 @@ class FleetBuilder:
                     if process_cpu_started is not None:
                         handle.set(
                             process_cpu_seconds=round(
-                                time.process_time() - process_cpu_started, 6
+                                self._process_cpu_clock() - process_cpu_started, 6
                             )
                         )
         finally:
@@ -450,6 +450,14 @@ class FleetBuilder:
             part=name,
             **attributes,
         )
+
+    @staticmethod
+    def _process_cpu_clock() -> float:
+        """The CPU seconds of the process and of the fetch workers that
+        compute for it (``time.process_time()`` does not see a child
+        until it is reaped, and these live on): a phase whose work left
+        for the workers would otherwise read fewer cores busy for it."""
+        return time.process_time() + fetch_pool.cpu_seconds()
 
     def _cpu_clock(self) -> Callable[[], float]:
         """``time.thread_time`` for work that is timed in place and
@@ -981,7 +989,7 @@ class FleetBuilder:
                 str(attrs.get("part", "")),
                 seconds,
                 int(attrs.get("count", 1)),
-                **{key: attrs.get(key) for key in telemetry.PART_SUMS},
+                **telemetry.part_sums(attrs),
             )
             nested_cpu = telemetry.nested_part_cpu_seconds(attrs)
             for part, nested in telemetry.nested_part_seconds(attrs).items():
@@ -1504,12 +1512,55 @@ class FleetBuilder:
         from ..dataset.exceptions import ConfigException, InsufficientDataError
 
         cpu_clock = self._cpu_clock()
+        # one algorithm under two executors, chosen from what the job
+        # shows: a job of many machines hands each fetch's computing to a
+        # worker process (dataset/fetch_pool.py has why, and the line);
+        # a job of one machine, and a dataset that cannot cross, compute
+        # on the thread that waits
+        pooled = fetch_pool.wanted(len(plans))
 
         def load(plan: _Plan):
             start = time.time()
+            # one span a machine: their summed seconds over the phase's
+            # wall seconds are the fetches that were computing at a time.
+            # This thread times it (and annotates it, on the profiler's
+            # clock); the main thread, which only waits, writes the span:
+            # written from here, among sixteen threads, a span cost the
+            # phase about a millisecond. The dataset's own parts ride on
+            # it as <part>_s attributes, their CPU seconds as
+            # <part>_cpu_seconds. Fetched in a worker, the span's seconds
+            # and cpu_seconds are the worker's for the call (this thread's
+            # would read its wait for a free worker); fetched here, this
+            # thread's, whose CPU clock is read only where the span will
+            # be written.
+            attrs = {
+                "phase": "data_fetch",
+                "part": "machine_fetch",
+                "machine": plan.machine.name,
+            }
+            if self.recorder.enabled:
+                plan.dataset.fetch_cpu_timed = True  # TimeSeriesDataset._timed
+            request = fetch_pool.crossing(plan.dataset) if pooled else None
+            #: what the workers gave, over this machine's attempts
+            crossed = {"seconds": 0.0, "cpu_seconds": 0.0, "bytes": 0}
 
             def fetch():
+                nonlocal request
                 fault_point("data_fetch", plan.machine.name)
+                if request is not None:
+                    try:
+                        fetched = fetch_pool.fetch(request)
+                    except fetch_pool.CannotCross as exc:
+                        logger.info(
+                            "%s is fetched on a thread: %s", plan.machine.name, exc
+                        )
+                        request = None
+                    else:
+                        crossed["seconds"] += fetched.seconds
+                        crossed["cpu_seconds"] += fetched.cpu_seconds
+                        crossed["bytes"] += fetched.nbytes
+                        vars(plan.dataset).update(fetched.state)
+                        return fetched.X, fetched.y
                 return plan.dataset.get_data()
 
             def note_retry(attempt: int, exc: BaseException):
@@ -1525,24 +1576,6 @@ class FleetBuilder:
                     exc,
                 )
 
-            # one span a machine: their summed seconds over the phase's
-            # wall seconds are what the pool's threads gave. This thread
-            # times it (and annotates it, on the profiler's clock); the
-            # main thread, which only waits, writes the span: written
-            # from here, among sixteen threads contending for the GIL,
-            # a span cost the phase about a millisecond. The dataset's
-            # own parts ride on it as <part>_s attributes, their CPU
-            # seconds as <part>_cpu_seconds; this thread's own CPU clock
-            # says how much of the fetch's seconds it computed and how
-            # much it waited (for the GIL, for the source). Both CPU
-            # clocks are read only where the span will be written.
-            attrs = {
-                "phase": "data_fetch",
-                "part": "machine_fetch",
-                "machine": plan.machine.name,
-            }
-            if self.recorder.enabled:
-                plan.dataset.fetch_cpu_timed = True  # TimeSeriesDataset._timed
             began = time.perf_counter()
             cpu_began = cpu_clock()
             try:
@@ -1566,24 +1599,44 @@ class FleetBuilder:
                     attrs[f"{name}_cpu_seconds"] = round(seconds, 6)
             finally:
                 attrs["retries"] = plan.data_retries
-                attrs["cpu_seconds"] = cpu_clock() - cpu_began
-                fetched[plan.machine.name] = (
-                    time.perf_counter() - began, start, attrs
-                )
+                if request is not None:
+                    seconds = crossed.pop("seconds")
+                    attrs.update(worker="process", **crossed)
+                else:
+                    attrs.update(worker="thread", cpu_seconds=cpu_clock() - cpu_began)
+                    seconds = time.perf_counter() - began
+                fetched_spans[plan.machine.name] = (seconds, start, attrs)
             plan.query_duration = time.time() - start
             plan.X, plan.y = X, y
 
-        fetched: Dict[str, Tuple[float, float, Dict[str, Any]]] = {}
+        fetched_spans: Dict[str, Tuple[float, float, Dict[str, Any]]] = {}
 
         def record_fetch(plan: _Plan, outcome):
-            if plan.machine.name in fetched:
-                seconds, began_wall, attrs = fetched.pop(plan.machine.name)
+            if plan.machine.name in fetched_spans:
+                seconds, began_wall, attrs = fetched_spans.pop(plan.machine.name)
                 self.recorder.record(
                     "build_part", seconds, start=began_wall, **attrs
                 )
             return outcome
 
         with self._phase("data_fetch"):
+            # the pool's start, where this job is the process's first over
+            # the line: a part of the phase, so the phase's seconds say
+            # what the job paid and the part's count which job paid it
+            began, cpu_began = time.perf_counter(), fetch_pool.cpu_seconds()
+            started = (
+                fetch_pool.ensure(
+                    fetch_pool.workers_for(self.data_workers, len(plans))
+                )
+                if pooled
+                else 0
+            )
+            self._record_part(
+                "pool_start",
+                time.perf_counter() - began,
+                started,
+                cpu_seconds=fetch_pool.cpu_seconds() - cpu_began,
+            )
             pool = concurrent.futures.ThreadPoolExecutor(self.data_workers)
             try:
                 outcomes = [

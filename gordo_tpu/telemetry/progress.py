@@ -216,7 +216,7 @@ class BuildProgress:
                     phases[name]["parts"] = {
                         part: {
                             key: int(value)
-                            if key in ("count", "bytes")
+                            if key in ("count", "bytes", "in_process")
                             else round(value, 6)
                             for key, value in entry.items()
                         }
@@ -351,9 +351,12 @@ def part_rates_text(measured: Dict[str, Any]) -> str:
         shown.append(f"cpu {100.0 * float(measured['cpu_seconds']) / seconds:.0f}%")
     if measured.get("bytes"):
         size = float(measured["bytes"])
-        if seconds > 0:
+        # a fetch's bytes are what crossed back after it was computed:
+        # over its seconds they would read as a rate nothing ran at
+        fetched = "in_process" in measured
+        if seconds > 0 and not fetched:
             shown.append(f"{size / 1e9 / seconds:.2f} GB/s")
-        if seconds <= 0 or "bytes_reused" in measured:
+        if seconds <= 0 or fetched or "bytes_reused" in measured:
             shown.append(_gigabytes(size))
         if "bytes_reused" in measured:
             shown.append(f"{100.0 * float(measured['bytes_reused']) / size:.0f}% reused")
@@ -375,7 +378,12 @@ def cores_busy_text(entry: Dict[str, Any]) -> str:
     A phase whose own thread reads near 100% is bound by the builder's
     one thread; a low share beside many cores is a pool or the runtime
     at work; both low, the host waited (for the device, for a lock).
-    Empty where the phase has neither."""
+    The process's CPU seconds hold the fetch workers' (a phase's cores
+    are the job's, whichever process computed), and beside them a
+    ``data_fetch`` says how many of its machines were fetched in worker
+    processes (``machine_fetch``'s ``in_process`` of its ``count``): all
+    of them where the cores were the pool's, none in a job of one
+    machine. Empty where the phase has none of these."""
     seconds = float(entry.get("seconds") or 0.0)
     if seconds <= 0:
         return ""
@@ -384,6 +392,12 @@ def cores_busy_text(entry: Dict[str, Any]) -> str:
         shown.append(f"{float(entry['process_cpu_seconds']) / seconds:.2f} cores busy")
     if "cpu_seconds" in entry:
         shown.append(f"own thread cpu {100.0 * float(entry['cpu_seconds']) / seconds:.0f}%")
+    fetched = (entry.get("parts") or {}).get("machine_fetch") or {}
+    if "in_process" in fetched:
+        shown.append(
+            f"{int(fetched['in_process'])} of {int(fetched.get('count', 0))} "
+            "machines fetched in processes"
+        )
     return f"  [{', '.join(shown)}]" if shown else ""
 
 

@@ -61,9 +61,11 @@ DEFAULT_KEEP = 3
 #: summed into its entry of ``build_status.json``: the CPU seconds of the
 #: thread (or, recorded as a sum, the threads) that ran it, the bytes it
 #: moved, of a ``stack``'s bytes those filled into buffers the staging
-#: pool already held (parallel/host_blocks.py), and inside ``collect`` the
-#: seconds of the device-to-host fetch alone
-PART_SUMS = ("cpu_seconds", "bytes", "bytes_reused", "d2h_seconds")
+#: pool already held (parallel/host_blocks.py), inside ``collect`` the
+#: seconds of the device-to-host fetch alone, and of the machines a
+#: ``machine_fetch`` counts those fetched in a worker process
+#: (:func:`part_sums` counts them off the span's ``worker``)
+PART_SUMS = ("cpu_seconds", "bytes", "bytes_reused", "d2h_seconds", "in_process")
 #: and a ``build_phase`` span beside its seconds: its own thread's CPU
 #: seconds and the whole process's between its two ends
 PHASE_SUMS = ("cpu_seconds", "process_cpu_seconds")
@@ -810,6 +812,17 @@ def _suffixed(attributes: Dict[str, Any], suffix: str) -> Dict[str, float]:
         for key, value in attributes.items()
         if key.endswith(suffix) and isinstance(value, (int, float))
     }
+
+
+def part_sums(attributes: Dict[str, Any]) -> Dict[str, Any]:
+    """What a ``build_part`` span adds to its entry beside its seconds
+    and ``count``: each of ``PART_SUMS`` it carries, and, where it says
+    its ``worker`` (a ``machine_fetch``: ``process`` or ``thread``), one
+    ``in_process`` or none."""
+    sums = {key: attributes[key] for key in PART_SUMS if attributes.get(key) is not None}
+    if "worker" in attributes:
+        sums["in_process"] = int(attributes["worker"] == "process")
+    return sums
 
 
 def nested_part_seconds(attributes: Dict[str, Any]) -> Dict[str, float]:

@@ -490,6 +490,7 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
         add_sums,
         nested_part_cpu_seconds,
         nested_part_seconds,
+        part_sums,
     )
 
     phases: Dict[str, Dict[str, Any]] = {}
@@ -541,7 +542,7 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
             label = str(attributes.get("part", ""))
         if phase in phases:
             sums = (
-                attributes
+                part_sums(attributes)
                 if span["name"] == "build_part"
                 # a program's ``bytes`` are its bucket's staged size, not
                 # what the span moved

@@ -25,6 +25,7 @@ work on the profiler's clock, beside the device's lines.
 import contextlib
 import logging
 import os
+import sys
 
 from .env import env_str
 
@@ -54,7 +55,12 @@ def annotate(label: str):
     plane of whichever profiler session is running, on that profiler's
     clock. The one way into ``TraceAnnotation`` for the build path. Not
     gated on ``GORDO_TPU_PROFILE_DIR``: the session may be someone
-    else's, and without one the annotation records nothing."""
+    else's, and without one the annotation records nothing. A process
+    that has not imported jax has no session to be in, and is not made
+    to import it for this: a fetch worker (``dataset/fetch_pool.py``)
+    times a dataset's parts through here and must stay off jax."""
+    if "jax" not in sys.modules:
+        return contextlib.nullcontext()
     import jax
 
     return jax.profiler.TraceAnnotation(label)

@@ -11,6 +11,7 @@ checked by ``python chip_smoke.py`` (see README.md).
 """
 
 import os
+import sys
 
 # Must be in place before the CPU backend initializes.
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -73,6 +74,19 @@ def tiny_model_definition() -> dict:
             }
         }
     }
+
+
+@pytest.fixture(autouse=True)
+def no_fetch_workers_by_accident(request, monkeypatch):
+    """No test starts fetch workers but the pool's own module: a fleet
+    over the line (``dataset/fetch_pool.py:MIN_MACHINES``) would start a
+    process a core in each of the suite's six runners. The line is a
+    constant of the program, not an option, so it is lifted here, in the
+    test's own process, as ``ATTENTION_TILE`` is lowered for the toys."""
+    if request.node.path.name != "test_fetch_pool.py":
+        from gordo_tpu.dataset import fetch_pool
+
+        monkeypatch.setattr(fetch_pool, "MIN_MACHINES", sys.maxsize)
 
 
 #: The cases of the chip benchmark's own tests that this tree cannot

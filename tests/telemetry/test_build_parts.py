@@ -46,7 +46,9 @@ CLOCK_SLACK = 0.025
 
 #: parts a dense job must record, by phase
 REQUIRED_PARTS = {
-    "data_fetch": {"machine_fetch", "provider_read", "resample_join", "row_filter"},
+    "data_fetch": {
+        "machine_fetch", "provider_read", "resample_join", "row_filter", "pool_start",
+    },
     "cv_train": {"stack", "h2d", "init", "collect"},
     "final_fit": {"stack", "h2d", "init", "collect"},
     "cv_predict": {"stack", "collect"},
@@ -394,12 +396,14 @@ def test_machine_fetch_carries_machine_rows_retries_and_the_datasets_parts(built
     for span in fetches:
         attributes = span["attributes"]
         assert set(attributes) == {
-            "phase", "part", "machine", "rows", "retries", "cpu_seconds",
+            "phase", "part", "machine", "rows", "retries", "cpu_seconds", "worker",
             "provider_read_s", "resample_join_s", "row_filter_s",
             "provider_read_cpu_seconds", "resample_join_cpu_seconds",
             "row_filter_cpu_seconds",
         }
         assert attributes["rows"] > 0 and attributes["retries"] == 0
+        # two machines are under the line: fetched on the builder's threads
+        assert attributes["worker"] == "thread"
         nested = telemetry.nested_part_seconds(attributes)
         assert set(nested) == {"provider_read", "resample_join", "row_filter"}
         assert 0 < sum(nested.values()) <= span["duration_ms"] / 1000.0
@@ -427,7 +431,12 @@ def test_status_holds_the_parts_of_each_phase(built, phase):
         # the parts of the host's scoring count the machine-folds that
         # fell back to it: none of a default evaluation's
         fell_back = phase == "cv_score" and part in ("metric_scores", "thresholds")
-        assert measured["count"] >= (0 if fell_back else 1)
+        # and the pool's start counts the workers started: none for a job
+        # of two machines in a process that has no pool (dataset/fetch_pool.py)
+        none_started = part == "pool_start"
+        assert measured["count"] >= (0 if fell_back or none_started else 1)
+        if none_started:
+            assert measured["count"] == 0 and measured["cpu_seconds"] == 0.0
         assert measured["seconds"] >= 0.0
         # thread-seconds: no more than the phase's wall seconds on
         # every worker at once
@@ -534,8 +543,12 @@ def test_status_keeps_the_bytes_and_the_cpu_of_a_part_that_moved_data(built, pha
 def test_status_has_a_key_only_where_a_span_gave_it(built):
     _, _, status = built
     phases = status["phases"]
-    for phase, part in (("cv_train", "init"), ("dump", "serialize"), ("data_fetch", "machine_fetch")):
+    for phase, part in (("cv_train", "init"), ("dump", "serialize"), ("data_fetch", "pool_start")):
         assert set(phases[phase]["parts"][part]) == {"seconds", "count", "cpu_seconds"}
+    # a fetch says where it was computed: both machines on the builder's threads
+    fetched = phases["data_fetch"]["parts"]["machine_fetch"]
+    assert set(fetched) == {"seconds", "count", "cpu_seconds", "in_process"}
+    assert (fetched["count"], fetched["in_process"]) == (2, 0)
     # the dataset's parts have their pool threads' CPU, by the paired attribute
     fetch = phases["data_fetch"]["parts"]
     nested = ("provider_read", "resample_join", "row_filter")
@@ -829,6 +842,20 @@ def test_progress_sums_cpu_bytes_and_d2h_where_given_and_renders_the_rates(tmp_p
          "  [0.05 cores busy, own thread cpu 2%]"),
         ({"seconds": 4.0, "process_cpu_seconds": 6.0}, "  [1.50 cores busy]"),
         ({"seconds": 4.0, "cpu_seconds": 1.0}, "  [own thread cpu 25%]"),
+        # the fetch workers at work (their CPU is in the process's), a job
+        # that fetched a machine on a thread beside them, a job of one
+        ({"seconds": 2.0, "process_cpu_seconds": 18.0, "cpu_seconds": 0.16,
+          "parts": {"machine_fetch": {"seconds": 16.0, "count": 160, "in_process": 160}}},
+         "  [9.00 cores busy, own thread cpu 8%, 160 of 160 machines fetched in processes]"),
+        ({"seconds": 2.0, "process_cpu_seconds": 3.0,
+          "parts": {"machine_fetch": {"seconds": 3.0, "count": 17, "in_process": 16}}},
+         "  [1.50 cores busy, 16 of 17 machines fetched in processes]"),
+        ({"seconds": 0.5, "cpu_seconds": 0.25,
+          "parts": {"machine_fetch": {"seconds": 0.4, "count": 1, "in_process": 0}}},
+         "  [own thread cpu 50%, 0 of 1 machines fetched in processes]"),
+        # an older program's fetch says nothing of its workers
+        ({"seconds": 2.0, "process_cpu_seconds": 3.0,
+          "parts": {"machine_fetch": {"seconds": 30.0, "count": 17}}}, "  [1.50 cores busy]"),
         # an older program's phase, and one too short to have seconds
         ({"seconds": 4.0, "status": "done"}, ""),
         ({"seconds": 0.0, "process_cpu_seconds": 1.0, "cpu_seconds": 1.0}, ""),
@@ -857,6 +884,10 @@ def test_a_phases_line_says_the_cores_busy_and_what_its_own_thread_computed(entr
          "  [1.00 GB/s, 1.32 GB, 0% reused]"),
         # bytes on a part too short to have seconds: the size alone
         ({"seconds": 0.0, "count": 1, "cpu_seconds": 0.0, "bytes": 5 * 10**8}, "  [0.50 GB]"),
+        # machines fetched in processes: what crossed back, which is no rate
+        # of the worker-seconds the fetches took
+        ({"seconds": 16.0, "count": 160, "cpu_seconds": 15.6, "bytes": 349_000_000,
+          "in_process": 160}, "  [cpu 98%, 0.35 GB]"),
         ({"seconds": 2.0, "count": 1}, ""),
     ],
 )
@@ -1032,9 +1063,10 @@ def test_trace_cli_prints_the_part_table_under_each_phase(built, tmp_path):
     assert result.exit_code == 0, result.output
     lines = result.output.splitlines()
     at = next(i for i, line in enumerate(lines) if line.startswith("data_fetch"))
-    assert sorted(line.split()[0] for line in lines[at + 1 : at + 5]) == [
-        "machine_fetch", "provider_read", "resample_join", "row_filter",
+    assert sorted(line.split()[0] for line in lines[at + 1 : at + 6]) == [
+        "machine_fetch", "pool_start", "provider_read", "resample_join", "row_filter",
     ]
+    assert "0 of 2 machines fetched in processes" in lines[at]
     assert any(line.startswith("  program fleet_fit") for line in lines)
     assert any(line.startswith("compile path: trace_s=") for line in lines)
     as_json = CliRunner().invoke(gordo_tpu_cli, ["trace", str(path), "--as-json"])
