@@ -17,7 +17,6 @@ from importlib.util import find_spec
 from pprint import pformat
 from typing import Any, Callable, Dict, Optional, Tuple, Union
 
-import jax
 import numpy as np
 import pandas as pd
 from sklearn.base import BaseEstimator, TransformerMixin
@@ -27,6 +26,7 @@ from sklearn.metrics import explained_variance_score
 from .. import serializer
 from ..ops.windows import sliding_windows, window_targets
 from .base import GordoBase
+from .in_flight import for_pickling
 from .register import register_model_builder
 from .spec import ModelSpec, Sequential
 from .training import (
@@ -230,11 +230,7 @@ class JaxBaseEstimator(GordoBase, BaseEstimator):
     def __getstate__(self):
         state = self.__dict__.copy()
         if state.get("params_") is not None:
-            state["params_"] = jax.tree_util.tree_map(
-                # gt-lint: disable=jax-device-sync -- pickling fetch on the
-                # serialization path, not timed device work; no span exists
-                lambda a: np.asarray(a), jax.device_get(state["params_"])
-            )
+            state["params_"] = for_pickling(state["params_"])
         return state
 
     def __setstate__(self, state):

@@ -29,7 +29,7 @@ import logging
 import time
 from dataclasses import dataclass, replace
 from functools import lru_cache
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, Iterable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +39,7 @@ from jax.sharding import Mesh
 
 from .. import telemetry
 from ..models.anomaly.diff import THRESHOLD_RUN
+from ..models.in_flight import Flight, LeafInFlight
 from ..models.nn import forward_fn_for, init_fn_for
 from ..models.spec import ModelSpec
 from ..models.training import (
@@ -141,7 +142,8 @@ class WindowedFleetMember:
 class FleetResult:
     name: str
     #: host numpy pytree; None when ``error`` is set, and when the fit
-    #: left its parameters on the device (``block``)
+    #: left its parameters on the device (``block``). Of a large
+    #: artifact the leaves are on their way (``in_flight``)
     params: Any
     history: History
     seed: int = 0  # the RNG seed this member actually trained with
@@ -161,6 +163,10 @@ class FleetResult:
     #: that refers to them goes.
     block: Any = None
     row: int = 0
+    #: of a fit that did not wait for its parameters (``defers``): the
+    #: transfers it started, whose leaves ``params`` holds
+    #: (models/in_flight.py); None on the eager schedule
+    in_flight: Optional[Flight] = None
 
 
 @dataclass
@@ -313,6 +319,63 @@ _FLAT_CONCAT_MAX_LEAVES = 256
 #: backbone's expert weights are 117 MB a leaf)
 _COALESCE_MAX_LEAF_BYTES = 64 << 20
 
+#: a fit whose ONE member holds a leaf of at least this many bytes does
+#: not wait for its parameters (:func:`defers`): the line above, drawn
+#: on a member's part of a stacked leaf. A backbone's expert weights are
+#: 64-120 MB a leaf and the member is the bucket; the four-chip LSTM
+#: cell's 64 members stack to leaves of exactly 64 MiB, 1 MiB a member,
+#: and stay eager
+DEFER_MIN_MEMBER_LEAF_BYTES = _COALESCE_MAX_LEAF_BYTES
+
+
+def _fetched_alone(leaf) -> bool:
+    """Whether ``fetch_to_host`` fetches ``leaf`` on its own and hands
+    out the runtime's read-only array (the rest it coalesces, and hands
+    out copies): one rule for both schedules, because a pickle tells
+    the two kinds of array apart."""
+    return leaf.nbytes >= _COALESCE_MAX_LEAF_BYTES
+
+
+def defers(params) -> bool:
+    """Whether a fit's stacked ``params`` are a large artifact, whose
+    transfer is started and not waited for (models/in_flight.py): a
+    member of it has a leaf that ``fetch_to_host`` would fetch on its
+    own anyway. Everything else comes back at once, coalesced, and so
+    does everything where the processes are several (the all-gather is
+    a collective: every process waits in it together)."""
+    if jax.process_count() > 1:
+        return False
+    return any(
+        leaf.nbytes // leaf.shape[0] >= DEFER_MIN_MEMBER_LEAF_BYTES
+        for leaf in jax.tree_util.tree_leaves(params)
+    )
+
+
+def _start_flight(params):
+    """Start every leaf of ``params`` on its way, in the order of the
+    tree, which is the order a pickler reaches them (a ``dict``'s keys
+    sorted: what ``tree_map`` makes of one); returns the flight and the
+    tree of its transfers. A leaf the eager schedule coalesces is a
+    copy of its own there, and lands writable here."""
+    flight = Flight()
+    return flight, jax.tree_util.tree_map(
+        lambda leaf: flight.start(leaf, writable=not _fetched_alone(leaf)), params
+    )
+
+
+def land_flights(flights: Iterable[Optional[Flight]]) -> None:
+    """Wait for the parameters that earlier fits left on their way, so
+    that no fit's block is on the device when the caller's next program
+    starts there (``Flight.land``): a ``collect`` part of the seconds
+    waited, which are the rest of those fits' fetch, and which starts
+    nothing itself. Nothing on the eager schedule, and nothing the
+    second time."""
+    aloft = {flight for flight in flights if flight is not None and flight.aloft}
+    if aloft:
+        with telemetry.part_span("collect", bytes_deferred=0):
+            for flight in aloft:
+                flight.land()
+
 
 def fetch_to_host(tree):
     """
@@ -343,7 +406,7 @@ def fetch_to_host(tree):
     by_dtype: Dict[Any, List[int]] = {}
     host_leaves: List[Any] = [None] * len(leaves)
     for idx, leaf in enumerate(leaves):
-        if leaf.nbytes >= _COALESCE_MAX_LEAF_BYTES:
+        if _fetched_alone(leaf):
             # a large leaf's transfer dwarfs the round trip it would
             # save, and coalescing would copy it twice more (into the
             # concatenation on the device, out of it on the host)
@@ -766,7 +829,11 @@ class FleetTrainer:
         ``block`` and its ``row`` in it (:meth:`device_params` stacks
         such results for a predict program). For models that exist to
         predict and go, a CV fold's; a fit whose parameters are the
-        artifact takes them to the host (the default).
+        artifact takes them to the host (the default), and where they
+        are a large one (:func:`defers`) does not wait for them: the
+        result's ``params`` then hold leaves on their way
+        (``FleetResult.in_flight``, models/in_flight.py), which
+        ``in_flight.landed`` turns into the ``numpy`` arrays they become.
 
         CONTRACT: a member whose device program fails in ISOLATION (after
         bucket bisection of a ``JaxRuntimeError``/``RESOURCE_EXHAUSTED``)
@@ -798,6 +865,7 @@ class FleetTrainer:
                     members[i], seed=members[i].seed + 7919 * attempt
                 )
                 retry_members.append(member)
+            land_flights(result.in_flight for result in results)  # as before every fit
             retried = self._train_once(retry_members, config, params_on_device)
             for i, result in zip(failed_idx, retried):
                 result.retries = attempt
@@ -890,6 +958,9 @@ class FleetTrainer:
         try:
             for member in bucket:
                 fault_point("device_program", member.name)
+            # an earlier bucket's parameters that are still on their way
+            # are still on the chip: this fit does not run beside them
+            land_flights(result.in_flight for result in by_name.values())
             results = run(bucket)
         except Exception as exc:
             if not is_device_error(exc):
@@ -1210,12 +1281,28 @@ class FleetTrainer:
         histories come to the host, and so do the parameters unless
         ``params_on_device``: then they are not touched, and every
         result refers to ``params`` itself, the program's block, and to
-        its row in it."""
+        its row in it. The parameters of a large artifact (``defers``)
+        are started on their way and not waited for: the span says how
+        many bytes (``bytes_deferred``, 0 on the eager schedule), and
+        every result holds leaves in flight."""
         with telemetry.part_span("collect") as span:
+            deferred = not params_on_device and defers(params)
             host_params, losses, val_losses, epochs_ran = _fetch_for(
                 span,
-                (None if params_on_device else params, losses, val_losses, epochs_ran),
+                (
+                    None if params_on_device or deferred else params,
+                    losses,
+                    val_losses,
+                    epochs_ran,
+                ),
             )
+            flight = None
+            if deferred:
+                # after the histories: a small fetch asked for behind
+                # these would wait for every one of them
+                flight, host_params = _start_flight(params)
+            if span.recording and not params_on_device:
+                span.set(bytes_deferred=flight.bytes_started if flight else 0)
             losses = np.asarray(losses)
             val_losses = np.asarray(val_losses)
             epochs_ran = np.asarray(epochs_ran)
@@ -1231,7 +1318,10 @@ class FleetTrainer:
                 if ran and not np.all(np.isnan(member_val)):
                     history["val_loss"] = [float(l) for l in member_val]
                 member_params = jax.tree_util.tree_map(
-                    lambda a: np.asarray(a[i]), host_params
+                    (lambda a: LeafInFlight(a, i))
+                    if deferred
+                    else (lambda a: np.asarray(a[i])),
+                    host_params,
                 )
                 results.append(
                     FleetResult(
@@ -1240,6 +1330,7 @@ class FleetTrainer:
                         params=member_params,
                         block=params if params_on_device else None,
                         row=i,
+                        in_flight=flight,
                         history=History(
                             history=history,
                             params={

@@ -64,6 +64,7 @@ from ..models.anomaly.diff import (
     threshold_run,
 )
 from ..models.estimators import JaxBaseEstimator, JaxWindowedBaseEstimator
+from ..models.in_flight import landed
 from ..models.training import FitConfig, fit_config_from_kwargs, split_fit_kwargs
 from ..ops.windows import model_offset as calc_model_offset
 from ..ops.windows import window_targets
@@ -79,6 +80,7 @@ from .fleet import (
     WindowedFleetMember,
     fetch_members,
     is_device_error,
+    land_flights,
 )
 from .journal import BuildJournal, clean_staging_dirs
 
@@ -638,6 +640,9 @@ class FleetBuilder:
         self._journal = None
         self._plan_actuals = defaultdict(float)
         self._member_actuals = defaultdict(int)
+        # the final fits that did not wait for their parameters: each
+        # plan with the transfers its estimator's leaves belong to
+        self._in_flight: List[Tuple[_Plan, Any]] = []
         self._device_peak_bytes = 0
         self._current_phase, self._phase_span_id = "", None
         self._ledger_flushed = False
@@ -907,6 +912,7 @@ class FleetBuilder:
             0, getattr(self.trainer, "bucket_bisects", 0) - trainer_bisects_start
         )
         with self._phase("finish"):
+            self._land_parameters()
             self._record_prometheus(machines)
             self._export_plan_accuracy()
             self._ledger.flush()
@@ -920,6 +926,7 @@ class FleetBuilder:
     def _build_sequentially(self, machines, fallbacks, results) -> None:
         """The machines the fleet path does not train, one by one on the
         sequential ModelBuilder, appended to ``results``."""
+        self._land_flights()  # its programs find the chip as it was
         for machine in fallbacks:
             logger.info("Fleet fallback to ModelBuilder for %s", machine.name)
             try:
@@ -1118,6 +1125,12 @@ class FleetBuilder:
         # not a machine
         timings: List[Tuple[float, float, float, float, serializer.Written]] = []
         clock, cpu_clock = time.perf_counter, self._cpu_clock()
+        # what the picklers find of the final fits' leaves that are on
+        # their way, and wait for, is the flights' count from here on
+        before = {
+            flight: (flight.bytes_landed, flight.wait_seconds)
+            for _, flight in self._in_flight
+        }
 
         def dump_one(item):
             model, machine = item
@@ -1180,7 +1193,21 @@ class FleetBuilder:
         # write: the pickle, hashed as it is written, + JSON into the
         # staging dir, renamed
         # (the helper threads that hash beside the write count their CPU
-        # into ``write``)
+        # into ``write``); collect: of ``write``'s bytes the leaves a
+        # pickler took from a transfer the final fit had started, and of
+        # its seconds those it waited for one (near none: the link
+        # outruns the md5)
+        found = [
+            (flight.bytes_landed - landed, flight.wait_seconds - waited)
+            for flight, (landed, waited) in before.items()
+            if flight.bytes_landed > landed
+        ]
+        fetched = sum(nbytes for nbytes, _ in found)
+        waited = sum(seconds for _, seconds in found)
+        if found:
+            self._record_part(
+                "collect", waited, len(found), bytes=fetched, d2h_seconds=waited
+            )
         self._record_part(
             "serialize",
             sum(t[0] for t in timings),
@@ -1196,14 +1223,41 @@ class FleetBuilder:
             bytes_hashed_beside_write=sum(
                 t[4].bytes_hashed_beside_write for t in timings
             ),
+            bytes_fetched_beside_write=fetched,
+            fetch_wait_seconds=waited,
         )
         saved = []
         for (model, machine), exc in zip(to_dump, outcomes):
             if exc is not None:
+                # also where a leaf on its way did not land: what the
+                # final fit's ``collect`` would have raised, met here
                 self._fail(machine.name, exc)
                 continue
             saved.append((model, machine))
         return saved
+
+    def _land_flights(self) -> None:
+        """The final fits' parameters that are still on their way,
+        waited for: a flight holds its fit's block on the device, and
+        what runs there next (a later final fit, the sequential
+        builder) runs without it, as on the eager schedule. The copies
+        are there within a second of their ``collect``."""
+        land_flights(flight for _, flight in self._in_flight)
+
+    def _land_parameters(self) -> None:
+        """Every leaf of a final fit that is still on its way, taken:
+        what ``build()`` returns holds plain ``numpy`` parameters,
+        whether a dump took the leaves first (then nothing is waited
+        for here) or none was asked for. A leaf that does not land then
+        fails its machine alone."""
+        for plan, _ in self._in_flight:
+            if self._skipped(plan.machine.name):
+                continue  # its dump met the error first
+            try:
+                plan.estimator.params_ = landed(plan.estimator.params_)
+            except Exception as exc:
+                self._fail(plan.machine.name, exc)
+        self._in_flight = []  # and with them the transfers' arrays
 
     # ------------------------------------------------------------- planning
 
@@ -2606,7 +2660,11 @@ class FleetBuilder:
             return
         try:
             with self._phase("final_fit"):
-                # these parameters are the artifact: they come to the host
+                # these parameters are the artifact: they come to the
+                # host, a large one's while its model.pkl is written
+                # (the last fit's: an earlier one's are landed here,
+                # off the chip before this one runs there)
+                self._land_flights()
                 results = self.trainer.train(
                     members, config, params_on_device=False
                 )
@@ -2657,6 +2715,8 @@ class FleetBuilder:
                 plan.fleet_retries += result.retries
                 self.robustness["fleet_retries"] += result.retries
                 plan.estimator.params_ = result.params
+                if result.in_flight is not None:
+                    self._in_flight.append((plan, result.in_flight))
                 plan.estimator.spec_ = plan.spec
                 plan.estimator._history = result.history
                 plan.train_duration = time.time() - start

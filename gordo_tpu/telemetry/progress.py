@@ -46,6 +46,16 @@ DEFAULT_HEARTBEAT_SECONDS = 0.5
 BUILD_STATUS_FILE = "build_status.json"
 BUILD_TRACE_FILE = "build_trace.jsonl"
 
+#: a part's sums that are counts (of pieces, bytes, machines), written
+#: whole; the rest are seconds
+_WHOLE_NUMBERS = (
+    "count",
+    "bytes",
+    "in_process",
+    "bytes_deferred",
+    "bytes_fetched_beside_write",
+)
+
 
 class BuildProgress:
     """
@@ -152,7 +162,8 @@ class BuildProgress:
     ) -> None:
         """Fold one ``build_part`` span into ``phases[phase]["parts"]``:
         its seconds and ``count``, and of ``PART_SUMS`` (``cpu_seconds``,
-        ``bytes``, ``bytes_reused``, ``d2h_seconds``) what the span gave,
+        ``bytes``, ``bytes_reused``, ``d2h_seconds``, ``bytes_deferred``,
+        ``bytes_fetched_beside_write``, ``fetch_wait_seconds``) what the span gave,
         so an entry has such a key only where a span had it. Nothing is
         written until the next heartbeat or phase entry."""
         with self._lock:
@@ -216,7 +227,7 @@ class BuildProgress:
                     phases[name]["parts"] = {
                         part: {
                             key: int(value)
-                            if key in ("count", "bytes", "in_process")
+                            if key in _WHOLE_NUMBERS
                             else round(value, 6)
                             for key, value in entry.items()
                         }
@@ -343,8 +354,12 @@ def part_rates_text(measured: Dict[str, Any]) -> str:
     computing for it (the rest it waited: for the GIL, for I/O, for the
     device, for a core), its GB/s where it moved bytes, of a ``stack``'s
     bytes the share filled into buffers the staging pool already held,
-    and a ``collect``'s fetch alone. Empty where the part carries none of
-    them."""
+    a ``collect``'s fetch alone and what it started on its way and did
+    not wait for (``bytes_deferred``), and of a ``write``'s bytes those
+    its pickler took from such a transfer, with the seconds it waited
+    for them (``bytes_fetched_beside_write``, ``fetch_wait_seconds``:
+    near none where the md5 sets the pace, and the link's where the
+    link does). Empty where the part carries none of them."""
     seconds = float(measured.get("seconds") or 0.0)
     shown = []
     if "cpu_seconds" in measured and seconds > 0:
@@ -367,6 +382,13 @@ def part_rates_text(measured: Dict[str, Any]) -> str:
             else ""
         )
         shown.append(f"d2h {float(measured['d2h_seconds']):.2f} s{rate}")
+    if measured.get("bytes_deferred"):
+        shown.append(f"{_gigabytes(measured['bytes_deferred'])} on their way")
+    if measured.get("bytes_fetched_beside_write"):
+        shown.append(
+            f"{_gigabytes(measured['bytes_fetched_beside_write'])} fetched beside, "
+            f"waited {float(measured.get('fetch_wait_seconds') or 0.0):.2f} s"
+        )
     return f"  [{', '.join(shown)}]" if shown else ""
 
 

@@ -62,10 +62,23 @@ DEFAULT_KEEP = 3
 #: thread (or, recorded as a sum, the threads) that ran it, the bytes it
 #: moved, of a ``stack``'s bytes those filled into buffers the staging
 #: pool already held (parallel/host_blocks.py), inside ``collect`` the
-#: seconds of the device-to-host fetch alone, and of the machines a
+#: seconds of the device-to-host fetch alone, of the machines a
 #: ``machine_fetch`` counts those fetched in a worker process
-#: (:func:`part_sums` counts them off the span's ``worker``)
-PART_SUMS = ("cpu_seconds", "bytes", "bytes_reused", "d2h_seconds", "in_process")
+#: (:func:`part_sums` counts them off the span's ``worker``), and of a
+#: large artifact's parameters (models/in_flight.py) the bytes a final
+#: fit's ``collect`` started on their way and did not wait for, of a
+#: ``write``'s bytes those its pickler took from such a transfer, and
+#: the seconds it waited for them
+PART_SUMS = (
+    "cpu_seconds",
+    "bytes",
+    "bytes_reused",
+    "d2h_seconds",
+    "in_process",
+    "bytes_deferred",
+    "bytes_fetched_beside_write",
+    "fetch_wait_seconds",
+)
 #: and a ``build_phase`` span beside its seconds: its own thread's CPU
 #: seconds and the whole process's between its two ends
 PHASE_SUMS = ("cpu_seconds", "process_cpu_seconds")

@@ -472,7 +472,9 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     and ``process_cpu_seconds`` (every thread's between its two ends),
     and a part ``cpu_seconds``, ``bytes`` and, a ``collect``, the
     ``d2h_seconds`` of its fetch alone: an attribute of the ``collect``
-    entry and no part of its own, so nothing counts it twice. A part
+    entry and no part of its own, so nothing counts it twice (and the
+    ``bytes_deferred`` it did not wait for, which ``dump``'s ``write``
+    meets again as ``bytes_fetched_beside_write``). A part
     covers its own interval (overlapping parts count once); a part
     recorded as a sum over many pieces (``count`` attribute) covers its
     seconds. Only parts whose parent is the phase cover it: a part
@@ -592,7 +594,7 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
             if key in entry:
                 entry[key] = round(entry[key], 6)
         for part in entry["parts"].values():
-            for key in ("seconds", "cpu_seconds", "d2h_seconds"):
+            for key in ("seconds", "cpu_seconds", "d2h_seconds", "fetch_wait_seconds"):
                 if key in part:
                     part[key] = round(part[key], 6)
     return {"phases": phases, "compile": compile_path}
