@@ -1,6 +1,6 @@
 # Convenience targets (CI runs scripts/tests.sh per matrix component)
 
-.PHONY: test test-fast test-faults test-observability test-serve test-wire test-planner test-lifecycle test-lifecycle-faults test-analysis test-concurrency test-fleet-health test-slo test-precision test-chaos test-scale test-stream test-ingest test-perfmodel docs bench bench-telemetry bench-serve bench-planner bench-lifecycle bench-route bench-fleet-health bench-slo bench-precision bench-chaos bench-scale bench-stream bench-ingest bench-perfmodel bench-check lint lint-gordo lockgraph-check image
+.PHONY: test test-fast test-faults test-observability test-serve test-wire test-planner test-lifecycle test-lifecycle-faults test-analysis test-concurrency test-fleet-health test-slo test-precision test-chaos test-scale test-stream test-ingest test-perfmodel docs bench lint lint-gordo lockgraph-check image
 
 test:
 	python -m pytest tests/ -q
@@ -49,38 +49,11 @@ test-lifecycle:
 test-lifecycle-faults:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m "lifecycle and faults"
 
-# Lifecycle hot-swap benchmark: concurrent clients through N canary
-# promote/rollback swaps; writes BENCH_LIFECYCLE.json (swap latency,
-# dropped requests — target: zero).
-bench-lifecycle:
-	JAX_PLATFORMS=cpu python benchmarks/bench_lifecycle.py
-
-# Serving micro-batching benchmark: concurrent single-model requests
-# with batching off vs on; writes BENCH_SERVE.json.
-bench-serve:
-	JAX_PLATFORMS=cpu python benchmarks/bench_serve.py
-
-# Bucket-planner benchmark: a heterogeneous synthetic fleet built with
-# the naive vs packed strategies; writes BENCH_PLAN.json.
-bench-planner:
-	JAX_PLATFORMS=cpu python benchmarks/bench_planner.py
-
-# Telemetry-overhead microbench: a small CPU fleet build with telemetry
-# off vs on; writes BENCH_TELEMETRY.json for the bench trajectory.
-bench-telemetry:
-	JAX_PLATFORMS=cpu python benchmarks/bench_telemetry.py
-
 # The fleet console suite: per-member health ledger, device-utilization
 # telemetry, the joined fleet-status CLI/route surface — CPU-only and
 # not slow-marked, so the same tests also run inside the tier-1 budget.
 test-fleet-health:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m fleet_health
-
-# Fleet-health overhead microbench: the same build with all telemetry
-# (ledger + device sampler included) off vs on; writes
-# BENCH_FLEET_HEALTH.json (<=2% overhead is the gate).
-bench-fleet-health:
-	JAX_PLATFORMS=cpu python benchmarks/bench_fleet_health.py
 
 # The fleet SLO suite: cross-worker rollup reducer, burn-rate alert
 # state machine, worker-sink merge, slo CLI/route/gauges — CPU-only and
@@ -95,11 +68,6 @@ test-slo:
 test-precision:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m precision
 
-# Precision-ladder bench: per-precision fused scoring throughput +
-# verdict-agreement rate; writes BENCH_PRECISION.json.
-bench-precision:
-	JAX_PLATFORMS=cpu python benchmarks/bench_precision.py
-
 # The serving fault-containment suite: circuit-breaker state machine,
 # batch bisection under injected device faults, NaN-poison detection,
 # OOM rung demotion, the route-level chaos drills, and the
@@ -107,13 +75,6 @@ bench-precision:
 # the same tests also run inside the tier-1 budget.
 test-chaos:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m chaos
-
-# Route-level chaos drill: >=8 concurrent clients + device faults
-# against one coalesced member + a hot-swap mid-drill; asserts zero
-# innocent-rider 5xx, breaker trip/recovery, ledger narration; writes
-# BENCH_CHAOS.json (gated by `gordo-tpu bench-check`).
-bench-chaos:
-	JAX_PLATFORMS=cpu python benchmarks/bench_chaos.py
 
 # The streaming scoring-plane suite: row/event rings, SSE session
 # replay + cursor resume, watermark scoring with breaker quarantine,
@@ -123,13 +84,6 @@ bench-chaos:
 test-stream:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m stream
 
-# Streaming soak harness: N long-lived sessions under sustained Arrow
-# ingest with >=5 mid-stream hot-swaps, a poisoned member (quarantine +
-# half-open recovery), and a drain audit; writes BENCH_STREAM.json
-# (gated by `gordo-tpu bench-check`).
-bench-stream:
-	JAX_PLATFORMS=cpu python benchmarks/bench_stream.py
-
 # The device-resident ingest suite: compiled preprocessing plans,
 # raw-column dlpack transfer with host fallback, compiled-vs-host
 # parity across wire formats / batching modes / routes, ladder-snapped
@@ -137,12 +91,6 @@ bench-stream:
 # run inside the tier-1 budget.
 test-ingest:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m ingest
-
-# Device-ingest microbench: host preprocessing pipeline vs the compiled
-# plan + raw-column transfer on the same payloads; writes
-# BENCH_INGEST.json (gated by `gordo-tpu bench-check`).
-bench-ingest:
-	JAX_PLATFORMS=cpu python benchmarks/bench_ingest.py
 
 # The learned performance-model suite: trace harvesting, closed-form
 # ridge fit + deterministic holdout, accuracy-gated promotion,
@@ -152,15 +100,6 @@ bench-ingest:
 test-perfmodel:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m perfmodel
 
-# Learned-cost-model bench: measure a real fleet_forward shape grid,
-# fit + promote through the accuracy gate, score predicted-vs-actual on
-# the deterministic holdout (learned must beat analytic), and replay a
-# ragged request stream through the static vs model-informed row
-# ladder; writes BENCH_PERFMODEL.json (gated by `gordo-tpu
-# bench-check`).
-bench-perfmodel:
-	JAX_PLATFORMS=cpu python benchmarks/bench_perfmodel.py
-
 # The fleet-scale observability suite: sharded ledger layout/migration/
 # dirty-flush contracts, rollup-manifest counting-open reads, bounded
 # fleet-status selection/paging, the 5k-member breaker-summary guard —
@@ -168,36 +107,6 @@ bench-perfmodel:
 # tier-1 budget.
 test-scale:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m scale
-
-# Fleet-scale observability harness: the synthetic-fleet generator
-# (benchmarks/fleetgen.py) drives build-plan, sharded health ledger,
-# rollup manifest, bounded fleet-status, breaker board and prometheus
-# scrape at N in {100, 1k, 10k}; writes BENCH_SCALE.json (gated by
-# `gordo-tpu bench-check`).
-bench-scale:
-	JAX_PLATFORMS=cpu python benchmarks/bench_scale.py
-
-# SLO-engine bench: aggregation throughput (spans/s), steady-state
-# evaluation overhead vs the telemetry-on floor (<=2% is the gate), and
-# the scripted burn drill; writes BENCH_SLO.json.
-bench-slo:
-	JAX_PLATFORMS=cpu python benchmarks/bench_slo.py
-
-# Full-route serving benchmark + observability acceptance surface:
-# per-stage attribution from serve_trace.jsonl (coverage >= 90% of p50
-# walltime) and the tracing/histogram overhead floor; writes
-# BENCH_ROUTE.json (override the path with BENCH_ROUTE_OUT).
-bench-route:
-	JAX_PLATFORMS=cpu python benchmarks/bench_route.py
-
-# The perf-regression gate: re-run the route bench into a scratch file
-# and compare it against the committed BENCH_ROUTE.json. Exits non-zero
-# on regression; CI runs the same comparison with --report-only.
-bench-check:
-	JAX_PLATFORMS=cpu BENCH_ROUTE_OUT=/tmp/bench_route_fresh.json \
-		python benchmarks/bench_route.py
-	python -m gordo_tpu bench-check /tmp/bench_route_fresh.json \
-		--baseline BENCH_ROUTE.json
 
 # The sub-5-minute tier: everything except the compile-heavy JAX suites
 # (tests/parallel, tests/models) and slow-marked tests.

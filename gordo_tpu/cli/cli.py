@@ -1098,9 +1098,8 @@ def slo_status(
 def slo_check(directory: str, config_path: Optional[str], as_json: bool):
     """
     The SLO gate: evaluate DIRECTORY and exit non-zero while any
-    burn-rate alert is FIRING (pending and resolved alerts exit 0) —
-    mirroring ``bench-check``, so deploy pipelines and cron monitors
-    can gate on one command.
+    burn-rate alert is FIRING (pending and resolved alerts exit 0), so
+    deploy pipelines and cron monitors can gate on one command.
     """
     from ..telemetry import render_slo_status
 
@@ -1110,103 +1109,6 @@ def slo_check(directory: str, config_path: Optional[str], as_json: bool):
     else:
         click.echo(render_slo_status(doc))
     if doc.get("firing"):
-        raise SystemExit(1)
-
-
-@click.command("bench-check")
-@click.argument("candidate", type=click.Path(exists=True, dir_okay=False))
-@click.option(
-    "--baseline",
-    "baseline_path",
-    default=None,
-    type=click.Path(exists=True, dir_okay=False),
-    help="Baseline bench JSON (default: the committed BENCH_*.json for "
-    "the candidate's bench kind, looked up beside the candidate and "
-    "then in the current directory).",
-)
-@click.option(
-    "--tolerance",
-    "tolerance_scale",
-    default=1.0,
-    type=float,
-    help="Scale every gate tolerance by this factor (2.0 = twice as "
-    "lenient; noisy hosts).",
-)
-@click.option(
-    "--report-only",
-    is_flag=True,
-    help="Always exit 0: print the comparison, never gate (CI visibility "
-    "mode).",
-)
-@click.option(
-    "--as-json",
-    "as_json",
-    is_flag=True,
-    help="Print the raw comparison document instead of the report",
-)
-def bench_check(
-    candidate: str,
-    baseline_path: Optional[str],
-    tolerance_scale: float,
-    report_only: bool,
-    as_json: bool,
-):
-    """
-    The performance-regression gate: compare a fresh bench run
-    (CANDIDATE, a ``BENCH_*.json``-shaped document) against the
-    committed baseline for the same bench kind, metric by metric under
-    each metric's direction and tolerance, and exit non-zero on any
-    regression (unless --report-only).
-
-    Example: ``make bench-route BENCH_ROUTE_OUT=/tmp/fresh.json &&
-    gordo-tpu bench-check /tmp/fresh.json``.
-    """
-    from ..telemetry.benchgate import (
-        BASELINE_FILES,
-        compare_files,
-        render_report,
-    )
-
-    if baseline_path is None:
-        try:
-            with open(candidate) as handle:
-                bench = json.load(handle).get("bench")
-        except (OSError, ValueError) as exc:
-            raise click.ClickException(f"Unreadable candidate: {exc}")
-        default_name = BASELINE_FILES.get(str(bench))
-        if default_name is None:
-            raise click.ClickException(
-                f"No default baseline known for bench {bench!r}; "
-                "pass --baseline"
-            )
-        for directory in (
-            os.path.dirname(os.path.abspath(candidate)),
-            os.getcwd(),
-        ):
-            probe = os.path.join(directory, default_name)
-            if os.path.exists(probe) and os.path.abspath(
-                probe
-            ) != os.path.abspath(candidate):
-                baseline_path = probe
-                break
-        if baseline_path is None:
-            raise click.ClickException(
-                f"Committed baseline {default_name} not found beside the "
-                "candidate or in the current directory; pass --baseline"
-            )
-
-    try:
-        report = compare_files(
-            baseline_path, candidate, tolerance_scale=tolerance_scale
-        )
-    except (OSError, ValueError) as exc:
-        raise click.ClickException(str(exc))
-
-    if as_json:
-        click.echo(json.dumps(report, indent=1, sort_keys=True))
-    else:
-        click.echo(render_report(report))
-    if not report["ok"] and not report_only:
         raise SystemExit(1)
 
 
@@ -2204,7 +2106,6 @@ gordo_tpu_cli.add_command(build_status)
 gordo_tpu_cli.add_command(fleet_status)
 gordo_tpu_cli.add_command(trace)
 gordo_tpu_cli.add_command(slo_cli)
-gordo_tpu_cli.add_command(bench_check)
 gordo_tpu_cli.add_command(lint)
 gordo_tpu_cli.add_command(lockgraph)
 gordo_tpu_cli.add_command(run_server_cli)

@@ -80,10 +80,11 @@ class ModelResolution:
     most once per revision: the loaded model, parsed metadata/info, tag
     lists (both as :class:`SensorTag` and as plain names), the training
     frequency offset, the detector's threshold arrays, and the wire
-    column-alignment plans. BENCH_ROUTE.json measured ``model_resolve``
-    at 50.9ms p50 (7.5% of the route) — almost all of it the per-request
-    zlib+pickle metadata round-trip and tag re-normalization this object
-    exists to not repeat: a request now pays dict probes.
+    column-alignment plans. A CPU run of the route (PR 7) measured
+    ``model_resolve`` at 50.9ms p50 (7.5% of the route) — almost all of
+    it the per-request zlib+pickle metadata round-trip and tag
+    re-normalization this object exists to not repeat: a request now
+    pays dict probes.
 
     Pinned to the :class:`RevisionFleet` snapshot, so the DELETE/hot-swap
     invalidation contract is inherited wholesale (an invalidated revision

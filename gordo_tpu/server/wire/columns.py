@@ -9,7 +9,7 @@ parquet/pandas MultiIndex frame) without committing to any of them. The
 point of the type is what it is NOT: a pandas DataFrame. The legacy
 response path built a MultiIndex frame column-group by column-group
 (``make_base_dataframe`` + joins) and then walked it cell by cell into
-wire dicts — measured at ~70% of full-route p50 (BENCH_ROUTE.json,
+wire dicts — measured at ~70% of full-route p50 (a CPU run, PR 7:
 ``response_assemble`` 493ms of 686ms). Here every column is composed
 once, as a numpy array, and handed to the encoder as-is.
 """

@@ -1,8 +1,8 @@
 """
 A low-overhead sampling profiler for the serving host pipeline.
 
-BENCH_SERVE.json's open finding is that the full HTTP route runs ~50x
-slower than scoring alone — the host pipeline (JSON decode, pandas
+The open finding of a CPU run (PR 4) is that the full HTTP route runs
+~50x slower than scoring alone — the host pipeline (JSON decode, pandas
 alignment, response serialization) dominates, but nothing could say
 *which functions* eat the time on a live server. Deterministic tracing
 (``sys.setprofile``) is off the table: it taxes every Python call on

@@ -25,10 +25,10 @@ error budget" — not raw spans. This module renders that judgment:
   (or the lifecycle supervisor, which holds promotions while a page
   alert fires) reads the same truth;
 - surfaces: ``gordo-tpu slo status|check`` (check exits non-zero while
-  firing, mirroring ``bench-check``), the ``/gordo/v0/<project>/slo``
-  route, a section in :func:`fleet_status_document`, and bounded
-  Prometheus gauges (``gordo_slo_*`` — label cardinality is the
-  declared SLO count, never fleet or traffic size).
+  firing), the ``/gordo/v0/<project>/slo`` route, a section in
+  :func:`fleet_status_document`, and bounded Prometheus gauges
+  (``gordo_slo_*`` — label cardinality is the declared SLO count, never
+  fleet or traffic size).
 
 Stdlib-only, like the whole telemetry package.
 """

@@ -13,6 +13,26 @@ checked by ``python chip_smoke.py`` (see README.md).
 import os
 import sys
 
+# One thread a native pool, for this process and every process a test
+# starts. The tier-1 command runs six workers on the host's eight cores,
+# and each loads three pools of a thread a core (numpy's OpenBLAS,
+# scipy's OpenBLAS, scikit-learn's libgomp): 144 threads that spin at
+# each other's barriers, so that ``tests/server/test_fleet_serving_lstm.py``
+# took 37 s alone and 223 s as one of six (ISSUE 44). A pool reads these
+# once, when its library loads; ``setdefault``, so a developer who runs
+# one file with a value of their own keeps it.
+_NATIVE_POOLS = ("OMP_NUM_THREADS", "OPENBLAS_NUM_THREADS", "MKL_NUM_THREADS")
+for _name in _NATIVE_POOLS:
+    os.environ.setdefault(_name, "1")
+if "numpy" in sys.modules and all(os.environ[name] == "1" for name in _NATIVE_POOLS):
+    # jaxtyping's pytest plugin imports numpy before any conftest is
+    # read: where this process did not inherit the variables (a run
+    # without xdist, or xdist's controller), numpy's pool is sized
+    # already and is bound here, for as long as the process lives.
+    import threadpoolctl
+
+    _pools_held_at_one = threadpoolctl.threadpool_limits(limits=1)
+
 # Must be in place before the CPU backend initializes.
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:

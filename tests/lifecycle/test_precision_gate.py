@@ -1,5 +1,5 @@
 """
-Precision-parity gate drills: pass on healthy bf16, fail on corrupted
+Precision-parity gate drills: pass on healthy bf16 and int8, fail on corrupted
 quantization, crash == fail (never an exception), and the canary gate
 (`evaluate_canary`) engages the precision check exactly when the active
 serving precision is reduced.
@@ -38,11 +38,14 @@ def shared_spec(fleet) -> FeedForwardSpec:
     return specs[NAMES[0]]
 
 
-def test_parity_gate_passes_healthy_bf16(fleet):
-    report = evaluate_precision_parity(fleet, shared_spec(fleet), "bf16")
+@pytest.mark.parametrize("precision", ["bf16", "int8"])
+def test_parity_gate_passes_healthy(fleet, precision):
+    """Both reduced precisions of the ladder keep every member's anomaly
+    verdicts: bfloat16 casts and per-channel int8 weights."""
+    report = evaluate_precision_parity(fleet, shared_spec(fleet), precision)
     assert report.passed, report.failures
     parity = report.checks["parity"]
-    assert parity["precision"] == "bf16"
+    assert parity["precision"] == precision
     assert parity["agreement_min"] >= 0.98
     assert set(parity["members"]) == set(NAMES)
 

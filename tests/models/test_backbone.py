@@ -225,7 +225,7 @@ def test_loss_and_every_gradient_leaf_against_the_reference(seeded, reference):
         out, penalty, _ = backbone.forward_backbone_aux(spec, p, x, remat=remat)
         return weighted_mean_loss(resolve_loss("mse")(out, y), w) + penalty
 
-    loss, grads = jax.value_and_grad(lambda p: loss_of(p, False))(params)
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: loss_of(p, False)))(params)
     want_loss, want = reference.loss_and_grads(layers, x, y, w)
     assert abs(float(loss) - want_loss) <= TOLERANCE * max(1.0, abs(want_loss))
     flat = jax.tree_util.tree_flatten_with_path(grads)[0]
@@ -238,7 +238,7 @@ def test_loss_and_every_gradient_leaf_against_the_reference(seeded, reference):
             assert not np.any(np.asarray(layer["moe"]["expert_bias"]))
     assert np.any(np.asarray(grads["layer_1"]["moe"]["router"]))
     # rematerialised and plain gradients agree
-    loss_r, grads_r = jax.value_and_grad(lambda p: loss_of(p, True))(params)
+    loss_r, grads_r = jax.jit(jax.value_and_grad(lambda p: loss_of(p, True)))(params)
     assert float(loss_r) == float(loss)
     for a, b in zip(jax.tree_util.tree_leaves(grads_r), jax.tree_util.tree_leaves(grads)):
         close(a, b, "remat")

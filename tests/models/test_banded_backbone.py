@@ -326,7 +326,7 @@ def loss_of(spec, x, y, w, remat=False, active=None):
 def test_loss_and_every_gradient_leaf_against_the_reference(seeded, reference):
     spec, params, layers, x, y = seeded
     w = np.array([1, 1, 0.5, 1], np.float32)
-    loss, grads = jax.value_and_grad(loss_of(spec, x, y, w))(params)
+    loss, grads = jax.jit(jax.value_and_grad(loss_of(spec, x, y, w)))(params)
     want_loss, want = reference.loss_and_grads(layers, x, y, w)
     assert abs(float(loss) - want_loss) <= TOLERANCE * max(1.0, abs(want_loss))
     flat = jax.tree_util.tree_flatten_with_path(grads)[0]
@@ -339,7 +339,7 @@ def test_loss_and_every_gradient_leaf_against_the_reference(seeded, reference):
         elif "moe" not in name or "shared" in name or "router" in name:
             assert np.any(np.asarray(ref)), name  # every gate and shared expert learns
     # rematerialised layers and plain agree
-    loss_r, grads_r = jax.value_and_grad(loss_of(spec, x, y, w, True))(params)
+    loss_r, grads_r = jax.jit(jax.value_and_grad(loss_of(spec, x, y, w, True)))(params)
     assert float(loss_r) == pytest.approx(float(loss), rel=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(grads_r), jax.tree_util.tree_leaves(grads)):
         close(a, b, "remat")

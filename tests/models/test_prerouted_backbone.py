@@ -214,7 +214,7 @@ def test_every_leafs_gradient_is_the_references(seeded, reference):
         out, penalty, _ = backbone.forward_backbone_aux(spec, tree, x)
         return jnp.mean(jnp.mean((out - y) ** 2, axis=-1)) + penalty
 
-    loss, got = jax.value_and_grad(loss_of)(params)
+    loss, got = jax.jit(jax.value_and_grad(loss_of))(params)
     assert float(loss) == pytest.approx(want_loss, rel=1e-5)
     leaves = jax.tree_util.tree_flatten_with_path(got)[0]
     assert len(leaves) == 4 * 10 + 5
@@ -234,8 +234,9 @@ def test_remat_on_and_off_give_the_same_outputs_and_gradients(seeded, active):
         out, _, aux = backbone.forward_backbone_aux(spec, tree, x, remat=remat, active=active)
         return jnp.sum(jnp.mean((out - y) ** 2, axis=-1) * weights) / jnp.sum(weights), (out, aux)
 
-    (plain_loss, (plain_out, plain_aux)), plain = jax.value_and_grad(loss_of, has_aux=True)(params, False)
-    (remat_loss, (remat_out, remat_aux)), remat = jax.value_and_grad(loss_of, has_aux=True)(params, True)
+    step = jax.jit(jax.value_and_grad(loss_of, has_aux=True), static_argnums=1)
+    (plain_loss, (plain_out, plain_aux)), plain = step(params, False)
+    (remat_loss, (remat_out, remat_aux)), remat = step(params, True)
     assert np.array_equal(plain_out, remat_out) and float(plain_loss) == float(remat_loss)
     for name in plain_aux:
         assert np.array_equal(plain_aux[name], remat_aux[name]), name

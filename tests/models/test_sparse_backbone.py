@@ -3,6 +3,7 @@ reference (loaded by path: it imports nothing of the program's layer
 code): the sparse-attention operator, the indexer's objective, the
 softmax router's share layer, and what the fit step counts."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -240,10 +241,18 @@ def loss_of(spec, x, y, w, remat=False, active=None):
     return loss
 
 
+@functools.partial(jax.jit, static_argnames=("spec", "remat"))
+def loss_and_grads(spec, params, x, y, w, remat=False, active=None):
+    """One program a shape for the whole file, the fit's own way. Run
+    op by op, every loop of the forward pass and of its transpose is
+    compiled alone, each time: 22 s a call where this takes 5 once."""
+    return jax.value_and_grad(loss_of(spec, x, y, w, remat, active))(params)
+
+
 def test_loss_and_every_gradient_leaf_against_the_reference(seeded, reference):
     spec, params, layers, x, y = seeded
     w = np.array([1, 1, 0.5, 0], np.float32)
-    loss, grads = jax.value_and_grad(loss_of(spec, x, y, w, active=jnp.asarray(w > 0)))(params)
+    loss, grads = loss_and_grads(spec, params, x, y, w, active=jnp.asarray(w > 0))
     want_loss, want = reference.loss_and_grads(layers, x, y, w)
     assert abs(float(loss) - want_loss) <= TOLERANCE * max(1.0, abs(want_loss))
     flat = jax.tree_util.tree_flatten_with_path(grads)[0]
@@ -255,7 +264,7 @@ def test_loss_and_every_gradient_leaf_against_the_reference(seeded, reference):
         if "indexer" in jax.tree_util.keystr(path):
             assert np.any(np.asarray(ref)), jax.tree_util.keystr(path)
     # rematerialised (layers, and the blocks inside them) and plain agree
-    loss_r, grads_r = jax.value_and_grad(loss_of(spec, x, y, w, True, jnp.asarray(w > 0)))(params)
+    loss_r, grads_r = loss_and_grads(spec, params, x, y, w, True, jnp.asarray(w > 0))
     assert float(loss_r) == pytest.approx(float(loss), rel=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(grads_r), jax.tree_util.tree_leaves(grads)):
         close(a, b, "remat")
@@ -283,8 +292,8 @@ def test_two_disjoint_gradients_in_one_step(seeded):
         out, penalty, _ = backbone.forward_backbone_aux(spec, p, x, remat=True)
         return jnp.mean(resolve_loss("mse")(out, y)), penalty
 
-    forecast = jax.grad(lambda p: parts(p)[0])(params)
-    objective = jax.grad(lambda p: parts(p)[1])(params)
+    forecast = jax.jit(jax.grad(lambda p: parts(p)[0]))(params)
+    objective = jax.jit(jax.grad(lambda p: parts(p)[1]))(params)
     carried = 0
     for (path, f), o in zip(
         jax.tree_util.tree_flatten_with_path(forecast)[0], jax.tree_util.tree_leaves(objective)
@@ -356,10 +365,10 @@ def test_a_window_of_padding_adds_nothing_to_penalty_or_counters(seeded, padding
     assert np.array_equal(aux["keys_selected"], [len(kept) * kept_by_arithmetic()] * 2)
     np.testing.assert_allclose(aux["indexer_kl"], aux_kept["indexer_kl"], rtol=1e-5)
     # the step's loss and gradients are those of the windows that count
-    loss, grads = jax.value_and_grad(loss_of(spec, x, y, weights, True, jnp.asarray(weights > 0)))(params)
-    loss_k, grads_k = jax.value_and_grad(
-        loss_of(spec, x[np.array(kept)], y[np.array(kept)], weights[np.array(kept)], True)
-    )(params)
+    loss, grads = loss_and_grads(spec, params, x, y, weights, True, jnp.asarray(weights > 0))
+    loss_k, grads_k = loss_and_grads(
+        spec, params, x[np.array(kept)], y[np.array(kept)], weights[np.array(kept)], True
+    )
     assert float(loss) == pytest.approx(float(loss_k), rel=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(grads_k)):
         close(a, b, "gradient")

@@ -279,6 +279,27 @@ def test_failed_machines_counted_and_status_completes(tmp_path):
     assert [s["attributes"]["machine"] for s in failed_events] == ["dead-m"]
 
 
+def test_an_instrumented_build_writes_the_health_ledger(tmp_path):
+    """The build narrates each machine into ``fleet_health.json`` beside
+    the artifacts: what ``fleet-status`` and the lifecycle supervisor
+    read before the first request is ever served."""
+    from gordo_tpu.telemetry.fleet_health import load_health, reset_ledgers
+
+    reset_ledgers()
+    out = tmp_path / "out"
+    try:
+        results = FleetBuilder([make_machine("led-a"), make_machine("led-b")]).build(
+            output_dir=str(out)
+        )
+    finally:
+        reset_ledgers()
+    assert len(results) == 2
+    assert (out / telemetry.FLEET_HEALTH_FILE).is_file()
+    machines = load_health(str(out))["machines"]
+    assert sorted(machines) == ["led-a", "led-b"]
+    assert [machines[name]["build"]["failed"] for name in sorted(machines)] == [False, False]
+
+
 def test_telemetry_off_leaves_no_trace_files(tmp_path, monkeypatch):
     monkeypatch.setenv(telemetry.TELEMETRY_ENV, "0")
     out = tmp_path / "out"

@@ -1,5 +1,5 @@
 """
-Synthetic fleet generator for the scale harness (``bench_scale.py``).
+Synthetic fleet generator for the scale suite (``test_scale.py``).
 
 Fabricates everything the observability plane holds for an N-machine
 collection — member names, model specs, plan-packer member proxies, a
@@ -7,30 +7,22 @@ populated fleet-health ledger, serve-trace span sinks for the rollup
 reducer — WITHOUT training a single model. The point is to exercise the
 telemetry surfaces (build-plan, fleet-status, fleet-health, SLO
 rollups, trace analysis, breaker board, prometheus scrape) at member
-counts no real CI build could afford (10k members), so their cost
-curves are measured, not assumed.
+counts no real CI build could afford, so the scale thresholds (reshard
+trigger, inline cap) are crossed, not assumed.
 
 Determinism: everything is derived from the member index (names,
 spec-family assignment, request/error counts, span ids/timestamps), so
-two runs over the same N produce byte-identical corpora — the bench's
+two runs over the same N produce byte-identical corpora — the suite's
 bytes-ratio and files-opened numbers are exact, not sampled.
 
-Importable from tests too (``tests/telemetry/test_scale.py`` uses the
-same generator for the scale-marked suites), so keep it stdlib +
-gordo_tpu only.
+Keep it stdlib + gordo_tpu only.
 """
 
 import datetime
 import json
 import os
-import sys
 import types
-from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
-
-REPO_ROOT = Path(__file__).resolve().parent.parent
-if str(REPO_ROOT) not in sys.path:
-    sys.path.insert(0, str(REPO_ROOT))
 
 #: a fixed, boring epoch (scale corpora must be reproducible; the
 #: harness never reads the host clock for data)
@@ -148,22 +140,6 @@ def populate_ledger(ledger, names: List[str]) -> None:
             revision="1754000000000",
             reasons=["gate error_rate"],
         )
-    ledger.flush()
-
-
-def observe_tick(ledger, names: List[str]) -> None:
-    """One lifecycle-observe ledger feed: every machine's scored rows
-    folded ``write=False``, drift verdicts batched, ONE forced snapshot
-    at the end — the supervisor's per-cycle write pattern, whose cost
-    at N is what the harness charts."""
-    for i, name in enumerate(names):
-        ledger.record_scores(
-            name, rows=10, residual_mean=0.011, write=False
-        )
-        if i % 1013 == 0:
-            ledger.record_drift(
-                name, False, stats={"residual_ratio": 1.0}, write=False
-            )
     ledger.flush()
 
 

@@ -6,33 +6,25 @@ counting-open read contract, manifest-window trace skipping, the
 bounded fleet-status surface with explicit machine selection/paging,
 and the O(unhealthy) breaker-board summary at 5k tracked members.
 
-Corpora come from ``benchmarks/fleetgen.py`` — the same deterministic
-generator the ``bench_scale.py`` harness drives at 10k members; here
-the fleets are sized to stay inside the tier-1 budget while still
-crossing every scale threshold (reshard trigger, inline cap).
+Corpora come from ``fleetgen.py`` beside this file, a deterministic
+generator; the fleets are sized to stay inside the tier-1 budget while
+still crossing every scale threshold (reshard trigger, inline cap).
 """
 
 import json
 import os
-import sys
 import time
 from pathlib import Path
 
 import pytest
 
-REPO_ROOT = Path(__file__).resolve().parents[2]
-if str(REPO_ROOT / "benchmarks") not in sys.path:
-    sys.path.insert(0, str(REPO_ROOT / "benchmarks"))
-
-import fleetgen  # noqa: E402  (benchmarks/fleetgen.py, path-injected above)
-
-from gordo_tpu.telemetry.aggregate import (  # noqa: E402
+from gordo_tpu.telemetry.aggregate import (
     ROLLUP_DIR,
     ROLLUP_MANIFEST_FILE,
     RollupStore,
     sink_window_index,
 )
-from gordo_tpu.telemetry.fleet_health import (  # noqa: E402
+from gordo_tpu.telemetry.fleet_health import (
     FLEET_HEALTH_FILE,
     FLEET_HEALTH_SHARD_DIR,
     FLEET_HEALTH_SUMMARY_FILE,
@@ -44,7 +36,9 @@ from gordo_tpu.telemetry.fleet_health import (  # noqa: E402
     load_merged_health,
     reset_ledgers,
 )
-from gordo_tpu.telemetry.trace_analysis import iter_trace_files  # noqa: E402
+from gordo_tpu.telemetry.trace_analysis import iter_trace_files
+
+from tests.telemetry import fleetgen
 
 pytestmark = [pytest.mark.scale, pytest.mark.observability]
 
