@@ -8,7 +8,10 @@ mixed, gated heads, a shared expert) and of ``kind: smallthinker``
 router that reads the layer's input before its attention, experts
 gated by ``relu``) and of ``kind: kanana`` (``deepseek_v3``'s latent
 attention in every layer, two shared experts as one) as pure functions
-over an explicit parameter tree, like :mod:`.nn`.
+and of ``kind: phi4flash`` (a selective state-space scan, differential
+attention in a window and in full, gated memory units and attention
+that read an earlier layer's tensors) as pure functions over an explicit
+parameter tree, like :mod:`.nn`.
 
 ``u`` is the ``[batch, T, hidden]`` sequence of a batch of windows.
 
@@ -63,6 +66,43 @@ over an explicit parameter tree, like :mod:`.nn`.
   the softmax of ``I`` over ``S_t``: the forward's ``penalty``. The
   forecast loss gives the indexer no gradient and the penalty gives
   nothing else any (:func:`_selected_attention` has the derivative rule).
+- ``mamba`` (:func:`mamba`): ``[xs | z] = u W_in``; ``c = silu(conv(xs)
+  + b_conv)``, the causal depthwise convolution ``conv`` shares with the
+  gated one above (:func:`causal_conv`), ``ssm_conv`` taps; ``[r | B |
+  C] = c W_x``; ``dt = softplus(r W_dt + b_dt)``; ``A = -exp(A_log)``;
+  ``s_t = exp(dt_t (x) A) * s_{t-1} + (dt_t * c_t) (x) B_t``, ``s`` a
+  float32 ``[ssm_inner, ssm_state]`` state that is zero before the
+  window; ``y_t = s_t C_t + D * c_t``; the output ``(y * silu(z))
+  W_out``. The scan (:func:`selective_scan`) runs a chunk of
+  :data:`SCAN_CHUNK` rows at a time under its own derivative rule: the
+  forward keeps the state each chunk starts from and no other, the
+  backward computes a chunk's states again from that one and walks the
+  chunk back; state and arithmetic are :data:`SCAN_DTYPE` whatever the
+  layer's dtype. The layer a ``gmu`` reads hands on ``y``: the scan
+  output before the gate, with the ``D`` term.
+- ``gmu`` (:func:`gated_memory`): ``(M * silu(u W_g)) W_o``, ``M`` the
+  ``y`` of the last ``mamba`` layer before it.
+- differential attention (``spec.differential``;
+  :func:`differential_attention`): the projections carry a bias
+  (``spec.attention_bias``); query heads ``2j`` and ``2j + 1`` are the
+  two maps of differential head ``j``, key heads ``2p`` and ``2p + 1``
+  those of pair ``p``, whose value is ``[v_2p | v_2p+1]``, twice a head
+  wide; head ``j`` reads pair ``j // (heads / kv_heads)``. ``O_j =
+  RMSNorm(A1_j - lam A2_j; g) * (1 - lam_0)``, ``A1``, ``A2`` the two
+  causal softmax maps over that value, ``lam = exp(lq1 . lk1) - exp(lq2
+  . lk2) + lam_0``, ``lam_0 = 0.8 - 0.6 exp(-0.3 l)`` at layer ``l``.
+  Each map is an attention of its own over the pair's value (``heads /
+  kv_heads`` query heads a key head, as any grouped attention), in
+  tiles (:func:`_banded_attention`) or, ``full_attention`` and
+  ``cross_attention`` over a window of a tile at most, with every score
+  held at once. ``cross_attention`` projects queries alone and takes the
+  keys and values of the last ``full_attention`` layer before it.
+- a block of those operators is every kind's (:func:`block`): ``h = x
+  + Op(Norm(x))``, ``out = h + FFN(Norm(h))`` with ``spec.norm``'s norm
+  (here a LayerNorm with a bias) and the dense feed-forward. The layer loop
+  hands ``M`` and ``(k, v)`` from the layer that makes them to the
+  layers that read them beside the residual, as arguments and results
+  of each block's ``jax.checkpoint``.
 - dense feed-forward ``W_2(silu(u W_1) * (u W_3))``; an expert is the
   same at its own width, its gate ``spec.expert_activation`` (``silu``
   or ``relu``).
@@ -128,13 +168,25 @@ SELECT_SCOPE = "sparse_select"
 SPARSE_ATTENTION_SCOPE = "sparse_attention"
 #: ... of attention in tiles under a mask by position, by operator, of
 #: the gate on its heads and of the shared expert
-TILES_SCOPES = {"full_attention": "full_attention_tiles", "sliding_attention": "sliding_attention_tiles"}
+TILES_SCOPES = {
+    "full_attention": "full_attention_tiles", "sliding_attention": "sliding_attention_tiles",
+    "cross_attention": "cross_attention_tiles",
+}
 GATE_SCOPE = "attention_gate"
 SHARED_SCOPE = "moe_shared"
 #: ... and of a latent attention's way to its heads: the projection to
 #: the latent and the shared rotary key, the latent's norm, the
 #: expansion, the rotary parts
 LATENT_SCOPE = "latent_kv"
+#: ... of a state-space layer (the operator, its convolution, its scan),
+#: of a gated memory unit, and of what makes a differential head of two
+#: maps (the pairing before the attention; the weight, the difference
+#: and its norm after it)
+MAMBA_SCOPE = "mamba"
+SCAN_CONV_SCOPE = "scan_conv"
+SCAN_SCOPE = "selective_scan"
+MEMORY_SCOPE = "gated_memory"
+DIFFERENTIAL_SCOPE = "differential_heads"
 
 #: what a rematerialised routed layer keeps for its backward pass: the two
 #: grouped products that feed the gate. They are the part of a step whose
@@ -151,6 +203,13 @@ SAVED_PRODUCTS = ("moe_h1", "moe_h3")
 #: time, and what it masks is what the first forward masked, to the bit
 SAVED_SELECTION = "sparse_selection"
 
+#: what a rematerialised ``mamba`` layer keeps: its scan's output and the
+#: state each chunk of it starts from (168 + 10 MB a window of 8,192
+#: rows). With them the rematerialised forward runs no scan: of the four
+#: passes a row a step would take (forward, forward again, the chunk's
+#: states again, the walk back) three are left
+SAVED_SCAN = "selective_scan_output"
+
 #: parameters of at least this many bytes rematerialise their layers in
 #: the backward pass: below it a step's saved activations are small
 #: beside the chip's memory, above it they are what runs it out
@@ -162,6 +221,21 @@ REMAT_MIN_PARAM_BYTES = 1 << 30
 #: (:func:`gqa_attention`): blocks of the computation, not of a model
 #: (any tile gives the same numbers)
 ATTENTION_TILE = 512
+
+#: rows of a chunk of the selective scan (:func:`selective_scan`): the
+#: forward keeps one state a chunk, the backward holds one chunk's states
+#: (84 MB at 5,120 x 16 and 256 rows). A block of the computation, as
+#: the tile is: any chunk gives the same numbers up to rounding
+SCAN_CHUNK = 256
+#: rows of a chunk that one trip of the scan's loops takes: the rows
+#: follow one another an operation each, and what they read out and the
+#: gradients of a trip are computed for a trip's rows at once. Timed on
+#: the chip beside a loop of single rows and trips of 16 and 64 (PERF.md
+#: 6, PR 45)
+SCAN_UNROLL = 32
+#: the scan's state and arithmetic, whatever ``compute_dtype`` says: it
+#: integrates over every row of a window
+SCAN_DTYPE = jnp.float32
 
 
 def _mxu_operand_dtype(dtype):
@@ -191,10 +265,13 @@ def init_backbone(rng: jax.Array, spec: BackboneSpec) -> Dict:
     # before the indexer keep their 8 a layer, and so their weights; those
     # before the gate and the shared expert their 8 or 12
     a_layer = 12 if "sparse_attention" in spec.layer_ops else 8
-    if spec.attention_gate or spec.shared_expert_intermediate_size:
+    if spec.attention_gate or spec.shared_expert_intermediate_size or spec.differential:
         a_layer = 16
     keys = iter(jax.random.split(rng, 2 + a_layer * len(spec.layer_ops)))
     ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
+    zeros = lambda n: jnp.zeros((n,), jnp.float32)  # noqa: E731
+    # a LayerNorm has a bias beside its gain
+    norm = (lambda: ones(h)) if spec.norm == "rms" else (lambda: {"gain": ones(h), "bias": zeros(h)})
     params: Dict = {
         "embed": {
             "W": _normal(next(keys), (spec.n_features, h), spec.n_features),
@@ -202,10 +279,28 @@ def init_backbone(rng: jax.Array, spec: BackboneSpec) -> Dict:
         }
     }
     for i, (op, ffn) in enumerate(zip(spec.layer_ops, spec.layer_ffns)):
-        layer: Dict = {"operator_norm": ones(h), "ffn_norm": ones(h)}
+        layer: Dict = {"operator_norm": norm(), "ffn_norm": norm()}
         heads = spec.heads_by_layer[i]
         qo = heads * dh
-        if op == "conv":
+        if op == "mamba":
+            layer["mamba"] = _init_mamba(keys, spec)
+        elif op == "gmu":
+            layer["gmu"] = {
+                "in_proj": _normal(next(keys), (h, spec.ssm_inner), h),
+                "out_proj": _normal(next(keys), (spec.ssm_inner, h), spec.ssm_inner),
+            }
+        elif spec.differential:
+            # a cross layer projects queries alone
+            names = (("q", qo),) if op == "cross_attention" else (("q", qo), ("k", kv), ("v", kv))
+            attn = {f"w{name}": _normal(next(keys), (h, width), h) for name, width in names}
+            attn["wo"] = _normal(next(keys), (qo, h), qo)
+            if spec.attention_bias:
+                attn.update({f"b{name}": zeros(width) for name, width in names + (("o", h),)})
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+                attn[name] = 0.1 * jax.random.normal(next(keys), (dh,), jnp.float32)
+            attn["sub_norm"] = ones(2 * dh)
+            layer["attn"] = attn
+        elif op == "conv":
             bound = 1.0 / jnp.sqrt(float(spec.conv_L_cache))
             layer["conv"] = {
                 "in_proj": _normal(next(keys), (h, 3 * h), h),
@@ -270,11 +365,36 @@ def init_backbone(rng: jax.Array, spec: BackboneSpec) -> Dict:
                 }
         params[f"layer_{i}"] = layer
     params["head"] = {
-        "norm": ones(h),
+        "norm": norm(),
         "W": _normal(next(keys), (h, spec.n_features_out), h),
         "b": jnp.zeros((spec.n_features_out,), jnp.float32),
     }
     return params
+
+
+def _init_mamba(keys, spec: BackboneSpec) -> Dict:
+    """One ``mamba`` operator, seeded as the family seeds it where a
+    seeded matrix would make rates no trained model has: ``A_log =
+    log(1..ssm_state)`` in every channel, ``D`` one, the step's bias the
+    inverse softplus of steps log-uniform in [1e-3, 1e-1]; the matrices
+    and the taps as the other kinds', the convolution's bias zero."""
+    h, d, n, rank, taps = spec.hidden_size, spec.ssm_inner, spec.ssm_state, spec.ssm_dt_rank, spec.ssm_conv
+    bound = 1.0 / jnp.sqrt(float(taps))
+    w = {
+        "in_proj": _normal(next(keys), (h, 2 * d), h),
+        "conv_kernel": jax.random.uniform(next(keys), (d, taps), jnp.float32, -bound, bound),
+        "conv_bias": jnp.zeros((d,), jnp.float32),
+        "x_proj": _normal(next(keys), (d, rank + 2 * n), d),
+        "dt_proj": _normal(next(keys), (rank, d), rank),
+    }
+    steps = jnp.exp(
+        jax.random.uniform(next(keys), (d,), jnp.float32) * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3)
+    )
+    w["dt_bias"] = steps + jnp.log(-jnp.expm1(-steps))
+    w["A_log"] = jnp.broadcast_to(jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)), (d, n))
+    w["D"] = jnp.ones((d,), jnp.float32)
+    w["out_proj"] = _normal(next(keys), (d, h), d)
+    return w
 
 
 def trained_param_count(params: Dict) -> int:
@@ -292,15 +412,32 @@ def rms_norm(x, gain, eps):
     return (x * jax.lax.rsqrt(variance + eps).astype(x.dtype)) * gain.astype(x.dtype)
 
 
+def block_norm(spec: BackboneSpec, x, w):
+    """``spec.norm``'s norm of a block's input or of the head's: an
+    RMSNorm under a gain, or a LayerNorm (statistics in float32) under
+    a gain and a bias."""
+    if spec.norm == "rms":
+        return rms_norm(x, w, spec.norm_eps)
+    return layer_norm(x.astype(jnp.float32), w["gain"], w["bias"], spec.norm_eps).astype(x.dtype)
+
+
+def causal_conv(x: jnp.ndarray, kernel: jnp.ndarray) -> jnp.ndarray:
+    """``x [B, T, C]`` -> ``sum_k kernel[:, k] * x_{t-(L-1)+k}``:
+    depthwise and causal, zeros before the window. The one convolution
+    under the gated three taps of ``conv`` and the four taps, bias and
+    ``silu`` of ``mamba``: the two differ in what goes in and what is
+    done to what comes out, not in this sum."""
+    taps, length = kernel.shape[1], x.shape[1]
+    z = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))  # zero state before the window
+    kernel = kernel.astype(x.dtype)
+    return sum(kernel[:, k] * z[:, k : k + length] for k in range(taps))
+
+
 def short_conv(spec: BackboneSpec, w: Dict, u: jnp.ndarray) -> jnp.ndarray:
     dtype = u.dtype
-    taps, length = spec.conv_L_cache, u.shape[1]
     with jax.named_scope(CONV_SCOPE):
         b, c, x = jnp.split(u @ w["in_proj"].astype(dtype), 3, axis=-1)
-        z = jnp.pad(b * x, ((0, 0), (taps - 1, 0), (0, 0)))  # zero state before the window
-        kernel = w["kernel"].astype(dtype)
-        conv = sum(kernel[:, k] * z[:, k : k + length] for k in range(taps))
-        return (c * conv) @ w["out_proj"].astype(dtype)
+        return (c * causal_conv(b * x, w["kernel"])) @ w["out_proj"].astype(dtype)
 
 
 def rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -358,12 +495,15 @@ def scaled_rotary(x: jnp.ndarray, rope: Dict[str, Any]) -> jnp.ndarray:
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., rotated:]], axis=-1)
 
 
-def _heads(spec: BackboneSpec, w: Dict, u: jnp.ndarray, op: str = "full_attention"):
+def _heads(spec: BackboneSpec, w: Dict, u: jnp.ndarray, op: str = "full_attention", kv=None):
     """``u [B, T, hidden]`` -> ``(q [B, T, heads, dh], k, v [B, T,
     kv_heads, dh])``: the projections (``heads`` is the layer's own: the
-    width of its ``wq``), the per-head RMSNorm of ``q`` and ``k`` where
+    width of its ``wq``) with a bias where the spec has one
+    (``attention_bias``), the per-head RMSNorm of ``q`` and ``k`` where
     the spec has one, and the rotary embedding of operator ``op``, as
-    every attention takes them. A latent attention's come the other
+    every attention takes them. ``kv``: the ``(k, v)`` of an earlier
+    layer, as that layer placed them; the layer then projects ``q``
+    alone (``cross_attention``). A latent attention's come the other
     way (:func:`_latent_heads`): ``k`` and ``q`` of one width, ``v`` of
     its own."""
     if spec.kv_lora_rank:
@@ -371,9 +511,15 @@ def _heads(spec: BackboneSpec, w: Dict, u: jnp.ndarray, op: str = "full_attentio
     dtype = u.dtype
     batch, length, _ = u.shape
     kv_heads, dh = spec.num_key_value_heads, spec.head_dim
-    q = (u @ w["wq"].astype(dtype)).reshape(batch, length, -1, dh)
-    k = (u @ w["wk"].astype(dtype)).reshape(batch, length, kv_heads, dh)
-    v = (u @ w["wv"].astype(dtype)).reshape(batch, length, kv_heads, dh)
+
+    def projected(name, heads):
+        out = u @ w["w" + name].astype(dtype)
+        if spec.attention_bias:
+            out = out + w["b" + name].astype(dtype)
+        return out.reshape(batch, length, heads, dh)
+
+    q = projected("q", -1)
+    k, v = (projected("k", kv_heads), projected("v", kv_heads)) if kv is None else kv
     rope = spec.rope_of(op)
     plain = rope["rope_type"] == "default" and rope["partial_rotary_factor"] == 1
 
@@ -384,7 +530,7 @@ def _heads(spec: BackboneSpec, w: Dict, u: jnp.ndarray, op: str = "full_attentio
             return x
         return rotary(x, rope["rope_theta"]) if plain else scaled_rotary(x, rope)
 
-    return placed(q, "q_norm"), placed(k, "k_norm"), v
+    return placed(q, "q_norm"), placed(k, "k_norm") if kv is None else k, v
 
 
 def interleaved_rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -430,20 +576,28 @@ def _gated(spec: BackboneSpec, w: Dict, u: jnp.ndarray, out: jnp.ndarray) -> jnp
     return out.reshape(batch, length, -1) @ w["wo"].astype(dtype)
 
 
+def _attend_in_one_piece(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """Causal attention with every score of a window held at once: ``q
+    [B, T, n, g, dh]`` (each key/value head serves ``g`` query heads: no
+    repeat of ``k``, ``v``), ``k [B, T, n, dh]``, ``v [B, T, n, dv]`` ->
+    ``[B, T, n, g, dv]``."""
+    dtype = q.dtype
+    length, dh = q.shape[1], q.shape[-1]
+    scores = jnp.einsum("bqngd,bknd->bngqk", q, k) * (1.0 / jnp.sqrt(float(dh))).astype(dtype)
+    causal = jnp.tril(jnp.ones((length, length), bool))
+    scores = jnp.where(causal, scores.astype(jnp.float32), -jnp.inf)
+    weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    return jnp.einsum("bngqk,bknd->bqngd", weights, v)
+
+
 def gqa_attention(spec: BackboneSpec, w: Dict, u: jnp.ndarray) -> jnp.ndarray:
     """``full_attention`` with every score of a window held at once."""
-    dtype = u.dtype
     batch, length, _ = u.shape
     kv_heads, dh = spec.num_key_value_heads, spec.head_dim
     with jax.named_scope(ATTENTION_SCOPE):
         q, k, v = _heads(spec, w, u)
-        # each key/value head serves `group` query heads: no repeat of k, v
         q = q.reshape(batch, length, kv_heads, -1, dh)
-        scores = jnp.einsum("bqngd,bknd->bngqk", q, k) * (1.0 / jnp.sqrt(float(dh))).astype(dtype)
-        causal = jnp.tril(jnp.ones((length, length), bool))
-        scores = jnp.where(causal, scores.astype(jnp.float32), -jnp.inf)
-        weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
-        return _gated(spec, w, u, jnp.einsum("bngqk,bknd->bqngd", weights, v))
+        return _gated(spec, w, u, _attend_in_one_piece(q, k, v))
 
 
 def layer_norm(x, gain, bias, eps):
@@ -903,6 +1057,246 @@ def banded_attention(
     return out, (windows * attended, windows * multiplied)
 
 
+def _scan_states(state, a, dt, dtx, b):
+    """``s_r = exp(dt_r (x) a) * s_{r-1} + dtx_r (x) b_r`` down a trip's
+    rows from ``state``: every ``s_r``, stacked ``[rows, N, d]`` (the
+    channels last: they fill a register's lanes). The sequential part of
+    the scan: a row is one operation on the device, which forms what it
+    multiplies and adds from the row's own vectors and writes nothing
+    else (held as arrays of all rows they were four times the states'
+    bytes, and the loop ran at the memory's pace: PERF.md 6, PR 45)."""
+    states = []
+    for row in range(dt.shape[0]):
+        state = jnp.exp(dt[row][None, :] * a) * state + dtx[row][None, :] * b[row][:, None]
+        states.append(state)
+    return jnp.stack(states)
+
+
+def _trips(t: jnp.ndarray) -> jnp.ndarray:
+    """A chunk's rows ``[rows, ...]`` as the trips of its loop, ``[trips,
+    SCAN_UNROLL rows, ...]`` (``_selective_scan`` takes chunks that divide)."""
+    rows = min(SCAN_UNROLL, t.shape[0])
+    return t.reshape((t.shape[0] // rows, rows) + t.shape[1:])
+
+
+@jax.custom_vjp
+def _selective_scan(x, dt, a, b, c):
+    """One window's scan, in chunks (:func:`_blocked`: ``x``, ``dt``
+    ``[chunks, rows, d]``, ``b``, ``c`` ``[chunks, rows, N]``; rows of
+    padding after the window's own carry ``dt = 0``: they leave the
+    state as it is) under the rates ``a [N, d]``: ``y [chunks, rows, d]``,
+    ``y_t = sum_n s_t[n] c_t[n]``, ``s_t = exp(dt_t (x) a) * s_{t-1} +
+    (dt_t * x_t) (x) b_t``, ``s`` zero before the first row. A chunk is
+    a loop of trips of :data:`SCAN_UNROLL` rows: the rows follow one
+    another in :func:`_scan_states`, an operation a row, and what a
+    trip's rows read out is computed for all of them at once. Its own
+    derivative rule: the forward keeps the state each chunk starts from
+    (``chunks`` states where a window has ``chunks x rows``), the
+    backward computes a chunk's states again from it, holds that one
+    chunk's, and walks it back."""
+    return _selective_scan_fwd(x, dt, a, b, c)[0]
+
+
+def _selective_scan_fwd(x, dt, a, b, c):
+    def chunk_of(state, rows):
+        def trip(state, rows):
+            x_r, dt_r, b_r, c_r = rows
+            states = _scan_states(state, a, dt_r, dt_r * x_r, b_r)
+            return states[-1], jnp.sum(states * c_r[:, :, None], axis=1)
+
+        with jax.named_scope(SCAN_SCOPE):
+            end, y = jax.lax.scan(trip, state, tuple(_trips(t) for t in rows))
+        return end, (y.reshape(rows[0].shape), state)
+
+    _, (y, starts) = jax.lax.scan(chunk_of, jnp.zeros(a.shape, a.dtype), (x, dt, b, c))
+    # what a rematerialised layer keeps: with them its second forward runs no scan
+    y, starts = checkpoint_name(y, SAVED_SCAN), checkpoint_name(starts, SAVED_SCAN)
+    return y, (x, dt, a, b, c, starts)
+
+
+def _selective_scan_bwd(kept, d_y):
+    x, dt, a, b, c, starts = kept
+
+    def chunk_of(carry, rows):
+        *rows, start = rows
+        x_c, dt_c, b_c, c_c, d_y_c = (_trips(t) for t in rows)
+
+        def again(state, rows):  # the state before each row of the trip
+            x_r, dt_r, b_r = rows
+            states = _scan_states(state, a, dt_r, dt_r * x_r, b_r)
+            return states[-1], jnp.concatenate([state[None], states[:-1]])
+
+        def back(carry, rows):
+            flowing, d_a = carry  # into the trip's last state from the rows after it; of the rates so far
+            x_r, dt_r, b_r, c_r, d_y_r, before = rows
+            dtx = dt_r * x_r
+            decay = jnp.exp(dt_r[:, None, :] * a[None])
+            states = decay * before + dtx[:, None, :] * b_r[:, :, None]
+            # of each state: what its own row reads out, and what flows
+            # back from the row after it, last row first
+            d_states = []
+            for row in reversed(range(dt_r.shape[0])):
+                d_states.append(flowing + c_r[row][:, None] * d_y_r[row][None, :])
+                flowing = jnp.exp(dt_r[row][None, :] * a) * d_states[-1]
+            d_states = jnp.stack(d_states[::-1])
+            d_exponent = d_states * before * decay  # of dt_t (x) a
+            entering = jnp.sum(d_states * b_r[:, :, None], axis=1)  # of dt_t * x_t
+            grads = (
+                entering * dt_r,
+                jnp.sum(d_exponent * a[None], axis=1) + entering * x_r,
+                jnp.sum(d_states * dtx[:, None, :], axis=2),
+                jnp.sum(states * d_y_r[:, None, :], axis=2),
+            )
+            return (flowing, d_a + jnp.sum(d_exponent * dt_r[:, None, :], axis=0)), grads
+
+        with jax.named_scope(SCAN_SCOPE):
+            _, befores = jax.lax.scan(again, start, (x_c, dt_c, b_c))
+            carry, grads = jax.lax.scan(back, carry, (x_c, dt_c, b_c, c_c, d_y_c, befores), reverse=True)
+        return carry, tuple(g.reshape(like.shape) for g, like in zip(grads, rows))
+
+    zero = jnp.zeros(a.shape, a.dtype)
+    (_, d_a), (d_x, d_dt, d_b, d_c) = jax.lax.scan(
+        chunk_of, (zero, zero), (x, dt, b, c, d_y, starts), reverse=True
+    )
+    return d_x, d_dt, d_a, d_b, d_c
+
+
+_selective_scan.defvjp(_selective_scan_fwd, _selective_scan_bwd)
+
+
+def scan_chunk_rows(length: int) -> int:
+    """Rows of a chunk of the scan over a window of ``length`` rows:
+    :data:`SCAN_CHUNK`, or the window where that is shorter, in whole
+    trips of the chunk's loop."""
+    trip = min(SCAN_UNROLL, SCAN_CHUNK, length)
+    return -(-min(SCAN_CHUNK, length) // trip) * trip
+
+
+def selective_scan(x, dt, a, b, c, d_skip):
+    """The selective scan over each window of ``x``, ``dt [B, T, d]``,
+    ``b``, ``c [B, T, N]`` under the rates ``a [d, N]`` (negative) and
+    the skip ``d_skip [d]``: ``y [B, T, d]``, ``y_t = s_t c_t + d_skip *
+    x_t`` (module docstring). In :data:`SCAN_DTYPE`, in chunks of
+    :data:`SCAN_CHUNK` rows, a window after another."""
+    length = x.shape[1]
+    chunk = scan_chunk_rows(length)
+    x, dt, b, c = (t.astype(SCAN_DTYPE) for t in (x, dt, b, c))
+    rates = a.T.astype(SCAN_DTYPE)
+    blocked = lambda t: _blocked(t, chunk)  # noqa: E731
+    y = jax.lax.map(
+        lambda one: _selective_scan(blocked(one[0]), blocked(one[1]), rates, blocked(one[2]), blocked(one[3])),
+        (x, dt, b, c),
+    )
+    return y.reshape(x.shape[0], -1, x.shape[2])[:, :length] + d_skip.astype(SCAN_DTYPE) * x
+
+
+def mamba(spec: BackboneSpec, w: Dict, u: jnp.ndarray, active: Optional[jnp.ndarray] = None):
+    """A selective state-space layer over ``u [B, T, hidden]`` (module
+    docstring): ``(output, y, scan_steps)``, ``y [B, T, ssm_inner]`` the
+    scan output before the gate and with the skip term (what a ``gmu``
+    reads), ``scan_steps`` float32 the rows of the windows that count
+    (``active``; None: all)."""
+    dtype = u.dtype
+    batch, length, _ = u.shape
+    d, n, rank = spec.ssm_inner, spec.ssm_state, spec.ssm_dt_rank
+    with jax.named_scope(MAMBA_SCOPE):
+        stream = u @ w["in_proj"].astype(dtype)
+        xs, z = stream[..., :d], stream[..., d:]
+        with jax.named_scope(SCAN_CONV_SCOPE):
+            conv = jax.nn.silu(causal_conv(xs, w["conv_kernel"]) + w["conv_bias"].astype(dtype))
+        # what sets the scan's step and its two matrices a row: two small
+        # products at full precision in the scan's own dtype, as a
+        # router's logits are (an error here is integrated over a window)
+        project = lambda t, m: jnp.dot(  # noqa: E731
+            t.astype(SCAN_DTYPE), m.astype(SCAN_DTYPE), precision=jax.lax.Precision.HIGHEST
+        )
+        row = project(conv, w["x_proj"])
+        dt = jax.nn.softplus(project(row[..., :rank], w["dt_proj"]) + w["dt_bias"].astype(SCAN_DTYPE))
+        y = selective_scan(
+            conv, dt, -jnp.exp(w["A_log"]), row[..., rank : rank + n], row[..., rank + n :], w["D"]
+        ).astype(dtype)
+        out = (y * jax.nn.silu(z)) @ w["out_proj"].astype(dtype)
+    windows = jnp.float32(batch) if active is None else jnp.sum(active.astype(jnp.float32))
+    return out, y, windows * length
+
+
+def gated_memory(w: Dict, u: jnp.ndarray, memory: jnp.ndarray) -> jnp.ndarray:
+    """A gated memory unit: ``memory [B, T, ssm_inner]``, an earlier
+    layer's scan output, gated by a projection of this layer's input."""
+    dtype = u.dtype
+    with jax.named_scope(MEMORY_SCOPE):
+        return (memory * jax.nn.silu(u @ w["in_proj"].astype(dtype))) @ w["out_proj"].astype(dtype)
+
+
+def differential_weight(w: Dict, index: int) -> Tuple[jnp.ndarray, float]:
+    """``(lam, lam_0)`` of the differential heads of layer ``index``."""
+    start = 0.8 - 0.6 * math.exp(-0.3 * index)
+    first = jnp.exp(jnp.sum(w["lambda_q1"] * w["lambda_k1"]))
+    second = jnp.exp(jnp.sum(w["lambda_q2"] * w["lambda_k2"]))
+    return first - second + start, start
+
+
+def differential_attention(
+    spec: BackboneSpec, op: str, index: int, w: Dict, u: jnp.ndarray,
+    active: Optional[jnp.ndarray] = None, kv=None,
+):
+    """Differential attention (module docstring) of operator ``op`` at
+    layer ``index`` over ``u [B, T, hidden]``: ``(output, band, (k,
+    v))``; ``band`` as :func:`banded_attention`'s counts (the pairs of
+    the mask, once: both maps read the same mask), None where every
+    score is held at once; ``(k, v)`` the keys and values as projected,
+    for a later ``cross_attention``, which is handed an earlier layer's
+    as ``kv`` and projects none."""
+    dtype = u.dtype
+    batch, length, _ = u.shape
+    kv_heads, dh = spec.num_key_value_heads, spec.head_dim
+    pairs = kv_heads // 2
+    tiled = op == "sliding_attention" or length > ATTENTION_TILE
+    scope = TILES_SCOPES[op] if tiled else ATTENTION_SCOPE
+    with jax.named_scope(scope):
+        q, k, v = _heads(spec, w, u, op, kv)
+    with jax.named_scope(DIFFERENTIAL_SCOPE):
+        # query head 2j + s is map s of differential head j = p * group +
+        # g; key head 2p + s is map s of pair p, whose value is the two
+        # heads' side by side
+        group = q.shape[2] // kv_heads
+        maps = q.reshape(batch, length, pairs, group, 2, dh)
+        keys = k.reshape(batch, length, pairs, 2, dh)
+        value = v.reshape(batch, length, pairs, 2 * dh)
+    band = None
+    if tiled:
+        chunk = min(ATTENTION_TILE, length)
+        window = min(spec.sliding_window, length) if op == "sliding_attention" else length
+        blocked = jax.vmap(lambda a: _blocked(a, chunk))
+        value = blocked(value)
+
+        def attend(queries, keys):
+            out = jax.lax.map(
+                lambda one: _banded_attention(scope, window, *one), (blocked(queries), blocked(keys), value)
+            )
+            return out.reshape((batch, -1) + out.shape[3:])[:, :length]
+
+        windows = jnp.float32(batch) if active is None else jnp.sum(active.astype(jnp.float32))
+        attended, multiplied = band_pairs(length, window, chunk)
+        band = (windows * attended, windows * multiplied)
+    else:
+        def attend(queries, keys):
+            with jax.named_scope(scope):
+                return _attend_in_one_piece(queries, keys, value)
+
+    # a map a call: the two over stacked heads as one call ran its
+    # backward twice as long on the chip (PERF.md 6, PR 45)
+    first, second = (attend(maps[:, :, :, :, s], keys[:, :, :, s]) for s in (0, 1))
+    with jax.named_scope(DIFFERENTIAL_SCOPE):
+        lam, start = differential_weight(w, index)
+        heads = rms_norm(first - lam.astype(dtype) * second, w["sub_norm"], spec.norm_eps) * (1.0 - start)
+    with jax.named_scope(scope):
+        out = heads.reshape(batch, length, -1) @ w["wo"].astype(dtype)
+        if spec.attention_bias:
+            out = out + w["bo"].astype(dtype)
+    return out, band, (k, v)
+
+
 def dense_ffn(w: Dict, u: jnp.ndarray) -> jnp.ndarray:
     dtype = u.dtype
     gate = jax.nn.silu(u @ w["w1"].astype(dtype)) * (u @ w["w3"].astype(dtype))
@@ -1025,39 +1419,54 @@ def moe_ffn(
     return out.reshape(u.shape), plan["routed"], pairs_here, gate
 
 
-def block(spec: BackboneSpec, op: str, ffn: str, w: Dict, h: jnp.ndarray, active=None):
-    """One pre-norm residual block; returns ``(h, counts, selection,
-    band)`` with ``counts = (routed, pairs_here, gate)`` of a routed
-    block (:func:`moe_ffn`'s), ``selection = (objective, (keys_selected,
-    keys_causal))`` of a ``sparse_attention`` block and ``band =
+def block(
+    spec: BackboneSpec, op: str, ffn: str, w: Dict, h: jnp.ndarray, active=None, index: int = 0, read=None,
+):
+    """One pre-norm residual block, ``h = x + Op(Norm(x))``, ``out = h +
+    FFN(Norm(h))`` under ``spec.norm``'s norm, at layer ``index``;
+    returns ``(h, counts, selection, band, scan_steps, made)`` with
+    ``counts = (routed, pairs_here, gate)`` of a routed block
+    (:func:`moe_ffn`'s), ``selection = (objective, (keys_selected,
+    keys_causal))`` of a ``sparse_attention`` block, ``band =
     (pairs_attended, pairs_multiplied)`` of an attention computed in
-    tiles under a mask by position, else None. ``active``: as
-    :func:`moe_ffn`. Where the router reads the layer's input
-    (``spec.router_input``) the routing plan is made first, from ``h``
-    as it comes in: nothing the operator computes enters it."""
+    tiles under a mask by position and ``scan_steps`` of a ``mamba``
+    block, else None. ``read``: what the layer reads of an earlier one
+    (``spec.layer_sources``): a ``gmu`` that layer's scan output, a
+    ``cross_attention`` its ``(k, v)``. ``made``: what this layer could
+    hand on in turn, a ``mamba``'s scan output or a differential
+    attention's ``(k, v)``, else None. ``active``: as :func:`moe_ffn`.
+    Where the router reads the layer's input (``spec.router_input``)
+    the routing plan is made first, from ``h`` as it comes in: nothing
+    the operator computes enters it."""
     plan = None
     if ffn == "moe" and spec.router_input == "layer_input":
         plan = routing_plan(spec, w["moe"], h.reshape(-1, h.shape[-1]), h.shape[1], active)
-    normed = rms_norm(h, w["operator_norm"], spec.norm_eps)
-    selection = band = None
+    normed = block_norm(spec, h, w["operator_norm"])
+    selection = band = steps = made = None
     if op == "conv":
-        h = h + short_conv(spec, w["conv"], normed)
+        out = short_conv(spec, w["conv"], normed)
+    elif op == "mamba":
+        out, made, steps = mamba(spec, w["mamba"], normed, active)
+    elif op == "gmu":
+        out = gated_memory(w["gmu"], normed, read)
+    elif spec.differential:
+        out, band, made = differential_attention(spec, op, index, w["attn"], normed, active, read)
     elif op == "sparse_attention":
         out, objective, keys = sparse_attention(spec, w["attn"], w["indexer"], normed, active)
-        h, selection = h + out, (objective, keys)
+        selection = (objective, keys)
     elif op == "full_attention" and h.shape[1] <= ATTENTION_TILE:
-        h = h + gqa_attention(spec, w["attn"], normed)
+        out = gqa_attention(spec, w["attn"], normed)
     else:
         out, band = banded_attention(spec, op, w["attn"], normed, active)
-        h = h + out
-    normed = rms_norm(h, w["ffn_norm"], spec.norm_eps)
+    h = h + out
+    normed = block_norm(spec, h, w["ffn_norm"])
     if ffn == "dense":
-        return h + dense_ffn(w["ffn"], normed), None, selection, band
+        return h + dense_ffn(w["ffn"], normed), None, selection, band, steps, made
     out, *counts = moe_ffn(spec, w["moe"], normed, active, plan)
     if "shared" in w["moe"]:
         with jax.named_scope(SHARED_SCOPE):
             out = out + dense_ffn(w["moe"]["shared"], normed)
-    return h + out, tuple(counts), selection, band
+    return h + out, tuple(counts), selection, band, steps, made
 
 
 def _param_bytes(params: Dict) -> int:
@@ -1085,7 +1494,9 @@ def forward_backbone_aux(
     (query-key pairs inside the mask and of the tiles visited); and, a
     row per expert layer whose gate is a ``relu``, float32
     ``gate_active`` and ``gate_total`` (the gate units of the pairs
-    computed here that are above zero, and all of them).
+    computed here that are above zero, and all of them); and, a row per
+    ``mamba`` layer, float32 ``scan_steps`` (rows its scan stepped
+    over). Without a routed layer the router's three are absent.
     ``penalty`` is the sum of those terms, 0 without such a layer.
 
     ``remat``: rematerialise each block in the backward pass; None
@@ -1099,16 +1510,25 @@ def forward_backbone_aux(
     if remat is None:
         remat = _param_bytes(params) >= REMAT_MIN_PARAM_BYTES
     h = x.astype(dtype) @ params["embed"]["W"].astype(dtype) + params["embed"]["b"].astype(dtype)
-    routed_rows, pairs_rows, gates, selections, bands = [], [], [], [], []
+    routed_rows, pairs_rows, gates, selections, bands, scans = [], [], [], [], [], []
+    # what a layer hands the layers that read it, beside the residual:
+    # through each block's checkpoint as an argument and a result
+    handed: Dict[int, Any] = {}
     for i, (op, ffn) in enumerate(zip(spec.layer_ops, spec.layer_ffns)):
-        run = lambda w, h, a, _op=op, _ffn=ffn: block(spec, _op, _ffn, w, h, a)  # noqa: E731
+        # its place among the layers to a differential attention alone
+        # (``lam_0``), ``read`` to a layer that reads an earlier one's
+        place = {"index": i} if spec.differential else {}
+        read = {} if spec.layer_sources[i] is None else {"read": handed[spec.layer_sources[i]]}
+        run = lambda w, h, a, read, _op=op, _ffn=ffn, _place=place: block(spec, _op, _ffn, w, h, a, **_place, **read)  # noqa: E731
         if remat:
-            saved = SAVED_PRODUCTS + ((SAVED_SELECTION,) if op == "sparse_attention" else ())
+            saved = SAVED_PRODUCTS + {"sparse_attention": (SAVED_SELECTION,), "mamba": (SAVED_SCAN,)}.get(op, ())
             run = jax.checkpoint(
                 run, policy=jax.checkpoint_policies.save_only_these_names(*saved)
             )
         with jax.named_scope(f"layer_{i}"):  # one scope a layer, as in params
-            h, counts, selection, band = run(params[f"layer_{i}"], h, active)
+            h, counts, selection, band, steps, made = run(params[f"layer_{i}"], h, active, read)
+        if i in spec.layer_sources:
+            handed[i] = made
         if counts is not None:
             routed_rows.append(counts[0])
             pairs_rows.append(counts[1])
@@ -1118,7 +1538,9 @@ def forward_backbone_aux(
             selections.append(selection)
         if band is not None:
             bands.append(band)
-    last = rms_norm(h[:, -1], params["head"]["norm"], spec.norm_eps)
+        if steps is not None:
+            scans.append(steps)
+    last = block_norm(spec, h[:, -1], params["head"]["norm"])
     out = last @ params["head"]["W"].astype(dtype) + params["head"]["b"].astype(dtype)
     aux = None
     if routed_rows:
@@ -1149,6 +1571,8 @@ def forward_backbone_aux(
             "pairs_attended": jnp.stack([attended for attended, _ in bands]),
             "pairs_multiplied": jnp.stack([multiplied for _, multiplied in bands]),
         }
+    if scans:
+        aux = {**(aux or {}), "scan_steps": jnp.stack(scans)}
     return out.astype(jnp.float32), penalty, aux
 
 

@@ -1,5 +1,5 @@
 from . import backbone, feedforward_autoencoder, lstm_autoencoder  # noqa: F401  (registration)
-from .backbone import kanana, keye_vl2, laguna, lfm2_moe, smallthinker
+from .backbone import kanana, keye_vl2, laguna, lfm2_moe, phi4flash, smallthinker
 from .feedforward_autoencoder import (
     feedforward_hourglass,
     feedforward_model,
@@ -19,4 +19,5 @@ __all__ = [
     "laguna",
     "smallthinker",
     "kanana",
+    "phi4flash",
 ]

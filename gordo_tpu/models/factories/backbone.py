@@ -594,3 +594,136 @@ def kanana(
         compute_dtype=compute_dtype,
         precision=precision,
     )
+
+
+#: microsoft/Phi-4-mini-flash-reasoning config.json (``model_type:
+#: phi4flash``; the SambaY decoder-hybrid-decoder of arXiv:2507.06607),
+#: every key of the catalog's row. The factory's defaults are these; the
+#: keys it does not take say nothing it can act on (the vocabulary and
+#: its tied head are replaced by the sensor projections, positions are a
+#: window's and the model encodes none, the two dropouts are 0) or name
+#: what it refuses to be told otherwise.
+PHI_4_MINI_FLASH_CONFIG: Dict[str, Any] = {
+    "embd_pdrop": 0,
+    "hidden_act": "silu",
+    "hidden_size": 2560,
+    "intermediate_size": 10240,
+    "layer_norm_eps": 1e-05,
+    "max_position_embeddings": 262144,
+    "mb_per_layer": 2,
+    "model_type": "phi4flash",
+    "num_attention_heads": 40,
+    "num_hidden_layers": 32,
+    "num_key_value_heads": 20,
+    "resid_pdrop": 0,
+    "sliding_window": 512,
+    "tie_word_embeddings": True,
+    "mlp_bias": False,
+    "lm_head_bias": False,
+    "vocab_size": 200064,
+}
+_PHI4FLASH = PHI_4_MINI_FLASH_CONFIG
+#: what the layers here cannot be told otherwise: silu gates, no bias in
+#: a feed-forward or at the head, a state-space layer every second layer
+_PHI4FLASH_FIXED = ("hidden_act", "mlp_bias", "lm_head_bias", "mb_per_layer")
+#: what the row does not spell of a state-space layer, as the published
+#: model's ``Phi3Mamba`` takes Mamba-1's defaults: the inner stream is
+#: ``expand`` times the hidden size, a channel's state ``d_state``
+#: numbers, the convolution ``d_conv`` taps, the step's rank the hidden
+#: size over ``dt_rank_divisor``, rounded up. With them 32 layers and the
+#: vocabulary count the published 3,852,562,944 weights
+MAMBA_1_DEFAULTS = {"expand": 2, "d_state": 16, "d_conv": 4, "dt_rank_divisor": 16}
+
+
+def phi4flash_layer_types(num_hidden_layers: int, mb_per_layer: int = 2) -> tuple:
+    """The published pattern, by the model's own rule: the first half
+    (the self-decoder) alternates ``mamba`` and ``sliding_attention``;
+    the layer at the half is a ``mamba`` (its scan output is the memory)
+    and the one after it the one ``full_attention`` (its keys and values
+    are kept); the rest (the cross-decoder) alternates ``gmu`` and
+    ``cross_attention``, which compute no scan, no key and no value."""
+    half = num_hidden_layers // 2
+    if num_hidden_layers % (2 * mb_per_layer) or num_hidden_layers < 2 * mb_per_layer:
+        raise ValueError(
+            f"phi4flash's rule needs num_hidden_layers divisible by {2 * mb_per_layer} "
+            f"(the layer at the half is a mamba): got {num_hidden_layers}; a cut names its layer_types"
+        )
+
+    def kind(i):
+        scans = i % mb_per_layer == 0
+        if i <= half + 1:
+            return "mamba" if scans else ("sliding_attention" if i < half else "full_attention")
+        return "gmu" if scans else "cross_attention"
+
+    return tuple(kind(i) for i in range(num_hidden_layers))
+
+
+@register_model_builder(type="JaxBackboneForecast")
+def phi4flash(
+    n_features: int,
+    n_features_out: Optional[int] = None,
+    lookback_window: int = 8192,
+    num_hidden_layers: int = _PHI4FLASH["num_hidden_layers"],
+    layer_types: Optional[Sequence[str]] = None,
+    hidden_size: int = _PHI4FLASH["hidden_size"],
+    num_attention_heads: int = _PHI4FLASH["num_attention_heads"],
+    num_key_value_heads: int = _PHI4FLASH["num_key_value_heads"],
+    intermediate_size: int = _PHI4FLASH["intermediate_size"],
+    sliding_window: int = _PHI4FLASH["sliding_window"],
+    layer_norm_eps: float = _PHI4FLASH["layer_norm_eps"],
+    optimizer: Union[str, OptimizerSpec] = "Adam",
+    optimizer_kwargs: Optional[Dict[str, Any]] = None,
+    compile_kwargs: Optional[Dict[str, Any]] = None,
+    compute_dtype: str = "float32",
+    precision: str = "",
+    **kwargs,
+) -> BackboneSpec:
+    """``model_type: phi4flash`` (defaults: Phi-4-mini-flash-reasoning).
+    ``num_hidden_layers`` layers in the published pattern
+    (:func:`phi4flash_layer_types`), or a cut's own ``layer_types`` (a
+    ``gmu`` after a ``mamba``, a ``cross_attention`` after a
+    ``full_attention``): selective state-space layers at Mamba-1's
+    sizes (:data:`MAMBA_1_DEFAULTS`), differential attention with a bias
+    on its projections and no position encoding at all (a window of
+    ``sliding_window`` rows, or every causal row), gated memory units
+    and attention that read one earlier layer's scan output and one
+    earlier layer's keys and values; LayerNorms with a bias and a dense
+    feed-forward of ``intermediate_size`` in every layer."""
+    for key in _PHI4FLASH_FIXED:
+        if key in kwargs and kwargs[key] != _PHI4FLASH[key]:
+            raise ValueError(f"phi4flash runs {key}={_PHI4FLASH[key]!r} only; got {kwargs[key]!r}")
+    if layer_types is None:
+        layer_types = phi4flash_layer_types(num_hidden_layers, _PHI4FLASH["mb_per_layer"])
+    if len(layer_types) < num_hidden_layers:
+        raise ValueError("phi4flash needs a layer type for every layer held")
+    layer_types = tuple(layer_types[:num_hidden_layers])
+    compile_kwargs = compile_kwargs or {}
+    no_position = (("rope_type", "none"),)
+    return BackboneSpec(
+        n_features=n_features,
+        n_features_out=n_features_out or n_features,
+        lookback_window=lookback_window,
+        layer_ops=layer_types,
+        layer_ffns=("dense",) * num_hidden_layers,
+        hidden_size=hidden_size,
+        num_attention_heads=num_attention_heads,
+        num_key_value_heads=num_key_value_heads,
+        intermediate_size=intermediate_size,
+        sliding_window=sliding_window,
+        rope_parameters=tuple(
+            (op, no_position) for op in ("cross_attention", "full_attention", "sliding_attention")
+        ),
+        qk_norm=False,
+        norm="layer",
+        attention_bias=True,
+        differential=True,
+        ssm_inner=MAMBA_1_DEFAULTS["expand"] * hidden_size,
+        ssm_state=MAMBA_1_DEFAULTS["d_state"],
+        ssm_conv=MAMBA_1_DEFAULTS["d_conv"],
+        ssm_dt_rank=-(-hidden_size // MAMBA_1_DEFAULTS["dt_rank_divisor"]),
+        norm_eps=float(layer_norm_eps),
+        optimizer=OptimizerSpec.from_config(optimizer, optimizer_kwargs),
+        loss=compile_kwargs.get("loss", "mse"),
+        compute_dtype=compute_dtype,
+        precision=precision,
+    )

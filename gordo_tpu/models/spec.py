@@ -275,9 +275,18 @@ class LSTMSpec(ModelSpec):
 
 
 #: layer kinds of a :class:`BackboneSpec`
-OPERATORS = ("conv", "full_attention", "sparse_attention", "sliding_attention")
-#: the operators that project to query, key and value heads
-ATTENTIONS = tuple(op for op in OPERATORS if op != "conv")
+OPERATORS = (
+    "conv", "full_attention", "sparse_attention", "sliding_attention",
+    "mamba", "gmu", "cross_attention",
+)
+#: the operators that project to query heads (and, all but
+#: ``cross_attention``, to key and value heads)
+ATTENTIONS = ("full_attention", "sparse_attention", "sliding_attention", "cross_attention")
+#: what an operator reads of an earlier layer beside the residual, and
+#: the operator that makes it: a ``gmu`` the scan output of the last
+#: ``mamba`` before it, a ``cross_attention`` the keys and values of the
+#: last ``full_attention`` before it
+READS = {"gmu": "mamba", "cross_attention": "full_attention"}
 FFNS = ("dense", "moe")
 #: how a routed layer scores its experts: ``sigmoid_bias`` (sigmoid
 #: scores, the ``k`` largest ``score + bias`` chosen, a bias buffer),
@@ -298,6 +307,9 @@ ROUTER_INPUTS = ("ffn_input", "layer_input")
 EXPERT_ACTIVATIONS = ("silu", "relu")
 #: a rotary embedding's ``rope_type``; ``none``: no position encoding
 ROPE_TYPES = ("default", "yarn", "none")
+#: the norm of a block's two inputs and of the head's: ``rms`` (a gain)
+#: or ``layer`` (``nn.LayerNorm``: mean and variance, a gain and a bias)
+NORMS = ("rms", "layer")
 
 
 @dataclass(frozen=True)
@@ -353,6 +365,24 @@ class BackboneSpec(ModelSpec):
     ``v_head_dim``; the rotary embedding turns the trailing
     ``qk_rope_head_dim`` of ``q`` and the shared key alone, in
     interleaved pairs under ``rope_interleave``.
+
+    ``mamba`` is a selective state-space layer (Mamba-1's): a causal
+    depthwise convolution of ``ssm_conv`` taps with a bias and a
+    ``silu`` over an inner stream ``ssm_inner`` wide, then a scan over
+    the window's rows of a float32 state of ``ssm_state`` numbers a
+    channel whose step, input and output matrices each row chooses (the
+    step through a projection of rank ``ssm_dt_rank``), gated by the
+    other half of the input projection. ``gmu`` (a gated memory unit)
+    gates the scan output of the last ``mamba`` before it, taken before
+    that layer's own gate, with a projection of its own input;
+    ``cross_attention`` projects queries alone and attends to the keys
+    and values of the last ``full_attention`` before it (``READS``;
+    :attr:`layer_sources`). Under ``differential`` every attention's
+    heads pair up, neighbours together: two softmax maps over one value
+    twice a head wide, their difference under a learned weight, an
+    RMSNorm over it. ``attention_bias`` puts a bias on an attention's
+    projections; ``norm: layer`` makes the blocks' norms and the head's
+    LayerNorms with a bias.
     """
 
     n_features: int
@@ -400,8 +430,21 @@ class BackboneSpec(ModelSpec):
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_interleave: bool = False
+    norm: str = "rms"
+    attention_bias: bool = False
+    differential: bool = False
+    ssm_inner: int = 0
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
 
     windowed = True
+    #: a backbone's member is a program of its own, whose steps of
+    #: padding alone are skipped and not masked: its windows are a
+    #: step's tokens and its layers are sized to fill a chip, and the
+    #: routed layers' grouped products (``lax.ragged_dot``) have no
+    #: batched form on the TPU
+    member_axis = False
 
     def __post_init__(self):
         if len(self.layer_ops) != len(self.layer_ffns) or not self.layer_ops:
@@ -468,6 +511,47 @@ class BackboneSpec(ModelSpec):
             )
         if self.num_experts_per_tok > self.num_experts:
             raise ValueError("num_experts_per_tok exceeds num_experts")
+        if self.norm not in NORMS:
+            raise ValueError(f"unknown norm {self.norm!r}; known: {NORMS}")
+        for i, (op, source) in enumerate(zip(self.layer_ops, self.layer_sources)):
+            if op in READS and source is None:
+                raise ValueError(
+                    f"layer {i} is a {op} and no {READS[op]} layer comes before it: "
+                    f"it reads that layer's {'scan output' if op == 'gmu' else 'keys and values'}"
+                )
+        if "mamba" in self.layer_ops and min(
+            self.ssm_inner, self.ssm_state, self.ssm_conv, self.ssm_dt_rank
+        ) < 1:
+            raise ValueError("a mamba layer needs an inner width, a state, taps and a step rank")
+        if self.differential and (
+            self.num_key_value_heads % 2
+            or any(heads % 2 for heads in self.heads_by_layer)
+            or self.kv_lora_rank or self.attention_gate or "sparse_attention" in self.layer_ops
+        ):
+            raise ValueError(
+                "differential attention pairs neighbouring heads: an even number of query and of "
+                "key/value heads, no latent, no gate and no indexer"
+            )
+        if "cross_attention" in self.layer_ops and not self.differential:
+            raise ValueError("cross_attention is built for differential heads alone")
+        if self.differential and "conv" in self.layer_ops:
+            raise ValueError("a backbone of differential heads has no gated short convolution")
+        if any(
+            ffn != "dense" and (self.differential or op in ("mamba", "gmu"))
+            for op, ffn in zip(self.layer_ops, self.layer_ffns)
+        ):
+            raise ValueError("mamba, gmu and differential attention layers carry the dense feed-forward")
+
+    @property
+    def layer_sources(self) -> Tuple[Optional[int], ...]:
+        """For each layer the index of the earlier layer whose tensors
+        it reads beside the residual (``READS``: the last layer of that
+        operator before it), None for a layer that reads none."""
+        sources, last = [], {}
+        for i, op in enumerate(self.layer_ops):
+            sources.append(last.get(READS.get(op)))
+            last[op] = i
+        return tuple(sources)
 
     @property
     def head_dim(self) -> int:
@@ -516,12 +600,6 @@ class BackboneSpec(ModelSpec):
         h, width = self.hidden_size, self.index_head_dim
         return h * self.index_n_heads * width + h * width + 2 * width + h * self.index_n_heads
 
-    @property
-    def member_axis(self) -> bool:
-        """The grouped products of a routed layer (``lax.ragged_dot``)
-        have no batched form on the TPU: a member a program."""
-        return "moe" not in self.layer_ffns
-
     def init_fn(self):
         from .backbone import init_backbone
 
@@ -533,20 +611,44 @@ class BackboneSpec(ModelSpec):
         return forward_backbone
 
     def forward_aux_fn(self):
-        from .backbone import forward_backbone_aux
+        """The forward with its counters where a layer has any: a
+        routed layer, an indexer, a scan, or an attention in tiles (a
+        ``sliding_attention``, or a window longer than a tile)."""
+        from .backbone import ATTENTION_TILE, forward_backbone_aux
 
-        return forward_backbone_aux if "moe" in self.layer_ffns else None
+        counted = {"sparse_attention", "sliding_attention", "mamba"}
+        if self.lookback_window > ATTENTION_TILE:
+            counted |= {"full_attention", "cross_attention"}
+        return forward_backbone_aux if "moe" in self.layer_ffns or counted & set(self.layer_ops) else None
 
     def fit_counter_attrs(self, counters: Dict[str, Any]) -> Dict[str, Any]:
-        """The router counts, with which of the published experts are
-        held here: who reads ``router_tokens`` needs the three; beside
-        the selection's counts, how many keys a query may keep."""
-        attrs = {
-            **super().fit_counter_attrs(counters),
-            "num_experts": self.num_experts,
-            "experts_held": self.experts_held,
-            "expert_offset": self.expert_offset,
-        }
+        """The counters, and what of the spec a reader needs to read
+        them: beside the router counts which of the published experts
+        are held here (who reads ``router_tokens`` needs the three);
+        beside the selection's counts, how many keys a query may keep;
+        beside ``scan_steps`` the scan's sizes and which layers read an
+        earlier layer's tensors."""
+        attrs = super().fit_counter_attrs(counters)
+        if "moe" in self.layer_ffns:
+            attrs.update(
+                num_experts=self.num_experts,
+                experts_held=self.experts_held,
+                expert_offset=self.expert_offset,
+            )
+        if "mamba" in self.layer_ops:
+            from .backbone import scan_chunk_rows
+
+            reads = lambda op: [  # noqa: E731
+                source for o, source in zip(self.layer_ops, self.layer_sources) if o == op
+            ]
+            attrs.update(
+                ssm_inner=self.ssm_inner,
+                ssm_state=self.ssm_state,
+                scan_chunk=scan_chunk_rows(self.lookback_window),
+                memory_width=self.ssm_inner if "gmu" in self.layer_ops else 0,
+                memory_reads=reads("gmu"),
+                kv_reads=reads("cross_attention"),
+            )
         if "sparse_attention" in self.layer_ops:
             attrs["index_topk"] = self.index_topk
         if self.kv_lora_rank:  # what a row keeps of itself, and what that expands to
@@ -565,20 +667,40 @@ class BackboneSpec(ModelSpec):
         h = self.hidden_size
         heads = self.num_attention_heads if heads is None else heads
         kv = self.num_key_value_heads * self.head_dim
-        total = 2 * h
+        total = 2 * h * (2 if self.norm == "layer" else 1)
         if op == "conv":
             total += h * 3 * h + h * self.conv_L_cache + h * h
+        elif op == "mamba":
+            total += self.mamba_param_count
+        elif op == "gmu":  # the gate's projection and the output's
+            total += 2 * h * self.ssm_inner
         elif self.kv_lora_rank:
             total += self.latent_param_count
         else:
-            total += 2 * h * heads * self.head_dim + 2 * h * kv
+            qo = heads * self.head_dim
+            keys = 0 if op == "cross_attention" else 2  # a cross layer projects no key and no value
+            total += 2 * h * qo + keys * h * kv
             total += 2 * self.head_dim * self.qk_norm + h * heads * self.attention_gate
+            total += (qo + keys * kv + h) * self.attention_bias
+            # the four vectors of the pair's weight and the norm over the difference
+            total += 6 * self.head_dim * self.differential
         if op == "sparse_attention":
             total += self.indexer_param_count
         if ffn == "dense":
             return total + 3 * h * self.intermediate_size
         shared = 3 * h * self.shared_expert_intermediate_size
         return total + h * self.num_experts + self.experts_held * 3 * h * self.moe_intermediate_size + shared
+
+    @property
+    def mamba_param_count(self) -> int:
+        """One ``mamba`` operator: the input projection to the stream
+        and its gate, the taps and their bias, the projection to the
+        step's rank and the row's two matrices, the step's projection
+        and bias, ``A_log``, ``D``, the output projection."""
+        h, d, n, rank = self.hidden_size, self.ssm_inner, self.ssm_state, self.ssm_dt_rank
+        return (
+            h * 2 * d + d * self.ssm_conv + d + d * (rank + 2 * n) + rank * d + d + d * n + d + d * h
+        )
 
     def param_count(self) -> int:
         h = self.hidden_size
@@ -587,7 +709,7 @@ class BackboneSpec(ModelSpec):
             for op, ffn, heads in zip(self.layer_ops, self.layer_ffns, self.heads_by_layer)
         )
         embed = self.n_features * h + h
-        head = h + h * self.n_features_out + self.n_features_out
+        head = h * (2 if self.norm == "layer" else 1) + h * self.n_features_out + self.n_features_out
         return embed + layers + head
 
     def flops_per_sample(self) -> float:
@@ -595,7 +717,9 @@ class BackboneSpec(ModelSpec):
         causal attention at its useful half (sliding attention at the
         keys inside its window, sparse attention at the keys a query
         keeps, its indexer over every causal key), the expert layer at
-        the pairs this holder expects under even routing."""
+        the pairs this holder expects under even routing; a scan at the
+        elementwise operations of a row's state update (no product, but
+        the planner's cost of a step has to hold them)."""
         h, t = self.hidden_size, self.lookback_window
         kv = self.num_key_value_heads * self.head_dim
         index = self.index_n_heads * self.index_head_dim
@@ -609,7 +733,14 @@ class BackboneSpec(ModelSpec):
             if op == "conv":
                 per_token += 2.0 * h * 3 * h + 2.0 * h * h + 2.0 * self.conv_L_cache * h
                 attended = 0.0
-            elif op == "full_attention":
+            elif op == "mamba":  # every matrix and the taps; 7 operations a state entry a row, 3 a channel
+                d, n = self.ssm_inner, self.ssm_state
+                per_token += 2.0 * (self.mamba_param_count - 3 * d - d * n) + 7.0 * d * n + 3.0 * d
+                attended = 0.0
+            elif op == "gmu":
+                per_token += 4.0 * h * self.ssm_inner
+                attended = 0.0
+            elif op in ("full_attention", "cross_attention"):
                 attended = t / 2.0
             elif op == "sliding_attention":
                 reach = min(t, self.sliding_window)  # keys of a query: min(t + 1, window)
@@ -621,8 +752,13 @@ class BackboneSpec(ModelSpec):
             if self.kv_lora_rank:  # every matrix of the latent attention; a score and a value a pair
                 per_token += 2.0 * (self.latent_param_count - self.kv_lora_rank)
                 per_token += 2.0 * attended * heads * (self.head_dim + self.v_head_dim)
-            elif op != "conv":  # q, k, v, o and the gate; a score and a value a pair
-                per_token += 2.0 * h * (2 * qo + 2 * kv + heads * self.attention_gate) + 4.0 * attended * qo
+            elif op not in ("conv", "mamba", "gmu"):
+                # q, o, the gate, and k, v but in a cross layer; a score and
+                # a value a pair (a differential pair: two maps over a
+                # value twice as wide)
+                keys = 0 if op == "cross_attention" else 2
+                per_token += 2.0 * h * (2 * qo + keys * kv + heads * self.attention_gate)
+                per_token += (6.0 if self.differential else 4.0) * attended * qo
             if ffn == "dense":
                 per_token += 6.0 * h * self.intermediate_size
             else:

@@ -433,6 +433,25 @@ def latent_text(attrs: Dict[str, Any]) -> str:
     )
 
 
+def scan_text(attrs: Dict[str, Any]) -> str:
+    """What a state-space backbone's scans hold and which layers read an
+    earlier layer's tensors (a fit program's ``ssm_inner``, ``ssm_state``,
+    ``scan_chunk``, ``memory_reads`` and ``kv_reads``:
+    ``BackboneSpec.fit_counter_attrs``)."""
+    text = (
+        f"scan {int(attrs['ssm_inner']):,} x {int(attrs['ssm_state']):,} "
+        f"in chunks of {int(attrs['scan_chunk']):,}"
+    )
+    reads = []
+    for key, what in (("memory_reads", "output"), ("kv_reads", "keys")):
+        sources = list(attrs.get(key) or ())
+        for source in sorted(set(sources)):
+            count = sources.count(source)
+            who = f"{count}" if reads else f"{count} layer{'' if count == 1 else 's'}"
+            reads.append(f"{who} read{'s' if count == 1 else ''} layer {source}'s {what}")
+    return f"{text}; {', '.join(reads)}" if reads else text
+
+
 def render_status(doc: Dict[str, Any]) -> str:
     """Human rendering of a build-status document (the ``build-status``
     CLI's output): header, progress bar + ETA, per-phase table."""
@@ -500,6 +519,9 @@ def render_status(doc: Dict[str, Any]) -> str:
     latent = next((c for c in doc.get("fit_counters") or () if c.get("kv_lora_rank")), None)
     if latent:
         lines.append(f"Attention: {latent_text(latent)}")
+    scanned = next((c for c in doc.get("fit_counters") or () if c.get("ssm_inner")), None)
+    if scanned:
+        lines.append(f"State space: {scan_text(scanned)}")
     resources = doc.get("resources")
     if resources:
         lines.append(

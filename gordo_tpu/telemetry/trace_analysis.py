@@ -466,7 +466,10 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     it took from the device, ``params_resident_members``; a fit of experts
     gated by ``relu`` with its ``gate_active`` and ``gate_total`` summed; a
     fit of latent attention with what a row keeps of itself,
-    ``kv_lora_rank`` + ``qk_rope_head_dim`` of ``kv_expanded_dim``) and its
+    ``kv_lora_rank`` + ``qk_rope_head_dim`` of ``kv_expanded_dim``; a fit
+    of state-space layers with what its scans hold and which layers read
+    an earlier layer's tensors, ``ssm_inner``, ``ssm_state``,
+    ``scan_chunk``, ``memory_reads``, ``kv_reads``) and its
     ``self_seconds``, the wall time no part or program covers. Where its
     spans carry them, a phase also has ``cpu_seconds`` (its own thread's)
     and ``process_cpu_seconds`` (every thread's between its two ends),
@@ -569,6 +572,9 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
             if attributes.get("kv_lora_rank"):  # a fit of latent attention: what a row keeps
                 for key in ("kv_lora_rank", "qk_rope_head_dim", "kv_expanded_dim"):
                     part[key] = int(attributes[key])
+            if attributes.get("ssm_inner"):  # a fit of state-space layers: what its scans hold
+                for key in ("ssm_inner", "ssm_state", "scan_chunk", "memory_reads", "kv_reads"):
+                    part[key] = attributes[key]
             if span["name"] == "build_part":
                 nested_cpu = nested_part_cpu_seconds(attributes)
                 for nested, nested_seconds in nested_part_seconds(attributes).items():
@@ -847,7 +853,7 @@ def render_analysis(doc: Dict[str, Any]) -> str:
 
     build = doc.get("build_breakdown")
     if build:
-        from .progress import cores_busy_text, latent_text, part_rates_text
+        from .progress import cores_busy_text, latent_text, part_rates_text, scan_text
 
         out.append(
             "\nBuild phases (seconds; self = wall time no part covers; "
@@ -876,6 +882,8 @@ def render_analysis(doc: Dict[str, Any]) -> str:
                     counters.append(f"gate_active_pct={share:.1f}")
                 if measured.get("kv_lora_rank"):
                     counters.append(latent_text(measured))
+                if measured.get("ssm_inner"):
+                    counters.append(scan_text(measured))
                 if counters:
                     part += f" [{', '.join(counters)}]"
                 rows.append(

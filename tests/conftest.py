@@ -132,6 +132,8 @@ MANIFEST_CASES_OUTGROWN = (
     "tests/chipbench/test_manifest.py::test_config_entry_and_file[smallthinker-21b-a3b-50tag-lb8192]",
     # ISSUE 41: a new case that never passed; ``tests/chipbench/test_kanana_cell.py`` holds it to every other line
     "tests/chipbench/test_manifest.py::test_config_entry_and_file[kanana-2-30b-a3b-50tag-lb8192]",
+    # ISSUE 45: a new case that never passed; ``tests/chipbench/test_phi4flash_cell.py`` holds it to every other line
+    "tests/chipbench/test_manifest.py::test_config_entry_and_file[phi4-mini-flash-50tag-lb8192]",
 )
 #: ``test_keye_dsa_cell.py`` asserts that its cell and its configuration
 #: are the manifest's last: true of the PR that added them, of no later

@@ -278,7 +278,7 @@ def test_the_choice_reads_the_layers_input_and_nothing_the_attention_computes(se
     h = jnp.asarray(np.random.RandomState(5).normal(0, 1, (4, T, 32)).astype(np.float32))
 
     def routed(spec, w, h, op="sliding_attention"):
-        out, counts, _, _ = backbone.block(spec, op, "moe", w, h)
+        out, counts, *_ = backbone.block(spec, op, "moe", w, h)
         return np.asarray(out), np.asarray(counts[0])
 
     w = params["layer_1"]
