@@ -140,12 +140,22 @@ update are zero and it keeps its seeded value.
 Layers are rematerialised in the backward pass (``jax.checkpoint`` a
 block) when the parameters are large enough that a step's activations
 compete with them for the device's memory: decided at trace time from
-the bytes of ``params``, :data:`REMAT_MIN_PARAM_BYTES`. A routed layer
-then still keeps :data:`SAVED_PRODUCTS`.
+the bytes of ``params``, :data:`REMAT_MIN_PARAM_BYTES`. A rematerialised
+layer still keeps four sets of values by name, each what its backward
+pass would otherwise compute a second time: a routed layer
+:data:`SAVED_PRODUCTS` (the two grouped products that feed the gate), a
+``sparse_attention`` layer :data:`SAVED_SELECTION` (the indexer's choice:
+no second top-k), a ``mamba`` layer :data:`SAVED_SCAN` (the scan's output
+and its chunks' states: no second scan), and a layer that attends in
+tiles :data:`SAVED_TILES` (the tile loops' output and normalisers, which
+their own derivative rule asks for: the second forward projects ``q``,
+``k``, ``v`` again and runs no tile loop), but a sliding layer whose band
+is no wider than a tile (:func:`keeps_tile_outputs`, from the operator
+and the shapes alone).
 """
 
 import math
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -209,6 +219,17 @@ SAVED_SELECTION = "sparse_selection"
 #: passes a row a step would take (forward, forward again, the chunk's
 #: states again, the walk back) three are left
 SAVED_SCAN = "selective_scan_output"
+
+#: what a rematerialised layer that attends in tiles keeps
+#: (:func:`keeps_tile_outputs`): what the tile loops' own derivative rule
+#: hands their backward pass beside ``q``, ``k``, ``v``: the output and
+#: its log-normalisers (134 + 1 MB a window of 8,192 rows a layer of 32
+#: heads 128 wide), and of a ``sparse_attention`` layer the objective's
+#: terms, the indexer's normalisers and the weights' sums besides (0.07
+#: MB). With them the rematerialised forward runs no tile loop: of the
+#: three passes over a layer's tiles a step would take (forward, forward
+#: again, backward) two are left
+SAVED_TILES = "attention_tiles_output"
 
 #: parameters of at least this many bytes rematerialise their layers in
 #: the backward pass: below it a step's saved activations are small
@@ -821,7 +842,10 @@ def _selected_attention_fwd(length, q, k, v, qi, ki, wi, selected):
         kl = jnp.sum(p_log_p - p_index + log_z_i * p_sum)
         return out, kl, log_z, log_z_i, p_sum
 
-    out, kl, log_z, log_z_i, p_sum = jax.lax.map(block_of, jnp.arange(blocks))
+    # what a rematerialised layer keeps: with all five its second forward runs neither loop
+    out, kl, log_z, log_z_i, p_sum = (
+        checkpoint_name(a, SAVED_TILES) for a in jax.lax.map(block_of, jnp.arange(blocks))
+    )
     return (out, jnp.sum(kl)), (q, k, v, qi, ki, wi, selected, out, log_z, log_z_i, p_sum)
 
 
@@ -992,6 +1016,8 @@ def _banded_attention_fwd(scope, window, q, k, v):
         return out, top + jnp.log(total)
 
     out, log_z = jax.lax.map(block_of, jnp.arange(blocks))
+    # what a rematerialised layer keeps: with them its second forward runs no tile loop
+    out, log_z = checkpoint_name(out, SAVED_TILES), checkpoint_name(log_z, SAVED_TILES)
     return out, (q, k, v, out, log_z)
 
 
@@ -1251,7 +1277,7 @@ def differential_attention(
     batch, length, _ = u.shape
     kv_heads, dh = spec.num_key_value_heads, spec.head_dim
     pairs = kv_heads // 2
-    tiled = op == "sliding_attention" or length > ATTENTION_TILE
+    tiled = attends_in_tiles(op, length)
     scope = TILES_SCOPES[op] if tiled else ATTENTION_SCOPE
     with jax.named_scope(scope):
         q, k, v = _heads(spec, w, u, op, kv)
@@ -1454,7 +1480,7 @@ def block(
     elif op == "sparse_attention":
         out, objective, keys = sparse_attention(spec, w["attn"], w["indexer"], normed, active)
         selection = (objective, keys)
-    elif op == "full_attention" and h.shape[1] <= ATTENTION_TILE:
+    elif not attends_in_tiles(op, h.shape[1]):
         out = gqa_attention(spec, w["attn"], normed)
     else:
         out, band = banded_attention(spec, op, w["attn"], normed, active)
@@ -1471,6 +1497,55 @@ def block(
 
 def _param_bytes(params: Dict) -> int:
     return sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree_util.tree_leaves(params))
+
+
+@lru_cache(maxsize=8)
+def _spec_param_bytes(spec: BackboneSpec) -> int:
+    """:func:`_param_bytes` of what :func:`init_backbone` makes for
+    ``spec``, from the shapes alone: nothing runs on a device (tens of
+    milliseconds of tracing at the published widths, once a spec: a
+    job's fit spans ask four times)."""
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)  # a raw key's shape: making one is a device program
+    return _param_bytes(jax.eval_shape(partial(init_backbone, spec=spec), key))
+
+
+def attends_in_tiles(op: str, length: int) -> bool:
+    """Whether a layer of operator ``op`` over windows of ``length``
+    rows attends in tile loops (:func:`_selected_attention`,
+    :func:`_banded_attention`): a selection and a sliding window always,
+    any other attention where the window is longer than a tile (up to
+    :data:`ATTENTION_TILE` rows every score is held at once)."""
+    if op in ("sparse_attention", "sliding_attention"):
+        return True
+    return op in TILES_SCOPES and length > ATTENTION_TILE
+
+
+def keeps_tile_outputs(spec: BackboneSpec, op: str, length: int) -> bool:
+    """Whether a rematerialised layer of operator ``op`` over windows of
+    ``length`` rows keeps :data:`SAVED_TILES`: every layer that attends
+    in tiles but a sliding one whose band is no wider than a tile. A
+    block of such a layer's queries visits two tiles, so its second
+    forward is short where its output is as large as any layer's (0.43 s
+    a GB kept in ``laguna_swa_build`` against 0.9-2.5 for a band of nine
+    to sixteen tiles), and with its three such layers kept that cell's
+    fit is 17.10 GiB by the compiler's count for a v5e, of 15.75
+    (PERF.md 6, PR 47)."""
+    if op == "sliding_attention" and min(spec.sliding_window, length) <= ATTENTION_TILE:
+        return False
+    return attends_in_tiles(op, length)
+
+
+def tile_outputs_kept(spec: BackboneSpec, remat: Optional[bool] = None) -> int:
+    """The layers of a fit over ``spec``'s windows whose tile loops'
+    output and normalisers the backward pass is handed by name
+    (:func:`keeps_tile_outputs`) and does not compute again; 0 where the
+    program rematerialises nothing (nothing is computed twice there) or
+    no layer runs a tile loop. ``remat``: as
+    :func:`forward_backbone_aux`'s, None decided from the bytes of the
+    spec's parameters."""
+    if remat is None:
+        remat = _spec_param_bytes(spec) >= REMAT_MIN_PARAM_BYTES
+    return sum(keeps_tile_outputs(spec, op, spec.lookback_window) for op in spec.layer_ops) if remat else 0
 
 
 def forward_backbone_aux(
@@ -1522,6 +1597,8 @@ def forward_backbone_aux(
         run = lambda w, h, a, read, _op=op, _ffn=ffn, _place=place: block(spec, _op, _ffn, w, h, a, **_place, **read)  # noqa: E731
         if remat:
             saved = SAVED_PRODUCTS + {"sparse_attention": (SAVED_SELECTION,), "mamba": (SAVED_SCAN,)}.get(op, ())
+            if keeps_tile_outputs(spec, op, x.shape[1]):
+                saved += (SAVED_TILES,)
             run = jax.checkpoint(
                 run, policy=jax.checkpoint_policies.save_only_these_names(*saved)
             )

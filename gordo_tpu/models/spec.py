@@ -614,12 +614,10 @@ class BackboneSpec(ModelSpec):
         """The forward with its counters where a layer has any: a
         routed layer, an indexer, a scan, or an attention in tiles (a
         ``sliding_attention``, or a window longer than a tile)."""
-        from .backbone import ATTENTION_TILE, forward_backbone_aux
+        from .backbone import attends_in_tiles, forward_backbone_aux
 
-        counted = {"sparse_attention", "sliding_attention", "mamba"}
-        if self.lookback_window > ATTENTION_TILE:
-            counted |= {"full_attention", "cross_attention"}
-        return forward_backbone_aux if "moe" in self.layer_ffns or counted & set(self.layer_ops) else None
+        counted = any(op == "mamba" or attends_in_tiles(op, self.lookback_window) for op in self.layer_ops)
+        return forward_backbone_aux if "moe" in self.layer_ffns or counted else None
 
     def fit_counter_attrs(self, counters: Dict[str, Any]) -> Dict[str, Any]:
         """The counters, and what of the spec a reader needs to read
@@ -627,8 +625,14 @@ class BackboneSpec(ModelSpec):
         are held here (who reads ``router_tokens`` needs the three);
         beside the selection's counts, how many keys a query may keep;
         beside ``scan_steps`` the scan's sizes and which layers read an
-        earlier layer's tensors."""
+        earlier layer's tensors; and how many layers' tile outputs the
+        backward pass is handed by name (``backbone.tile_outputs_kept``:
+        0 where the program rematerialises nothing or runs no tile
+        loop)."""
+        from .backbone import scan_chunk_rows, tile_outputs_kept
+
         attrs = super().fit_counter_attrs(counters)
+        attrs["tile_outputs_kept"] = tile_outputs_kept(self)
         if "moe" in self.layer_ffns:
             attrs.update(
                 num_experts=self.num_experts,
@@ -636,8 +640,6 @@ class BackboneSpec(ModelSpec):
                 expert_offset=self.expert_offset,
             )
         if "mamba" in self.layer_ops:
-            from .backbone import scan_chunk_rows
-
             reads = lambda op: [  # noqa: E731
                 source for o, source in zip(self.layer_ops, self.layer_sources) if o == op
             ]
