@@ -54,7 +54,11 @@ parameter tree, like :mod:`.nn`.
   but query ``t`` attends to ``S_t``, the ``min(t + 1, index_topk)``
   causal keys of largest index score ``I[t, s] = sum_j w[t, j] *
   relu(qI[t, j] . kI[s])`` (a small indexer of its own heads over
-  ``stop_gradient(u)``). Computed in square tiles of ``index_chunk``
+  ``stop_gradient(u)``). ``S_t`` is what a top-k of the row takes, ties
+  to the earlier key, and no row is sorted for it: a block of queries
+  finds each query's k-th largest score by a search over the bits of
+  the score, a compare and a row sum a bit (:func:`kth_largest`), and
+  keeps what lies above it. Computed in square tiles of ``index_chunk``
   queries by ``index_chunk`` keys: a block of queries against the tiles
   up to its diagonal under a running softmax, the keys outside ``S_t``
   masked. No score outlives its tile, forward or backward, nothing
@@ -145,7 +149,7 @@ layer still keeps four sets of values by name, each what its backward
 pass would otherwise compute a second time: a routed layer
 :data:`SAVED_PRODUCTS` (the two grouped products that feed the gate), a
 ``sparse_attention`` layer :data:`SAVED_SELECTION` (the indexer's choice:
-no second top-k), a ``mamba`` layer :data:`SAVED_SCAN` (the scan's output
+no second search), a ``mamba`` layer :data:`SAVED_SCAN` (the scan's output
 and its chunks' states: no second scan), and a layer that attends in
 tiles :data:`SAVED_TILES` (the tile loops' output and normalisers, which
 their own derivative rule asks for: the second forward projects ``q``,
@@ -171,8 +175,8 @@ ATTENTION_SCOPE = "gqa_attention"
 ROUTE_SCOPE = "moe_route"
 EXPERTS_SCOPE = "moe_experts"
 #: ... and of sparse attention's three parts: the index scores (and the
-#: indexer's objective), the k-th largest of each query, the attention's
-#: tiles under the selection
+#: indexer's objective), the search for each query's k-th largest score,
+#: the attention's tiles under the selection
 INDEX_SCOPE = "sparse_index"
 SELECT_SCOPE = "sparse_select"
 SPARSE_ATTENTION_SCOPE = "sparse_attention"
@@ -704,6 +708,74 @@ def _tile_gradients(j, q_i, k_j, v_j, d_out_i, weights, delta, scale, d_q, d_k, 
     return d_q, d_k, d_v
 
 
+def searches_selection(spec: BackboneSpec, last_row) -> bool:
+    """Whether a block of queries that ends at row ``last_row`` (a
+    number or a traced one) holds a query with more causal keys than
+    ``index_topk``: such a block searches for its queries' k-th largest
+    score (:func:`kth_largest`), any other keeps its causal keys as
+    they are."""
+    return spec.index_topk < last_row
+
+
+def selection_blocks_searched(spec: BackboneSpec) -> int:
+    """The blocks of ``index_chunk`` queries a window a
+    ``sparse_attention`` layer whose selection runs the search, by the
+    function :func:`select_keys` decides with
+    (:func:`searches_selection`): 12 of 16 at 8,192 rows that keep 2,048
+    in blocks of 512, 0 where ``index_topk`` is at least the window."""
+    chunk = spec.index_chunk
+    blocks = -(-spec.lookback_window // chunk)
+    return sum(searches_selection(spec, (i + 1) * chunk) for i in range(blocks))
+
+
+def _ordered(bits: jnp.ndarray) -> jnp.ndarray:
+    """A float32's bits as int32 <-> the key that compares as
+    ``jax.lax.top_k`` compares the floats (its own inverse): by sign and
+    magnitude, so ``-0.0`` is below ``0.0`` and a NaN beyond the
+    infinity of its sign."""
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def kth_largest(scores: jnp.ndarray, k: int, group: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``scores [Q, S]`` float32, ``k <= S`` -> the k-th entry of
+    ``jax.lax.top_k(scores, k)``, ``(value [Q, 1], position [Q, 1])``,
+    to the bit and for any input, without sorting a row: that top-k
+    lists by value (in the order of :func:`_ordered`) and equal values
+    by position.
+
+    The value is the largest key that at least ``k`` of the row's keys
+    reach: a loop of 32 passes settles a bit of it a pass, from the top
+    (from the lowest key up; the addition wraps at bit 31). A pass is a
+    compare and a row sum over the block, and nothing moves; a bit stays
+    where ``k`` keys still reach the candidate, and the last count that
+    fell short is of the keys ``above`` what is found. The position is
+    that of the ``k - above``-th key equal to it, in groups of ``group``
+    positions (``S`` a multiple of it): the group from the groups'
+    counts, the place in it from a running count over that group alone."""
+    rows, width = scores.shape
+    keys = _ordered(jax.lax.bitcast_convert_type(scores, jnp.int32))
+
+    def settle(step, carry):
+        found, above = carry
+        candidate = found + (jnp.int32(1) << (31 - step))
+        reach = jnp.sum(keys >= candidate, axis=-1, keepdims=True, dtype=jnp.int32)
+        stays = reach >= k
+        return jnp.where(stays, candidate, found), jnp.where(stays, above, reach)
+
+    lowest = jnp.full((rows, 1), jnp.iinfo(jnp.int32).min, jnp.int32)
+    found, above = jax.lax.fori_loop(0, 32, settle, (lowest, jnp.zeros((rows, 1), jnp.int32)))
+    equal = (keys == found).reshape(rows, width // group, group)
+    wanted = k - above
+    upto = jnp.cumsum(jnp.sum(equal, axis=-1, dtype=jnp.int32), axis=-1)  # equal keys up to each group's end
+    at_group = jnp.sum(upto < wanted, axis=-1, keepdims=True)
+    wanted = wanted - jnp.take_along_axis(jnp.pad(upto, ((0, 0), (1, 0))), at_group, axis=1)
+    inside = jnp.sum(
+        equal & (jnp.arange(width // group)[None, :, None] == at_group[:, :, None]), axis=1, dtype=jnp.int32
+    )
+    at = at_group * group + jnp.sum(jnp.cumsum(inside, axis=-1) < wanted, axis=-1, keepdims=True)
+    return jax.lax.bitcast_convert_type(_ordered(found), jnp.float32), at
+
+
 def select_keys(spec: BackboneSpec, qi, ki, wi) -> jnp.ndarray:
     """One window's selection ``S_t`` of every query, a bit a key, in
     tiles of ``index_chunk`` queries by ``index_chunk`` keys: blocked
@@ -713,10 +785,13 @@ def select_keys(spec: BackboneSpec, qi, ki, wi) -> jnp.ndarray:
     causal index scores takes: the ``index_topk`` largest, of equal
     scores the earlier key (a relu makes exact ties: every head's
     product negative is a score of 0). Found from the k-th entry of that
-    top-k alone: a key is kept if its score is larger, or equal and its
-    position no later. One loop over the blocks of queries; a block's
+    top-k alone, its value and its position, which a search over the
+    value's bits gives without sorting the row (:func:`kth_largest`): a
+    key is kept if its score is larger, or equal and its position no
+    later. One loop over the blocks of queries; a block's
     scores are computed a tile at a time up to its diagonal, the tiles
-    above it never."""
+    above it never, and a block that ends at or before row
+    ``index_topk`` searches nothing (:func:`searches_selection`)."""
     blocks, chunk = qi.shape[:2]
     keys = blocks * chunk
     qi, ki, wi = jax.lax.stop_gradient((qi, ki, wi))
@@ -737,15 +812,14 @@ def select_keys(spec: BackboneSpec, qi, ki, wi) -> jnp.ndarray:
                 scores = jnp.where(causal, scores, -jnp.inf)
                 # the k-th largest is -inf for a query with fewer causal
                 # keys than that, which keeps them all
-                values, at = jax.lax.top_k(scores, spec.index_topk)
-                kth, kth_at = values[:, -1:], at[:, -1:]
+                kth, kth_at = kth_largest(scores, spec.index_topk, chunk)
                 keep = (scores > kth) | ((scores == kth) & (positions[None, :] <= kth_at))
                 return keep & causal
 
-        if spec.index_topk >= keys:  # no query has more causal keys than it may keep
+        if not searches_selection(spec, keys):  # no query has more causal keys than it may keep
             keep = causal
         else:  # nor has one of a block that ends at or before row top-k
-            keep = jax.lax.cond((i + 1) * chunk <= spec.index_topk, lambda: causal, chosen)
+            keep = jax.lax.cond(searches_selection(spec, (i + 1) * chunk), chosen, lambda: causal)
         return _pack_bits(keep.reshape(chunk, blocks, chunk))
 
     return jax.lax.map(block_of, jnp.arange(blocks))

@@ -133,6 +133,12 @@ class ModelSpec:
         them."""
         return {name: value.tolist() for name, value in counters.items()}
 
+    def program_attrs(self) -> Dict[str, Any]:
+        """What every device program of the spec, fit or predict, says of
+        itself on its ``device_program`` span: numbers of the program
+        that its shapes decide."""
+        return {}
+
     def param_count(self) -> int:
         """Trainable parameters from the geometry alone; 0 = unknown
         (the planner then keeps the member in a group of its own)."""
@@ -625,13 +631,14 @@ class BackboneSpec(ModelSpec):
         are held here (who reads ``router_tokens`` needs the three);
         beside the selection's counts, how many keys a query may keep;
         beside ``scan_steps`` the scan's sizes and which layers read an
-        earlier layer's tensors; and how many layers' tile outputs the
+        earlier layer's tensors; how many layers' tile outputs the
         backward pass is handed by name (``backbone.tile_outputs_kept``:
         0 where the program rematerialises nothing or runs no tile
-        loop)."""
+        loop); and what every program of the spec says
+        (:meth:`program_attrs`)."""
         from .backbone import scan_chunk_rows, tile_outputs_kept
 
-        attrs = super().fit_counter_attrs(counters)
+        attrs = {**super().fit_counter_attrs(counters), **self.program_attrs()}
         attrs["tile_outputs_kept"] = tile_outputs_kept(self)
         if "moe" in self.layer_ffns:
             attrs.update(
@@ -661,6 +668,19 @@ class BackboneSpec(ModelSpec):
                 kv_expanded_dim=self.kv_expanded_dim,
             )
         return attrs
+
+    def program_attrs(self) -> Dict[str, Any]:
+        """Of a spec with ``sparse_attention`` layers, in its fit and its
+        predict programs alike: ``selection_blocks_searched``, the blocks
+        of queries a window a layer whose selection searches for a k-th
+        largest score (``backbone.selection_blocks_searched``: static,
+        by the function ``select_keys`` decides with; 0 where
+        ``index_topk`` is at least the window)."""
+        if "sparse_attention" not in self.layer_ops:
+            return {}
+        from .backbone import selection_blocks_searched
+
+        return {"selection_blocks_searched": selection_blocks_searched(self)}
 
     def layer_param_count(self, op: str, ffn: str, heads: Optional[int] = None) -> int:
         """One block of ``heads`` query heads (None: ``num_attention_heads``):

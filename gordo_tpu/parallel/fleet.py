@@ -1482,6 +1482,7 @@ class FleetTrainer:
             params_resident_members=resident,
             shape=str(tuple(X.shape)),
             spec=type(spec).__name__,
+            **spec.program_attrs(),
         ):
             out = _traced_outputs(program(stacked_params, X, *scored))
             return self._collect_predictions(out, scoring, m, n)
@@ -1548,6 +1549,7 @@ class FleetTrainer:
             params_resident_members=resident,
             shape=str(tuple(series.shape)),
             spec=type(spec).__name__,
+            **spec.program_attrs(),
         ):
             out = _traced_outputs(program(stacked_params, series, order, *scored))
             return self._collect_predictions(out, scoring, m, nv)

@@ -463,7 +463,9 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
     program with the ``validation_slots`` of its buckets summed and, for
     the dense fit, the widest ``shuffle_columns`` among them; a predict
     program with its ``members`` and, of them, those whose parameters
-    it took from the device, ``params_resident_members``; a fit of experts
+    it took from the device, ``params_resident_members``; a program of
+    ``sparse_attention`` layers with the blocks of queries a window a
+    layer whose selection searches, ``selection_blocks_searched``; a fit of experts
     gated by ``relu`` with its ``gate_active`` and ``gate_total`` summed; a
     fit of latent attention with what a row keeps of itself,
     ``kv_lora_rank`` + ``qk_rope_head_dim`` of ``kv_expanded_dim``; a fit
@@ -566,6 +568,8 @@ def build_breakdown(spans: Iterable[dict]) -> Optional[Dict[str, Any]]:
             if "params_resident_members" in attributes:  # a predict program's span
                 for key in ("members", "params_resident_members"):
                     part[key] = part.get(key, 0) + int(attributes.get(key) or 0)
+            if "selection_blocks_searched" in attributes:  # a program of sparse attention
+                part["selection_blocks_searched"] = int(attributes["selection_blocks_searched"])
             if "gate_total" in attributes:  # a fit of experts gated by relu: a list a layer
                 for key in ("gate_active", "gate_total"):
                     part[key] = part.get(key, 0.0) + float(sum(attributes.get(key) or ()))
@@ -873,7 +877,7 @@ def render_analysis(doc: Dict[str, Any]) -> str:
                     f"{key}={measured[key]}"
                     for key in (
                         "validation_slots", "shuffle_columns",
-                        "members", "params_resident_members",
+                        "members", "params_resident_members", "selection_blocks_searched",
                     )
                     if key in measured
                 ]

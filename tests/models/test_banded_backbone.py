@@ -494,7 +494,13 @@ def test_the_kinds_that_were_here_keep_their_weights_and_lfm2s_fit_program_its_t
     end of private functions' names (``@closed_call_317``) runs further,
     so those numbers move and nothing else does
     (``test_tiles_kept.py`` holds the parent's text against the new one
-    with the numbers stripped; a toy rematerialises nothing)."""
+    with the numbers stripped; a toy rematerialises nothing). Taken once
+    more at commit ``5ef8279`` with PR 48's change applied: the
+    selection's ``top_k`` of a block's scores is a search of 32 compare-
+    and-row-sum passes (``backbone.kth_largest``), which selects the
+    same bits (``test_sparse_backbone.py`` holds it to ``top_k``) in
+    another text; ``lfm2_moe``'s is as it was, and so are the four other
+    kinds' in their files."""
     tiles_of_four.undo()  # the program as it ships
     assert backbone.ATTENTION_TILE == PUBLISHED_TILE
     sparse = keye_vl2(
@@ -528,4 +534,4 @@ def test_the_kinds_that_were_here_keep_their_weights_and_lfm2s_fit_program_its_t
 
 KEYE_TOY_DIGEST = "3e2ebade1865677a88f9ab10cb0612a11ba8a4991ac9c2aa6c4000cec3ee07fb"
 LFM2_TOY_FIT_TEXT = "9fc2c9e9fe4e74c3a3334d5b0b16d9b7120e9629a5893aab0290f52a967d05df"
-KEYE_TOY_FIT_TEXT = "4e5b2fe38a2ee4639eb7119816392cccbbc1d0c08776fbe0d1a33ad215a574d4"
+KEYE_TOY_FIT_TEXT = "a8528ef5fb8cfc3817ec5e70bcee1fd81f1b865d7317aa77775e1f2d55cbf811"

@@ -687,7 +687,8 @@ KANANA_TOY_FIT_TEXT = {
 #: gives, which ``fleet._fit_counter_attrs`` lists in ``fit_counters``;
 #: and, since PR 47, how many layers' tile outputs the backward pass is
 #: handed by name (every backbone's span says: 0 for these toys, which
-#: rematerialise nothing)
+#: rematerialise nothing) and, since PR 48, a ``sparse_attention`` kind's
+#: ``selection_blocks_searched``
 _ROUTED = [
     "expert_offset", "experts_held", "num_experts", "pairs_here", "pairs_total", "router_tokens", "steps_run",
     "tile_outputs_kept",
@@ -696,7 +697,10 @@ _BAND = ["pairs_attended", "pairs_multiplied"]
 _LATENT = ["kv_expanded_dim", "kv_lora_rank", "qk_rope_head_dim", "v_head_dim"]
 SPAN_ATTRIBUTES_AT_THE_PARENT = {
     "lfm2_moe": {"shipped": _ROUTED, "tiles_of_four": _ROUTED + _BAND},
-    "keye_vl2": {tile: _ROUTED + ["index_topk", "indexer_kl", "keys_causal", "keys_selected"] for tile in ("shipped", "tiles_of_four")},
+    "keye_vl2": {
+        tile: _ROUTED + ["index_topk", "indexer_kl", "keys_causal", "keys_selected", "selection_blocks_searched"]
+        for tile in ("shipped", "tiles_of_four")
+    },
     "laguna": {tile: _ROUTED + _BAND for tile in ("shipped", "tiles_of_four")},
     "smallthinker": {tile: _ROUTED + _BAND + ["gate_active", "gate_total"] for tile in ("shipped", "tiles_of_four")},
     "kanana": {"shipped": _ROUTED + _LATENT, "tiles_of_four": _ROUTED + _LATENT + _BAND},

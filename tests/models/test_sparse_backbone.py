@@ -7,6 +7,7 @@ import functools
 import importlib.util
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -334,20 +335,104 @@ def test_equal_scores_are_taken_in_order_of_position_as_the_reference_takes_them
     assert np.array_equal(backbone._unpack_bits(backbone._pack_bits(jnp.asarray(mask)), 70), mask)
 
 
+def _rows_of(what, rng, rows, keys):
+    """Crafted index scores ``[rows, keys]`` (before the causal mask)."""
+    tiny, lowest = np.float32(1e-45), np.finfo(np.float32).min
+    signs = np.where(rng.uniform(size=(rows, keys)) < 0.5, np.float32(0.0), np.float32(-0.0))
+    if what == "distinct":
+        return np.stack([rng.permutation(keys) for _ in range(rows)]).astype(np.float32) - keys / 3
+    if what == "every score zero, of both signs":
+        return signs
+    if what == "duplicates that straddle the k-th place":
+        few = rng.randint(-2, 3, size=(rows, keys)).astype(np.float32)
+        return np.where(few == 0, signs, few)
+    if what == "one value":
+        return np.full((rows, keys), -1.5, np.float32)
+    if what == "infinities, denormals and the largest negative float":
+        special = np.array([np.inf, -np.inf, tiny, -tiny, lowest, -lowest, 0.0, -0.0, 1.0], np.float32)
+        return rng.choice(special, size=(rows, keys))
+    assert what == "not a number among them"
+    a = rng.normal(size=(rows, keys)).astype(np.float32)
+    at = rng.uniform(size=(rows, keys))
+    return np.where(at < 0.15, np.float32(np.nan), np.where(at < 0.3, -np.float32(np.nan), a))
+
+
+@pytest.mark.parametrize("top_k", [10, 8, 1, 31])
+@pytest.mark.parametrize("what", [
+    "distinct", "every score zero, of both signs", "duplicates that straddle the k-th place", "one value",
+    "infinities, denormals and the largest negative float", "not a number among them",
+])
+def test_the_selection_is_what_the_kth_entry_of_a_top_k_decides(monkeypatch, what, top_k):
+    """The search (``backbone.kth_largest``) against the sort it took
+    the place of, on crafted rows: 32 keys in blocks of 8, so that with a
+    top-k of 10 or 8 there are queries with fewer causal keys than that,
+    one with exactly as many, and a block that searches nothing. The
+    selection is, to the bit, what the formula of ``select_keys`` makes
+    of the k-th entry of ``jax.lax.top_k``, value and position: a top-k
+    lists ``-0.0`` after ``0.0`` whatever their positions and the
+    formula's ``==`` does not tell them apart, so a row of zeros keeps
+    fewer or more than ``top_k`` keys, as it did."""
+    keys, chunk = 32, 8
+    spec = toy(lookback_window=keys, sa_config={"topk": top_k})
+    scores = _rows_of(what, np.random.RandomState(len(what) + top_k), keys, keys)
+    # the crafted scores stand in for the indexer's: a query's row rides in qI, a key's position in kI
+    monkeypatch.setattr(
+        backbone, "index_scores",
+        lambda qi, ki, wi: jnp.take_along_axis(qi[:, 0, :], ki[:, 0].astype(jnp.int32)[None, :], axis=1),
+    )
+    blocked = [
+        backbone._blocked(jnp.asarray(a), chunk)
+        for a in (scores[:, None, :], np.arange(keys, dtype=np.float32)[:, None], np.zeros((keys, 1), np.float32))
+    ]
+    selected = backbone._unpack_bits(backbone.select_keys(spec, *blocked), chunk)
+    selected = np.asarray(selected).reshape(keys, keys)
+    positions = np.arange(keys)
+    causal = positions[None, :] <= positions[:, None]
+    masked = jnp.where(causal, scores, -jnp.inf)
+    values, at = jax.lax.top_k(masked, top_k)
+    kth, kth_at = values[:, -1:], at[:, -1:]
+    want = np.asarray(((masked > kth) | ((masked == kth) & (positions[None, :] <= kth_at))) & causal)
+    # a block that ends at or before row top-k keeps its causal keys unasked (where nothing is a NaN, the same)
+    unasked = ((positions // chunk + 1) * chunk <= top_k)[:, None]
+    assert what == "not a number among them" or np.array_equal(want[unasked[:, 0]], causal[unasked[:, 0]])
+    assert np.array_equal(selected, np.where(unasked, causal, want))
+    # and the two numbers themselves, where a block searches: to the bit, a NaN's too
+    value, position = backbone.kth_largest(masked, top_k, chunk)
+    assert np.array_equal(np.asarray(value).view(np.int32), np.asarray(kth).view(np.int32))
+    assert np.array_equal(np.asarray(position), np.asarray(kth_at))
+    if what == "distinct":
+        assert np.array_equal(selected.sum(axis=1), np.minimum(positions + 1, top_k))
+    assert backbone.selection_blocks_searched(spec) == sum(top_k < (i + 1) * chunk for i in range(keys // chunk))
+
+
 def test_the_selection_is_made_once_a_layer_and_kept_for_the_backward_pass(seeded):
     """Rematerialised, a layer selects once: the selection is kept by
     name and the backward pass only masks."""
     spec, params, _, x, y = seeded
     w = np.ones(4, np.float32)
 
-    def top_ks(remat):
-        return str(jax.make_jaxpr(jax.grad(loss_of(spec, x, y, w, remat)))(params)).count("top_k")
+    def chosen(traced):
+        """(the selection's searches, the ``top_k`` s) of a traced program,
+        as its text shows them: a search takes its scores' bits and hands
+        the k-th largest back as a float, two ``bitcast_convert_type`` that
+        nothing else of the program has, and the only ``top_k`` left is
+        the router's."""
+        text = str(traced)
+        return text.count("bitcast_convert_type") / 2, text.count("top_k")
 
-    # a layer's selection is one loop with one top-k in it, and the router's
-    forward = str(jax.make_jaxpr(loss_of(spec, x, y, w))(params)).count("top_k")
-    assert forward == top_ks(False) == 2 * (1 + 1)
+    def in_the_gradient(remat):
+        return chosen(jax.make_jaxpr(jax.grad(loss_of(spec, x, y, w, remat)))(params))
+
+    # a layer's selection is one loop with one search in it, and the router's choice
+    forward = chosen(jax.make_jaxpr(loss_of(spec, x, y, w))(params))
+    assert forward == in_the_gradient(False) == (2, 2)
     # rematerialised, only the router's choice, which nothing keeps, is made again
-    assert top_ks(True) == forward + 2
+    assert in_the_gradient(True) == (2, 2 + 2)
+    # and no row is sorted for a selection: the lowered program's sorts are the router's
+    lowered = jax.jit(jax.grad(loss_of(spec, x, y, w, True))).lower(params).as_text()
+    router_only = jax.jit(jax.grad(loss_of(toy(sa_config={"topk": T}), x, y, w, True))).lower(params).as_text()
+    for operation in ("top_k", "stablehlo.sort"):  # (the router sorts its pairs by expert)
+        assert lowered.count(operation) == router_only.count(operation) > 0
 
 
 @pytest.mark.parametrize("padding", [(1,), (0, 3), (1, 2, 3)])

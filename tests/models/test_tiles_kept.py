@@ -200,6 +200,34 @@ def test_a_member_without_a_tile_loop_reads_zero(monkeypatch):
     assert spec.fit_counter_attrs({})["tile_outputs_kept"] == 0 == backbone.tile_outputs_kept(spec, remat=True)
 
 
+@pytest.mark.parametrize("rows, top_k, chunk, searched", [
+    (8192, 2048, 512, 12),  # keye_dsa_build: the blocks that end after row 2,048, of 16
+    (8192, 8192, 512, 0), (4096, 8192, 512, 0),  # a query may keep every key there is
+    (8192, 2047, 512, 13), (8192, 1, 512, 16),
+    (100, 24, 32, 4), (24, 6, 8, 3),  # the toys': a last block of padding searches as the rest
+])
+def test_the_span_says_how_many_blocks_search_their_selection(rows, top_k, chunk, searched):
+    """``selection_blocks_searched`` of every ``device_program`` span of a
+    spec with ``sparse_attention`` layers (``BackboneSpec.program_attrs``,
+    which its ``fit_counter_attrs`` carries too): the blocks of queries a
+    window a layer that search for a k-th largest score, by the function
+    ``select_keys`` decides with; and a spec without such a layer says
+    nothing."""
+    from gordo_tpu.models.factories import keye_vl2
+
+    spec = keye_vl2(
+        5, lookback_window=rows, num_hidden_layers=2, experts_held=2,
+        sa_config={"topk": top_k, "q_chunk_size": chunk, "kv_chunk_size": chunk},
+    )
+    assert backbone.selection_blocks_searched(spec) == searched
+    assert spec.program_attrs() == {"selection_blocks_searched": searched}
+    assert spec.fit_counter_attrs({})["selection_blocks_searched"] == searched
+    blocks = -(-rows // chunk)
+    assert searched == sum(bool(backbone.searches_selection(spec, (i + 1) * chunk)) for i in range(blocks))
+    plain = toy_of("lfm2_moe")
+    assert plain.program_attrs() == {} and "selection_blocks_searched" not in plain.fit_counter_attrs({})
+
+
 #: operator, the spec's sliding window, the rows of a fit's window ->
 #: attends in tiles, keeps; under the tile as it ships (512 rows)
 RULE = [

@@ -6,6 +6,7 @@ masked epoch loop gave."""
 
 import json
 import os
+import re
 
 import jax
 import numpy as np
@@ -214,6 +215,7 @@ def test_a_sparse_attention_backbone_takes_the_same_path(tmp_path_factory):
     """``kind: keye_vl2`` through ``build-fleet``, the artifact and the
     server, as ``lfm2_moe`` goes: its fits carry the selection's counters
     beside the router's, and the indexer's objective is in their loss."""
+    from gordo_tpu.telemetry.trace_analysis import build_breakdown, render_analysis
     from tests.server.conftest import temp_env_vars
 
     code, root = build_fleet(tmp_path_factory.mktemp("sparse") / REVISION, TOY_SPARSE, ("turbine-k",))
@@ -237,6 +239,17 @@ def test_a_sparse_attention_backbone_takes_the_same_path(tmp_path_factory):
             if s["name"] == "device_program" and "fit" in s["attributes"]["program"]]
     assert len(fits) == 4 and sum(bool(a["compile"]) for a in fits) == 1
     assert all(set(a["fit_counters"]) >= {"keys_selected", "keys_causal", "indexer_kl", "index_topk"} for a in fits)
+    # 100 rows in blocks of 32 that keep 24: every block of the four searches, in fit and predict programs alike
+    programs = [s["attributes"] for s in spans if s["name"] == "device_program"]
+    assert len(programs) > len(fits) and all(a["selection_blocks_searched"] == 4 for a in programs)
+    assert all("selection_blocks_searched" in a["fit_counters"] for a in fits)
+    assert all(c["selection_blocks_searched"] == 4 for c in counters)
+    rendered = render_analysis({"trace": "t", "spans_read": len(spans), "build_breakdown": build_breakdown(spans)})
+    assert "  program fleet_windowed_fit [validation_slots=0, selection_blocks_searched=4]" in rendered
+    assert re.search(
+        r"  program fleet_windowed_predict\w* \[members=(\d+), params_resident_members=\1, selection_blocks_searched=4\]",
+        rendered,
+    )
     model = serializer.load(os.path.join(root, "turbine-k"))
     X = rows(LOOKBACK + 6)
     prediction = np.asarray(model.predict(X))
